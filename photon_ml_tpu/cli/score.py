@@ -27,6 +27,7 @@ from photon_ml_tpu.io.results import write_scoring_results
 from photon_ml_tpu.game.models import RandomEffectModel
 from photon_ml_tpu.transformers import GameTransformer
 from photon_ml_tpu.utils import PhotonLogger, profile_trace, timed
+from photon_ml_tpu.utils.compile_cache import configure_compile_cache
 
 
 def run(
@@ -314,6 +315,7 @@ def _random_effects(game_dir: str) -> dict:
 
 
 def main(argv: list[str] | None = None) -> None:
+    configure_compile_cache()
     p = argparse.ArgumentParser(description="GAME scoring driver")
     p.add_argument("--model-dir", required=True)
     p.add_argument("--data", required=True, nargs="+")

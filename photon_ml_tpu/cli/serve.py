@@ -37,6 +37,7 @@ import os
 import numpy as np
 
 from photon_ml_tpu.utils import PhotonLogger
+from photon_ml_tpu.utils.compile_cache import configure_compile_cache
 
 
 def _synthetic_requests(
@@ -135,6 +136,7 @@ def run(
     )
     lat_p50 = lat_p99 = occupancy = 0.0
     windows = 0
+    scores: dict[int, float] = {}
     base_s = 0.0
     for sl in slices:
         # each slice re-anchors its arrivals so a long manifest poll (or
@@ -146,6 +148,7 @@ def run(
             store, sl, max_batch=max_batch, max_wait_ms=max_wait_ms,
         )
         windows += summary["windows"]
+        scores.update(summary["scores"])
         lat_p50, lat_p99 = summary["latency_p50_ms"], summary["latency_p99_ms"]
         occupancy = summary["window_occupancy_mean"]
         if poll_every > 0 and fingerprint is not None:
@@ -168,10 +171,13 @@ def run(
         "fingerprint": fingerprint,
     }
     print(json.dumps(out))
-    return out
+    # the per-request scores ride the return value only: the stdout
+    # contract stays one summary line
+    return {**out, "scores": scores}
 
 
 def main(argv: list[str] | None = None) -> None:
+    configure_compile_cache()
     p = argparse.ArgumentParser(description="online GAME serving driver")
     p.add_argument(
         "--model-root", required=True,

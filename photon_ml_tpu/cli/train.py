@@ -28,6 +28,7 @@ from photon_ml_tpu.io.model_io import load_game_model, save_game_model
 from photon_ml_tpu.obs import span
 from photon_ml_tpu.types import ModelOutputMode
 from photon_ml_tpu.utils import PhotonLogger, profile_trace, timed
+from photon_ml_tpu.utils.compile_cache import configure_compile_cache
 
 
 def run(
@@ -274,10 +275,11 @@ def _should_auto_stream(
     has_validation: bool = True,
 ) -> bool:
     """Auto-select the out-of-core path when the raw input bytes already
-    exceed the CLUSTER's queried HBM budget (per-device
-    ``device_hbm_budget_bytes`` — memory_stats when the backend exposes
-    them, 8 GB fallback — times the global device count: the in-memory
-    multihost path shards compute over every chip). Avro is more compact
+    exceed the queried HBM budget of every visible device together
+    (per-device ``device_hbm_budget_bytes`` — ``memory_stats`` on an
+    accelerator, the 8 GB default on the CPU backend only — times the
+    global device count: ``main`` trains over a mesh of all of them,
+    multihost or not). Avro is more compact
     than the decoded f32 columns, so raw bytes > budget means the
     in-memory read is guaranteed to blow HBM; smaller inputs keep the
     in-memory fast path. Sizes EXACTLY the file set the readers will read
@@ -721,6 +723,7 @@ def _load_entity_maps(model_dir: str) -> dict | None:
 
 
 def main(argv: list[str] | None = None) -> None:
+    configure_compile_cache()
     p = argparse.ArgumentParser(description="GAME training driver")
     p.add_argument("--config", required=True, help="GameTrainingConfig JSON file")
     p.add_argument("--train-data", required=True, nargs="+")
@@ -828,10 +831,14 @@ def main(argv: list[str] | None = None) -> None:
         )
     ):
         args.streaming_chunk_rows = 1 << 20
-    if args.multihost and args.streaming_chunk_rows is None:
-        from photon_ml_tpu.parallel import data_mesh
+    if args.streaming_chunk_rows is None:
+        # every visible device trains: a four-chip host is one process over
+        # a four-device mesh, exactly the budget _should_auto_stream sized
+        # the input against. One device keeps the unsharded fused path.
+        from photon_ml_tpu.parallel import data_mesh, local_device_count
 
-        mesh = data_mesh()
+        if args.multihost or local_device_count() > 1:
+            mesh = data_mesh()
     # telemetry AFTER multihost init: only the output process writes (the
     # sink checks process_index), and `report` renders/diffs the JSONL
     from photon_ml_tpu import obs

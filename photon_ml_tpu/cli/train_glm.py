@@ -39,6 +39,7 @@ from photon_ml_tpu.types import (
     VarianceComputationType,
 )
 from photon_ml_tpu.utils import PhotonLogger, profile_trace, timed
+from photon_ml_tpu.utils.compile_cache import configure_compile_cache
 
 STAGES = ("INIT", "PROCESSED", "TRAINED", "VALIDATED")
 
@@ -494,6 +495,7 @@ def _run_streamed(
 
 
 def main(argv: list[str] | None = None) -> None:
+    configure_compile_cache()
     p = argparse.ArgumentParser(description="Single-GLM training driver (legacy)")
     p.add_argument("--task", required=True, choices=[t.value for t in TaskType])
     p.add_argument("--train-data", required=True, nargs="+")

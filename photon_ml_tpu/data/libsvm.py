@@ -1,6 +1,6 @@
 """LIBSVM format reader → padded device batches.
 
-Used by benchmark config A (a9a logistic — BASELINE.md). The reference
+Used by benchmark config A (a9a logistic — BASELINE.json). The reference
 reads Avro, but its test fixtures and the baseline configs are
 LIBSVM-shaped; this reader produces either a ``SparseBatch`` (padded
 per-row index/value pairs) or a ``DenseBatch``.

@@ -2,7 +2,7 @@
 
 Parity role: ``photon-test-utils::GameTestUtils`` / ``CommonTestUtils``
 dataset builders (SURVEY.md §2.5) — plus the benchmark configs of
-BASELINE.md need reproducible data at arbitrary scale.
+BASELINE.json need reproducible data at arbitrary scale.
 """
 
 from __future__ import annotations

@@ -26,7 +26,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from photon_ml_tpu.utils import compat
 
 Array = jnp.ndarray
 
@@ -127,7 +126,7 @@ def bucketed_auc_sharded(
         return _auc_from_histograms(pos_hist, neg_hist)
 
     args = (scores, labels) + ((weights,) if has_weights else ())
-    return compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis_name),) * len(args),

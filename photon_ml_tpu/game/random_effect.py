@@ -278,8 +278,8 @@ class RandomEffectTrainingResult:
 
     def release_device_diagnostics(self) -> None:
         """Drop the device refs WITHOUT materializing (a host transfer here
-        would stall the async enqueue pipeline — measured 20x on the relay
-        bench). Coordinate descent calls this on the previous iteration's
+        would stall the async enqueue pipeline — measured 20x in the
+        round-5 bench, where each host sync cost 0.1 s or more). Coordinate descent calls this on the previous iteration's
         tracker when a coordinate is revisited, so HBM retention is bounded
         to the latest visit's O(E) diagnostic buffers regardless of
         iteration count; older visits' per-entity diagnostics become

@@ -321,6 +321,7 @@ def summarize_run(path: str, records: list[dict] | None = None) -> dict:
             bool(wm_avail) if wm else None  # None = never sampled
         ),
         "watermark_samples": len(wm_avail),
+        "batch_devices": gauges.get("mesh.batch_devices"),
         "peak_bytes_in_use": (
             max(int(r.get("peak_bytes_in_use") or 0) for r in wm_avail)
             if wm_avail else None
@@ -786,6 +787,10 @@ def format_summary(s: dict) -> str:
             f"  hbm: budget {_fmt_qty(hbm.get('budget_bytes'))}B"
             + (f" ({src})" if src else "")
             + f"; {wm_txt}"
+            + (
+                f"; sharded batch over {int(hbm['batch_devices'])} devices"
+                if hbm.get("batch_devices") else ""
+            )
         )
     if s["warnings"]:
         lines.append(f"  warnings: {s['warnings']}")

@@ -54,8 +54,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from photon_ml_tpu.ops import _pallas_compat
-
 Array = jnp.ndarray
 
 # VMEM budget for pipelined inputs (X double-buffer + padded aux blocks).
@@ -204,7 +202,7 @@ def _prep(X, labels, offsets, weights):
 
 # Mosaic's default 16MB scoped-vmem cap undercounts the transpose staging
 # for the reverse contraction; the chip has more physical VMEM than the cap.
-_COMPILER_PARAMS = _pallas_compat.compiler_params(
+_COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("arbitrary",),
     vmem_limit_bytes=32 * 1024 * 1024,
 )

@@ -177,23 +177,27 @@ def device_hbm_budget_bytes(
 ) -> float:
     """The HBM budget for dataset residency, QUERIED from the device
     (``memory_stats()['bytes_limit']`` scaled by ``fraction`` to leave room
-    for coefficients, optimizer state and XLA scratch). Falls back to
-    ``default`` on backends that expose no memory stats (e.g. CPU).
+    for coefficients, optimizer state and XLA scratch). On a TPU a device
+    that reports no limit raises: every layout and streaming decision
+    downstream would otherwise be sized for a chip that is not the one
+    attached. Only the CPU backend, which exposes no memory stats, takes
+    ``default`` (the test suite's path).
 
     Which source won is recorded (``hbm.budget_bytes`` /
     ``hbm.budget_queried`` gauges + a one-per-run ``hbm_budget`` event):
     a fallback-budget run on a memory-stats-less backend is
     distinguishable from a device-quoted one in ``report`` output."""
-    queried = None
-    try:
-        if device is None:
-            device = jax.local_devices()[0]
-        stats = device.memory_stats() or {}
-        limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-        if limit:
-            queried = fraction * float(limit)
-    except Exception:
-        pass
+    if device is None:
+        device = jax.local_devices()[0]
+    stats = device.memory_stats() or {}
+    limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+    queried = fraction * float(limit) if limit else None
+    if queried is None and device.platform != "cpu":
+        raise RuntimeError(
+            f"{device.platform} device {device.device_kind!r} reports no "
+            f"bytes_limit in memory_stats(); refusing to assume "
+            f"{default:.3g} bytes of HBM"
+        )
     budget = default if queried is None else queried
     from photon_ml_tpu.obs import devcost
 

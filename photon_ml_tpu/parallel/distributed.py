@@ -30,11 +30,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from photon_ml_tpu.config import OptimizerConfig
 from photon_ml_tpu.normalization import NormalizationContext
+from photon_ml_tpu.obs.metrics import REGISTRY
 from photon_ml_tpu.ops.batch import Batch, pad_batch
 from photon_ml_tpu.ops.glm import make_objective
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.optim.common import OptimizationResult, select_minimize_fn
-from photon_ml_tpu.utils import compat
 
 Array = jnp.ndarray
 
@@ -50,7 +50,13 @@ def shard_batch(batch: Batch, mesh: Mesh, axis_name: str = "data") -> Batch:
     target = -(-n // n_dev) * n_dev
     batch = pad_batch(batch, target)
     sharding = NamedSharding(mesh, P(axis_name))
-    return jax.tree.map(lambda a: jax.device_put(a, sharding), batch)
+    out = jax.tree.map(lambda a: jax.device_put(a, sharding), batch)
+    # how many devices the placed rows actually span: the one number that
+    # tells a one-chip run from a whole-host run in a telemetry snapshot
+    REGISTRY.gauge_set(
+        "mesh.batch_devices", float(len(out.labels.sharding.device_set))
+    )
+    return out
 
 
 def _densify_sharded(batch, mesh: Mesh, axis_name: str = "data"):
@@ -62,7 +68,7 @@ def _densify_sharded(batch, mesh: Mesh, axis_name: str = "data"):
 
     batch = shard_batch(batch, mesh, axis_name)
     fn = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             densify,
             mesh=mesh,
             in_specs=P(axis_name),
@@ -123,7 +129,7 @@ def _sharded_solve(
         kwargs = {"l1_weight": l1w} if use_l1 else {}
         return minimize_fn(obj, w0, config, **kwargs)
 
-    return compat.shard_map(
+    return jax.shard_map(
         solve,
         mesh=mesh,
         in_specs=(P(axis_name), P(), P(), P(), P(), P()),
@@ -297,7 +303,7 @@ def _sharded_tiled_solve(
         kwargs = {"l1_weight": l1w} if use_l1 else {}
         return minimize_fn(obj, w0, config, **kwargs)
 
-    return compat.shard_map(
+    return jax.shard_map(
         solve,
         mesh=mesh,
         in_specs=(P(axis_name), P(), P(), P(), P(), P()),

@@ -54,45 +54,27 @@ def initialize_multihost(
     if process_id is None and os.environ.get("JAX_PROCESS_ID"):
         process_id = int(os.environ["JAX_PROCESS_ID"])
     # PHOTON_COORD_MAX_MISSING_HEARTBEATS (strict int parse, default =
-    # jax's own): how many 10 s heartbeats the coordination service /
-    # client tolerate missing before declaring a task dead and FATALing
-    # every member. An elastic fleet (PHOTON_DESCENT_DEGRADE /
+    # jax's own 100 s timeout): how many 10 s heartbeats the coordination
+    # service / client tolerate missing before declaring a task dead and
+    # FATALing every member — passed on as ``heartbeat_timeout_seconds``
+    # (10 s x the value). An elastic fleet (PHOTON_DESCENT_DEGRADE /
     # PHOTON_REJOIN) raises it so the repo's own roll-call tier — not
     # the jax coordination service, which cannot degrade in place — is
     # what decides who is dead.
     hb = os.environ.get("PHOTON_COORD_MAX_MISSING_HEARTBEATS")
-    if hb is not None and hb != "":
-        hb = int(hb)  # strict parse OUTSIDE the init-error rewrap: a
-        # typo'd knob must name itself, not masquerade as a cluster
-        # configuration problem
-    else:
-        hb = None
+    # strict parse OUTSIDE the init-error rewrap: a typo'd knob must name
+    # itself, not masquerade as a cluster configuration problem
+    heartbeat = (
+        {"heartbeat_timeout_seconds": 10 * int(hb)}
+        if hb is not None and hb != "" else {}
+    )
     try:
-        if hb is not None:
-            # the public initialize() wrapper does not forward the
-            # heartbeat options — go through the same State the wrapper
-            # drives, with the same must-precede-backends check
-            from jax._src import distributed as _jax_distributed
-            from jax._src import xla_bridge as _xla_bridge
-
-            if _xla_bridge.backends_are_initialized():
-                raise RuntimeError(
-                    "initialize_multihost must be called before any JAX "
-                    "computations are executed"
-                )
-            _jax_distributed.global_state.initialize(
-                coordinator_address=coordinator_address,
-                num_processes=num_processes,
-                process_id=process_id,
-                service_max_missing_heartbeats=int(hb),
-                client_max_missing_heartbeats=int(hb),
-            )
-        else:
-            jax.distributed.initialize(
-                coordinator_address=coordinator_address,
-                num_processes=num_processes,
-                process_id=process_id,
-            )
+        jax.distributed.initialize(
+            coordinator_address=coordinator_address,
+            num_processes=num_processes,
+            process_id=process_id,
+            **heartbeat,
+        )
     except (ValueError, RuntimeError) as e:
         raise RuntimeError(
             "multihost initialization failed — on non-auto-detected "
@@ -843,14 +825,10 @@ def _all_to_all_jit():
     test in tests/test_multihost.py (``_a2a_cache_size``)."""
     global _A2A_JIT
     if _A2A_JIT is None:
-        try:  # jax.experimental.shard_map moved in newer jax releases
-            from photon_ml_tpu.utils.compat import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         _A2A_JIT = jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda x: jax.lax.all_to_all(
                     x, "proc", split_axis=0, concat_axis=0, tiled=True
                 ),

@@ -1,4 +1,4 @@
-"""The bench harness's honesty machinery (guards + generated BASELINE)."""
+"""The bench harness's honesty machinery (guards + output contract)."""
 
 from __future__ import annotations
 
@@ -40,39 +40,6 @@ class TestGuards:
     def test_median_of_runs(self):
         vals = iter([5.0, 1.0, 100.0])
         assert bench._median_of_runs(lambda: next(vals)) == 5.0
-
-
-class TestBaselineGeneration:
-    def test_update_baseline_renders_from_artifact(self, tmp_path, monkeypatch):
-        """The measured table is generated VERBATIM from the artifact and
-        replaces only the marked region (hand-edits inside don't survive;
-        text outside does)."""
-        results = {
-            "cfg_a": {
-                "samples_per_sec": 123456.0,
-                "sec_per_pass_marginal": 0.005,
-                "sec_per_iteration": 0.01,
-                "implied_hbm_fraction": 0.25,
-                "vs_one_core_proxy": 7.5,
-                "quality_ok": True,
-            },
-            "cfg_err": {"error": "boom"},
-        }
-        (tmp_path / "BENCH_DETAIL.json").write_text(json.dumps(results))
-        (tmp_path / "BASELINE.md").write_text(
-            "# header stays\n\n"
-            f"{bench._BASELINE_BEGIN}\nHAND EDIT MUST DIE\n{bench._BASELINE_END}\n"
-            "\nfooter stays\n"
-        )
-        monkeypatch.setattr(
-            bench.os.path, "abspath", lambda p: str(tmp_path / "bench.py")
-        )
-        bench.update_baseline()
-        text = (tmp_path / "BASELINE.md").read_text()
-        assert "# header stays" in text and "footer stays" in text
-        assert "HAND EDIT MUST DIE" not in text
-        assert "| cfg_a | 123456 | 0.005 | 0.01 | 0.25 | 7.5 | yes |" in text
-        assert "cfg_err" in text and "boom" in text
 
 
 class TestQuickMode:
@@ -206,21 +173,16 @@ class TestQuickMode:
             lambda name, quick=False: (calls.append((name, quick)),
                                        results[name])[1],
         )
-        baseline_writes = []
-        monkeypatch.setattr(
-            bench, "update_baseline",
-            lambda *a, **k: baseline_writes.append(a),
-        )
         detail_writes = []
         monkeypatch.setattr(
             bench.json, "dump",
             lambda *a, **k: detail_writes.append(a),
         )
         bench.main(quick=quick)
-        return calls, baseline_writes, detail_writes, capsys.readouterr()
+        return calls, detail_writes, capsys.readouterr()
 
     def test_quick_keeps_single_json_line_contract(self, monkeypatch, capsys):
-        calls, baseline_writes, detail_writes, cap = self._run_main(
+        calls, detail_writes, cap = self._run_main(
             monkeypatch, capsys, self.FAKE
         )
         lines = [l for l in cap.out.splitlines() if l.strip()]
@@ -302,8 +264,8 @@ class TestQuickMode:
         assert s_cfg["telemetry"]["metrics"]["gauges"][
             "serve.hot.hit_rate"
         ] == 0.74
-        # quick writes NO artifacts (BENCH_DETAIL.json / BASELINE.md)
-        assert not baseline_writes and not detail_writes
+        # quick writes NO artifacts (BENCH_DETAIL.json)
+        assert not detail_writes
 
     def test_quick_quality_failure_exits_nonzero_with_contract(
         self, monkeypatch, capsys
@@ -341,7 +303,6 @@ class TestQuickMode:
 
         orig_child = bench._run_config_subprocess
         monkeypatch.setattr(bench, "_run_config_subprocess", fake_child)
-        monkeypatch.setattr(bench, "update_baseline", lambda *a, **k: None)
         bench.main(quick=True, telemetry_dir=tdir)
         lines = [l for l in capsys.readouterr().out.splitlines()
                  if l.strip()]
@@ -383,11 +344,6 @@ class TestQuickMode:
             bench, "_run_config_subprocess",
             lambda name, quick=False: results[name],
         )
-        baseline_writes = []
-        monkeypatch.setattr(
-            bench, "update_baseline",
-            lambda *a, **k: baseline_writes.append(a),
-        )
         detail_writes = []
         monkeypatch.setattr(
             bench.json, "dump", lambda *a, **k: detail_writes.append(a)
@@ -395,7 +351,7 @@ class TestQuickMode:
         bench.main(quick=False)
         lines = [l for l in capsys.readouterr().out.splitlines() if l.strip()]
         assert len(lines) == 1 and json.loads(lines[0])["quick"] is False
-        assert baseline_writes and detail_writes  # full mode DOES write
+        assert detail_writes  # full mode DOES write
 
     def test_retune_env_reaches_kernel_constants(self, monkeypatch):
         import photon_ml_tpu.ops.sparse_tiled as st
@@ -684,29 +640,18 @@ class TestServeContract:
 
 
 class TestNarrativeNumberDiscipline:
-    """Every 'Nx'/'N×' multiplier in README/BASELINE prose must be backed by
-    a committed artifact or be an explicitly reviewed protocol constant —
-    r3 and r4 each shipped a prose perf claim matching NO artifact (VERDICT
-    r4 weak #5: README's 6.8x A2 row), and the generated-table machinery
-    cannot regenerate prose."""
+    """Every 'Nx'/'N×' multiplier in README prose must be backed by a
+    committed artifact or be an explicitly reviewed protocol constant —
+    rounds 3 and 4 each shipped a prose perf claim matching NO artifact
+    (README's 6.8x A2 row)."""
 
     # Reviewed non-claim constants. Each entry documents WHY the number is
-    # allowed to live in prose without appearing in BENCH_DETAIL.json.
-    # Perf claims about THIS framework's kernels/configs never belong here —
-    # they go in the generated table or die.
+    # allowed to live in prose without appearing in a committed artifact.
+    # Perf claims about THIS framework's kernels/configs never belong here.
     ALLOWED = {
         "10x": "north-star TARGET from BASELINE.json, not a measurement",
         "1000x": "hypothetical under-report bound in the guard rationale",
-        "100x": "relay dedup-cache phenomenon (protocol history)",
-        "3x": "relay between-session variance (protocol history)",
-        "1.7x": "one-core proxy load spread (protocol history)",
-        "5x": "r5 profile narration: bucket padding factor, trace-cited",
-        "5.0x": "r5 profile narration: old ladder padding, trace-cited",
-        "2.0x": "r5 profile narration: new ladder padding, trace-cited",
-        "20x": "host-sync stall phenomenon (protocol history)",
         "2x": "padding allowance in the exchange traffic test",
-        "2.7x": "r4 builder-vs-driver session swing (protocol history)",
-        "1.9x": "r4 A2 session swing (protocol history)",
     }
 
     def _numbers(self, text: str) -> list[str]:
@@ -735,15 +680,9 @@ class TestNarrativeNumberDiscipline:
         assert pieces, "no committed JSON artifact found to audit against"
         artifact = "\n".join(pieces)
         offenders = []
-        for name in ("README.md", "BASELINE.md"):
+        for name in ("README.md",):
             with open(os.path.join(here, name)) as f:
                 text = f.read()
-            if bench._BASELINE_BEGIN in text:
-                # the generated block IS the artifact — exempt
-                text = (
-                    text.split(bench._BASELINE_BEGIN)[0]
-                    + text.split(bench._BASELINE_END, 1)[1]
-                )
             for hit in self._numbers(text):
                 token = hit.replace(" ", "").rstrip("x")
                 if hit.replace(" ", "") in self.ALLOWED:

@@ -76,10 +76,8 @@ def test_sharded_objective_value_grad_hvp_match(rng):
         f, g = obj.value_and_grad(w)
         return f, g, obj.hvp(w, v), obj.hessian_diag(w)
 
-    from photon_ml_tpu.utils import compat
-
     f, g, hv, hd = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             compute, mesh=mesh, in_specs=(P("data"), P(), P()), out_specs=P(),
             check_vma=False,
         )

@@ -1,0 +1,196 @@
+"""Deviceless AOT compile of the Pallas kernels for a TPU v5e.
+
+The CPU suite runs every kernel under ``interpret=True``, which accepts
+programs Mosaic refuses: the shipped tile-COO constants and two storage
+rungs went twenty PRs without ever lowering for a chip. libtpu can compile
+for a ``v5e:2x2`` topology with no device attached, so these tests lower
+and compile the real kernels (``interpret=False``) and fail tier-1 the day
+one stops compiling. Nothing here executes, so nothing is ``kernel``-marked
+(that marker retunes the constants DOWN, and the point is the shipped ones).
+"""
+
+from __future__ import annotations
+
+import importlib.util
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+import photon_ml_tpu.ops.sparse_tiled as st
+from photon_ml_tpu.ops import fused
+from photon_ml_tpu.ops.losses import loss_for_task
+from photon_ml_tpu.types import TaskType
+
+LOSS = loss_for_task(TaskType.LOGISTIC_REGRESSION)
+
+
+@pytest.fixture(autouse=True)
+def _x64_off_like_the_chip():
+    """The suite enables x64 for its finite-difference checks; a chip run
+    does not, and Mosaic has no 64-bit types."""
+    with jax.enable_x64(False):
+        yield
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """Skipped only where libtpu is not installed; an installed libtpu
+    that cannot describe a v5e fails the module, or these tests would
+    go quiet exactly when it is needed."""
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("libtpu is not installed: no deviceless TPU compile here")
+    from jax.experimental import topologies
+
+    t = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    assert t.devices[0].device_kind == "TPU v5 lite"
+    return t
+
+
+def _spec(topo):
+    sharding = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _layout_specs(spec, storage, n=1 << 12, d=1 << 14, k=8):
+    rng = np.random.default_rng(0)
+    lay = st.build_write_major_layout(
+        np.repeat(np.arange(n), k), rng.integers(0, d, n * k),
+        rng.normal(size=n * k).astype(np.float32), n, d, storage=storage,
+    )
+    arrays = (lay.packed, lay.wslab, lay.rslab, lay.rrun, lay.srun)
+    return tuple(spec(a.shape, a.dtype) for a in arrays), n, d
+
+
+def _stream_specs(spec, groups, storage="f32"):
+    """The five layout streams of a chunk of ``groups`` groups, as shapes
+    only — no layout is built."""
+    return (
+        spec((groups, 1 if storage == "int8" else 3, st.GROUP), jnp.int32),
+        spec((groups // st.GROUPS_PER_STEP,), jnp.int32),
+        spec((groups,), jnp.int32),
+        spec((groups // st.GROUPS_PER_RUN,), jnp.int32),
+        spec((groups // st.GROUPS_PER_RUN,), jnp.float32),
+    )
+
+
+def _lower_shipped(spec, specs, n, d, storage="f32"):
+    return st._tiled_apply_jit.lower(
+        specs, spec((d,), jnp.float32), n, d, False,
+        st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA, st.GROUPS_PER_RUN,
+        True, True, storage, False, None,
+    )
+
+
+def _compile_tile(spec, storage, *, pipeline, seg_batched=None, square=False):
+    specs, n, d = _layout_specs(spec, storage)
+    return st._tiled_apply_jit.lower(
+        specs, spec((d,), jnp.float32), n, d, square,
+        st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA, st.GROUPS_PER_RUN,
+        st.SEGMENT_BATCHED if seg_batched is None else seg_batched,
+        pipeline, storage, False, None,
+    ).compile()
+
+
+class TestTileCooCompiles:
+    def test_shipped_constants_are_the_ones_compiled(self):
+        assert (st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA) == (32, 4)
+        assert st.GROUPS_PER_RUN == 2 and st.SEGMENT_BATCHED is True
+
+    @pytest.mark.parametrize("pipeline", [True, False])
+    @pytest.mark.parametrize("storage", ["f32", "int8"])
+    def test_shipped_kernel_both_schedules(self, topo, storage, pipeline):
+        _compile_tile(_spec(topo), storage, pipeline=pipeline)
+
+    def test_hessian_diagonal_variant(self, topo):
+        _compile_tile(_spec(topo), "f32", pipeline=True, square=True)
+
+    def test_per_group_fallback_kernel(self, topo):
+        _compile_tile(_spec(topo), "f32", pipeline=True, seg_batched=False)
+
+    def test_a2_full_shape_fits_smem(self, topo):
+        """A2's bench shape (n=2^19, d=2^17, 32 nonzeros a row, ~166k
+        groups): with all four scalar streams prefetched it needed 1.29M
+        of a v5e's 1.00M SMEM. The padded group count is the chip's own
+        (200,448 at GROUPS_PER_RUN=2, PR 21)."""
+        spec = _spec(topo)
+        _lower_shipped(spec, _stream_specs(spec, 200448), 1 << 19, 1 << 17).compile()
+
+    @pytest.mark.parametrize("storage", ["f32", "int8"])
+    def test_stream_beyond_smem_compiles_as_pieces(self, topo, storage):
+        """n=2^21 rows of 8 uniform nonzeros at d=2^17 pad 4.0x to 524,288
+        groups: 1.11 MB of prefetch on f32 (2.16 MB on int8), more than
+        one call's SMEM. The stream runs as several calls, and each
+        compiles."""
+        spec = _spec(topo)
+        text = _lower_shipped(
+            spec, _stream_specs(spec, 524288, storage), 1 << 21, 1 << 17, storage
+        ).compile().as_text()
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == (
+            2 if storage == "f32" else 3
+        )
+
+
+class TestBf16RungRefusesOnTpu:
+    def test_bf16_layout_build_raises_naming_itself(self, monkeypatch):
+        """Mosaic cannot slice the 3-stream int16 block for the per-step
+        DMA, so on a TPU the rung must refuse where the layout is built —
+        never a silent f32."""
+        monkeypatch.setattr(st, "_interpret", lambda: False)
+        with pytest.raises(NotImplementedError, match="PHOTON_KERNEL_DTYPE=bf16"):
+            st.build_write_major_layout(
+                np.arange(8), np.arange(8), np.ones(8, np.float32),
+                st.SLAB, st.SLAB, storage="bf16",
+            )
+
+
+class TestFusedCompiles:
+    """Both fused kernels, both storage dtypes, with and without the
+    optional offset/weight streams, at the headline width."""
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    @pytest.mark.parametrize("aux", [False, True])
+    def test_value_grad_and_hvp(self, topo, dtype, aux):
+        spec = _spec(topo)
+        n, d = 1 << 14, 512
+        X, col = spec((n, d), dtype), spec((n,), jnp.float32)
+        off = col if aux else None
+        u, c = spec((d,), jnp.float32), spec((), jnp.float32)
+        jax.jit(
+            lambda X, y, off, wt, u, c: fused.fused_value_grad(
+                X, y, off, wt, u, c, loss=LOSS
+            )
+        ).lower(X, col, off, off, u, c).compile()
+        jax.jit(
+            lambda X, y, off, wt, u, v, c, cv: fused.fused_hvp(
+                X, y, off, wt, u, v, c, cv, loss=LOSS
+            )
+        ).lower(X, col, off, off, u, u, c, c).compile()
+
+
+def test_sharded_fused_solve_compiles_for_four_chips(topo):
+    """The four-chip path of ``DistributedTrainer``: the whole L-BFGS loop
+    under ``shard_map`` with the fused kernel inside and one psum per
+    evaluation, compiled for the 2x2 mesh."""
+    from photon_ml_tpu.config import OptimizerConfig
+    from photon_ml_tpu.ops.batch import DenseBatch
+    from photon_ml_tpu.optim import lbfgs_minimize
+    from photon_ml_tpu.parallel.distributed import _sharded_solve
+
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    rows = NamedSharding(mesh, P("data"))
+    rep = NamedSharding(mesh, P())
+    n, d = 1 << 14, 512
+    row = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rows)
+    scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)
+    batch = DenseBatch(X=row((n, d)), labels=row((n,)), offsets=row((n,)), weights=row((n,)))
+    _sharded_solve.lower(
+        batch, jax.ShapeDtypeStruct((d,), jnp.float32, sharding=rep),
+        scalar, scalar, None, None,
+        minimize_fn=lbfgs_minimize, loss=LOSS,
+        config=OptimizerConfig(max_iterations=3, tolerance=0.0),
+        intercept_index=None, axis_name="data", mesh=mesh, use_l1=False,
+        fused=True, data_hints=(True, False),
+    ).compile()

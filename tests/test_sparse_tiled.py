@@ -888,6 +888,87 @@ def test_layout_tracks_retuned_segment_constants(rng, monkeypatch):
     )
 
 
+class TestSmemPieces:
+    """A kernel call's scalar-prefetch streams must fit SMEM. How many
+    groups a layout pads to depends on where the nonzeros fall, so the
+    split into calls is read off the built stream, never off a row bound
+    guessed from shapes."""
+
+    SHIPPED_STEP_BYTES = 272  # 4 segments + 64 runs of i32 per 128 groups
+
+    @pytest.mark.parametrize(
+        "groups",
+        # padded group counts at shipped constants: A2's shape and the
+        # n=2^20 shape (chip, PR 21); n=2^21 with 8 uniform nonzeros a row
+        # at d=2^17 (pads 4.0x); 2^22 rows of the same
+        [200448, 401024, 524288, 1 << 20],
+    )
+    def test_every_piece_fits_the_shipped_budget(self, groups):
+        import photon_ml_tpu.ops.sparse_tiled as st
+
+        n_steps = groups // 128
+        bounds = st._piece_bounds(n_steps, self.SHIPPED_STEP_BYTES)
+        assert bounds[-1] == n_steps and bounds == sorted(set(bounds))
+        sizes = np.diff([0] + bounds)
+        assert (sizes * self.SHIPPED_STEP_BYTES <= st._SMEM_PREFETCH_BUDGET).all()
+        # one call when the stream fits, and never a call more than needed
+        max_steps = st._SMEM_PREFETCH_BUDGET // self.SHIPPED_STEP_BYTES
+        assert (len(bounds) - 1) * max_steps < n_steps
+        assert len(bounds) == {200448: 1, 401024: 1, 524288: 2, 1 << 20: 3}[groups]
+
+    @pytest.mark.kernel
+    @pytest.mark.parametrize("columns", ["uniform_k8", "zipf"])
+    @pytest.mark.parametrize("storage", ["f32", "int8"])
+    def test_split_stream_matches_the_xla_path(
+        self, rng, monkeypatch, columns, storage
+    ):
+        """Narrow rows and skewed columns pad far past the 1.5x of A2's
+        traffic; with the budget cut to a few DMA steps the stream runs
+        as several kernel calls whose summed outputs match the XLA path."""
+        import photon_ml_tpu.ops.sparse_tiled as st
+
+        monkeypatch.setenv("PHOTON_KERNEL_DTYPE", storage)
+        n, d, k = 2048, 8192, 8
+        if columns == "zipf":
+            idx = (rng.zipf(1.3, size=(n, k)) - 1) % d
+        else:
+            idx = rng.integers(0, d, size=(n, k))
+        val = rng.normal(size=(n, k)).astype(np.float32)
+        b = SparseBatch(
+            indices=jnp.asarray(idx.astype(np.int32)), values=jnp.asarray(val),
+            labels=jnp.zeros(n, jnp.float32),
+            offsets=jnp.zeros(n, jnp.float32),
+            weights=jnp.ones(n, jnp.float32), num_features=d,
+        )
+        tb = st.tile_sparse_batch(b)
+        (chunk,) = tb.chunks
+        step_groups = st.GROUPS_PER_STEP * st.SEGMENTS_PER_DMA
+        n_steps = chunk.m_arrays[0].shape[0] // step_groups
+        assert n_steps >= 3
+        per_step = 4 * (st.SEGMENTS_PER_DMA + step_groups // st.GROUPS_PER_RUN
+                        * (2 if storage == "int8" else 1))
+        monkeypatch.setattr(st, "_SMEM_PREFETCH_BUDGET", 2 * per_step)
+        assert len(st._piece_bounds(n_steps, per_step)) == -(-n_steps // 2)
+        # the budget is read at trace time and is not a jit key
+        st._tiled_apply_jit.clear_cache()
+        try:
+            w = jnp.asarray(rng.normal(size=d).astype(np.float32))
+            r = jnp.asarray(rng.normal(size=n).astype(np.float32))
+            # of the largest entry: f32 rounding, or the int8 rung's
+            # documented quantization bound
+            rel = 1e-5 if storage == "f32" else 6e-2
+            for got, want in (
+                (tb.matvec(w), b.matvec(w)), (tb.rmatvec(r), b.rmatvec(r)),
+            ):
+                want = np.asarray(want)
+                np.testing.assert_allclose(
+                    np.asarray(got), want, rtol=0,
+                    atol=rel * float(np.abs(want).max()),
+                )
+        finally:
+            st._tiled_apply_jit.clear_cache()
+
+
 class TestTopologyKeyedCaches:
     """Executable and layout caches key on the EFFECTIVE device topology
     (backend, local device count, effective process count): re-entering
