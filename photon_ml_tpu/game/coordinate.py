@@ -37,6 +37,13 @@ from photon_ml_tpu.normalization import (
     NormalizationContext,
     require_intercept_for_shifts,
 )
+from photon_ml_tpu.obs.stages import (
+    RE_OFFSETS,
+    RE_SCORE,
+    VISIT_FIXED,
+    VISIT_RE,
+    stage,
+)
 from photon_ml_tpu.ops.glm import compute_variances, make_objective
 from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.optim.common import OptimizationResult, select_minimize_fn
@@ -370,25 +377,28 @@ class FixedEffectCoordinate:
         def run(base_batch, total, own_score, w0):
             import dataclasses as _dc
 
-            offsets = total - own_score
-            train_batch = _dc.replace(base_batch, offsets=offsets)
-            if norm is not None:
-                w0_n = norm.model_from_original_space(w0)
-            else:
-                w0_n = w0
-            obj = make_objective(
-                train_batch, loss, l2_weight=l2, norm=norm,
-                intercept_index=self.intercept_index, prior=prior,
-            )
-            result = minimize_fn(obj, w0_n, opt.optimizer, **extra)
-            w = result.w
-            variances = compute_variances(obj, w, self.variance_computation)
-            if norm is not None:
-                w, _ = norm.model_to_original_space(w)
-                if variances is not None:
-                    variances = norm.factors**2 * variances
-            new_score = train_batch.matvec(w)
-            return w, variances, result, new_score, offsets + new_score
+            with stage(VISIT_FIXED):
+                offsets = total - own_score
+                train_batch = _dc.replace(base_batch, offsets=offsets)
+                if norm is not None:
+                    w0_n = norm.model_from_original_space(w0)
+                else:
+                    w0_n = w0
+                obj = make_objective(
+                    train_batch, loss, l2_weight=l2, norm=norm,
+                    intercept_index=self.intercept_index, prior=prior,
+                )
+                result = minimize_fn(obj, w0_n, opt.optimizer, **extra)
+                w = result.w
+                variances = compute_variances(
+                    obj, w, self.variance_computation
+                )
+                if norm is not None:
+                    w, _ = norm.model_to_original_space(w)
+                    if variances is not None:
+                        variances = norm.factors**2 * variances
+                new_score = train_batch.matvec(w)
+                return w, variances, result, new_score, offsets + new_score
 
         return run
 
@@ -795,43 +805,46 @@ class RandomEffectCoordinate:
         def run(total, own_score, W0, bucket_args, feats, ids):
             import dataclasses as _dc
 
-            # rebind the device tensors through jit ARGUMENTS (closing over
-            # them would bake every bucket tensor and the feature shard
-            # into the executable as trace constants — the closure-capture
-            # accumulation bench.py isolates per-config subprocesses for);
-            # the host-side metadata (entity_ids, num_real) rides the
-            # closure, unused in the trace
-            prep = [
-                _dc.replace(pb, static=s, row_idx=ri, mask=mk, ids=bi, columns=co)
-                for pb, (s, ri, mk, bi, co) in zip(prepared, bucket_args)
-            ]
-            offsets = total - own_score
-            W, V, diag = _train_prepared_core(
-                prep,
-                offsets,
-                self._train_num_features,
-                self.num_entities,
-                loss,
-                opt.optimizer,
-                l2_weight=l2,
-                l1_weight=l1,
-                intercept_index=(
-                    None if self.projector is not None else self.intercept_index
-                ),
-                initial_coefficients=W0,
-                variance_computation=self.variance_computation,
-                norm=self.normalization,
-                prior_coefficients=prior_W,
-                prior_variances=prior_V,
-            )
-            # scoring in the TRAINING subspace: (XP)w_p == X(P w_p), so the
-            # projected-space score equals the original-space model's
-            from photon_ml_tpu.game.random_effect import random_effect_scores
+            with stage(VISIT_RE):
+                # rebind the device tensors through jit ARGUMENTS (closing over
+                # them would bake every bucket tensor and the feature shard
+                # into the executable as trace constants — the closure-capture
+                # accumulation bench.py isolates per-config subprocesses for);
+                # the host-side metadata (entity_ids, num_real) rides the
+                # closure, unused in the trace
+                prep = [
+                    _dc.replace(pb, static=s, row_idx=ri, mask=mk, ids=bi, columns=co)
+                    for pb, (s, ri, mk, bi, co) in zip(prepared, bucket_args)
+                ]
+                with stage(RE_OFFSETS):
+                    offsets = total - own_score
+                W, V, diag = _train_prepared_core(
+                    prep,
+                    offsets,
+                    self._train_num_features,
+                    self.num_entities,
+                    loss,
+                    opt.optimizer,
+                    l2_weight=l2,
+                    l1_weight=l1,
+                    intercept_index=(
+                        None if self.projector is not None else self.intercept_index
+                    ),
+                    initial_coefficients=W0,
+                    variance_computation=self.variance_computation,
+                    norm=self.normalization,
+                    prior_coefficients=prior_W,
+                    prior_variances=prior_V,
+                )
+                # scoring in the TRAINING subspace: (XP)w_p == X(P w_p), so the
+                # projected-space score equals the original-space model's
+                from photon_ml_tpu.game.random_effect import random_effect_scores
 
-            in_range = (ids >= 0) & (ids < self.num_entities)
-            safe_ids = jnp.where(in_range, ids, 0)
-            raw = random_effect_scores(feats, safe_ids, W)
-            new_score = jnp.where(in_range, raw, 0.0)
-            return W, V, diag, new_score, offsets + new_score
+                with stage(RE_SCORE):
+                    in_range = (ids >= 0) & (ids < self.num_entities)
+                    safe_ids = jnp.where(in_range, ids, 0)
+                    raw = random_effect_scores(feats, safe_ids, W)
+                    new_score = jnp.where(in_range, raw, 0.0)
+                return W, V, diag, new_score, offsets + new_score
 
         return run

@@ -24,6 +24,7 @@ import numpy as np
 
 from photon_ml_tpu.evaluation import EvaluationResults, evaluate_all
 from photon_ml_tpu.obs import emit_event, span
+from photon_ml_tpu.obs.stages import coord, stage
 from photon_ml_tpu.game.coordinate import Coordinate
 from photon_ml_tpu.game.data import GameBatch
 from photon_ml_tpu.game.models import GameModel
@@ -69,9 +70,10 @@ def _build_fused_outer(coordinates: Mapping[str, Any], seq: Sequence[str]):
             owns = list(owns)
             statics = list(statics)
             for i in range(len(applies)):
-                aux, s_new, total = applies[i](statics[i], total, owns[i])
-                owns[i] = s_new
-                statics[i] = advances[i](aux, statics[i])
+                with stage(coord(seq[i])):
+                    aux, s_new, total = applies[i](statics[i], total, owns[i])
+                    owns[i] = s_new
+                    statics[i] = advances[i](aux, statics[i])
                 outs.append(aux)  # scores come from the carry, not the ys
             return (total, tuple(owns), tuple(statics)), tuple(outs)
 

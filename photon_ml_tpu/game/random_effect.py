@@ -36,6 +36,7 @@ from photon_ml_tpu.game.data import (
     DenseFeatures,
     gather_bucket,
 )
+from photon_ml_tpu.obs.stages import RE_OFFSETS, RE_SOLVE, stage
 from photon_ml_tpu.ops.batch import Batch, DenseBatch
 from photon_ml_tpu.ops.glm import make_objective
 from photon_ml_tpu.ops.losses import PointwiseLoss
@@ -800,9 +801,10 @@ def _solve_bucket(
     # None (static absence) across all lanes
     in_axes = (0, 0, None if prior_mu is None else 0,
                None if prior_var is None else 0)
-    return jax.vmap(solve_one, in_axes=in_axes)(
-        bucket_batch, w0, prior_mu, prior_var
-    )
+    with stage(RE_SOLVE):
+        return jax.vmap(solve_one, in_axes=in_axes)(
+            bucket_batch, w0, prior_mu, prior_var
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -851,7 +853,10 @@ def _lanes_init(
         return init_fn(obj, w0_e, config, **extra)
 
     in_axes = (0, 0) + _prior_axes(prior_mu, prior_var)
-    return jax.vmap(one, in_axes=in_axes)(bucket_batch, w0, prior_mu, prior_var)
+    with stage(RE_SOLVE):
+        return jax.vmap(one, in_axes=in_axes)(
+            bucket_batch, w0, prior_mu, prior_var
+        )
 
 
 @partial(jax.jit, static_argnames=("run_fn", "loss", "config", "intercept_index"))
@@ -866,9 +871,10 @@ def _lanes_run(
         return run_fn(obj, st, config, it_bound, **extra)
 
     in_axes = (0, 0) + _prior_axes(prior_mu, prior_var)
-    return jax.vmap(one, in_axes=in_axes)(
-        bucket_batch, state, prior_mu, prior_var
-    )
+    with stage(RE_SOLVE):
+        return jax.vmap(one, in_axes=in_axes)(
+            bucket_batch, state, prior_mu, prior_var
+        )
 
 
 @partial(
@@ -894,9 +900,10 @@ def _lanes_finalize(
         return res.w, res.value, res.iterations, res.reason, var
 
     in_axes = (0, 0) + _prior_axes(prior_mu, prior_var)
-    return jax.vmap(one, in_axes=in_axes)(
-        bucket_batch, state, prior_mu, prior_var
-    )
+    with stage(RE_SOLVE):
+        return jax.vmap(one, in_axes=in_axes)(
+            bucket_batch, state, prior_mu, prior_var
+        )
 
 
 def _next_pow2(n: int) -> int:
@@ -2140,55 +2147,57 @@ def _bucket_step(
     cost ~6 host→device dispatches per bucket — pure latency on remote-
     attached accelerators (SURVEY.md §7 / VERDICT weak #6)."""
     d = W.shape[1]
-    off_b = offsets[row_idx] * mask
-    bucket_batch = dataclasses.replace(static_batch, offsets=off_b)
-    k_pad = static_batch.labels.shape[0]
+    with stage(RE_OFFSETS):
+        off_b = offsets[row_idx] * mask
+    with stage(RE_SOLVE):
+        bucket_batch = dataclasses.replace(static_batch, offsets=off_b)
+        k_pad = static_batch.labels.shape[0]
 
-    def lane(M, pad_value=0.0):
-        return _extract_lanes(M, ids, columns, k, k_pad, d, pad_value, sharding)
+        def lane(M, pad_value=0.0):
+            return _extract_lanes(M, ids, columns, k, k_pad, d, pad_value, sharding)
 
-    w0 = lane(W)
-    mu_l = lane(prior_mu)
-    var_l = lane(prior_var, pad_value=1.0)  # padded lanes: harmless unit variance
-    solve_intercept = intercept_index
-    if columns is not None:
-        # subspace projection solves at width p over each entity's own
-        # columns; the intercept (always the last full-space column by
-        # framework convention) lands at slot p-1
-        if intercept_index is not None:
-            solve_intercept = columns.shape[1] - 1
-    if hash_S is not None:
-        # hash-folded class: the solve runs at width m — fold the warm
-        # start and MAP prior through the same signed matrix the static
-        # features were folded through at prepare time (the intercept
-        # owns slot m-1 alone by construction, so it stays addressable)
-        w0, mu_l, var_l = _hash_fold_lanes(w0, mu_l, var_l, hash_S)
-        if intercept_index is not None:
-            solve_intercept = hash_S.shape[1] - 1
+        w0 = lane(W)
+        mu_l = lane(prior_mu)
+        var_l = lane(prior_var, pad_value=1.0)  # padded lanes: harmless unit variance
+        solve_intercept = intercept_index
+        if columns is not None:
+            # subspace projection solves at width p over each entity's own
+            # columns; the intercept (always the last full-space column by
+            # framework convention) lands at slot p-1
+            if intercept_index is not None:
+                solve_intercept = columns.shape[1] - 1
+        if hash_S is not None:
+            # hash-folded class: the solve runs at width m — fold the warm
+            # start and MAP prior through the same signed matrix the static
+            # features were folded through at prepare time (the intercept
+            # owns slot m-1 alone by construction, so it stays addressable)
+            w0, mu_l, var_l = _hash_fold_lanes(w0, mu_l, var_l, hash_S)
+            if intercept_index is not None:
+                solve_intercept = hash_S.shape[1] - 1
 
-    w_b, f_b, it_b, reason_b, var_b = _solve_bucket(
-        bucket_batch,
-        w0,
-        l2_weight,
-        norm,
-        mu_l,
-        var_l,
-        minimize_fn=minimize_fn,
-        loss=loss,
-        config=config,
-        intercept_index=solve_intercept,
-        variance_computation=variance_computation,
-        **minimize_kwargs,
-    )
-    if hash_S is not None:
-        # expand the folded solution back to the support width before
-        # the column scatter: each support column takes its slot's
-        # coefficient (times its sign); variances propagate through the
-        # same linear map with |S| (diagonal approximation)
-        w_b = hash_expand_coefficients(w_b, hash_S)
-        var_b = hash_expand_variances(var_b, hash_S)
-    W, V = _scatter_lanes(W, V, ids, columns, w_b, var_b, k)
-    return W, V, f_b[:k], it_b[:k], reason_b[:k]
+        w_b, f_b, it_b, reason_b, var_b = _solve_bucket(
+            bucket_batch,
+            w0,
+            l2_weight,
+            norm,
+            mu_l,
+            var_l,
+            minimize_fn=minimize_fn,
+            loss=loss,
+            config=config,
+            intercept_index=solve_intercept,
+            variance_computation=variance_computation,
+            **minimize_kwargs,
+        )
+        if hash_S is not None:
+            # expand the folded solution back to the support width before
+            # the column scatter: each support column takes its slot's
+            # coefficient (times its sign); variances propagate through the
+            # same linear map with |S| (diagonal approximation)
+            w_b = hash_expand_coefficients(w_b, hash_S)
+            var_b = hash_expand_variances(var_b, hash_S)
+        W, V = _scatter_lanes(W, V, ids, columns, w_b, var_b, k)
+        return W, V, f_b[:k], it_b[:k], reason_b[:k]
 
 
 @partial(jax.jit, static_argnames=("k",))
@@ -2202,19 +2211,21 @@ def _lane_prologue(
     loop pays one dispatch, not ~6. Same ops as the fused prologue with
     ``sharding=None`` — identical values."""
     d = W.shape[1]
-    off_b = offsets[row_idx] * mask
-    bucket_batch = dataclasses.replace(static_batch, offsets=off_b)
-    k_pad = static_batch.labels.shape[0]
+    with stage(RE_OFFSETS):
+        off_b = offsets[row_idx] * mask
+    with stage(RE_SOLVE):
+        bucket_batch = dataclasses.replace(static_batch, offsets=off_b)
+        k_pad = static_batch.labels.shape[0]
 
-    def lane(M, pad_value=0.0):
-        return _extract_lanes(M, ids, columns, k, k_pad, d, pad_value)
+        def lane(M, pad_value=0.0):
+            return _extract_lanes(M, ids, columns, k, k_pad, d, pad_value)
 
-    w0 = lane(W)
-    mu_l = lane(prior_mu)
-    var_l = lane(prior_var, pad_value=1.0)
-    if hash_S is not None:
-        w0, mu_l, var_l = _hash_fold_lanes(w0, mu_l, var_l, hash_S)
-    return bucket_batch, w0, mu_l, var_l
+        w0 = lane(W)
+        mu_l = lane(prior_mu)
+        var_l = lane(prior_var, pad_value=1.0)
+        if hash_S is not None:
+            w0, mu_l, var_l = _hash_fold_lanes(w0, mu_l, var_l, hash_S)
+        return bucket_batch, w0, mu_l, var_l
 
 
 # W/V donation: same O(1)-coefficient-copies HBM discipline as _bucket_step —
@@ -2224,10 +2235,11 @@ def _lane_prologue(
 def _lane_scatter(W, V, ids, columns, w_b, var_b, hash_S=None, *, k):
     """Eager-path twin of ``_bucket_step``'s (E, d) scatter epilogue
     (including the hash expansion back to the support width)."""
-    if hash_S is not None:
-        w_b = hash_expand_coefficients(w_b, hash_S)
-        var_b = hash_expand_variances(var_b, hash_S)
-    return _scatter_lanes(W, V, ids, columns, w_b, var_b, k)
+    with stage(RE_SOLVE):
+        if hash_S is not None:
+            w_b = hash_expand_coefficients(w_b, hash_S)
+            var_b = hash_expand_variances(var_b, hash_S)
+        return _scatter_lanes(W, V, ids, columns, w_b, var_b, k)
 
 
 def _bucket_step_compacted(

@@ -1,10 +1,13 @@
 """Run-telemetry subsystem: spans, metrics registry, JSONL sink, exporters.
 
 The TPU-native replacement for the observability the reference got from
-Spark's UI/event timeline (SURVEY.md §5.1). Five pieces:
+Spark's UI/event timeline (SURVEY.md §5.1). Six pieces:
 
 - **spans** (``span("descent/iter", coordinate=cid)``) — nested host-side
   wall-clock spans, thread-correct across the prefetch worker pool;
+- **stages** (``stages.stage(stages.RE_SOLVE)``) — names inside the
+  compiled programs (``jax`` name scopes): every device operation of a
+  profiler trace says which stage of the program it belongs to;
 - **metrics registry** (``metrics.REGISTRY``) — typed counters / gauges /
   histograms / timers, always on, subsuming the legacy stage counters
   (``utils/profiling`` is a compatibility shim over it);

@@ -9,6 +9,11 @@ exit as one complete event — name, ids, thread, start time, duration,
 attributes — which maps 1:1 onto a Chrome-trace complete event for the
 Perfetto exporter.
 
+A live span also opens a ``jax.profiler.TraceAnnotation`` of its name, so
+a run with both a telemetry sink and a profiler trace (``--telemetry-dir``
+and ``--profile-dir``) shows ``descent/iter``, ``glm/lambda`` or
+``serve/window`` on the profiler's own clock, beside the device operations.
+
 Disabled fast path: with no active sink, ``span()`` returns one shared
 module-level no-op context manager — no object allocation, no stack
 touch, no clock read — so spans stay wired through production hot paths
@@ -20,6 +25,8 @@ from __future__ import annotations
 import itertools
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 from photon_ml_tpu.obs import sink as _sink_mod
 
@@ -51,7 +58,8 @@ NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "attrs", "span_id", "parent_id", "t0", "start_unix")
+    __slots__ = ("name", "attrs", "span_id", "parent_id", "t0", "start_unix",
+                 "annotation")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
@@ -62,12 +70,15 @@ class _Span:
         self.parent_id = st[-1].span_id if st else None
         self.span_id = next(_ids)
         st.append(self)
+        self.annotation = TraceAnnotation(self.name)
+        self.annotation.__enter__()
         self.start_unix = time.time()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self.t0
+        self.annotation.__exit__(exc_type, exc, tb)
         st = _stack()
         # tolerate exotic unwind orders; normal exits pop the top
         if st and st[-1] is self:

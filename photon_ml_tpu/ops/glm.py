@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from photon_ml_tpu.normalization import NormalizationContext, no_normalization
+from photon_ml_tpu.obs.stages import GLM_OBJECTIVE, stage
 from photon_ml_tpu.ops.batch import Batch, DenseBatch
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.types import VarianceComputationType
@@ -132,8 +133,9 @@ class GLMObjective:
 
     # -- margins --------------------------------------------------------------
     def margins(self, w: Array) -> Array:
-        u, c = self.norm.to_effective(w)
-        return self.batch.matvec(u) - c + self.batch.offsets
+        with stage(GLM_OBJECTIVE):
+            u, c = self.norm.to_effective(w)
+            return self.batch.matvec(u) - c + self.batch.offsets
 
     # -- regularizer (plain L2 or Gaussian prior) ------------------------------
     def _reg_delta(self, w: Array) -> Array:
@@ -163,9 +165,10 @@ class GLMObjective:
         return self.fused or isinstance(self.batch, TiledSparseBatch)
 
     def value(self, w: Array) -> Array:
-        m = self.margins(w)
-        local = jnp.sum(self._weighted(self.loss.value(m, self.batch.labels)))
-        return self._reduce(local) + self._l2_term(w)
+        with stage(GLM_OBJECTIVE):
+            m = self.margins(w)
+            local = jnp.sum(self._weighted(self.loss.value(m, self.batch.labels)))
+            return self._reduce(local) + self._l2_term(w)
 
     # -- margin-state API (Newton's hot loop) ----------------------------------
     # Margins are affine in w, so a solver can carry m = margins(w) in its
@@ -177,43 +180,47 @@ class GLMObjective:
 
     def direction_margins(self, p: Array) -> Array:
         """d margins / d t along direction p (no offset term)."""
-        u_p, c_p = self.norm.to_effective(p)
-        return self.batch.matvec(u_p) - c_p
+        with stage(GLM_OBJECTIVE):
+            u_p, c_p = self.norm.to_effective(p)
+            return self.batch.matvec(u_p) - c_p
 
     def value_and_grad_from_margins(self, m: Array, w: Array) -> tuple[Array, Array]:
         """``value_and_grad(w)`` given m = margins(w) — saves the forward
         matvec; the gradient contraction still reads the data once."""
-        lv = self.loss.value(m, self.batch.labels)
-        r = self._weighted(self.loss.d1(m, self.batch.labels))
-        local = (jnp.sum(self._weighted(lv)), self.batch.rmatvec(r), jnp.sum(r))
-        val, g_raw, r_sum = self._reduce(local)
-        g = (self.norm.grad_to_model_space(g_raw, r_sum)
-             + self.l2_weight * self.reg_mask * self._reg_delta(w))
-        return val + self._l2_term(w), g
+        with stage(GLM_OBJECTIVE):
+            lv = self.loss.value(m, self.batch.labels)
+            r = self._weighted(self.loss.d1(m, self.batch.labels))
+            local = (jnp.sum(self._weighted(lv)), self.batch.rmatvec(r), jnp.sum(r))
+            val, g_raw, r_sum = self._reduce(local)
+            g = (self.norm.grad_to_model_space(g_raw, r_sum)
+                 + self.l2_weight * self.reg_mask * self._reg_delta(w))
+            return val + self._l2_term(w), g
 
     def hessian_from_margins(self, m: Array, w: Array) -> Array:
         """``hessian(w)`` given m = margins(w) (dense batches only)."""
-        if not isinstance(self.batch, DenseBatch):
-            raise NotImplementedError(
-                "full Hessian requires a DenseBatch; use hessian_diag or hvp"
-            )
-        d2 = self._weighted(self.loss.d2(m, self.batch.labels))
-        Z = (self.batch.X - self.norm.shifts) * self.norm.factors
-        h = self._reduce(Z.T @ (d2[:, None] * Z))
-        return h + jnp.diag(self.l2_weight * self.reg_mask * self._reg_curvature(self.reg_mask))
+        with stage(GLM_OBJECTIVE):
+            if not isinstance(self.batch, DenseBatch):
+                raise NotImplementedError(
+                    "full Hessian requires a DenseBatch; use hessian_diag or hvp"
+                )
+            d2 = self._weighted(self.loss.d2(m, self.batch.labels))
+            Z = (self.batch.X - self.norm.shifts) * self.norm.factors
+            h = self._reduce(Z.T @ (d2[:, None] * Z))
+            return h + jnp.diag(self.l2_weight * self.reg_mask * self._reg_curvature(self.reg_mask))
 
     def ray_values_from_margins(
         self, m: Array, dm: Array, w: Array, p: Array, ts: Array
     ) -> Array:
         """``ray_values`` given m = margins(w) and dm = direction_margins(p)
         — the whole Armijo ladder with NO matvec at all."""
-        y = self.batch.labels
+        with stage(GLM_OBJECTIVE):
+            y = self.batch.labels
 
-        def at(t):
-            return jnp.sum(self._weighted(self.loss.value(m + t * dm, y)))
+            def at(t):
+                return jnp.sum(self._weighted(self.loss.value(m + t * dm, y)))
 
-        data = self._reduce(jax.vmap(at)(ts))
-        return data + self._reg_ray(w, p, ts)
+            data = self._reduce(jax.vmap(at)(ts))
+            return data + self._reg_ray(w, p, ts)
 
     def _reg_ray(self, w: Array, p: Array, ts: Array) -> Array:
         """0.5·λ·Σ mask·prec·(δ + t·p)² for every t (δ = w − μ, or w)."""
@@ -241,23 +248,24 @@ class GLMObjective:
         )
 
     def value_and_grad(self, w: Array) -> tuple[Array, Array]:
-        if self.fused and isinstance(self.batch, DenseBatch):
-            from photon_ml_tpu.ops.fused import fused_value_grad
+        with stage(GLM_OBJECTIVE):
+            if self.fused and isinstance(self.batch, DenseBatch):
+                from photon_ml_tpu.ops.fused import fused_value_grad
 
-            u, c = self.norm.to_effective(w)
-            local = fused_value_grad(
-                self.batch.X, self.batch.labels,
-                None if self.offsets_zero else self.batch.offsets,
-                None if self.weights_one else self.batch.weights,
-                u, c, loss=self.loss,
-                interpret=_interpret_fused(),
-            )
-        else:
-            return self.value_and_grad_from_margins(self.margins(w), w)
-        val, g_raw, r_sum = self._reduce(local)
-        g = (self.norm.grad_to_model_space(g_raw, r_sum)
-             + self.l2_weight * self.reg_mask * self._reg_delta(w))
-        return val + self._l2_term(w), g
+                u, c = self.norm.to_effective(w)
+                local = fused_value_grad(
+                    self.batch.X, self.batch.labels,
+                    None if self.offsets_zero else self.batch.offsets,
+                    None if self.weights_one else self.batch.weights,
+                    u, c, loss=self.loss,
+                    interpret=_interpret_fused(),
+                )
+            else:
+                return self.value_and_grad_from_margins(self.margins(w), w)
+            val, g_raw, r_sum = self._reduce(local)
+            g = (self.norm.grad_to_model_space(g_raw, r_sum)
+                 + self.l2_weight * self.reg_mask * self._reg_delta(w))
+            return val + self._l2_term(w), g
 
     def grad(self, w: Array) -> Array:
         return self.value_and_grad(w)[1]
@@ -266,41 +274,43 @@ class GLMObjective:
         """Gauss-Newton/Hessian-vector product H·v = AᵀDA·v + λ₂·v (A = the
         normalized design matrix, D = diag(weight·d2)). One forward matmul +
         one reverse matmul; for TRON's CG loop this is the hot kernel."""
-        v_eff = self.norm.factors * v
-        if self.fused and isinstance(self.batch, DenseBatch):
-            from photon_ml_tpu.ops.fused import fused_hvp
+        with stage(GLM_OBJECTIVE):
+            v_eff = self.norm.factors * v
+            if self.fused and isinstance(self.batch, DenseBatch):
+                from photon_ml_tpu.ops.fused import fused_hvp
 
-            u, c = self.norm.to_effective(w)
-            local = fused_hvp(
-                self.batch.X, self.batch.labels,
-                None if self.offsets_zero else self.batch.offsets,
-                None if self.weights_one else self.batch.weights,
-                u, v_eff, c,
-                jnp.dot(self.norm.shifts, v_eff), loss=self.loss,
-                interpret=_interpret_fused(),
-            )
-        else:
-            m = self.margins(w)
-            d2 = self._weighted(self.loss.d2(m, self.batch.labels))
-            mv = self.batch.matvec(v_eff) - jnp.dot(self.norm.shifts, v_eff)
-            q = d2 * mv
-            local = (self.batch.rmatvec(q), jnp.sum(q))
-        hv_raw, q_sum = self._reduce(local)
-        hv = self.norm.grad_to_model_space(hv_raw, q_sum)
-        return hv + self.l2_weight * self.reg_mask * self._reg_curvature(v) * v
+                u, c = self.norm.to_effective(w)
+                local = fused_hvp(
+                    self.batch.X, self.batch.labels,
+                    None if self.offsets_zero else self.batch.offsets,
+                    None if self.weights_one else self.batch.weights,
+                    u, v_eff, c,
+                    jnp.dot(self.norm.shifts, v_eff), loss=self.loss,
+                    interpret=_interpret_fused(),
+                )
+            else:
+                m = self.margins(w)
+                d2 = self._weighted(self.loss.d2(m, self.batch.labels))
+                mv = self.batch.matvec(v_eff) - jnp.dot(self.norm.shifts, v_eff)
+                q = d2 * mv
+                local = (self.batch.rmatvec(q), jnp.sum(q))
+            hv_raw, q_sum = self._reduce(local)
+            hv = self.norm.grad_to_model_space(hv_raw, q_sum)
+            return hv + self.l2_weight * self.reg_mask * self._reg_curvature(v) * v
 
     def hessian_diag(self, w: Array) -> Array:
         """diag(H) — for VarianceComputationType.SIMPLE.
 
         diag_j = f_j² [ Σ d2ᵢxᵢⱼ² − 2 s_j Σ d2ᵢxᵢⱼ + s_j² Σ d2ᵢ ] + λ₂·mask.
         """
-        m = self.margins(w)
-        d2 = self._weighted(self.loss.d2(m, self.batch.labels))
-        local = (self.batch.rmatvec_sq(d2), self.batch.rmatvec(d2), jnp.sum(d2))
-        sq, lin, tot = self._reduce(local)
-        f, s = self.norm.factors, self.norm.shifts
-        diag = f * f * (sq - 2.0 * s * lin + s * s * tot)
-        return diag + self.l2_weight * self.reg_mask * self._reg_curvature(diag)
+        with stage(GLM_OBJECTIVE):
+            m = self.margins(w)
+            d2 = self._weighted(self.loss.d2(m, self.batch.labels))
+            local = (self.batch.rmatvec_sq(d2), self.batch.rmatvec(d2), jnp.sum(d2))
+            sq, lin, tot = self._reduce(local)
+            f, s = self.norm.factors, self.norm.shifts
+            diag = f * f * (sq - 2.0 * s * lin + s * s * tot)
+            return diag + self.l2_weight * self.reg_mask * self._reg_curvature(diag)
 
     def hessian(self, w: Array) -> Array:
         """Full (d, d) Hessian — for VarianceComputationType.FULL. Dense

@@ -28,6 +28,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from photon_ml_tpu.config import OptimizerConfig
+from photon_ml_tpu.obs.stages import (
+    LBFGS_LINE_SEARCH,
+    LBFGS_TWO_LOOP,
+    LBFGS_UPDATE,
+    stage,
+)
 from photon_ml_tpu.optim.common import (
     ConvergenceReason,
     OptimizationResult,
@@ -166,153 +172,157 @@ def _lbfgs_funcs(objective: Any, config: OptimizerConfig, l1w: Array | None):
         return jnp.logical_and(st.it < T, jnp.logical_not(st.done))
 
     def body(st: _LbfgsState) -> _LbfgsState:
-        p = -_two_loop(st.pg, st.S, st.Y, st.rho, st.count, m)
-        if use_l1:
-            # constrain the search direction to the descent orthant
-            p = jnp.where(p * (-st.pg) > 0.0, p, 0.0)
-        # fall back to steepest descent if the direction isn't a descent dir
-        descent = jnp.dot(p, st.pg) < 0.0
-        p = jnp.where(descent, p, -st.pg)
+        with stage(LBFGS_TWO_LOOP):
+            p = -_two_loop(st.pg, st.S, st.Y, st.rho, st.count, m)
+            if use_l1:
+                # constrain the search direction to the descent orthant
+                p = jnp.where(p * (-st.pg) > 0.0, p, 0.0)
+            # fall back to steepest descent if the direction isn't a descent dir
+            descent = jnp.dot(p, st.pg) < 0.0
+            p = jnp.where(descent, p, -st.pg)
 
-        if use_l1:
-            xi = jnp.where(st.w != 0.0, jnp.sign(st.w), jnp.sign(-st.pg))
+        with stage(LBFGS_LINE_SEARCH):
+            if use_l1:
+                xi = jnp.where(st.w != 0.0, jnp.sign(st.w), jnp.sign(-st.pg))
 
-            def trial_point(t):
-                x = st.w + t * p
-                return jnp.where(jnp.sign(x) == xi, x, 0.0)
+                def trial_point(t):
+                    x = st.w + t * p
+                    return jnp.where(jnp.sign(x) == xi, x, 0.0)
 
-        else:
+            else:
 
-            def trial_point(t):
-                return st.w + t * p
+                def trial_point(t):
+                    return st.w + t * p
 
-        # First iteration: the Hessian guess is the identity, so scale the
-        # initial step to unit length (Breeze does the same for iter 0).
-        p_norm = jnp.linalg.norm(p)
-        t0 = jnp.where(st.count == 0, 1.0 / jnp.maximum(1.0, p_norm), 1.0)
+            # First iteration: the Hessian guess is the identity, so scale the
+            # initial step to unit length (Breeze does the same for iter 0).
+            p_norm = jnp.linalg.norm(p)
+            t0 = jnp.where(st.count == 0, 1.0 / jnp.maximum(1.0, p_norm), 1.0)
 
-        def armijo_rhs(w_new):
-            # Armijo on the (possibly projected) actual step
-            return st.f + _ARMIJO_C1 * jnp.dot(st.pg, w_new - st.w)
+            def armijo_rhs(w_new):
+                # Armijo on the (possibly projected) actual step
+                return st.f + _ARMIJO_C1 * jnp.dot(st.pg, w_new - st.w)
 
-        def hopeless(w_new):
-            # Achievable decrease (~|pgᵀΔw|, the first-order model of the
-            # step — NOT the c1-scaled Armijo threshold) below the f32
-            # resolution of f: further halvings only shrink it, so no
-            # representable improvement is possible; stop backtracking
-            # instead of spinning max_line_search_steps objective passes
-            # on the terminal iteration.
-            return jnp.abs(jnp.dot(st.pg, w_new - st.w)) < 1e-7 * jnp.abs(st.f)
+            def hopeless(w_new):
+                # Achievable decrease (~|pgᵀΔw|, the first-order model of the
+                # step — NOT the c1-scaled Armijo threshold) below the f32
+                # resolution of f: further halvings only shrink it, so no
+                # representable improvement is possible; stop backtracking
+                # instead of spinning max_line_search_steps objective passes
+                # on the terminal iteration.
+                return jnp.abs(jnp.dot(st.pg, w_new - st.w)) < 1e-7 * jnp.abs(st.f)
 
-        def ls_should_continue(f_new, w_new, k):
-            insufficient = jnp.logical_or(f_new > armijo_rhs(w_new), jnp.isnan(f_new))
-            keep_going = jnp.logical_and(
-                insufficient, jnp.logical_not(hopeless(w_new))
+            def ls_should_continue(f_new, w_new, k):
+                insufficient = jnp.logical_or(f_new > armijo_rhs(w_new), jnp.isnan(f_new))
+                keep_going = jnp.logical_and(
+                    insufficient, jnp.logical_not(hopeless(w_new))
+                )
+                return jnp.logical_and(keep_going, k < config.max_line_search_steps)
+
+            slope0 = jnp.dot(st.pg, p)  # directional derivative at t = 0
+
+            def next_t(t, f_t):
+                # Safeguarded quadratic interpolation through f(0), f'(0), f(t):
+                # the minimizer of the fitted parabola, clamped to [t/10, t/2].
+                # An overshot step lands near the right t in one refit instead
+                # of O(log) plain halvings (Breeze's line search interpolates
+                # the same way) — this keeps the terminal iteration cheap.
+                denom = 2.0 * (f_t - st.f - slope0 * t)
+                t_q = -slope0 * t * t / jnp.where(denom != 0.0, denom, 1.0)
+                t_q = jnp.where(
+                    jnp.logical_and(jnp.isfinite(t_q), denom > 0.0), t_q, 0.5 * t
+                )
+                return jnp.clip(t_q, 0.1 * t, 0.5 * t)
+
+            w_try = trial_point(t0)
+            if fused_eval:
+                # One-pass objective (ops/fused.py): value_and_grad costs the
+                # same single X read as value alone, so each trial evaluates
+                # both and an accepted step needs NO extra gradient pass —
+                # the typical iteration touches X exactly once.
+                def ls_cond(carry):
+                    t, f_new, _, _, w_new, k = carry
+                    return ls_should_continue(f_new, w_new, k)
+
+                def ls_body(carry):
+                    t, f_prev, _, _, _, k = carry
+                    t_new = next_t(t, f_prev)
+                    w_new = trial_point(t_new)
+                    f, g, pg = value_and_grads(w_new)
+                    return t_new, f, g, pg, w_new, k + 1
+
+                f1, g1, pg1 = value_and_grads(w_try)
+                t, f2, g2, pg2, w_new, ls_k = lax.while_loop(
+                    ls_cond, ls_body, (t0, f1, g1, pg1, w_try, jnp.int32(0))
+                )
+                new_evals = st.evals + 1 + ls_k
+            else:
+
+                def ls_cond(carry):
+                    t, f_new, w_new, k = carry
+                    return ls_should_continue(f_new, w_new, k)
+
+                def ls_body(carry):
+                    t, f_prev, _, k = carry
+                    t_new = next_t(t, f_prev)
+                    w_new = trial_point(t_new)
+                    return t_new, full_value(w_new), w_new, k + 1
+
+                t, f_new, w_new, ls_k = lax.while_loop(
+                    ls_cond, ls_body, (t0, full_value(w_try), w_try, jnp.int32(0))
+                )
+                f2, g2, pg2 = value_and_grads(w_new)
+                new_evals = st.evals + 2 + ls_k
+
+        with stage(LBFGS_UPDATE):
+            rhs = armijo_rhs(w_new)
+            # Armijo acceptance, EXCEPT the degenerate terminal case: a
+            # fully-backtracked below-f32-resolution step (hopeless) that does
+            # not decrease f satisfies "f_new <= rhs" with f_new == f, and
+            # accepting it spins the solver at max_line_search_steps evals per
+            # iteration with zero progress — that state means converged within
+            # arithmetic precision: stop (reported as LINE_SEARCH_FAILED, the
+            # same terminal state Breeze's FirstOrderMinimizer reaches).
+            # Substantive steps with f_new == f are still accepted: near the
+            # optimum of a large-n sum objective, f sits on an f32 plateau
+            # while real steps keep improving w and the gradient norm.
+            degenerate = jnp.logical_and(hopeless(w_new), f2 >= st.f)
+            ls_ok = jnp.logical_and(
+                jnp.logical_and(f2 <= rhs, jnp.logical_not(degenerate)),
+                jnp.logical_not(jnp.isnan(f2)),
             )
-            return jnp.logical_and(keep_going, k < config.max_line_search_steps)
+            s = w_new - st.w
+            y = g2 - st.g
+            sy = jnp.dot(s, y)
+            store = jnp.logical_and(ls_ok, sy > _CURVATURE_EPS)
+            slot = jnp.mod(st.count, m)
+            S = jnp.where(store, st.S.at[slot].set(s), st.S)
+            Y = jnp.where(store, st.Y.at[slot].set(y), st.Y)
+            rho = jnp.where(store, st.rho.at[slot].set(1.0 / jnp.maximum(sy, _CURVATURE_EPS)), st.rho)
+            count = jnp.where(store, st.count + 1, st.count)
 
-        slope0 = jnp.dot(st.pg, p)  # directional derivative at t = 0
+            g2_norm = jnp.linalg.norm(pg2)
+            converged = grad_converged(g2_norm, st.g0_norm, config.tolerance)
 
-        def next_t(t, f_t):
-            # Safeguarded quadratic interpolation through f(0), f'(0), f(t):
-            # the minimizer of the fitted parabola, clamped to [t/10, t/2].
-            # An overshot step lands near the right t in one refit instead
-            # of O(log) plain halvings (Breeze's line search interpolates
-            # the same way) — this keeps the terminal iteration cheap.
-            denom = 2.0 * (f_t - st.f - slope0 * t)
-            t_q = -slope0 * t * t / jnp.where(denom != 0.0, denom, 1.0)
-            t_q = jnp.where(
-                jnp.logical_and(jnp.isfinite(t_q), denom > 0.0), t_q, 0.5 * t
+            # On line-search failure keep the old iterate and stop.
+            w_out = jnp.where(ls_ok, w_new, st.w)
+            f_out = jnp.where(ls_ok, f2, st.f)
+            g_out = jnp.where(ls_ok, g2, st.g)
+            pg_out = jnp.where(ls_ok, pg2, st.pg)
+            reason = jnp.where(
+                jnp.logical_not(ls_ok),
+                jnp.int32(ConvergenceReason.LINE_SEARCH_FAILED),
+                jnp.where(
+                    converged,
+                    jnp.int32(ConvergenceReason.GRADIENT_CONVERGED),
+                    jnp.int32(ConvergenceReason.MAX_ITERATIONS),
+                ),
             )
-            return jnp.clip(t_q, 0.1 * t, 0.5 * t)
+            done = jnp.logical_or(jnp.logical_not(ls_ok), converged)
 
-        w_try = trial_point(t0)
-        if fused_eval:
-            # One-pass objective (ops/fused.py): value_and_grad costs the
-            # same single X read as value alone, so each trial evaluates
-            # both and an accepted step needs NO extra gradient pass —
-            # the typical iteration touches X exactly once.
-            def ls_cond(carry):
-                t, f_new, _, _, w_new, k = carry
-                return ls_should_continue(f_new, w_new, k)
-
-            def ls_body(carry):
-                t, f_prev, _, _, _, k = carry
-                t_new = next_t(t, f_prev)
-                w_new = trial_point(t_new)
-                f, g, pg = value_and_grads(w_new)
-                return t_new, f, g, pg, w_new, k + 1
-
-            f1, g1, pg1 = value_and_grads(w_try)
-            t, f2, g2, pg2, w_new, ls_k = lax.while_loop(
-                ls_cond, ls_body, (t0, f1, g1, pg1, w_try, jnp.int32(0))
-            )
-            new_evals = st.evals + 1 + ls_k
-        else:
-
-            def ls_cond(carry):
-                t, f_new, w_new, k = carry
-                return ls_should_continue(f_new, w_new, k)
-
-            def ls_body(carry):
-                t, f_prev, _, k = carry
-                t_new = next_t(t, f_prev)
-                w_new = trial_point(t_new)
-                return t_new, full_value(w_new), w_new, k + 1
-
-            t, f_new, w_new, ls_k = lax.while_loop(
-                ls_cond, ls_body, (t0, full_value(w_try), w_try, jnp.int32(0))
-            )
-            f2, g2, pg2 = value_and_grads(w_new)
-            new_evals = st.evals + 2 + ls_k
-        rhs = armijo_rhs(w_new)
-        # Armijo acceptance, EXCEPT the degenerate terminal case: a
-        # fully-backtracked below-f32-resolution step (hopeless) that does
-        # not decrease f satisfies "f_new <= rhs" with f_new == f, and
-        # accepting it spins the solver at max_line_search_steps evals per
-        # iteration with zero progress — that state means converged within
-        # arithmetic precision: stop (reported as LINE_SEARCH_FAILED, the
-        # same terminal state Breeze's FirstOrderMinimizer reaches).
-        # Substantive steps with f_new == f are still accepted: near the
-        # optimum of a large-n sum objective, f sits on an f32 plateau
-        # while real steps keep improving w and the gradient norm.
-        degenerate = jnp.logical_and(hopeless(w_new), f2 >= st.f)
-        ls_ok = jnp.logical_and(
-            jnp.logical_and(f2 <= rhs, jnp.logical_not(degenerate)),
-            jnp.logical_not(jnp.isnan(f2)),
-        )
-        s = w_new - st.w
-        y = g2 - st.g
-        sy = jnp.dot(s, y)
-        store = jnp.logical_and(ls_ok, sy > _CURVATURE_EPS)
-        slot = jnp.mod(st.count, m)
-        S = jnp.where(store, st.S.at[slot].set(s), st.S)
-        Y = jnp.where(store, st.Y.at[slot].set(y), st.Y)
-        rho = jnp.where(store, st.rho.at[slot].set(1.0 / jnp.maximum(sy, _CURVATURE_EPS)), st.rho)
-        count = jnp.where(store, st.count + 1, st.count)
-
-        g2_norm = jnp.linalg.norm(pg2)
-        converged = grad_converged(g2_norm, st.g0_norm, config.tolerance)
-
-        # On line-search failure keep the old iterate and stop.
-        w_out = jnp.where(ls_ok, w_new, st.w)
-        f_out = jnp.where(ls_ok, f2, st.f)
-        g_out = jnp.where(ls_ok, g2, st.g)
-        pg_out = jnp.where(ls_ok, pg2, st.pg)
-        reason = jnp.where(
-            jnp.logical_not(ls_ok),
-            jnp.int32(ConvergenceReason.LINE_SEARCH_FAILED),
-            jnp.where(
-                converged,
-                jnp.int32(ConvergenceReason.GRADIENT_CONVERGED),
-                jnp.int32(ConvergenceReason.MAX_ITERATIONS),
-            ),
-        )
-        done = jnp.logical_or(jnp.logical_not(ls_ok), converged)
-
-        it = st.it + 1
-        loss_hist = st.loss_hist.at[it].set(f_out)
-        gnorm_hist = st.gnorm_hist.at[it].set(jnp.linalg.norm(pg_out))
+            it = st.it + 1
+            loss_hist = st.loss_hist.at[it].set(f_out)
+            gnorm_hist = st.gnorm_hist.at[it].set(jnp.linalg.norm(pg_out))
 
         return _LbfgsState(
             w=w_out,

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from photon_ml_tpu.config import OptimizerConfig
+from photon_ml_tpu.obs.stages import NEWTON_SOLVE, stage
 from photon_ml_tpu.optim.common import (
     ConvergenceReason,
     OptimizationResult,
@@ -151,14 +152,15 @@ def newton_minimize(
             H = objective.hessian_from_margins(st["m"], st["w"])
         else:
             H = objective.hessian(st["w"])
-        if d <= _UNROLL_MAX_D:
-            p = -_solve_spd_small(H + _JITTER * eye, st["g"])
-        else:
-            L = jnp.linalg.cholesky(H + _JITTER * eye)
-            p = -jax.scipy.linalg.cho_solve((L, True), st["g"])
-        # a failed factorization (NaN) falls back to steepest descent
-        bad = jnp.any(jnp.isnan(p))
-        p = jnp.where(bad, -st["g"], p)
+        with stage(NEWTON_SOLVE):
+            if d <= _UNROLL_MAX_D:
+                p = -_solve_spd_small(H + _JITTER * eye, st["g"])
+            else:
+                L = jnp.linalg.cholesky(H + _JITTER * eye)
+                p = -jax.scipy.linalg.cho_solve((L, True), st["g"])
+            # a failed factorization (NaN) falls back to steepest descent
+            bad = jnp.any(jnp.isnan(p))
+            p = jnp.where(bad, -st["g"], p)
         gTp = jnp.dot(st["g"], p)
         # Newton decrement test: the quadratic model promises ~(-gTp)/2 of
         # decrease; below f32 resolution of f, further steps only walk the

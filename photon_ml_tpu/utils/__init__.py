@@ -6,4 +6,4 @@ from photon_ml_tpu.utils.atomic_io import (  # noqa: F401
     atomic_savez,
 )
 from photon_ml_tpu.utils.logging import PhotonLogger, timed  # noqa: F401
-from photon_ml_tpu.utils.profiling import annotate, profile_trace  # noqa: F401
+from photon_ml_tpu.utils.profiling import profile_trace  # noqa: F401

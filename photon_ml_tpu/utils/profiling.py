@@ -37,14 +37,6 @@ def profile_trace(profile_dir: str | None, label: str = "trace") -> Iterator[Non
         yield
 
 
-def annotate(name: str):
-    """Named sub-span inside an active trace (TraceAnnotation passthrough);
-    usable as a context manager around host-side dispatch of a hot op."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
-
-
 # -- stage counters --------------------------------------------------------
 # COMPATIBILITY SHIM over the run-telemetry metrics registry
 # (``photon_ml_tpu.obs.metrics.REGISTRY``): the process-wide wall-second

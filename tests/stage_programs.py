@@ -1,0 +1,193 @@
+"""The three compiled programs whose stage names the tests hold (not a
+test module): the fused outer iteration of a tiny GAME descent (fixed
+effect + one random effect), ``lbfgs_minimize`` over a tile-COO batch, and
+``DistributedTrainer``'s ``_sharded_solve``. Each builder returns
+``(jitted function, positional arguments, keyword arguments)``; the
+arguments are concrete arrays (or, for the sharded solve, shapes on the
+mesh handed in), so a caller can run, lower, or compile deviceless
+(``as_specs`` turns arrays into shapes on a described device).
+
+``tests/test_stages.py`` lowers them on the CPU backend;
+``tests/test_kernels_compile_tpu.py`` compiles them for a described v5e
+(the deviceless compiles live in that one file: one process loads libtpu).
+"""
+
+from __future__ import annotations
+
+import contextlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+# which stage must appear in which program's op names
+DESCENT_COORDINATES = ("fixed", "per-user")
+DESCENT_STAGES = (
+    "coord.fixed", "coord.per-user", "visit.fixed", "visit.re", "re.offsets",
+    "re.solve", "re.score", "glm.objective", "lbfgs.two_loop",
+    "lbfgs.line_search", "lbfgs.update", "newton.solve",
+)
+FIT_STAGES = (
+    "glm.objective", "lbfgs.two_loop", "lbfgs.line_search", "lbfgs.update",
+)
+PROGRAM_STAGES = {
+    "descent": DESCENT_STAGES, "tile_fit": FIT_STAGES, "sharded": FIT_STAGES,
+}
+
+
+def descent_coordinates(n=256, d=5, entities=12, seed=0):
+    from photon_ml_tpu.config import (
+        OptimizationConfig,
+        OptimizerConfig,
+        RegularizationContext,
+    )
+    from photon_ml_tpu.game import (
+        DenseFeatures,
+        FixedEffectCoordinate,
+        RandomEffectCoordinate,
+        bucket_entities,
+        group_by_entity,
+        make_game_batch,
+    )
+    from photon_ml_tpu.types import OptimizerType, RegularizationType, TaskType
+
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, entities, n).astype(np.int32)
+    y = (rng.random(n) < 0.5).astype(np.float32)
+    batch = make_game_batch(
+        y,
+        {"global": DenseFeatures(X=rng.normal(size=(n, d + 1)).astype(np.float32)),
+         "per_user": DenseFeatures(X=rng.normal(size=(n, 3)).astype(np.float32))},
+        id_tags={"user": ids},
+    )
+
+    def opt(kind):
+        return OptimizationConfig(
+            optimizer=OptimizerConfig(
+                optimizer_type=kind, max_iterations=4, tolerance=1e-6
+            ),
+            regularization=RegularizationContext(RegularizationType.L2),
+            regularization_weight=1.0,
+        )
+
+    task = TaskType.LOGISTIC_REGRESSION
+    grouping = group_by_entity(ids, num_entities=entities)
+    fixed, per_user = DESCENT_COORDINATES
+    coordinates = {
+        fixed: FixedEffectCoordinate(
+            coordinate_id=fixed, batch=batch, feature_shard_id="global",
+            config=opt(OptimizerType.LBFGS), task_type=task, intercept_index=d,
+        ),
+        per_user: RandomEffectCoordinate(
+            coordinate_id=per_user, batch=batch, feature_shard_id="per_user",
+            random_effect_type="user",
+            config=opt(OptimizerType.NEWTON_CHOLESKY), grouping=grouping,
+            buckets=bucket_entities(grouping), task_type=task,
+            num_entities=entities,
+        ),
+    }
+    return coordinates, batch, task
+
+
+def descent_program():
+    """The jitted ``fused`` of ``game/descent._build_fused_outer`` with the
+    arguments ``run_outer`` gives it from the zero model."""
+    from photon_ml_tpu.game.descent import _build_fused_outer
+
+    coordinates, batch, _ = descent_coordinates()
+    seq = list(DESCENT_COORDINATES)
+    run_outer = _build_fused_outer(coordinates, seq)
+    fused = next(
+        cell.cell_contents for cell in run_outer.__closure__
+        if getattr(cell.cell_contents, "__name__", "") == "fused"
+    )
+    total = jnp.zeros((batch.labels.shape[0],), jnp.float32)
+    owns = tuple(jnp.zeros_like(total) for _ in seq)
+    statics = tuple(coordinates[c]._fused_visit_parts()[0](None) for c in seq)
+    return fused, (total, owns, statics), {"r": 1}
+
+
+def tile_fit_program():
+    from photon_ml_tpu.config import OptimizerConfig
+    from photon_ml_tpu.ops.batch import SparseBatch
+    from photon_ml_tpu.ops.glm import make_objective
+    from photon_ml_tpu.ops.losses import loss_for_task
+    from photon_ml_tpu.ops.sparse_tiled import tile_sparse_batch
+    from photon_ml_tpu.optim import lbfgs_minimize
+    from photon_ml_tpu.types import TaskType
+
+    rng = np.random.default_rng(0)
+    n, d, k = 1 << 12, 1 << 13, 8
+    batch = SparseBatch(
+        indices=jnp.asarray(rng.integers(0, d, (n, k)), jnp.int32),
+        values=jnp.asarray(rng.normal(size=(n, k)), jnp.float32),
+        labels=jnp.asarray(rng.random(n) < 0.5, jnp.float32),
+        offsets=jnp.zeros((n,), jnp.float32),
+        weights=jnp.ones((n,), jnp.float32), num_features=d,
+    )
+    objective = make_objective(
+        tile_sparse_batch(batch),
+        loss_for_task(TaskType.LOGISTIC_REGRESSION), l2_weight=1.0,
+    )
+    config = OptimizerConfig(max_iterations=3, tolerance=0.0)
+    return lbfgs_minimize, (objective, jnp.zeros((d,), jnp.float32)), {
+        "config": config
+    }
+
+
+def sharded_program(mesh):
+    """``_sharded_solve`` over a bf16 dense batch row-sharded on ``mesh``
+    (axis ``data``), fused kernel on, as shapes."""
+    from photon_ml_tpu.config import OptimizerConfig
+    from photon_ml_tpu.ops.batch import DenseBatch
+    from photon_ml_tpu.ops.losses import loss_for_task
+    from photon_ml_tpu.optim import lbfgs_minimize
+    from photon_ml_tpu.parallel.distributed import _sharded_solve
+    from photon_ml_tpu.types import TaskType
+
+    rows = NamedSharding(mesh, P("data"))
+    rep = NamedSharding(mesh, P())
+    n, d = 1 << 14, 512
+
+    def row(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rows)
+
+    scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)
+    batch = DenseBatch(
+        X=row((n, d), jnp.bfloat16), labels=row((n,)), offsets=row((n,)),
+        weights=row((n,)),
+    )
+    w0 = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=rep)
+    return _sharded_solve, (batch, w0, scalar, scalar, None, None), dict(
+        minimize_fn=lbfgs_minimize,
+        loss=loss_for_task(TaskType.LOGISTIC_REGRESSION),
+        config=OptimizerConfig(max_iterations=3, tolerance=0.0),
+        intercept_index=None, axis_name="data", mesh=mesh, use_l1=False,
+        fused=True, data_hints=(True, False),
+    )
+
+
+def build(name: str, mesh):
+    """The program ``name`` of ``PROGRAM_STAGES``; ``mesh`` is the sharded
+    solve's."""
+    if name == "sharded":
+        return sharded_program(mesh)
+    return {"descent": descent_program, "tile_fit": tile_fit_program}[name]()
+
+
+def without_scopes(monkeypatch) -> None:
+    """``stage`` as a null context, for programs traced from here on: what
+    they were before ``obs/stages.py`` existed. Callers drop JAX's trace
+    caches on both sides (``jax.clear_caches()``), or a program traced
+    under one arm is served to the other."""
+    monkeypatch.setattr(
+        jax, "named_scope", lambda name: contextlib.nullcontext()
+    )
+
+
+def as_specs(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree,
+    )

@@ -12,6 +12,7 @@ one stops compiling. Nothing here executes, so nothing is ``kernel``-marked
 from __future__ import annotations
 
 import importlib.util
+import re
 
 import jax
 import jax.numpy as jnp
@@ -194,3 +195,80 @@ def test_sharded_fused_solve_compiles_for_four_chips(topo):
         intercept_index=None, axis_name="data", mesh=mesh, use_l1=False,
         fused=True, data_hints=(True, False),
     ).compile()
+
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = .*$", re.M)
+
+
+def _instructions(text: str) -> list[tuple[str, bool]]:
+    """(name, is a Pallas custom call) of every instruction, in order."""
+    return [
+        (m.group(1), 'custom_call_target="tpu_custom_call"' in m.group(0))
+        for m in _INSTRUCTION.finditer(text)
+    ]
+
+
+class TestStagesAreMetadataOnly:
+    """``obs/stages.py`` scopes change the ``op_name`` metadata of the
+    compiled TPU program and nothing else: the same instructions under the
+    same names with the scopes as without, so nothing a benchmark measures
+    can move. One exception, by XLA's own naming: a Pallas custom call is
+    named after the innermost scope around it. The tile-COO kernels sit
+    inside ``jit(_tiled_apply_jit)`` and keep their name (the benchmark's
+    ``sparse_tiled_roofline`` finds them by it); the fused dense kernel has
+    no jit of its own, so ``body.<n>`` becomes ``glm.objective.<n>``
+    (``fused_roofline`` matches ``custom-call(``, which stays)."""
+
+    def _compiled(self, topo, program: str) -> str:
+        import stage_programs as programs
+
+        mesh = Mesh(np.array(topo.devices), ("data",))
+        fn, args, kwargs = programs.build(program, mesh)
+        if program != "sharded":  # concrete arrays: shapes on the described chip
+            args = programs.as_specs(
+                args, SingleDeviceSharding(topo.devices[0])
+            )
+        return fn.lower(*args, **kwargs).compile().as_text()
+
+    @pytest.mark.parametrize("program", ["descent", "tile_fit", "sharded"])
+    def test_same_instructions_with_and_without_scopes(
+        self, topo, monkeypatch, program
+    ):
+        import stage_programs as programs
+        from photon_ml_tpu.ops import glm
+
+        # compile the kernels, as a chip would
+        monkeypatch.setattr(st, "_interpret", lambda: False)
+        monkeypatch.setattr(glm, "_interpret_fused", lambda: False)
+        jax.clear_caches()
+        try:
+            scoped = self._compiled(topo, program)
+            jax.clear_caches()
+            programs.without_scopes(monkeypatch)
+            plain = self._compiled(topo, program)
+        finally:
+            jax.clear_caches()
+
+        paths = set(re.findall(r'op_name="([^"]*)"', scoped))
+        segments = {s for path in paths for s in path.split("/")}
+        assert set(programs.PROGRAM_STAGES[program]) <= segments
+        assert not set(programs.PROGRAM_STAGES[program]) & {
+            s for path in re.findall(r'op_name="([^"]*)"', plain)
+            for s in path.split("/")
+        }
+
+        with_, without = _instructions(scoped), _instructions(plain)
+        assert len(with_) == len(without)
+        renamed = [(a, b) for a, b in zip(with_, without) if a != b]
+        kernels = [name for name, is_kernel in with_ if is_kernel]
+        if program == "sharded":
+            assert len(kernels) == 3
+            assert all(re.fullmatch(r"glm\.objective\.\d+", k) for k in kernels)
+            assert all(a[1] and b[1] for a, b in renamed)  # kernels only
+            assert len(renamed) == len(kernels)
+        else:
+            assert renamed == []
+        if program == "tile_fit":
+            assert kernels and all(
+                re.fullmatch(r"_tiled_apply_jit\.\d+", k) for k in kernels
+            )
