@@ -96,8 +96,8 @@ class GLMObjective:
                   from HBM once per evaluation instead of 2-3 times).
       offsets_zero / weights_one — static data hints (detected once at
                   construction): constant-0 offsets / constant-1 weights
-                  let the fused kernels skip those VMEM-padded aux streams
-                  and run larger X tiles.
+                  let the fused kernels skip those per-row streams (4 B
+                  a row each since they are lane-dense, ``ops/fused.py``).
       prior_mean / prior_precision — optional (d,) Gaussian prior for
                   incremental training: the regularizer becomes
                   0.5·λ₂·Σ maskⱼ·precⱼ·(wⱼ−μⱼ)², i.e. a MAP update toward

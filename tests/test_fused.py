@@ -7,6 +7,9 @@ or to bf16-accumulation tolerance (bf16 storage)."""
 
 from __future__ import annotations
 
+import math
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -14,7 +17,7 @@ import pytest
 from photon_ml_tpu.config import OptimizerConfig
 from photon_ml_tpu.normalization import NormalizationType, build_normalization
 from photon_ml_tpu.ops.batch import DenseBatch
-from photon_ml_tpu.ops.fused import supports_fused
+from photon_ml_tpu.ops.fused import fused_hvp, fused_value_grad, supports_fused
 from photon_ml_tpu.ops.glm import make_objective
 from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.optim import lbfgs_minimize, owlqn_minimize
@@ -206,12 +209,56 @@ def test_fused_inside_shard_map_matches_unsharded(rng):
     )
 
 
+def _narrow(shape) -> bool:
+    """More than 8 rows of fewer than 128 elements: what a TPU stores at
+    one 128-lane line (512 B in f32) a row, in HBM and in VMEM."""
+    shape = [1 if s is None or not isinstance(s, int) else s for s in shape]
+    return bool(shape) and math.prod(shape[:-1]) > 8 and shape[-1] < 128
+
+
+@pytest.mark.parametrize("n", [37, 512, 8192 + 300])
+@pytest.mark.parametrize("streams", [0, 1, 2])
+@pytest.mark.parametrize("kernel", ["value_grad", "hvp"])
+def test_fused_operands_are_lane_dense(kernel, streams, n):
+    """Labels, offsets and weights reach the kernel lane-dense: no operand,
+    block or output of the ``pallas_call`` is a narrow column, and no
+    per-row vector is reshaped to (n, 1) on the way (PERF.md §6, PR 26)."""
+    d = 128
+    row = jax.ShapeDtypeStruct((n,), jnp.float32)
+    vec = jax.ShapeDtypeStruct((d,), jnp.float32)
+    X = jax.ShapeDtypeStruct((n, d), jnp.float32)
+    loss = loss_for_task(TaskType.LOGISTIC_REGRESSION)
+
+    def evaluate(X, y, u, *aux):
+        off, wt = (*aux, None, None)[:2]
+        if kernel == "value_grad":
+            return fused_value_grad(X, y, off, wt, u, 0.1, loss=loss)
+        return fused_hvp(X, y, off, wt, u, u, 0.1, 0.1, loss=loss)
+
+    jaxpr = jax.make_jaxpr(evaluate)(X, row, vec, *[row] * streams)
+    calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1  # one custom call an evaluation
+    (call,) = calls
+    assert len(call.invars) == 2 + streams + 2  # X, labels, streams, vector(s), shift(s)
+    shapes = [v.aval.shape for v in (*call.invars, *call.outvars)]
+    shapes += [bm.block_shape for bm in call.params["grid_mapping"].block_mappings]
+    assert [s for s in shapes if _narrow(tuple(s))] == []
+    for eqn in jaxpr.eqns:  # the wrapper around the call, too
+        for v in eqn.outvars:
+            assert not _narrow(v.aval.shape), (eqn.primitive.name, v.aval.shape)
+
+
 def test_supports_fused_gates():
     assert supports_fused(1024, 512, jnp.float32)
     assert supports_fused(1024, 512, jnp.bfloat16)
     assert not supports_fused(1024, 500, jnp.float32)  # lane-unaligned d
     assert not supports_fused(1024, 512, jnp.int8)
     assert not supports_fused(1024, 1 << 17, jnp.float32)  # tile over budget
+    # an f32 tile's full-precision dots are bounded by scoped VMEM: Mosaic
+    # refused d = 5504 while the gate still let it in (deviceless, PR 26)
+    assert supports_fused(1024, 5376, jnp.float32)
+    assert not supports_fused(1024, 5504, jnp.float32)
+    assert supports_fused(1024, 14336, jnp.bfloat16)
 
 
 def test_disable_fused_knob_strict_parse(monkeypatch):

@@ -147,6 +147,25 @@ class TestBf16RungRefusesOnTpu:
             )
 
 
+def _compile_fused_pair(topo, n, d, dtype, aux):
+    """Lower and compile ``fused_value_grad`` and ``fused_hvp`` for one
+    chip, with or without the optional offset/weight streams."""
+    spec = _spec(topo)
+    X, col = spec((n, d), dtype), spec((n,), jnp.float32)
+    off = col if aux else None
+    u, c = spec((d,), jnp.float32), spec((), jnp.float32)
+    jax.jit(
+        lambda X, y, off, wt, u, c: fused.fused_value_grad(
+            X, y, off, wt, u, c, loss=LOSS
+        )
+    ).lower(X, col, off, off, u, c).compile()
+    jax.jit(
+        lambda X, y, off, wt, u, v, c, cv: fused.fused_hvp(
+            X, y, off, wt, u, v, c, cv, loss=LOSS
+        )
+    ).lower(X, col, off, off, u, u, c, c).compile()
+
+
 class TestFusedCompiles:
     """Both fused kernels, both storage dtypes, with and without the
     optional offset/weight streams, at the headline width."""
@@ -154,47 +173,87 @@ class TestFusedCompiles:
     @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
     @pytest.mark.parametrize("aux", [False, True])
     def test_value_grad_and_hvp(self, topo, dtype, aux):
-        spec = _spec(topo)
-        n, d = 1 << 14, 512
-        X, col = spec((n, d), dtype), spec((n,), jnp.float32)
-        off = col if aux else None
-        u, c = spec((d,), jnp.float32), spec((), jnp.float32)
-        jax.jit(
-            lambda X, y, off, wt, u, c: fused.fused_value_grad(
-                X, y, off, wt, u, c, loss=LOSS
-            )
-        ).lower(X, col, off, off, u, c).compile()
-        jax.jit(
-            lambda X, y, off, wt, u, v, c, cv: fused.fused_hvp(
-                X, y, off, wt, u, v, c, cv, loss=LOSS
-            )
-        ).lower(X, col, off, off, u, u, c, c).compile()
+        _compile_fused_pair(topo, 1 << 14, 512, dtype, aux)
+
+    @pytest.mark.parametrize(
+        "n,d,dtype",
+        [
+            (37, 128, jnp.float32),  # one ragged tile, n < 128
+            (5000, 128, jnp.bfloat16),  # n < bn = 8192
+            (100_000, 512, jnp.bfloat16),  # a ragged last tile of 4096
+            (1000, 5376, jnp.float32),  # the widest f32 the gate lets in
+            (1000, 14336, jnp.bfloat16),  # the widest bf16
+        ],
+    )
+    def test_ragged_and_wide_shapes(self, topo, n, d, dtype):
+        """Every shape ``supports_fused`` admits has to lower: the masked
+        last tile, the stream reshaped in VMEM at each tile size, and the
+        f32 tile whose full-precision dots need the most scoped VMEM."""
+        assert fused.supports_fused(n, d, dtype)
+        _compile_fused_pair(topo, n, d, dtype, aux=True)
 
 
-def test_sharded_fused_solve_compiles_for_four_chips(topo):
-    """The four-chip path of ``DistributedTrainer``: the whole L-BFGS loop
-    under ``shard_map`` with the fused kernel inside and one psum per
-    evaluation, compiled for the 2x2 mesh."""
+def _compile_sharded_fused_solve(topo, monkeypatch, n, dtype, data_hints):
+    """``DistributedTrainer``'s program: the whole L-BFGS loop under
+    ``shard_map`` with the fused kernel (compiled, as a chip would) inside
+    and one psum per evaluation, for the 2x2 mesh."""
     from photon_ml_tpu.config import OptimizerConfig
+    from photon_ml_tpu.ops import glm
     from photon_ml_tpu.ops.batch import DenseBatch
     from photon_ml_tpu.optim import lbfgs_minimize
     from photon_ml_tpu.parallel.distributed import _sharded_solve
 
+    monkeypatch.setattr(glm, "_interpret_fused", lambda: False)
     mesh = Mesh(np.array(topo.devices), ("data",))
     rows = NamedSharding(mesh, P("data"))
     rep = NamedSharding(mesh, P())
-    n, d = 1 << 14, 512
-    row = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rows)
+    d = 512
+    row = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt, sharding=rows)
     scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)
-    batch = DenseBatch(X=row((n, d)), labels=row((n,)), offsets=row((n,)), weights=row((n,)))
-    _sharded_solve.lower(
-        batch, jax.ShapeDtypeStruct((d,), jnp.float32, sharding=rep),
-        scalar, scalar, None, None,
-        minimize_fn=lbfgs_minimize, loss=LOSS,
-        config=OptimizerConfig(max_iterations=3, tolerance=0.0),
-        intercept_index=None, axis_name="data", mesh=mesh, use_l1=False,
-        fused=True, data_hints=(True, False),
-    ).compile()
+    batch = DenseBatch(X=row((n, d), dtype), labels=row((n,)),
+                       offsets=row((n,)), weights=row((n,)))
+    # ``lbfgs_minimize``'s cached trace holds whichever kernel mode the
+    # process traced first: drop it before, and again for the tests after
+    jax.clear_caches()
+    try:
+        return _sharded_solve.lower(
+            batch, jax.ShapeDtypeStruct((d,), jnp.float32, sharding=rep),
+            scalar, scalar, None, None,
+            minimize_fn=lbfgs_minimize, loss=LOSS,
+            config=OptimizerConfig(max_iterations=3, tolerance=0.0),
+            intercept_index=None, axis_name="data", mesh=mesh, use_l1=False,
+            fused=True, data_hints=data_hints,
+        ).compile()
+    finally:
+        jax.clear_caches()
+
+
+# The loop body may not lay a per-row vector out as a column again: 512 B a
+# row of HBM, written before every pass (PERF.md §6, PR 22 finding 3).
+_COLUMN_COPY = re.compile(r"= f32\[\d+,1\]\S* copy\(")
+
+
+@pytest.mark.parametrize(
+    "n,dtype,data_hints",
+    [
+        (1 << 14, jnp.float32, (True, False)),
+        # dense_dp4_fit's shape: 2^22 rows x 512 bf16 a chip, all three streams
+        (1 << 24, jnp.bfloat16, (False, False)),
+        # what ISSUE 22 asked for and PR 22 found refused (20.1 GB a chip)
+        (1 << 25, jnp.bfloat16, (False, False)),
+    ],
+    ids=["small", "cell_2p24", "2p25"],
+)
+def test_sharded_fused_solve_compiles_for_four_chips(
+    topo, monkeypatch, n, dtype, data_hints
+):
+    compiled = _compile_sharded_fused_solve(topo, monkeypatch, n, dtype, data_hints)
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert not _COLUMN_COPY.search(text)
+    if n == 1 << 24:
+        # 6.45 GB a chip while the streams were (rows, 1) columns
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = .*$", re.M)
