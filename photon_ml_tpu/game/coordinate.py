@@ -24,7 +24,13 @@ import numpy as np
 from jax.sharding import Mesh
 
 from photon_ml_tpu.config import OptimizationConfig
-from photon_ml_tpu.game.data import EntityBuckets, EntityGrouping, GameBatch
+from photon_ml_tpu.game.data import (
+    EntityBuckets,
+    EntityGrouping,
+    GameBatch,
+    NonzeroMajorSparseFeatures,
+    SparseFeatures,
+)
 from photon_ml_tpu.game.random_effect import (
     RandomEffectTrainingResult,
     prepare_buckets,
@@ -591,7 +597,7 @@ class RandomEffectCoordinate:
         the buckets it now owns."""
         for key in (
             "_prepared_cache", "_fusion_units_cache", "_visit_fn",
-            "_features_cache",
+            "_features_cache", "_score_features_cache",
         ):
             self.__dict__.pop(key, None)
 
@@ -692,6 +698,13 @@ class RandomEffectCoordinate:
             for pb in self._prepared
         )
         feats = self._features()
+        if isinstance(feats, SparseFeatures):
+            # scored nonzero-major inside the program; staged once
+            cached = self.__dict__.get("_score_features_cache")
+            if cached is None:
+                cached = NonzeroMajorSparseFeatures.of(feats)
+                object.__setattr__(self, "_score_features_cache", cached)
+            feats = cached
         ids = self.batch.id_tags[self.random_effect_type]
 
         def make_static(initial):

@@ -15,6 +15,14 @@ TPU-native redesign:
   ``p`` is derived from the reference's ``numFeaturesToSamplesRatioUpperBound``
   knob: p = min(d, ceil(ratio · C)) per bucket. One gather at prepare time,
   zero ragged shapes, and the MXU sees (C, p) instead of (C, d) matmuls.
+- **Per-entity index map for sparse shards** (``sparse_index_map``): a
+  ``SparseFeatures`` random effect always trains each entity in the
+  subspace of the columns its own rows touch, with no switch: the map is
+  built on the host once (one sort of the shard's (entity, column) keys),
+  every nonzero gets its LOCAL index in its entity's sorted support, and
+  widths sit on a ladder of powers of two from 128 so that few (capacity,
+  width) geometries compile. Exact for L2 at zero: a column an entity
+  never saw receives only the penalty and stays at 0.
 - **Random projection**: one ``(d, p)`` Gaussian matrix per coordinate,
   applied to the shard features ONCE at prepare time (a single MXU matmul);
   trained coefficients map back exactly via ``w = P @ w_p`` (scores are
@@ -123,6 +131,114 @@ def entity_top_columns(
     # stable top-p: sort by (-count, index)
     order = np.argsort(-counts, axis=1, kind="stable")[:, :p]  # (k, p)
     return np.sort(order, axis=1)
+
+
+# The narrowest rung of the subspace width ladder: one vreg's lanes.
+SUBSPACE_MIN_WIDTH = 128
+
+
+def width_rungs(widths: np.ndarray, num_features: int) -> np.ndarray:
+    """The ladder rung of each support width: the smallest power of two
+    >= the width, from ``SUBSPACE_MIN_WIDTH`` up, capped at the shard's
+    full width (which need not be a power of two)."""
+    w = np.maximum(np.asarray(widths, np.int64), 1)
+    pow2 = np.int64(1) << np.ceil(np.log2(w)).astype(np.int64)
+    return np.minimum(
+        np.maximum(pow2, SUBSPACE_MIN_WIDTH), int(num_features)
+    ).astype(np.int64)
+
+
+@dataclass(frozen=True)
+class EntityIndexMap:
+    """Per-entity index maps of one sparse shard (parity:
+    ``IndexMapProjection``, one per entity of a random-effect dataset).
+
+    Entity ``e``'s support is ``columns[starts[e]:starts[e + 1]]``, sorted
+    ascending, WITHOUT the intercept column; an intercept, when the shard
+    has one, is in every support and always takes the last slot of the
+    entity's rung (the framework's intercept-last convention, kept in the
+    subspace). ``local`` holds, for every nonzero of every mapped row, its
+    slot in its entity's map (0 beside a zero value: inert).
+    """
+
+    num_features: int
+    intercept_index: int | None
+    starts: np.ndarray  # (E + 1,) offsets into ``columns``
+    columns: np.ndarray  # (sum of supports,) int32 original column ids
+    local: np.ndarray  # (n, nnz) int32 slots
+    widths: np.ndarray  # (E,) support width p_e, the intercept included
+    rungs: np.ndarray  # (E,) the ladder rung P_e >= p_e (0: no mapped row)
+
+    def support(self, entity: int) -> np.ndarray:
+        """Original column ids entity ``entity`` trains on, ascending."""
+        cols = self.columns[self.starts[entity]:self.starts[entity + 1]]
+        if self.intercept_index is None or not self.rungs[entity]:
+            return cols
+        return np.append(cols, np.int32(self.intercept_index))
+
+    def bucket_columns(self, entity_ids: np.ndarray, width: int) -> np.ndarray:
+        """The ``(k, width)`` column map of one bucket: each entity's
+        support from slot 0, the intercept in slot ``width - 1``, and
+        ``num_features`` (one past the last column: gathers read 0 there
+        and scatters drop it) in the slots between."""
+        ents = np.asarray(entity_ids, np.int64)
+        lo = self.starts[ents]
+        n_cols = self.starts[ents + 1] - lo
+        out = np.full((len(ents), int(width)), self.num_features, np.int32)
+        slot = np.arange(int(n_cols.sum())) - np.repeat(
+            np.cumsum(n_cols) - n_cols, n_cols
+        )
+        out[np.repeat(np.arange(len(ents)), n_cols), slot] = self.columns[
+            np.repeat(lo, n_cols) + slot
+        ]
+        if self.intercept_index is not None:
+            out[:, -1] = self.intercept_index
+        return out
+
+
+def sparse_index_map(
+    indices: np.ndarray,  # (n, nnz) column ids, pad (0, 0.0)
+    values: np.ndarray,  # (n, nnz)
+    row_entity: np.ndarray,  # (n,) the entity a row trains, -1 for none
+    num_entities: int,
+    num_features: int,
+    intercept_index: int | None = None,
+) -> EntityIndexMap:
+    """Build every entity's index map with ONE sort of the (entity, column)
+    keys of the mapped rows' nonzeros. Deterministic: a support depends on
+    its entity's rows alone, not on the entity's id or the rows' order."""
+    if intercept_index is not None and intercept_index != num_features - 1:
+        raise ValueError(
+            "a sparse random effect requires the intercept at the last "
+            "column (framework convention)"
+        )
+    idx = np.asarray(indices)
+    ent = np.asarray(row_entity, np.int64)
+    d = int(num_features)
+    live = (np.asarray(values) != 0) & (ent >= 0)[:, None]
+    is_icpt = np.zeros_like(live)
+    if intercept_index is not None:
+        is_icpt = live & (idx == intercept_index)
+        live &= ~is_icpt
+    keys = (ent[:, None] * d + idx)[live]
+    uniq, inverse = np.unique(keys, return_inverse=True)
+    owner = uniq // d
+    starts = np.zeros(num_entities + 1, np.int64)
+    np.cumsum(np.bincount(owner, minlength=num_entities), out=starts[1:])
+    mapped = np.bincount(ent[ent >= 0], minlength=num_entities) > 0
+    widths = np.diff(starts) + (mapped if intercept_index is not None else 0)
+    rungs = np.where(mapped, width_rungs(widths, d), 0)
+    local = np.zeros(idx.shape, np.int32)
+    local[live] = inverse - starts[owner[inverse]]
+    if intercept_index is not None:
+        local[is_icpt] = np.broadcast_to(
+            (rungs - 1)[np.maximum(ent, 0)][:, None], idx.shape
+        )[is_icpt]
+    return EntityIndexMap(
+        num_features=d, intercept_index=intercept_index, starts=starts,
+        columns=(uniq % d).astype(np.int32), local=local,
+        widths=widths.astype(np.int64), rungs=rungs.astype(np.int64),
+    )
 
 
 # Knuth multiplicative hash constants — any fixed mixing function of the

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import time
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Sequence
@@ -34,10 +35,13 @@ from photon_ml_tpu.game.data import (
     EntityBuckets,
     Features,
     DenseFeatures,
+    NonzeroMajorSparseFeatures,
+    SparseFeatures,
+    class_buckets_by_width,
     gather_bucket,
 )
-from photon_ml_tpu.obs.stages import RE_OFFSETS, RE_SOLVE, stage
-from photon_ml_tpu.ops.batch import Batch, DenseBatch
+from photon_ml_tpu.obs.stages import RE_OFFSETS, RE_SOLVE, RE_SUBSPACE, stage
+from photon_ml_tpu.ops.batch import Batch, DenseBatch, LocalSparseBatch
 from photon_ml_tpu.ops.glm import make_objective
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.optim.common import (
@@ -375,7 +379,17 @@ def prepare_buckets(
     (parity: ``numFeaturesToSamplesRatioUpperBound`` + ``IndexMapProjection``,
     SURVEY.md §2.2): each bucket solves at width
     p = min(d, ceil(ratio · capacity)) over each entity's most-frequent
-    columns. Dense features only (sparse rows are already width-bounded).
+    columns. Dense features only.
+
+    A SPARSE shard always trains every entity in the subspace of the
+    columns its own rows touch (plus the intercept), through the same
+    column machinery: ``game/projector.sparse_index_map`` builds the
+    per-entity maps on the host once, the buckets are classed by width
+    rung beside capacity (``class_buckets_by_width``), the bucket tensors
+    hold local indices (``LocalSparseBatch``) and solve at the rung's
+    width, never the shard's. Exact for L2 at zero; a Gaussian prior with
+    means outside an entity's support is not representable there. The
+    returned buckets, not ``buckets``, are the classes that solve.
 
     ``PHOTON_RE_SHARD=1`` with a mesh switches to OWNED-BUCKET prep:
     buckets are staged whole (no entity-lane padding or mesh sharding)
@@ -430,6 +444,14 @@ def prepare_buckets(
         ladder = projection_ladder(
             classes, activity, features.num_features, project_mode,
             re_project_dim(), intercept_index,
+        )
+
+    index_map = None
+    if isinstance(features, SparseFeatures):
+        index_map, buckets = _sparse_subspace(features, buckets, intercept_index)
+        features = SparseFeatures(
+            indices=index_map.local, values=np.asarray(features.values),
+            num_features=features.num_features,
         )
 
     owned_prep = mesh is not None and re_shard_enabled()
@@ -503,10 +525,20 @@ def prepare_buckets(
                 )
             )
             continue
-        static = gather_bucket(features, labels, zeros_off, weights, row_idx)
+        cols = None
+        lane_multiple = n_dev
+        if index_map is not None:
+            cols = index_map.bucket_columns(ent_ids, buckets.widths[bi])
+            if n_dev == 1:
+                lane_multiple = subspace_chunk_lanes(
+                    row_idx.shape[1], cols.shape[1], k
+                )
+        static = gather_bucket(
+            features, labels, zeros_off, weights, row_idx, columns=cols
+        )
         idx = jnp.asarray(np.maximum(row_idx, 0), jnp.int32)
         mask = jnp.asarray((row_idx >= 0).astype(np.float32))
-        columns = None
+        columns = None if cols is None else jnp.asarray(cols)
         hash_S = None
         if spec is not None and isinstance(static, DenseBatch):
             # gather the static features to the class support (the same
@@ -552,8 +584,8 @@ def prepare_buckets(
                     weights=static.weights,
                 )
                 columns = jnp.asarray(cols, jnp.int32)
-        if n_dev > 1:
-            k_pad = _pad_rows(k, n_dev)
+        if lane_multiple > 1:
+            k_pad = _pad_rows(k, lane_multiple)
             if k_pad != k:
                 pad = k_pad - k
                 pad0 = lambda a: jnp.concatenate(
@@ -566,6 +598,7 @@ def prepare_buckets(
                 columns = jnp.concatenate(
                     [columns, jnp.zeros((pad, columns.shape[1]), columns.dtype)]
                 )
+        if n_dev > 1:
             sharding = NamedSharding(mesh, P(axis_name))
             static = jax.tree.map(lambda a: jax.device_put(a, sharding), static)
             idx = jax.device_put(idx, sharding)
@@ -628,6 +661,58 @@ def prepare_buckets(
             ],
         )
     return prepared
+
+
+# Lanes of one (capacity, width) class that are densified and solved at a
+# time: the dense (lanes, C, P) float32 block of a chunk stays under this.
+_SUBSPACE_CHUNK_BYTES = 256 << 20
+
+
+def subspace_chunk_lanes(capacity: int, width: int, lanes: int) -> int:
+    """How many lanes of a (capacity, width) class one chunk of the
+    subspace solve holds: the class in the fewest chunks the budget
+    allows, all of one size. A function of the geometry alone, and the
+    same for ``lanes`` padded to whole chunks, so that ``prepare_buckets``
+    (which pads) and ``_solve_bucket`` agree."""
+    most = max(1, _SUBSPACE_CHUNK_BYTES // (4 * capacity * width))
+    chunks = -(-lanes // most)
+    return -(-lanes // chunks)
+
+
+def _sparse_subspace(
+    features: SparseFeatures, buckets: EntityBuckets,
+    intercept_index: int | None,
+):
+    """The per-entity index maps of a sparse shard over the rows its
+    buckets train on, and the buckets re-classed by width rung. Counted in
+    the registry: ``re_subspace.{entities, support_columns, padded_columns,
+    width_classes}`` and the timer ``re_subspace.build``."""
+    from photon_ml_tpu.game.projector import sparse_index_map
+    from photon_ml_tpu.obs.metrics import REGISTRY
+
+    t0 = time.perf_counter()
+    row_entity = np.full(features.num_rows, -1, np.int64)
+    for ents, rows in zip(buckets.entity_ids, buckets.row_indices):
+        held = rows >= 0
+        row_entity[rows[held]] = np.broadcast_to(ents[:, None], rows.shape)[held]
+    num_entities = 1 + max((int(e.max()) for e in buckets.entity_ids), default=-1)
+    index_map = sparse_index_map(
+        np.asarray(features.indices), np.asarray(features.values), row_entity,
+        num_entities, features.num_features, intercept_index,
+    )
+    buckets = class_buckets_by_width(buckets, index_map.rungs)
+    REGISTRY.timer_add("re_subspace.build", time.perf_counter() - t0)
+    REGISTRY.counter_inc("re_subspace.entities", float(buckets.num_entities))
+    REGISTRY.counter_inc(
+        "re_subspace.support_columns", float(index_map.widths.sum())
+    )
+    REGISTRY.counter_inc(
+        "re_subspace.padded_columns", float(index_map.rungs.sum())
+    )
+    REGISTRY.counter_inc(
+        "re_subspace.width_classes", float(len(set(buckets.widths)))
+    )
+    return index_map, buckets
 
 
 def _plan_bucket_owners(
@@ -787,6 +872,8 @@ def _solve_bucket(
         prior = None
         if mu_e is not None:
             prior = GaussianPrior(means=mu_e, variances=var_e)
+        if isinstance(batch, LocalSparseBatch):
+            batch = batch.densified()
         obj = make_objective(
             batch, loss, l2_weight=l2_weight, norm=norm,
             intercept_index=intercept_index, prior=prior,
@@ -801,10 +888,31 @@ def _solve_bucket(
     # None (static absence) across all lanes
     in_axes = (0, 0, None if prior_mu is None else 0,
                None if prior_var is None else 0)
+    solve_lanes = jax.vmap(solve_one, in_axes=in_axes)
     with stage(RE_SOLVE):
-        return jax.vmap(solve_one, in_axes=in_axes)(
-            bucket_batch, w0, prior_mu, prior_var
+        if not isinstance(bucket_batch, LocalSparseBatch):
+            return solve_lanes(bucket_batch, w0, prior_mu, prior_var)
+        # A subspace class is densified and solved a chunk of lanes at a
+        # time, so the dense (chunk, C, P) block is the only one live. The
+        # optimizer's vector dots at float32: batched over lanes they are
+        # matmuls, which a TPU rounds to bfloat16 by default, and at widths
+        # of hundreds to thousands that leaves line searches failing at
+        # gradients of 5e-3 of their start (my chip runs, PR 27).
+        lanes = w0.shape[0]
+        chunk = subspace_chunk_lanes(
+            bucket_batch.labels.shape[1], bucket_batch.num_features, lanes
         )
+        while lanes % chunk:  # lanes not padded to whole chunks (a mesh)
+            chunk -= 1
+        with jax.default_matmul_precision("highest"):
+            if chunk == lanes:
+                return solve_lanes(bucket_batch, w0, prior_mu, prior_var)
+            split = lambda a: a.reshape((lanes // chunk, chunk) + a.shape[1:])
+            out = jax.lax.map(
+                lambda args: solve_lanes(*args),
+                jax.tree.map(split, (bucket_batch, w0, prior_mu, prior_var)),
+            )
+        return jax.tree.map(lambda a: a.reshape((lanes,) + a.shape[2:]), out)
 
 
 # ---------------------------------------------------------------------------
@@ -831,6 +939,8 @@ def _lane_objective(batch, loss, l2_weight, norm, intercept_index, mu_e, var_e):
     prior = None
     if mu_e is not None:
         prior = GaussianPrior(means=mu_e, variances=var_e)
+    if isinstance(batch, LocalSparseBatch):
+        batch = batch.densified()
     return make_objective(
         batch, loss, l2_weight=l2_weight, norm=norm,
         intercept_index=intercept_index, prior=prior,
@@ -2056,13 +2166,26 @@ def _extract_lanes(M, ids, columns, k, k_pad, d, pad_value=0.0, sharding=None):
     drift between the schedules and break their bitwise-parity contract."""
     if M is None:
         return None
-    rows = M[ids]
-    if k_pad != k:
-        rows = jnp.concatenate(
-            [rows, jnp.full((k_pad - k, d), pad_value, rows.dtype)]
-        )
-    if columns is not None:
-        rows = jnp.take_along_axis(rows, columns, axis=1)
+    if columns is None:
+        rows = M[ids]
+        if k_pad != k:
+            rows = jnp.concatenate(
+                [rows, jnp.full((k_pad - k, d), pad_value, rows.dtype)]
+            )
+    else:
+        with stage(RE_SUBSPACE):
+            # straight to the (k_pad, p) lanes, no (k, d) rows between. A
+            # padded lane reads row E, and a sparse shard's maps hold d
+            # (one past the last column) in the slots between an entity's
+            # support and its rung: out of bounds both, so the pad value
+            lane_ids = ids
+            if k_pad != k:
+                lane_ids = jnp.concatenate(
+                    [ids, jnp.full((k_pad - k,), M.shape[0], ids.dtype)]
+                )
+            rows = M.at[lane_ids[:, None], columns].get(
+                mode="fill", fill_value=pad_value
+            )
     if sharding is not None:
         rows = jax.lax.with_sharding_constraint(rows, sharding)
     return rows
@@ -2075,12 +2198,14 @@ def _scatter_lanes(W, V, ids, columns, w_b, var_b, k):
     if columns is not None:
         cols = columns[:k]
         # coefficients outside an entity's subspace are 0 (reference:
-        # projected training never touches them)
-        W = W.at[ids].set(0.0)
-        W = W.at[ids[:, None], cols].set(w_b[:k])
-        if V is not None:
-            V = V.at[ids].set(0.0)
-            V = V.at[ids[:, None], cols].set(var_b[:k])
+        # projected training never touches them); the slots a sparse
+        # shard's maps pad with d are dropped
+        with stage(RE_SUBSPACE):
+            W = W.at[ids].set(0.0)
+            W = W.at[ids[:, None], cols].set(w_b[:k], mode="drop")
+            if V is not None:
+                V = V.at[ids].set(0.0)
+                V = V.at[ids[:, None], cols].set(var_b[:k], mode="drop")
     else:
         W = W.at[ids].set(w_b[:k])
         if V is not None:
@@ -2377,4 +2502,8 @@ def random_effect_scores(features: Features, entity_ids: Array, W: Array) -> Arr
     """
     if isinstance(features, DenseFeatures):
         return jnp.einsum("nd,nd->n", features.X, W[entity_ids])
+    if isinstance(features, NonzeroMajorSparseFeatures):
+        return jnp.sum(
+            features.values * W[entity_ids[None, :], features.indices], axis=0
+        )
     return jnp.sum(features.values * W[entity_ids[:, None], features.indices], axis=-1)

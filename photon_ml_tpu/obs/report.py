@@ -378,6 +378,23 @@ def summarize_run(path: str, records: list[dict] | None = None) -> dict:
             "wait_s": timer_s("re_combine.wait_s"),
             "mode": run_start.get("knobs", {}).get("re_combine"),
         }
+    # per-entity index maps of sparse random effects (re_subspace.*,
+    # game/random_effect.prepare_buckets): entities mapped, the columns
+    # their rows touch, the columns of the width rungs they are solved at,
+    # the width classes, and the host build's seconds. Present only on
+    # runs that prepared a sparse random effect.
+    if "re_subspace.entities" in counters or \
+            "re_subspace.entities" in base_counters:
+        support = counter_v("re_subspace.support_columns")
+        padded = counter_v("re_subspace.padded_columns")
+        out["re_subspace"] = {
+            "entities": counter_v("re_subspace.entities"),
+            "support_columns": support,
+            "padded_columns": padded,
+            "width_pad_ratio": padded / support if support > 0 else None,
+            "width_classes": counter_v("re_subspace.width_classes"),
+            "build_s": timer_s("re_subspace.build"),
+        }
     # per-entity feature projection (re_project.*, game/projector): the
     # mean solved-width ratio and the per-lane bytes the subspace solves
     # shaved off the full-width schedule, plus the ladder narrative
@@ -640,6 +657,16 @@ def format_summary(s: dict) -> str:
                 f"{_fmt_s(rc['wait_s'])}"
             )
         lines.append(seg)
+    sub = s.get("re_subspace") or {}
+    if sub.get("entities"):
+        pad = sub.get("width_pad_ratio")
+        lines.append(
+            f"  re-subspace: {_fmt_qty(sub['entities'])} entities, "
+            f"{_fmt_qty(sub['support_columns'])} support columns"
+            + (f" solved at {pad:.2f}x" if pad else "")
+            + f" in {int(sub['width_classes'])} width classes, "
+            f"index maps built in {_fmt_s(sub['build_s'])}"
+        )
     prj = s.get("re_project") or {}
     if prj.get("mean_ratio") is not None or prj.get("classes"):
         ratio = prj.get("mean_ratio")

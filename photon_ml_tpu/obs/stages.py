@@ -32,6 +32,8 @@ VISIT_FIXED = "visit.fixed"  # the whole fixed-effect visit (game/coordinate)
 VISIT_RE = "visit.re"  # the whole random-effect visit (game/coordinate)
 RE_OFFSETS = "re.offsets"  # residual offsets gathered into bucket slots
 RE_SOLVE = "re.solve"  # everything bucket-shaped: lanes, solve, scatter
+RE_SUBSPACE = "re.subspace"  # lanes through a sparse shard's column maps
+RE_SPARSE_PASS = "re.sparse_pass"  # a subspace lane's passes over its rows
 RE_SCORE = "re.score"  # the W[ids] gathers and (n, d_e) work of scoring
 GLM_OBJECTIVE = "glm.objective"  # every pass over the data (ops/glm)
 LBFGS_TWO_LOOP = "lbfgs.two_loop"  # the search direction (optim/lbfgs)
@@ -45,7 +47,7 @@ COORD_PREFIX = "coord."  # + the coordinate id: which coordinate's visit
 # cache with the names it was compiled with: after a scope moves with no
 # instruction changing, a warm cache would keep serving the old names to
 # every profile. Raise this when a site or a name of this module changes.
-VERSION = 1
+VERSION = 2
 
 _NOT_SEGMENT = re.compile(r"[^A-Za-z0-9_.\-]")
 

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from photon_ml_tpu.obs.stages import RE_SPARSE_PASS, stage
+
 Array = jnp.ndarray
 
 
@@ -115,7 +117,73 @@ class SparseBatch:
         return jnp.zeros((self.num_features,), dtype=contrib.dtype).at[self.indices].add(contrib)
 
 
-Batch = DenseBatch | SparseBatch
+@partial(jax.tree_util.register_dataclass, data_fields=["X", "labels", "offsets", "weights"], meta_fields=[])
+@dataclass(frozen=True)
+class SubspaceDenseBatch(DenseBatch):
+    """One entity's rows densified over its own support (``LocalSparseBatch.
+    densified``). The contractions are float32 multiply-reduces, not MXU
+    matmuls: a matrix-vector product leaves the MXU idle anyway, a TPU's
+    default float32 matmul rounds its operands to bfloat16, and the
+    multiply-reduce reads X at the HBM's rate and is exact."""
+
+    # a trial value costs one read of X and a value-and-gradient two, so
+    # evaluating both at every trial point (two reads when the first trial
+    # is accepted, as it mostly is) beats value-then-gradient (three)
+    one_pass_value_grad = True
+
+    def matvec(self, w: Array) -> Array:
+        with stage(RE_SPARSE_PASS):
+            return jnp.sum(self.X * w, axis=-1)
+
+    def rmatvec(self, r: Array) -> Array:
+        with stage(RE_SPARSE_PASS):
+            return jnp.sum(self.X * r[:, None], axis=0)
+
+    def rmatvec_sq(self, r: Array) -> Array:
+        with stage(RE_SPARSE_PASS):
+            return jnp.sum(self.X * self.X * r[:, None], axis=0)
+
+
+@partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["indices", "values", "labels", "offsets", "weights"],
+    meta_fields=["num_features"],
+)
+@dataclass(frozen=True)
+class LocalSparseBatch:
+    """One entity's padded sparse rows in ITS OWN column subspace (a
+    random effect over a sparse shard, ``game/projector.sparse_index_map``).
+
+    indices: (C * nnz,) int32 slots in [0, num_features), row-major: row
+    r's nonzeros are [r * nnz, (r + 1) * nnz); values likewise, 0.0 in
+    padding. Flat, so that a bucket of lanes is stored (k, C * nnz) with
+    the long axis minor: (k, C, nnz) costs 512 B a slot on a TPU, this 8.
+    labels/offsets/weights: (C,). num_features: the lane's width P.
+    """
+
+    indices: Array
+    values: Array
+    labels: Array
+    offsets: Array
+    weights: Array
+    num_features: int = field(metadata=dict(static=True))
+
+    def densified(self) -> SubspaceDenseBatch:
+        """The (C, P) matrix of this lane: one scatter a solve, after which
+        every objective pass reads it at the HBM's rate (XLA's elementwise
+        gathers run at 1.5e8 elements a second on a v5e, PERF.md)."""
+        capacity = self.labels.shape[-1]
+        slots = self.indices.shape[-1]
+        rows = jnp.arange(slots, dtype=jnp.int32) // (slots // capacity)
+        with stage(RE_SPARSE_PASS):
+            X = jnp.zeros((capacity, self.num_features), self.values.dtype)
+            X = X.at[rows, self.indices].add(self.values)
+        return SubspaceDenseBatch(
+            X=X, labels=self.labels, offsets=self.offsets, weights=self.weights
+        )
+
+
+Batch = DenseBatch | SparseBatch | LocalSparseBatch
 
 
 def dense_batch_from_numpy(

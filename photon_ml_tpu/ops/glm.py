@@ -159,10 +159,15 @@ class GLMObjective:
         fused dense kernel makes value_and_grad cost one X read anyway, or
         (b) the tile-COO sparse kernels make the typical one-trial
         iteration cheaper that way (margins+grad = 2 kernel passes beats
-        margins-trial + margins+grad = 3)."""
+        margins-trial + margins+grad = 3), or (c) the batch says the same
+        of itself (``SubspaceDenseBatch``: 2 reads of X against 3)."""
         from photon_ml_tpu.ops.sparse_tiled import TiledSparseBatch
 
-        return self.fused or isinstance(self.batch, TiledSparseBatch)
+        return (
+            self.fused
+            or isinstance(self.batch, TiledSparseBatch)
+            or getattr(self.batch, "one_pass_value_grad", False)
+        )
 
     def value(self, w: Array) -> Array:
         with stage(GLM_OBJECTIVE):

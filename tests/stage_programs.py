@@ -31,12 +31,22 @@ DESCENT_STAGES = (
 FIT_STAGES = (
     "glm.objective", "lbfgs.two_loop", "lbfgs.line_search", "lbfgs.update",
 )
+# a descent whose random effect is over a SPARSE shard: L-BFGS lanes in
+# per-entity subspaces
+SPARSE_DESCENT_STAGES = (
+    "coord.fixed", "coord.per-user", "visit.fixed", "visit.re", "re.offsets",
+    "re.solve", "re.subspace", "re.sparse_pass", "re.score", "glm.objective",
+    "lbfgs.two_loop", "lbfgs.line_search", "lbfgs.update",
+)
 PROGRAM_STAGES = {
     "descent": DESCENT_STAGES, "tile_fit": FIT_STAGES, "sharded": FIT_STAGES,
+    "sparse_descent": SPARSE_DESCENT_STAGES,
 }
 
 
-def descent_coordinates(n=256, d=5, entities=12, seed=0):
+def descent_coordinates(n=256, d=5, entities=12, seed=0, sparse=False):
+    """``sparse``: the random effect's shard is 300 columns wide with 4
+    nonzeros a row, and its entities are trained by L-BFGS."""
     from photon_ml_tpu.config import (
         OptimizationConfig,
         OptimizerConfig,
@@ -46,6 +56,7 @@ def descent_coordinates(n=256, d=5, entities=12, seed=0):
         DenseFeatures,
         FixedEffectCoordinate,
         RandomEffectCoordinate,
+        SparseFeatures,
         bucket_entities,
         group_by_entity,
         make_game_batch,
@@ -55,12 +66,21 @@ def descent_coordinates(n=256, d=5, entities=12, seed=0):
     rng = np.random.default_rng(seed)
     ids = rng.integers(0, entities, n).astype(np.int32)
     y = (rng.random(n) < 0.5).astype(np.float32)
+    if sparse:
+        per_user = SparseFeatures(
+            indices=jnp.asarray(rng.integers(0, 300, (n, 4)), jnp.int32),
+            values=jnp.asarray(rng.uniform(0.2, 1.0, (n, 4)), jnp.float32),
+            num_features=300,
+        )
+    else:
+        per_user = DenseFeatures(X=rng.normal(size=(n, 3)).astype(np.float32))
     batch = make_game_batch(
         y,
         {"global": DenseFeatures(X=rng.normal(size=(n, d + 1)).astype(np.float32)),
-         "per_user": DenseFeatures(X=rng.normal(size=(n, 3)).astype(np.float32))},
+         "per_user": per_user},
         id_tags={"user": ids},
     )
+    re_optimizer = OptimizerType.LBFGS if sparse else OptimizerType.NEWTON_CHOLESKY
 
     def opt(kind):
         return OptimizationConfig(
@@ -82,7 +102,7 @@ def descent_coordinates(n=256, d=5, entities=12, seed=0):
         per_user: RandomEffectCoordinate(
             coordinate_id=per_user, batch=batch, feature_shard_id="per_user",
             random_effect_type="user",
-            config=opt(OptimizerType.NEWTON_CHOLESKY), grouping=grouping,
+            config=opt(re_optimizer), grouping=grouping,
             buckets=bucket_entities(grouping), task_type=task,
             num_entities=entities,
         ),
@@ -90,12 +110,12 @@ def descent_coordinates(n=256, d=5, entities=12, seed=0):
     return coordinates, batch, task
 
 
-def descent_program():
+def descent_program(sparse=False):
     """The jitted ``fused`` of ``game/descent._build_fused_outer`` with the
     arguments ``run_outer`` gives it from the zero model."""
     from photon_ml_tpu.game.descent import _build_fused_outer
 
-    coordinates, batch, _ = descent_coordinates()
+    coordinates, batch, _ = descent_coordinates(sparse=sparse)
     seq = list(DESCENT_COORDINATES)
     run_outer = _build_fused_outer(coordinates, seq)
     fused = next(
@@ -173,6 +193,8 @@ def build(name: str, mesh):
     solve's."""
     if name == "sharded":
         return sharded_program(mesh)
+    if name == "sparse_descent":
+        return descent_program(sparse=True)
     return {"descent": descent_program, "tile_fit": tile_fit_program}[name]()
 
 

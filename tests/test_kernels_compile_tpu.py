@@ -289,7 +289,9 @@ class TestStagesAreMetadataOnly:
             )
         return fn.lower(*args, **kwargs).compile().as_text()
 
-    @pytest.mark.parametrize("program", ["descent", "tile_fit", "sharded"])
+    @pytest.mark.parametrize(
+        "program", ["descent", "tile_fit", "sharded", "sparse_descent"]
+    )
     def test_same_instructions_with_and_without_scopes(
         self, topo, monkeypatch, program
     ):
@@ -331,3 +333,101 @@ class TestStagesAreMetadataOnly:
             assert kernels and all(
                 re.fullmatch(r"_tiled_apply_jit\.\d+", k) for k in kernels
             )
+
+
+# the (capacity, width, entities) classes of ``glmix_sparse_re``'s per-user
+# effect at its 1/8 cut, as ``prepare_buckets`` builds them from the
+# configuration's data (counted on the CPU, PR 27): 17,312 users,
+# 16,238,476 support columns
+SPARSE_RE_CLASSES = (
+    (64, 256, 98), (64, 512, 4847), (64, 1024, 1523), (128, 1024, 5147),
+    (128, 2048, 20), (256, 1024, 281), (256, 2048, 3163), (512, 2048, 1048),
+    (512, 4096, 529), (1024, 4096, 515), (2048, 4096, 71), (2048, 8192, 48),
+    (4096, 8192, 20), (8192, 8192, 2),
+)
+
+
+def test_sparse_random_effect_visit_fits_the_chip_at_the_benchmarks_cut(topo):
+    """The fused visit of the benchmark's wide sparse per-user effect
+    (2,500,033 rows, 17,312 users, 16,384 columns, 16 nonzeros a row, the
+    bucket classes above) compiles for a v5e, and what it needs leaves room
+    for the rest of the descent: the whole fused outer iteration asked for
+    13.4 GB of the chip's 16.9 GB when this test was written (7.9 GB of it
+    scratch), the visit alone for less. A change that densifies more lanes
+    at a time, or brings an (n, 16) or (k, d) temporary back, shows here
+    without a chip."""
+    from photon_ml_tpu.config import (
+        OptimizationConfig,
+        OptimizerConfig,
+        RegularizationContext,
+    )
+    from photon_ml_tpu.game import RandomEffectCoordinate, SparseFeatures
+    from photon_ml_tpu.game.data import (
+        EntityBuckets,
+        EntityGrouping,
+        GameBatch,
+        NonzeroMajorSparseFeatures,
+    )
+    from photon_ml_tpu.game.random_effect import (
+        PreparedBucket,
+        subspace_chunk_lanes,
+    )
+    from photon_ml_tpu.ops.batch import LocalSparseBatch
+    from photon_ml_tpu.types import RegularizationType
+
+    n, entities, d, nnz = 2_500_033, 17_312, 16_384, 16
+    spec = _spec(topo)
+    f32, i32 = jnp.float32, jnp.int32
+    prepared = []
+    for capacity, width, k in SPARSE_RE_CLASSES:
+        chunk = subspace_chunk_lanes(capacity, width, k)
+        lanes = -(-k // chunk) * chunk  # padded to whole chunks
+        prepared.append(PreparedBucket(
+            entity_ids=np.zeros(k, np.int64), ids=spec((k,), i32),
+            static=LocalSparseBatch(
+                indices=spec((lanes, capacity * nnz), i32),
+                values=spec((lanes, capacity * nnz), f32),
+                labels=spec((lanes, capacity), f32),
+                offsets=spec((lanes, capacity), f32),
+                weights=spec((lanes, capacity), f32), num_features=width,
+            ),
+            row_idx=spec((lanes, capacity), i32), mask=spec((lanes, capacity), f32),
+            num_real=k, columns=spec((lanes, width), i32),
+        ))
+    shard = SparseFeatures(
+        indices=spec((n, nnz), i32), values=spec((n, nnz), f32), num_features=d
+    )
+    coordinate = RandomEffectCoordinate(
+        coordinate_id="per_userId",
+        batch=GameBatch(
+            labels=spec((n,), f32), offsets=spec((n,), f32),
+            weights=spec((n,), f32), features={"per_userId": shard},
+            id_tags={"userId": spec((n,), i32)},
+        ),
+        feature_shard_id="per_userId", random_effect_type="userId",
+        config=OptimizationConfig(
+            optimizer=OptimizerConfig(max_iterations=50, tolerance=3e-3),
+            regularization=RegularizationContext(RegularizationType.L2),
+            regularization_weight=1.0,
+        ),
+        grouping=EntityGrouping(entities, np.zeros(0), np.zeros(0), []),
+        buckets=EntityBuckets((), [], []), task_type=TaskType.LOGISTIC_REGRESSION,
+        num_entities=entities,
+    )
+    object.__setattr__(coordinate, "_prepared_cache", prepared)
+    visit = coordinate._build_visit_fn()
+    compiled = visit.lower(
+        spec((n,), f32), spec((n,), f32), spec((entities, d), f32),
+        tuple((pb.static, pb.row_idx, pb.mask, pb.ids, pb.columns) for pb in prepared),
+        NonzeroMajorSparseFeatures(
+            indices=spec((nnz, n), i32), values=spec((nnz, n), f32), num_features=d
+        ),
+        spec((n,), i32),
+    ).compile()
+    need = compiled.memory_analysis()
+    total = (need.argument_size_in_bytes + need.output_size_in_bytes
+             + need.temp_size_in_bytes)
+    assert total < 9.0e9, (
+        need.argument_size_in_bytes, need.output_size_in_bytes,
+        need.temp_size_in_bytes,
+    )
