@@ -395,6 +395,21 @@ def summarize_run(path: str, records: list[dict] | None = None) -> dict:
             "width_classes": counter_v("re_subspace.width_classes"),
             "build_s": timer_s("re_subspace.build"),
         }
+    # tile-COO layout builds (tile_layout.*, ops/sparse_tiled.
+    # tile_sparse_batch): the stored nonzeros each build left to the
+    # kernels' streams (the tail) and those it moved into the dense head of
+    # popular columns, with the head's width. Present only on runs that
+    # built a tile-COO layout.
+    if "tile_layout.tail_nonzeros" in counters or \
+            "tile_layout.tail_nonzeros" in base_counters:
+        head = counter_v("tile_layout.head_nonzeros")
+        tail = counter_v("tile_layout.tail_nonzeros")
+        out["tile_layout"] = {
+            "head_columns": counter_v("tile_layout.head_columns"),
+            "head_nonzeros": head,
+            "tail_nonzeros": tail,
+            "head_nonzero_share": head / (head + tail) if head + tail > 0 else None,
+        }
     # per-entity feature projection (re_project.*, game/projector): the
     # mean solved-width ratio and the per-lane bytes the subspace solves
     # shaved off the full-width schedule, plus the ladder narrative
@@ -666,6 +681,14 @@ def format_summary(s: dict) -> str:
             + (f" solved at {pad:.2f}x" if pad else "")
             + f" in {int(sub['width_classes'])} width classes, "
             f"index maps built in {_fmt_s(sub['build_s'])}"
+        )
+    til = s.get("tile_layout") or {}
+    if til.get("head_nonzero_share") is not None:
+        lines.append(
+            f"  tile-layout: {_fmt_qty(til['tail_nonzeros'])} nonzeros in the "
+            f"tile-COO tail, {_fmt_qty(til['head_nonzeros'])} "
+            f"({100.0 * til['head_nonzero_share']:.1f}%) in a dense head of "
+            f"{int(til['head_columns'])} columns"
         )
     prj = s.get("re_project") or {}
     if prj.get("mean_ratio") is not None or prj.get("classes"):

@@ -113,6 +113,9 @@ class SparseBatch:
         return jnp.zeros((self.num_features,), dtype=contrib.dtype).at[self.indices].add(contrib)
 
     def rmatvec_sq(self, r: Array) -> Array:
+        """Squares every stored value: equal to the matrix's (X ⊙ X)ᵀ r
+        unless a row names a column twice (such slots add, so the entry is
+        their sum; ``densify`` and the tile-COO build square that)."""
         contrib = self.values * self.values * r[:, None]
         return jnp.zeros((self.num_features,), dtype=contrib.dtype).at[self.indices].add(contrib)
 
@@ -249,7 +252,17 @@ def optimize_batch_layout(
     beat everything at modest d), otherwise re-block genuinely
     high-dimensional sparse data into the tile-COO Pallas layout
     (``ops/sparse_tiled.py`` — ~9x over the XLA gather/scatter path), and
-    leave everything else unchanged."""
+    leave everything else unchanged.
+
+    Between the two lies the hybrid the tile-COO build chooses when it is
+    handed the budget, as here (``sparse_tiled.tile_sparse_batch``): columns
+    filled in more than ``sparse_tiled.HEAD_MIN_FILL`` of the rows, where a
+    stored nonzero costs the kernels more than a dense float32 column costs
+    in bytes, become a dense head, in blocks of 128 by descending count, as
+    far as head, tail, the tail's relayout copy and the input batch fit
+    ``hbm_budget_bytes``; the kernels then scatter the tail only. A matrix
+    without popular columns is tiled whole, as before. Either way a row's
+    repeated draws of a column are merged into one entry."""
     out = maybe_densify(batch, hbm_budget_bytes, dtype)
     if isinstance(out, SparseBatch):
         from photon_ml_tpu.ops import tile_cache
@@ -258,7 +271,9 @@ def optimize_batch_layout(
         if supports_tiling(out):
             # process-wide layout cache: identical sparsity structure
             # (re-ingested data, repeated fits) never re-packs
-            return tile_cache.tiled_layout_for(out)
+            return tile_cache.tiled_layout_for(
+                out, hbm_budget_bytes=float(hbm_budget_bytes)
+            )
     return out
 
 

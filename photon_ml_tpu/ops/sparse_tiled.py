@@ -58,6 +58,27 @@ Design — write-slab-major tile-COO, built ONCE at ingest:
   layout — write=row/read=col and write=col/read=row respectively — the
   one-time ingest cost buys both directions their batched write slab.
 
+- A DENSE HEAD beside the tile-COO tail (PR 28). The kernels cost ~37 ns a
+  128-nonzero group whatever the group holds, so a column that is filled
+  in more than ``HEAD_MIN_FILL`` of the rows is cheaper read as a dense
+  float32 column at the HBM's rate. A caller that wants this passes the
+  device bytes it may pin (``tile_sparse_batch(hbm_budget_bytes=...)``;
+  ``ops/batch.optimize_batch_layout`` does, for the resident single-device
+  layout): the build then counts every column's stored nonzeros and moves
+  the most popular ones, in blocks of 128 lanes, out of the streams into
+  one float32 matrix that both directions sweep (see ``_head_columns``).
+  The rule reads the input and the budget and nothing else: no knob. A
+  matrix without popular columns gets no head and the layout it always
+  had; streamed chunks, per-device shards and feature-range slices pass no
+  budget (their pytrees must share one structure) and are never asked.
+
+- ONE ENTRY A (ROW, COLUMN). A padded-sparse row may name a column twice,
+  and such entries add. The build merges them (``_merge_repeats``: the
+  first draw keeps the sum), so head and tail alike hold the MATRIX's
+  entry and ``rmatvec_sq`` squares that entry everywhere, as
+  ``ops/batch.densify`` does. A batch without repeats, as real rows are,
+  is laid out value for value as before.
+
 ``TiledSparseBatch`` is a drop-in ``Batch``: ``GLMObjective`` consumes it
 through ``matvec``/``rmatvec``/``rmatvec_sq`` unchanged. On the CPU
 backend the kernels run in Pallas interpreter mode, so CPU tests trace the
@@ -104,6 +125,18 @@ SEGMENTS_PER_DMA = 4  # segments per DMA step (128 groups = 16K nnz per fetch)
 # padding-neutral default — retune per workload like the two constants
 # above (must divide GROUPS_PER_STEP).
 GROUPS_PER_RUN = 2  # groups per slab RUN: all read ONE source slab
+# The dense head's rule (see _head_columns): a column joins the head when it
+# stores a nonzero in at least this share of the rows. Origin: a dense
+# float32 column costs 8 B a row a pass (read once in each direction) at
+# the ~750 GB/s XLA's multiply-reduce sweeps reach (756 over
+# f32[5000066,128] in ml20m_fixed_only, 788 over the head itself), 10.7 ps
+# a row; a stored nonzero costs the kernels 0.63 ns a pass
+# (PERF_LEDGER.jsonl, PR 27, rcv1_fit: fit.objective_s_per_fit 1.424 s /
+# 23 passes / 98,900,254 nonzeros); break-even is their ratio. Widths 256,
+# 384 (the rule's own) and 512 forced on rcv1_fit read fit_s within 11%
+# (PERF.md, PR 28): the threshold needs to be right to a factor of two.
+HEAD_MIN_FILL = 0.017
+HEAD_LANES = 128  # the head grows by whole lane blocks of columns
 # Software pipeline across SEGMENTS (the r6 addendum's recorded next
 # kernel lever): phase 1 (VPU gather/select/product) and phase 2 (scatter
 # staging + MXU contraction) of one segment touch disjoint scratch, so the
@@ -989,7 +1022,8 @@ class _TileChunk:
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=["chunks", "labels", "offsets", "weights"],
+    data_fields=["chunks", "labels", "offsets", "weights", "head_X",
+                 "head_cols"],
     meta_fields=["num_features", "num_rows_real", "n_pad_total", "d_pad_total",
                  "fe_range"],
 )
@@ -998,7 +1032,15 @@ class TiledSparseBatch:
     """Drop-in ``Batch`` whose margins/gradient run the tile-COO Pallas
     kernels. ``labels``/``offsets``/``weights`` are (n,) with the ORIGINAL
     row indexing. Build with ``tile_sparse_batch``; shapes beyond one
-    kernel's VMEM bounds arrive as multiple row/col chunks."""
+    kernel's VMEM bounds arrive as multiple row/col chunks.
+
+    ``head_X``/``head_cols`` are the dense head (module docstring): the
+    (n, H) float32 matrix of the H most popular columns, H a multiple of
+    128, and their (H,) int32 column ids; the chunks then hold the other
+    columns' nonzeros only. None (both) where the build was not asked for
+    a head or found none. Head and chunks hold one entry a (row, column),
+    the sum of a row's draws of that column, so ``rmatvec_sq`` squares the
+    matrix's entry everywhere, as ``ops/batch.densify`` has it."""
 
     chunks: tuple  # tuple[_TileChunk, ...]
     labels: Array
@@ -1014,6 +1056,8 @@ class TiledSparseBatch:
     # ride every jit key that takes the batch — the dtype-ladder
     # discipline: a re-plan invalidates by key, never by luck.
     fe_range: tuple | None = field(default=None, metadata=dict(static=True))
+    head_X: Array | None = None
+    head_cols: Array | None = None
 
     @property
     def num_rows(self) -> int:
@@ -1030,7 +1074,12 @@ class TiledSparseBatch:
                 + c.matvec_part(w_pad),
                 (c.row_start,),
             )
-        return m[: self.num_rows]
+        m = m[: self.num_rows]
+        if self.head_X is not None:
+            # float32 multiply-reduces, not matmuls (which a TPU rounds to
+            # bfloat16 by default): exact, and read at the HBM's rate
+            m = m + jnp.sum(self.head_X * w[self.head_cols], axis=1)
+        return m
 
     def _rmatvec(self, r: Array, squared: bool) -> Array:
         n = self.num_rows
@@ -1043,7 +1092,11 @@ class TiledSparseBatch:
                 + c.rmatvec_part(r_pad, squared),
                 (c.col_start,),
             )
-        return g[: self.num_features]
+        g = g[: self.num_features]
+        if self.head_X is not None:
+            X = self.head_X * self.head_X if squared else self.head_X
+            g = g.at[self.head_cols].add(jnp.sum(X * r[:, None], axis=0))
+        return g
 
     def rmatvec(self, r: Array) -> Array:
         return self._rmatvec(r, squared=False)
@@ -1081,26 +1134,160 @@ def _build_chunk(
     )
 
 
+# bytes one packed slot of the tile-COO streams holds, by storage rung
+_SLOT_BYTES = {"f32": 12, "bf16": 6, "int8": 4}
+
+
+def _head_columns(counts: np.ndarray, num_rows: int,
+                  free_bytes: float) -> np.ndarray | None:
+    """The dense head's columns for a matrix whose column c stores
+    ``counts[c]`` nonzeros in ``num_rows`` rows: int64 ids by descending
+    count, a multiple of ``HEAD_LANES`` of them, or None for no head.
+
+    Columns join by whole lane blocks, most popular first, while a block
+    is filled in at least ``HEAD_MIN_FILL`` of its rows x lanes: the rule
+    knows fills only, not sizes. The head then shrinks until what the
+    layout will hold at a fit fits ``free_bytes``: the head's float32, and
+    for every nonzero left to the tail a packed slot in each direction,
+    a quarter of padding on top (the padding is known only once built;
+    1.09-1.22 measured, PERF.md, PR 28) and the same again for the copy
+    into which XLA relayouts the streams once a fit (PERF.md finding 4).
+    A head that would hold under an eighth of the nonzeros is not worth
+    its second code path: None."""
+    blocks = len(counts) // HEAD_LANES
+    total = int(counts.sum())
+    if not blocks or not total:
+        return None
+    order = np.argsort(-counts, kind="stable")[: blocks * HEAD_LANES]
+    block_nnz = counts[order].reshape(blocks, HEAD_LANES).sum(axis=1)
+    # counts descend, so the blocks that pay are a prefix
+    width = int(np.count_nonzero(
+        block_nnz >= HEAD_MIN_FILL * HEAD_LANES * num_rows
+    ))
+    head_nnz = np.concatenate([[0], np.cumsum(block_nnz)])
+    # a tail nonzero: two slots, a quarter of padding, the relayout's copy
+    tail_bytes = 2 * _SLOT_BYTES[kernel_dtype()] * 1.25 * 2
+    while width and (
+        4.0 * num_rows * width * HEAD_LANES
+        + tail_bytes * (total - head_nnz[width]) > free_bytes
+    ):
+        width -= 1
+    if head_nnz[width] * 8 < total:
+        return None
+    return order[: width * HEAD_LANES]
+
+
+@functools.partial(jax.jit, static_argnames=("num_features",))
+def _head_matrix(indices, values, head_cols, num_features):
+    """The (n, H) float32 matrix of the head's columns: one scatter-add of
+    the padded-sparse rows on the device (a row's repeated draws of a
+    column add; entries of other columns fall out of bounds and drop)."""
+    n, k = indices.shape
+    width = head_cols.shape[0]
+    slot = jnp.full((num_features,), width, jnp.int32).at[head_cols].set(
+        jnp.arange(width, dtype=jnp.int32)
+    )
+    rows = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None], (n, k))
+    return jnp.zeros((n, width), jnp.float32).at[rows, slot[indices]].add(
+        values.astype(jnp.float32), mode="drop"
+    )
+
+
+def _merge_repeats(indices: np.ndarray, values: np.ndarray,
+                   live: np.ndarray, num_features: int) -> np.ndarray:
+    """``values`` with every row's repeated draws of a column merged: of
+    the ``live`` slots of a row that name one column, the first holds their
+    sum and the others 0. Slots that are not ``live`` (padding, columns
+    that went to the head) are left alone. ``values`` itself comes back
+    where no row repeats a column, as in real rows."""
+    n, k = indices.shape
+    slot = np.arange(k, dtype=np.int32)
+    # dead slots get keys no column has, distinct within their row
+    keyed = np.where(live, indices.astype(np.int32), num_features + slot)
+    by_col = np.sort(keyed, axis=1)
+    rep = np.flatnonzero((by_col[:, 1:] == by_col[:, :-1]).any(axis=1))
+    if not len(rep):
+        return values
+    # rows that repeat a column: sort (column, slot) pairs, sum each run of
+    # one column and hand the sum to the run's earliest slot
+    pairs = np.sort(keyed[rep].astype(np.int64) * k + slot, axis=1)
+    col, at = pairs // k, pairs % k
+    starts = np.ones(col.shape, bool)
+    starts[:, 1:] = col[:, 1:] != col[:, :-1]
+    starts = np.flatnonzero(starts.reshape(-1))
+    sums = np.add.reduceat(
+        np.take_along_axis(values[rep], at, axis=1).reshape(-1), starts
+    )
+    merged = np.zeros(len(rep) * k, values.dtype)
+    merged[at.reshape(-1)[starts] + starts // k * k] = sums
+    out = values.copy()
+    out[rep] = merged.reshape(len(rep), k)
+    return out
+
+
 def tile_sparse_batch(batch, keep_empty_chunks: bool = False,
-                      fe_range: tuple | None = None) -> TiledSparseBatch:
+                      fe_range: tuple | None = None,
+                      hbm_budget_bytes: float | None = None,
+                      ) -> TiledSparseBatch:
     """Build a ``TiledSparseBatch`` from a padded-sparse ``SparseBatch``
     (host-side one-time transform; zero-valued padding slots are dropped
-    before tiling). Shapes beyond the per-kernel VMEM bounds are split
-    into row/col chunks along SLAB-aligned boundaries.
+    before tiling, a row's repeated draws of a column merged into one
+    entry). Shapes beyond the per-kernel VMEM bounds are split into
+    row/col chunks along SLAB-aligned boundaries.
 
     ``keep_empty_chunks`` keeps nonzero-free chunks instead of skipping
     them — the per-device-shard builder needs every shard to carry the
     SAME chunk structure so the stacked pytrees line up under shard_map.
+
+    ``hbm_budget_bytes`` asks for the resident single-device layout: the
+    device bytes the caller may pin, the input batch included. With it the
+    popular columns move into a dense head where ``_head_columns`` finds
+    one, and the chunks tile the tail alone; without it (None) every
+    nonzero is tiled. It cannot be combined with ``keep_empty_chunks`` or
+    ``fe_range``: shards and streamed chunks must share one pytree
+    structure, and a head under a feature range is not built.
+    ``tile_layout.{head_columns, head_nonzeros, tail_nonzeros}`` in the
+    registry count where every build put the input's stored nonzeros (the
+    streams hold ``tail_nonzeros`` less the repeats merged away).
     """
+    from photon_ml_tpu.obs.metrics import REGISTRY
+
     indices = np.asarray(batch.indices)
-    values = np.asarray(batch.values)
+    values = np.asarray(batch.values).astype(np.float32)
     n, k = indices.shape
     d = batch.num_features
-    rows = np.repeat(np.arange(n, dtype=np.int64), k)
-    cols = indices.reshape(-1).astype(np.int64)
-    vals = values.reshape(-1).astype(np.float32)
-    keep = vals != 0.0
-    rows, cols, vals = rows[keep], cols[keep], vals[keep]
+    live = values != 0.0
+    stored = int(np.count_nonzero(live))
+    head_cols = head_X = None
+    if hbm_budget_bytes is not None:
+        if keep_empty_chunks or fe_range is not None:
+            raise ValueError(
+                "hbm_budget_bytes selects the resident single-device layout; "
+                "it cannot go with keep_empty_chunks or fe_range"
+            )
+        head_cols = _head_columns(
+            np.bincount(indices[live], minlength=d), n,
+            hbm_budget_bytes - indices.nbytes - values.nbytes,
+        )
+    if head_cols is not None:
+        in_tail = np.ones(d, bool)
+        in_tail[head_cols] = False
+        live &= in_tail[indices]
+        head_cols = jnp.asarray(head_cols, jnp.int32)
+        head_X = _head_matrix(
+            jnp.asarray(batch.indices), jnp.asarray(batch.values), head_cols, d
+        )
+    tail = int(np.count_nonzero(live))
+    REGISTRY.counter_inc(
+        "tile_layout.head_columns", 0.0 if head_cols is None else len(head_cols)
+    )
+    REGISTRY.counter_inc("tile_layout.head_nonzeros", float(stored - tail))
+    REGISTRY.counter_inc("tile_layout.tail_nonzeros", float(tail))
+    values = _merge_repeats(indices, values, live, d)
+    keep = (live & (values != 0.0)).reshape(-1)
+    rows = np.repeat(np.arange(n, dtype=np.int64), k)[keep]
+    cols = indices.reshape(-1).astype(np.int64)[keep]
+    vals = values.reshape(-1)[keep]
 
     n_pad_total = -(-n // SLAB) * SLAB
     d_pad_total = -(-d // SLAB) * SLAB
@@ -1138,6 +1325,8 @@ def tile_sparse_batch(batch, keep_empty_chunks: bool = False,
         n_pad_total=n_pad_total,
         d_pad_total=d_pad_total,
         fe_range=fe_range,
+        head_X=head_X,
+        head_cols=head_cols,
     )
 
 

@@ -19,7 +19,8 @@ shape, feature count) and the tuned constants are the module-level
 GROUPS_PER_STEP / SEGMENTS_PER_DMA / GROUPS_PER_RUN / SEGMENT_BATCHED /
 PIPELINE_SEGMENTS knobs read at call time — a retune invalidates by key,
 never by luck.
-Only the layout (the ``_TileChunk`` tuple + pad metadata) is cached;
+Only the layout (the ``_TileChunk`` tuple, the dense head beside it and
+the pad metadata) is cached;
 labels/offsets/weights always come from the caller's batch, so GAME
 coordinate visits that only swap residual offsets hit the cache by
 construction. Executable reuse is the other half: ``_tiled_apply`` keys
@@ -75,6 +76,8 @@ def tuned_constants() -> tuple:
         st.SEGMENTS_PER_DMA,
         st.GROUPS_PER_RUN,
         bool(st.SEGMENT_BATCHED),
+        # the dense head's rule decides which nonzeros the streams hold
+        st.HEAD_MIN_FILL,
         # the pipeline schedule does not reshape the layout, but it keys
         # here anyway so a toggle can NEVER reuse a stale entry (the same
         # never-by-luck rule as the stream-shaping constants; the cost of
@@ -171,17 +174,21 @@ def clear() -> None:
         _stats["misses"] = 0
 
 
-def _chunks_nbytes(chunks) -> int:
+def _layout_nbytes(tb) -> int:
+    """Device bytes a built layout pins: the chunks' streams and the head."""
     total = 0
-    for c in chunks:
+    for c in tb.chunks:
         for arrays in (c.m_arrays, c.g_arrays):
             total += sum(int(a.nbytes) for a in arrays)
+    if tb.head_X is not None:
+        total += int(tb.head_X.nbytes) + int(tb.head_cols.nbytes)
     return total
 
 
 def tiled_layout_for(batch, keep_empty_chunks: bool = False,
                      fingerprint: tuple | None = None,
-                     fe_range: tuple | None = None):
+                     fe_range: tuple | None = None,
+                     hbm_budget_bytes: float | None = None):
     """A ``TiledSparseBatch`` for ``batch``, reusing the cached layout when
     an identical sparsity structure was already packed under the current
     tuned constants. The returned batch ALWAYS carries the caller's
@@ -191,14 +198,18 @@ def tiled_layout_for(batch, keep_empty_chunks: bool = False,
     is the feature-range identity ((pid, lo, hi, P)) of a range-sliced
     batch under PHOTON_FE_SHARD — it joins the cache key (a re-plan or
     P change invalidates by key, never by luck) and rides the built
-    batch as its static ``fe_range`` meta field."""
+    batch as its static ``fe_range`` meta field. ``hbm_budget_bytes``
+    asks for the resident single-device layout, which may carry a dense
+    head inside that budget (``tile_sparse_batch``), so it joins the key
+    too."""
     import photon_ml_tpu.ops.sparse_tiled as st
 
     if fingerprint is None:
         fingerprint = sparsity_fingerprint(
             batch.indices, batch.values, batch.num_features
         )
-    key = (fingerprint, bool(keep_empty_chunks), fe_range, tuned_constants())
+    key = (fingerprint, bool(keep_empty_chunks), fe_range, hbm_budget_bytes,
+           tuned_constants())
     with _lock:
         cached = _entries.get(key)
         if cached is not None:
@@ -207,7 +218,7 @@ def tiled_layout_for(batch, keep_empty_chunks: bool = False,
     if cached is not None:
         # only the layout is cached — never the first caller's per-row
         # arrays (which a stored full batch would pin alive)
-        chunks, num_rows_real, n_pad_total, d_pad_total = cached
+        chunks, head_X, head_cols, num_rows_real, n_pad_total, d_pad_total = cached
         return st.TiledSparseBatch(
             chunks=chunks,
             labels=batch.labels,
@@ -218,19 +229,20 @@ def tiled_layout_for(batch, keep_empty_chunks: bool = False,
             n_pad_total=n_pad_total,
             d_pad_total=d_pad_total,
             fe_range=fe_range,
+            head_X=head_X,
+            head_cols=head_cols,
         )
     # build OUTSIDE the lock (packing is the expensive part) through the
     # module attribute, so instrumented/monkeypatched builders see misses
-    # (and keep the plain one-arg call shape they expect)
-    if fe_range is not None:
-        tb = st.tile_sparse_batch(
-            batch, keep_empty_chunks=keep_empty_chunks, fe_range=fe_range
-        )
-    elif keep_empty_chunks:
-        tb = st.tile_sparse_batch(batch, keep_empty_chunks=True)
-    else:
-        tb = st.tile_sparse_batch(batch)
-    nbytes = _chunks_nbytes(tb.chunks)
+    # (and keep the plain one-arg call shape they expect where nothing
+    # else was asked for)
+    asked = dict(keep_empty_chunks=keep_empty_chunks, fe_range=fe_range,
+                 hbm_budget_bytes=hbm_budget_bytes)
+    tb = st.tile_sparse_batch(
+        batch,
+        **{k: v for k, v in asked.items() if v is not None and v is not False},
+    )
+    nbytes = _layout_nbytes(tb)
     global _total_bytes
     with _lock:
         _stats["misses"] += 1
@@ -247,7 +259,8 @@ def tiled_layout_for(batch, keep_empty_chunks: bool = False,
             if prev is not None:  # concurrent miss already inserted this key
                 _total_bytes -= prev
             _entries[key] = (
-                tb.chunks, tb.num_rows_real, tb.n_pad_total, tb.d_pad_total
+                tb.chunks, tb.head_X, tb.head_cols,
+                tb.num_rows_real, tb.n_pad_total, tb.d_pad_total,
             )
             _entry_bytes[key] = nbytes
             _total_bytes += nbytes

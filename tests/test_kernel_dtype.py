@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import photon_ml_tpu.ops.sparse_tiled as st
 from photon_ml_tpu.config import OptimizerConfig
 from photon_ml_tpu.ops import prefetch
-from photon_ml_tpu.ops.batch import SparseBatch
+from photon_ml_tpu.ops.batch import SparseBatch, densify
 from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.ops.streaming import (
     StreamingGLMObjective,
@@ -435,7 +435,8 @@ class TestTiledLadderParity:
         ref = (
             np.asarray(batch.matvec(w)),
             np.asarray(batch.rmatvec(r)),
-            np.asarray(batch.rmatvec_sq(r)),
+            # of the matrix's entry: the build merges a row's repeated draws
+            np.asarray(densify(batch).rmatvec_sq(r)),
         )
         for a, b in zip(got, ref):
             scale = np.max(np.abs(b)) or 1.0

@@ -134,6 +134,37 @@ class TestTileCooCompiles:
         )
 
 
+class TestDenseHeadCompiles:
+    """The dense head beside the tile-COO tail (PR 28) at ``rcv1_fit``'s
+    shape and the width its rule picks there: each direction must stay one
+    float32 multiply-reduce over the head as it is stored. A matmul would
+    round to bfloat16 on a TPU, and a relayout copy of 2 GB a pass is what
+    ``ops/fused`` paid before PR 26."""
+
+    @pytest.mark.parametrize("method", ["matvec", "rmatvec", "rmatvec_sq"])
+    def test_head_sweep_is_one_float32_fusion_without_a_copy(self, topo, method):
+        spec = _spec(topo)
+        n, d, width = 1_354_798, 47_236, 384
+        row = spec((n,), jnp.float32)
+        tb = st.TiledSparseBatch(
+            chunks=(), labels=row, offsets=row, weights=row,
+            num_features=d, num_rows_real=n,
+            n_pad_total=-(-n // st.SLAB) * st.SLAB,
+            d_pad_total=-(-d // st.SLAB) * st.SLAB,
+            head_X=spec((n, width), jnp.float32),
+            head_cols=spec((width,), jnp.int32),
+        )
+        arg = spec((d,), jnp.float32) if method == "matvec" else row
+        compiled = jax.jit(
+            lambda tb, x: getattr(tb, method)(x)
+        ).lower(tb, arg).compile()
+        text = compiled.as_text()
+        assert len(re.findall(r"= f32\[[0-9]+\]\S* fusion\(%tb_head_X", text)) == 1
+        assert "convolution(" not in text and " dot(" not in text
+        assert "bf16[" not in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 class TestBf16RungRefusesOnTpu:
     def test_bf16_layout_build_raises_naming_itself(self, monkeypatch):
         """Mosaic cannot slice the 3-stream int16 block for the per-step

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 # builder invariants, cache bookkeeping) stay unmarked so `-m 'not
 # kernel'` keeps that cheap coverage.
 
-from photon_ml_tpu.ops.batch import SparseBatch
+from photon_ml_tpu.ops.batch import SparseBatch, densify
 from photon_ml_tpu.ops.sparse_tiled import (
     SLAB,
     TiledSparseBatch,
@@ -57,9 +57,11 @@ class TestTiledSparse:
             np.asarray(tiled.rmatvec(r)), np.asarray(batch.rmatvec(r)),
             rtol=1e-5, atol=1e-5,
         )
+        # the Hessian diagonal squares the matrix's ENTRY: the build merges
+        # a row's repeated draws of a column (SparseBatch squares each)
         np.testing.assert_allclose(
-            np.asarray(tiled.rmatvec_sq(r)), np.asarray(batch.rmatvec_sq(r)),
-            rtol=1e-5, atol=1e-5,
+            np.asarray(tiled.rmatvec_sq(r)),
+            np.asarray(densify(batch).rmatvec_sq(r)), rtol=1e-5, atol=1e-5,
         )
 
     def test_non_slab_aligned_shapes(self, rng):
@@ -143,8 +145,6 @@ class TestTiledSparse:
         assert supports_tiling(big)
         small = _sparse_problem(rng, n=200, d=512, k=4)
         assert not supports_tiling(small)
-        from photon_ml_tpu.ops.batch import densify
-
         assert not supports_tiling(densify(small))
 
     def test_supports_tiling_rejects_all_zero_values(self, rng):
@@ -234,9 +234,9 @@ def test_game_fixed_effect_rides_tiled_kernel(rng):
     built = {"n": 0}
     orig = st.tile_sparse_batch
 
-    def counting(b):
+    def counting(b, **kw):
         built["n"] += 1
-        return orig(b)
+        return orig(b, **kw)
 
     # a tiny HBM budget forces the layout decision past densify into tiling
     orig_budget = ops_streaming.device_hbm_budget_bytes
@@ -399,10 +399,10 @@ class TestSlabRunBatching:
             np.asarray(tb.rmatvec(r)), np.asarray(batch.rmatvec(r)),
             rtol=rtol, atol=atol,
         )
-        if squared:
+        if squared:  # of the matrix's entry: repeated draws are merged
             np.testing.assert_allclose(
-                np.asarray(tb.rmatvec_sq(r)), np.asarray(batch.rmatvec_sq(r)),
-                rtol=rtol, atol=atol,
+                np.asarray(tb.rmatvec_sq(r)),
+                np.asarray(densify(batch).rmatvec_sq(r)), rtol=rtol, atol=atol,
             )
         return tb
 
@@ -1047,3 +1047,430 @@ class TestTopologyKeyedCaches:
         s = tile_cache.stats()
         assert (s["hits"], s["misses"]) == (0, 2)
         tile_cache.clear()
+
+
+def _zipf_problem(rng, n=1100, d=4608, k=12, repeats=True):
+    """Rows whose columns follow Zipf(1.0) popularity ranks sent through an
+    affine permutation, as ``benchmark/datagen.sparse_glm_rows`` draws them:
+    a row may draw a column twice, and such entries add. ``repeats=False``
+    blanks a row's later draws of a column, as real rows have none."""
+    u = rng.uniform(size=(n, k))
+    rank = np.clip(np.floor(np.exp(u * np.log(d + 1.0)) - 1.0), 0, d - 1)
+    idx = ((rank.astype(np.int64) * 3571 + 17) % d).astype(np.int32)
+    val = rng.uniform(0.05, 1.0, size=(n, k)).astype(np.float32)
+    val[rng.uniform(size=(n, k)) < 0.05] = 0.0  # ingest padding slots
+    if not repeats:
+        val[_later_draws(idx)] = 0.0
+    return SparseBatch(
+        indices=jnp.asarray(idx), values=jnp.asarray(val),
+        labels=jnp.asarray((rng.uniform(size=n) < 0.5).astype(np.float32)),
+        offsets=jnp.zeros((n,), jnp.float32),
+        weights=jnp.ones((n,), jnp.float32), num_features=d,
+    )
+
+
+def _later_draws(idx):
+    """Slots that name a column an earlier slot of their row names."""
+    same = idx[:, :, None] == idx[:, None, :]
+    return np.tril(same, k=-1).any(axis=2)
+
+
+def _without_repeats(batch):
+    val = np.asarray(batch.values).copy()
+    val[_later_draws(np.asarray(batch.indices))] = 0.0
+    import dataclasses
+
+    return dataclasses.replace(batch, values=jnp.asarray(val))
+
+
+def _parents_chunk(batch):
+    """The one chunk the builder made before the dense head existed: every
+    stored nonzero through the (untouched) tile-COO layout builder."""
+    import photon_ml_tpu.ops.sparse_tiled as st
+
+    idx, val = np.asarray(batch.indices), np.asarray(batch.values)
+    n, k = idx.shape
+    keep = val.reshape(-1) != 0.0
+    return st._build_chunk(
+        np.repeat(np.arange(n, dtype=np.int64), k)[keep],
+        idx.reshape(-1).astype(np.int64)[keep], val.reshape(-1)[keep],
+        row_start=0, col_start=0,
+        n_pad=-(-n // SLAB) * SLAB, d_pad=-(-batch.num_features // SLAB) * SLAB,
+    )
+
+
+def _assert_chunks_equal(chunk, parent):
+    for side in ("m_arrays", "g_arrays"):
+        for a, b in zip(getattr(chunk, side), getattr(parent, side)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _stored_values(arrays):
+    """Nonzero values a direction's packed f32 stream holds."""
+    return np.count_nonzero(np.asarray(arrays[0])[:, 2, :])
+
+
+class TestDenseHead:
+    """The dense head beside the tile-COO tail (PR 28): popular columns
+    leave the kernels' streams for one float32 matrix, by a rule that reads
+    the column counts and the budget and nothing else; and one entry a
+    (row, column) in head and tail alike."""
+
+    @pytest.mark.kernel
+    def test_zipf_matrix_builds_a_head_and_matches_sparse_batch(self, rng):
+        batch = _zipf_problem(rng)
+        tiled = tile_sparse_batch(batch, hbm_budget_bytes=8e9)
+        n, d = batch.num_rows, batch.num_features
+        assert tiled.head_X.shape == (n, 128) and tiled.head_X.dtype == jnp.float32
+        assert tiled.head_cols.shape == (128,) and tiled.head_cols.dtype == jnp.int32
+        head_cols = np.asarray(tiled.head_cols)
+        idx, val = np.asarray(batch.indices), np.asarray(batch.values)
+        in_head = np.isin(idx, head_cols) & (val != 0.0)
+        in_tail = ~np.isin(idx, head_cols) & (val != 0.0)
+        assert in_head.sum() > np.count_nonzero(val) // 3  # log(129) / log(d + 1)
+        # every (row, column) of the tail lies in the streams once
+        rows = np.repeat(np.arange(n)[:, None], idx.shape[1], axis=1)
+        entries = len(set(zip(rows[in_tail], idx[in_tail])))
+        assert entries < in_tail.sum()  # the tail has repeated draws too
+        for c in tiled.chunks:
+            assert _stored_values(c.m_arrays) == entries
+            assert _stored_values(c.g_arrays) == entries
+        # a row's repeated draws of a head column add into one entry
+        slot = np.searchsorted(np.sort(head_cols), idx[in_head])
+        expect = np.zeros((n, 128), np.float64)
+        np.add.at(expect, (rows[in_head], slot), val[in_head])
+        by_id = np.argsort(head_cols)
+        assert (np.count_nonzero(expect) < in_head.sum())  # duplicates exist
+        np.testing.assert_allclose(
+            np.asarray(tiled.head_X)[:, by_id], expect, rtol=1e-6
+        )
+
+        w = jnp.asarray(rng.normal(size=d).astype(np.float32))
+        r = jnp.asarray(rng.normal(size=n).astype(np.float32))
+        np.testing.assert_allclose(
+            np.asarray(tiled.matvec(w)), np.asarray(batch.matvec(w)),
+            rtol=1e-5, atol=1e-5,
+        )
+        np.testing.assert_allclose(
+            np.asarray(tiled.rmatvec(r)), np.asarray(batch.rmatvec(r)),
+            rtol=1e-5, atol=1e-4,
+        )
+        # the Hessian diagonal squares the matrix's ENTRY, the sum of a
+        # row's draws of a column, in head and tail alike
+        np.testing.assert_allclose(
+            np.asarray(tiled.rmatvec_sq(r)),
+            np.asarray(densify(batch).rmatvec_sq(r)),
+            rtol=1e-5, atol=1e-4,
+        )
+        # and where no row repeats a column that is SparseBatch's answer
+        plain = _without_repeats(batch)
+        np.testing.assert_allclose(
+            np.asarray(tile_sparse_batch(plain, hbm_budget_bytes=8e9).rmatvec_sq(r)),
+            np.asarray(plain.rmatvec_sq(r)), rtol=1e-5, atol=1e-4,
+        )
+
+    @pytest.mark.kernel
+    @pytest.mark.parametrize(
+        "kwargs",
+        [dict(), dict(hbm_budget_bytes=8e9), dict(keep_empty_chunks=True),
+         dict(fe_range=(0, 0, 4608, 1))],
+    )
+    def test_repeated_draws_merge_into_one_entry_in_every_build(self, rng, kwargs):
+        """One contract whatever the builder and whatever the column: the
+        layout holds the sum of a row's draws of a column once."""
+        batch = _zipf_problem(rng)
+        tiled = tile_sparse_batch(batch, **kwargs)
+        assert (tiled.head_X is not None) == ("hbm_budget_bytes" in kwargs)
+        dense = densify(batch)
+        w = jnp.asarray(rng.normal(size=batch.num_features).astype(np.float32))
+        r = jnp.asarray(rng.normal(size=batch.num_rows).astype(np.float32))
+        for method, arg in (("matvec", w), ("rmatvec", r), ("rmatvec_sq", r)):
+            np.testing.assert_allclose(
+                np.asarray(getattr(tiled, method)(arg)),
+                np.asarray(getattr(dense, method)(arg)), rtol=1e-5, atol=1e-4,
+            )
+
+    def test_merge_keeps_the_first_draw_and_leaves_plain_rows_alone(self):
+        import photon_ml_tpu.ops.sparse_tiled as st
+
+        idx = np.array([[7, 3, 7, 0, 7], [1, 2, 3, 4, 5], [0, 0, 9, 9, 0]], np.int32)
+        val = np.array([[1, 2, 4, 0, 8], [1, 2, 3, 4, 5], [0, 2, -3, 3, 5]], np.float32)
+        live = val != 0.0
+        out = st._merge_repeats(idx, val, live, 16)
+        np.testing.assert_array_equal(
+            out, [[13, 2, 0, 0, 0], [1, 2, 3, 4, 5], [0, 7, 0, 0, 0]]
+        )
+        # slots that are not live (the head's columns) neither merge nor move
+        live[0, 0] = False
+        np.testing.assert_array_equal(
+            st._merge_repeats(idx, val, live, 16)[0], [1, 2, 12, 0, 0]
+        )
+        # no repeated draw: the very array comes back
+        plain = np.array([[1.0, 2.0], [3.0, 0.0]], np.float32)
+        cols = np.array([[4, 5], [4, 0]], np.int32)
+        assert st._merge_repeats(cols, plain, plain != 0.0, 16) is plain
+
+    @pytest.mark.kernel
+    @pytest.mark.parametrize("popular", [False, True])
+    def test_matrix_without_repeats_or_head_is_the_parents_layout_bit_for_bit(
+        self, rng, popular
+    ):
+        """Uniform columns find no head; Zipf columns without a budget are
+        not asked. Either way, rows that repeat no column are laid out
+        value for value as the builder laid them out before PR 28."""
+        batch = (
+            _zipf_problem(rng, repeats=False) if popular
+            else _without_repeats(_sparse_problem(rng))
+        )
+        tiled = tile_sparse_batch(
+            batch, **({} if popular else dict(hbm_budget_bytes=8e9))
+        )
+        assert tiled.head_X is None and tiled.head_cols is None
+        parent = _parents_chunk(batch)
+        (chunk,) = tiled.chunks
+        _assert_chunks_equal(chunk, parent)
+        import dataclasses
+
+        before = dataclasses.replace(tiled, chunks=(parent,))
+        w = jnp.asarray(rng.normal(size=batch.num_features).astype(np.float32))
+        r = jnp.asarray(rng.normal(size=batch.num_rows).astype(np.float32))
+        np.testing.assert_array_equal(
+            np.asarray(tiled.matvec(w)), np.asarray(before.matvec(w))
+        )
+        np.testing.assert_array_equal(
+            np.asarray(tiled.rmatvec(r)), np.asarray(before.rmatvec(r))
+        )
+
+    @staticmethod
+    def _zipf_counts(d=47236, nnz=98_900_254):
+        ranks = np.arange(d)
+        share = np.log((ranks + 2.0) / (ranks + 1.0)) / np.log(d + 1.0)
+        counts = np.zeros(d, np.int64)
+        counts[(ranks * 3571 + 17) % d] = np.round(share * nnz)
+        return counts
+
+    @pytest.mark.parametrize("rows", [1_354_798, 2 * 1_354_798, 1 << 20])
+    def test_rule_takes_the_most_popular_columns_in_lane_blocks(self, rows):
+        import photon_ml_tpu.ops.sparse_tiled as st
+
+        # the rule knows fills, not sizes: the same matrix at another
+        # number of rows (a streamed chunk's 2^20 too) gets the same head
+        counts = self._zipf_counts(nnz=73 * rows)
+        head = st._head_columns(counts, rows, 1e12)
+        assert len(head) == 384
+        np.testing.assert_array_equal(
+            head, np.argsort(-counts, kind="stable")[: len(head)]
+        )
+        # the last block is filled to the threshold, the next one is not
+        by_count = np.sort(counts)[::-1]
+        last = by_count[len(head) - 128: len(head)].sum()
+        following = by_count[len(head): len(head) + 128].sum()
+        assert last >= st.HEAD_MIN_FILL * 128 * rows > following
+
+    @pytest.mark.parametrize("fits_blocks", [2, 1])
+    def test_budget_cap_shrinks_the_head_to_what_fits_at_the_edge(self, fits_blocks):
+        import photon_ml_tpu.ops.sparse_tiled as st
+
+        counts = self._zipf_counts()
+        rows = 1_354_798
+        free = st._head_columns(counts, rows, 1e12)
+        by_count = np.sort(counts)[::-1]
+        # what ``fits_blocks`` blocks of head pin beside their tail: the
+        # tail's two slots a nonzero, a quarter of padding, and the
+        # relayout's copy of the streams
+        width = 128 * fits_blocks
+        need = 4.0 * rows * width + 2 * 12 * 1.25 * 2 * by_count[width:].sum()
+        capped = st._head_columns(counts, rows, need)
+        assert len(capped) == width < len(free)
+        np.testing.assert_array_equal(capped, free[:width])
+        # a byte less and the last block does not fit any more
+        fewer = st._head_columns(counts, rows, need - 1.0)
+        assert (0 if fewer is None else len(fewer)) == width - 128
+
+    def test_cap_counts_the_input_batch(self, rng):
+        """The budget is what the caller may pin, the padded-sparse input
+        still on the device included."""
+        batch = _zipf_problem(rng)
+        n, k = batch.indices.shape
+        idx, val = np.asarray(batch.indices), np.asarray(batch.values)
+        counts = np.bincount(idx[val != 0.0], minlength=batch.num_features)
+        by_count = np.sort(counts)[::-1]
+        need = 4.0 * n * 128 + 2 * 12 * 1.25 * 2 * by_count[128:].sum()
+        at_edge = tile_sparse_batch(batch, hbm_budget_bytes=need + 8 * n * k)
+        assert at_edge.head_X is not None
+        short = tile_sparse_batch(batch, hbm_budget_bytes=need + 8 * n * k - 1.0)
+        assert short.head_X is None
+
+    def test_no_budget_for_any_block_means_no_head(self):
+        import photon_ml_tpu.ops.sparse_tiled as st
+
+        assert st._head_columns(self._zipf_counts(), 1_354_798, 1.0) is None
+
+    @pytest.mark.parametrize("case", ["uniform", "one_full_column", "empty"])
+    def test_matrix_without_popular_columns_gets_no_head(self, case):
+        import photon_ml_tpu.ops.sparse_tiled as st
+
+        rows, d = 100_000, 8192
+        counts = np.zeros(d, np.int64)
+        if case == "uniform":
+            counts[:] = rows * 32 // d  # a fill of 0.4% everywhere
+        elif case == "one_full_column":
+            # an intercept among 31 uniform nonzeros a row: its block pays,
+            # but holds a thirtieth of the nonzeros, under the eighth
+            counts[:] = rows * 31 // d
+            counts[5] = rows
+        assert st._head_columns(counts, rows, 1e12) is None
+
+    @pytest.mark.parametrize(
+        "kwargs", [dict(keep_empty_chunks=True), dict(fe_range=(0, 0, 4608, 1))]
+    )
+    def test_streamed_sharded_and_range_builds_never_get_a_head(
+        self, rng, kwargs
+    ):
+        batch = _zipf_problem(rng, repeats=False)
+        tiled = tile_sparse_batch(batch, **kwargs)
+        assert tiled.head_X is None and tiled.head_cols is None
+        (chunk,) = tiled.chunks
+        _assert_chunks_equal(chunk, _parents_chunk(batch))
+        # the head is asked for by handing the budget, and only for the
+        # resident single-device layout
+        with pytest.raises(ValueError, match="resident single-device"):
+            tile_sparse_batch(batch, hbm_budget_bytes=8e9, **kwargs)
+
+    def test_sharded_build_has_no_head(self, rng):
+        from photon_ml_tpu.ops.sparse_tiled import tile_sparse_batch_sharded
+
+        stacked, _ = tile_sparse_batch_sharded(_zipf_problem(rng, n=2048), 2)
+        assert stacked.head_X is None and stacked.head_cols is None
+
+    def test_cache_hit_returns_the_head_and_counts_its_bytes(self, rng):
+        import dataclasses
+
+        from photon_ml_tpu.ops import tile_cache
+
+        tile_cache.clear()
+        b1 = _zipf_problem(rng)
+        tb1 = tile_cache.tiled_layout_for(b1, hbm_budget_bytes=8e9)
+        assert tb1.head_X is not None
+        b2 = dataclasses.replace(b1, labels=jnp.ones_like(b1.labels))
+        tb2 = tile_cache.tiled_layout_for(b2, hbm_budget_bytes=8e9)
+        s = tile_cache.stats()
+        assert (s["hits"], s["misses"]) == (1, 1)
+        assert tb2.chunks is tb1.chunks
+        assert tb2.head_X is tb1.head_X and tb2.head_cols is tb1.head_cols
+        streams = sum(
+            int(a.nbytes) for c in tb1.chunks
+            for arrays in (c.m_arrays, c.g_arrays) for a in arrays
+        )
+        assert s["bytes"] == streams + tb1.head_X.nbytes + tb1.head_cols.nbytes
+        # another budget is another layout decision: a miss, not a stale hit
+        tb3 = tile_cache.tiled_layout_for(b1, hbm_budget_bytes=1.0)
+        assert tb3.head_X is None
+        assert tile_cache.stats()["misses"] == 2
+        # and so is no budget at all (the streamed builders' call)
+        assert tile_cache.tiled_layout_for(b1).head_X is None
+        assert tile_cache.stats()["misses"] == 3
+        tile_cache.clear()
+
+    @pytest.mark.parametrize("popular", [True, False])
+    def test_counters_equal_the_counted_nonzeros(self, rng, popular):
+        from photon_ml_tpu.obs.metrics import REGISTRY
+
+        batch = _zipf_problem(rng) if popular else _sparse_problem(rng)
+        REGISTRY.reset(prefix="tile_layout.")
+        tiled = tile_sparse_batch(batch, hbm_budget_bytes=8e9)
+        got = {
+            k: v["value"]
+            for k, v in REGISTRY.snapshot("tile_layout.")["counters"].items()
+        }
+        idx, val = np.asarray(batch.indices), np.asarray(batch.values)
+        head_cols = [] if tiled.head_cols is None else np.asarray(tiled.head_cols)
+        in_head = int((np.isin(idx, head_cols) & (val != 0.0)).sum())
+        assert (in_head > 0) == popular
+        assert got == {
+            "tile_layout.head_columns": float(len(head_cols)),
+            "tile_layout.head_nonzeros": float(in_head),
+            "tile_layout.tail_nonzeros": float(np.count_nonzero(val) - in_head),
+        }
+
+    @pytest.mark.parametrize("built", ["head", "no_head", "never"])
+    def test_the_benchmarks_reader_gives_the_heads_share(self, rng, built):
+        """``layout.head_nonzero_share`` reads the registry itself (the
+        counters are set in set-up, before the harness's window), and finds
+        nothing in a program that built no tile-COO layout."""
+        from benchmark import harness
+        from photon_ml_tpu.obs.metrics import REGISTRY
+
+        read = harness.layer_reader("layout.head_nonzero_share")
+        REGISTRY.reset(prefix="tile_layout.")
+        if built == "never":
+            assert read(None) is None
+            return
+        batch = _zipf_problem(rng) if built == "head" else _sparse_problem(rng)
+        tiled = tile_sparse_batch(batch, hbm_budget_bytes=8e9)
+        idx, val = np.asarray(batch.indices), np.asarray(batch.values)
+        head_cols = [] if tiled.head_cols is None else np.asarray(tiled.head_cols)
+        in_head = (np.isin(idx, head_cols) & (val != 0.0)).sum()
+        assert read(None) == pytest.approx(100.0 * in_head / np.count_nonzero(val))
+        assert (read(None) > 30.0) == (built == "head")
+
+    @pytest.mark.parametrize("built", ["head", "no_head", "never"])
+    def test_the_benchmarks_reader_gives_the_tails_pad_ratio(self, rng, built):
+        """``layout.tail_pad_ratio`` divides the streams' slots by the
+        nonzeros the build left to them, not by the whole matrix's, which
+        is what ``layout.pad_ratio`` does and reads under 1 beside a head."""
+        from types import SimpleNamespace
+
+        from benchmark import harness
+        from photon_ml_tpu.obs.metrics import REGISTRY
+
+        read = harness.layer_reader("layout.tail_pad_ratio")
+        whole = harness.layer_reader("layout.pad_ratio")
+        REGISTRY.reset(prefix="tile_layout.")
+        if built == "never":
+            assert read(SimpleNamespace(counters={"layout.slots": 4096.0})) is None
+            return
+        batch = _zipf_problem(rng) if built == "head" else _sparse_problem(rng)
+        tiled = tile_sparse_batch(batch, hbm_budget_bytes=8e9)
+        # as benchmark/runners/fit.py counts them
+        slots = float(sum(
+            int(arrays[0].shape[0]) * int(arrays[0].shape[-1])
+            for c in tiled.chunks for arrays in (c.m_arrays, c.g_arrays)
+        ))
+        stored = float(np.count_nonzero(np.asarray(batch.values)))
+        obs = SimpleNamespace(
+            counters={"layout.slots": slots, "layout.nonzeros": stored}
+        )
+        tail = REGISTRY.snapshot("tile_layout.")["counters"][
+            "tile_layout.tail_nonzeros"]["value"]
+        assert read(obs) == pytest.approx(slots / (2.0 * tail)) and read(obs) >= 1.0
+        if built == "head":
+            assert tail < stored and read(obs) > whole(obs)
+        else:
+            assert tail == stored and read(obs) == whole(obs)
+
+    @pytest.mark.parametrize("built", [True, False])
+    def test_the_run_report_renders_the_layout_counters(self, tmp_path, built):
+        from photon_ml_tpu.obs.report import format_summary, summarize_run
+        from photon_ml_tpu.obs.sink import TelemetrySink
+
+        counters = {
+            "tile_layout.head_columns": {"value": 384.0},
+            "tile_layout.head_nonzeros": {"value": 3000.0},
+            "tile_layout.tail_nonzeros": {"value": 1000.0},
+        } if built else {}
+        sink = TelemetrySink(str(tmp_path), run_id="HEAD", shard_index=None)
+        sink.emit({"event": "run_start", "t": 1000.0, "schema_version": 1,
+                   "run_id": "HEAD", "pid": 0, "process_index": 0, "knobs": {},
+                   "fleet": {"process_count": 1}, "metrics_baseline": {}})
+        sink.emit({"event": "run_end", "t": 1002.0, "run_id": "HEAD",
+                   "metrics": {"counters": counters, "gauges": {},
+                               "histograms": {}, "timers": {}}})
+        sink.close()
+        summary = summarize_run(sink.path)
+        if not built:
+            assert "tile_layout" not in summary
+            assert "tile-layout" not in format_summary(summary)
+            return
+        assert summary["tile_layout"]["head_nonzero_share"] == 0.75
+        assert "(75.0%) in a dense head of 384 columns" in format_summary(summary)

@@ -815,6 +815,10 @@ class TestTiledStreamedChunks:
         # chunks tile to different stream lengths — exercising the
         # pad-to-common-groups path, not just the equal-length early return
         val[n // 2:, 2:] = 0.0
+        # a row names a column once, as real rows do: the tile-COO build
+        # merges repeated draws and squares the merged entry in
+        # hessian_diag, where the XLA path squares each stored value
+        val[np.tril(idx[:, :, None] == idx[:, None, :], k=-1).any(axis=2)] = 0.0
         y = (rng.uniform(size=n) < 0.5).astype(np.float32)
         chunks = sparse_chunks(idx, val, y, chunk_rows=1024)
         plain = StreamingGLMObjective(
