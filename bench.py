@@ -71,7 +71,7 @@ RETUNE_ENV = {
     # overlaps phase 2 of segment s), 0 = straight-line reference
     "PHOTON_PIPELINE_SEGMENTS": "PIPELINE_SEGMENTS",
     # storage precision rung for the packed slabs + gathered operands
-    # (f32 = bitwise anchor | bf16 | int8 with per-tile scales); the ONE
+    # (f32 = bitwise anchor | int8 with per-tile scales); the ONE
     # string-valued knob — parsed strictly by validate_kernel_dtype, so a
     # typo fails the run instead of silently benching f32
     "PHOTON_KERNEL_DTYPE": "KERNEL_DTYPE",
@@ -671,8 +671,7 @@ def _sparse_logistic_bench(jax, jnp, n, d, k, iters, densify_dtype,
         # one value+grad pass streams BOTH write-major layouts (margins +
         # gradient): the packed streams are the traffic, at their ACTUAL
         # storage width (nbytes) — the precision ladder's bytes-moved win
-        # is auditable straight from this number (f32: 12 B/nnz, bf16: 6,
-        # int8: 4)
+        # is auditable straight from this number (f32: 12 B/nnz, int8: 4)
         bytes_per_pass = float(
             sum(
                 int(c.m_arrays[0].nbytes + c.g_arrays[0].nbytes)
@@ -722,7 +721,6 @@ def _sparse_logistic_bench(jax, jnp, n, d, k, iters, densify_dtype,
             "groups_per_step": st.GROUPS_PER_STEP,
             "segments_per_dma": st.SEGMENTS_PER_DMA,
             "groups_per_run": st.GROUPS_PER_RUN,
-            "segment_batched": bool(st.SEGMENT_BATCHED),
             "pipeline_segments": int(st.PIPELINE_SEGMENTS),
             "kernel_dtype": st.kernel_dtype(),
         }

@@ -481,8 +481,8 @@ def leg_sparse(
     """``cli.train_glm.main`` on a wide LIBSVM file: the layout decision
     must tile it, the tile-COO kernels must run compiled, and the final
     loss must agree with the same solve on the untiled XLA path. Then each
-    storage rung either agrees with the XLA products within its documented
-    tolerance or refuses by name."""
+    storage rung agrees with the XLA products within its documented
+    tolerance."""
     import jax
     import jax.numpy as jnp
 
@@ -569,17 +569,9 @@ def leg_sparse(
     rungs = {}
     prev = os.environ.get("PHOTON_KERNEL_DTYPE")
     try:
-        for rung, tol in (("f32", 1e-5), ("bf16", 2e-2), ("int8", 6e-2)):
+        for rung, tol in (("f32", 1e-5), ("int8", 6e-2)):
             os.environ["PHOTON_KERNEL_DTYPE"] = rung
-            try:
-                tb = st.tile_sparse_batch(batch)
-            except NotImplementedError as e:
-                _check(
-                    f"PHOTON_KERNEL_DTYPE={rung}" in str(e),
-                    f"rung {rung} refused without naming itself: {e}",
-                )
-                rungs[rung] = "refused by name"
-                continue
+            tb = st.tile_sparse_batch(batch)
             err = max(
                 _rel(np.asarray(tb.matvec(w)), want[0]),
                 _rel(np.asarray(tb.rmatvec(r)), want[1]),
@@ -592,10 +584,6 @@ def leg_sparse(
         else:
             os.environ["PHOTON_KERNEL_DTYPE"] = prev
     facts["rungs"] = rungs
-    _check(
-        isinstance(rungs["f32"], dict) and isinstance(rungs["int8"], dict),
-        f"a rung that must work refused: {rungs}",
-    )
     return facts
 
 

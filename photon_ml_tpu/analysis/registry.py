@@ -142,7 +142,7 @@ KNOBS: tuple[Knob, ...] = (
     Knob(
         name="PHOTON_KERNEL_DTYPE", kind="enum", parse="enum",
         default="f32", owner="photon_ml_tpu/ops/sparse_tiled.py",
-        doc="storage precision rung: f32 (bitwise anchor) | bf16 | int8",
+        doc="storage precision rung: f32 (bitwise anchor) | int8",
         accessors=("kernel_dtype",),
         retune_global="KERNEL_DTYPE", retune_table="RETUNE_ENV",
         sink_key="kernel_dtype",

@@ -557,7 +557,7 @@ def record_layout_pack(nbytes: int, chunks: int) -> None:
     """Called by ``ops/tile_cache`` on a layout-cache MISS: the packed
     tile-COO streams are the kernel's HBM traffic, so the per-knob packed
     byte total is the analytic half of the dtype ladder's bytes-moved
-    claim (f32 12 B/nnz → bf16 6 → int8 4) — published next to the
+    claim (f32 12 B/nnz → int8 4) — published next to the
     executable costs and rendered in the same roofline table."""
     try:
         reg = _metrics.REGISTRY

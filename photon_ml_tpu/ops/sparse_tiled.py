@@ -19,8 +19,7 @@ Design — write-slab-major tile-COO, built ONCE at ingest:
   each cell padded to a whole number of GROUPS_PER_RUN-group RUNS of
   GROUP=128 nonzeros (zero-valued fillers) — consecutive groups of one
   cell read ONE source slab, so the kernel loads each shared slab once
-  per run and batches the gather over the whole run (the r5 ablation's
-  per-group skeleton floor, hoisted; see GROUPS_PER_RUN).
+  per run and batches the gather over the whole run (see GROUPS_PER_RUN).
 - Each WRITE SLAB's nonzeros are further padded to a multiple of
   GROUPS_PER_STEP groups, so one grid step processes GROUPS_PER_STEP
   groups that ALL write to the same (8, 128) output slab. Per group the
@@ -38,22 +37,15 @@ Design — write-slab-major tile-COO, built ONCE at ingest:
       slower end to end).
 - One ``dot_general`` contracts A and B_T over their last dims: a single
   (8, G*128) x (128, G*128) -> (8, 128) MXU call scatters ALL of the
-  step's nonzeros into the shared write slab (one matmul per G groups vs
-  one per group in the first design — matmul issue count was the round-3
-  bottleneck). B_T is exactly representable in bf16, and A is split into
+  step's nonzeros into the shared write slab (one matmul per G groups:
+  a matmul per group is bound by the matmul issue count). B_T is exactly
+  representable in bf16, and A is split into
   hi+mid+lo bf16 terms (Dekker-style, 24 mantissa bits), so the scatter
   runs at the MXU's bf16 rate while staying f32-exact (three passes
   instead of six for HIGHEST-f32).
-- The one-hot operands stage per SEGMENT, not per group
-  (``SEGMENT_BATCHED``, the r5 kernel): the r5 ablation (see the note at
-  the kernel) measured the read gather as fully hidden and the per-group
-  A/B_T staging as the cost center; batching the staging bought 1.41x on
-  the margins direction (30.3 -> 21.4 ms on the A2 shapes, one round-5
-  session). Known open asymmetry: the gradient direction (write=col)
-  runs ~3x the margins direction on identical group counts, invariant to
-  read-table size (row chunking), staging mode, and MXU term count — the
-  next profiling step needs per-op visibility inside the kernel, which
-  round 5 did not have.
+- The one-hot operands stage per SEGMENT, not per group: the read
+  gather hides behind the scatter pipeline, and staging A and B_T is the
+  cost center (see ``_tile_kernel_seg``).
 - margins (``matvec``) and gradient (``rmatvec``) each get their OWN
   layout — write=row/read=col and write=col/read=row respectively — the
   one-time ingest cost buys both directions their batched write slab.
@@ -108,15 +100,15 @@ from jax.experimental.pallas import tpu as pltpu
 Array = jnp.ndarray
 
 GROUP = 128  # nonzeros per group: one vreg row, shares one (write, read) cell
-# 32-group segments halve the number of sequential (matmul + accumulate)
-# steps chained onto each write slab — measured 1.83x on the gradient
-# direction (66.8 -> 36.6 ms on the A2 shapes, same session, parity
-# intact; the margins direction is insensitive) at +1.4% stream padding.
-# The DMA step stays at 128 groups (16K nnz per fetch).
+# 32-group segments halve (against 16) the number of sequential (matmul +
+# accumulate) steps chained onto each write slab, which bound the
+# gradient direction (the margins direction is insensitive), at +1.4%
+# stream padding. Chosen on a remote-attached chip and never swept on the
+# v5e (ROADMAP S3). The DMA step stays at 128 groups (16K nnz per fetch).
 GROUPS_PER_STEP = 32  # groups per SEGMENT: all share ONE write slab
 SEGMENTS_PER_DMA = 4  # segments per DMA step (128 groups = 16K nnz per fetch)
-# Slab-RUN batching (the r5 addendum's recorded next lever): consecutive
-# groups of one cell read the SAME source slab, so the builder pads every
+# Slab-RUN batching: consecutive groups of one cell read the SAME source
+# slab, so the builder pads every
 # cell to whole runs of GROUPS_PER_RUN groups and the kernel loads the
 # shared slab ONCE per run, gathering/staging all of the run's nonzeros in
 # batched ops instead of per group. Bigger runs amortize more of the
@@ -137,55 +129,49 @@ GROUPS_PER_RUN = 2  # groups per slab RUN: all read ONE source slab
 # (PERF.md, PR 28): the threshold needs to be right to a factor of two.
 HEAD_MIN_FILL = 0.017
 HEAD_LANES = 128  # the head grows by whole lane blocks of columns
-# Software pipeline across SEGMENTS (the r6 addendum's recorded next
-# kernel lever): phase 1 (VPU gather/select/product) and phase 2 (scatter
-# staging + MXU contraction) of one segment touch disjoint scratch, so the
+# Software pipeline across SEGMENTS: phase 1 (VPU gather/select/product)
+# and phase 2 (scatter staging + MXU contraction) of one segment touch
+# disjoint scratch, so the
 # kernel double-buffers ``p_scratch`` (two segment slots) and issues
 # segment s+1's phase 1 BEFORE segment s's phase 2 — the VPU gather stream
 # of one segment overlaps the MXU dots of the previous one, hiding
 # whichever side is shorter. The skew carries across the DMA-step
 # boundary too (the last segment of step t overlaps the first segment of
-# step t+1, composing with the double-buffered DMA). 0 restores the
-# straight-line schedule bit-for-bit (same per-phase math, same
-# accumulation order — the parity tests assert bitwise equality); retune
-# from the environment via PHOTON_PIPELINE_SEGMENTS (bench.py RETUNE_ENV).
+# step t+1, composing with the double-buffered DMA). 0 is the
+# straight-line schedule: the same per-phase math in the same
+# accumulation order, so the two are bitwise equal (tests). On the v5e
+# the straight-line schedule is the FASTER one (PERF.md, PR 29: fit_s
+# 0.748 against 0.787 s in rcv1_fit); the default is the next perf_opt
+# PR's to flip (ROADMAP S3). The package reads no environment variable
+# for it: only bench.py's RETUNE_ENV sets the global.
 PIPELINE_SEGMENTS = 1  # 1 = skewed segment schedule, 0 = straight-line
 SLAB = 1024  # outputs/inputs per slab: an (8, 128) block of a table
-# Precision ladder for the PACKED SLAB STORAGE and the gathered source
-# operand (ROADMAP "Mixed-precision sparse-tiled kernels"): A2 is
-# HBM-bound, so after pipelining/prefetch/caching hid latency, the next
-# raw-speed lever is to move fewer bytes. The rungs change STORAGE only —
-# the MXU contraction always accumulates in f32 through the existing
-# 3-term Dekker split, and ``p_scratch``/``acc_scratch`` stay f32:
+# Storage rungs of the PACKED SLAB STREAMS and the gathered source
+# operand: the reduced rung holds and moves a third of the bytes (on the
+# v5e that buys device memory, not time: PERF.md, PR 29). A rung changes
+# STORAGE only — the MXU contraction always accumulates in f32 through
+# the 3-term Dekker split, and ``p_scratch``/``acc_scratch`` stay f32:
 #
-#   f32  — today's layout bit-for-bit (12 B/nnz: full i32 write/read
-#          indices + f32 value bits). The BITWISE-parity anchor: knob
-#          unset and knob=f32 reproduce the pre-ladder kernels exactly
-#          (asserted with assert_array_equal across all four streamed
-#          consumers).
-#   bf16 — the same three streams at HALF width (6 B/nnz): the kernel
-#          only ever consumes the low 10 bits of each index (lane +
-#          sublane; the slab id rides the SMEM wslab/rslab/rrun streams),
-#          so indices narrow to within-slab i16 offsets and values store
-#          as bf16 bits in i16. The gathered source is rounded to bf16
-#          values (in a 32-bit table); products are f32. CPU-only: on a
-#          TPU the layout builder raises, because Mosaic tiles 16-bit
-#          data (4, 128) and cannot slice a 3-stream block for the DMA.
+#   f32  — 12 B/nnz: full i32 write/read indices + f32 value bits. The
+#          BITWISE-parity anchor: knob unset and knob=f32 are one layout
+#          and one kernel.
 #   int8 — ONE i32 stream (4 B/nnz): write-offset(10) | read-offset(10)
-#          | symmetric-int8 value(8), with per-CELL scale factors (one
+#          | symmetric-int8 value(8). The kernel only ever consumes the
+#          low 10 bits of each index (lane + sublane; the slab id rides
+#          the SMEM wslab/rrun streams). Per-CELL scale factors (one
 #          (write-slab, read-slab) tile shares one scale, carried per
 #          aligned RUN in the scalar-prefetched ``srun`` stream so the
 #          kernel pays one SMEM read per run). Dequantized to f32 at
-#          gather time; accumulation unchanged. Compiles for a v5e.
+#          gather time; the gathered source is rounded to bf16 values (in
+#          a 32-bit table); products are f32.
 #
-# bf16/int8 are NOT bitwise rungs — they gate on model-quality parity
-# (AUC/RMSE deltas in the bench ``telemetry`` block, per BASELINE's
-# "never report speed without a parity check" protocol). The dtype is a
-# static key of the ``_tiled_apply`` jit cache, the tile-layout cache and
-# the shared scoring program: toggling recompiles, never reuses. Retune
-# from the environment via PHOTON_KERNEL_DTYPE (bench.py RETUNE_ENV).
-KERNEL_DTYPE = "f32"  # storage rung: "f32" (parity anchor) | "bf16" | "int8"
-KERNEL_DTYPES = ("f32", "bf16", "int8")
+# int8 is NOT a bitwise rung — it gates on model-quality parity (AUC/RMSE
+# deltas against the f32 fit). The dtype is a static key of the
+# ``_tiled_apply`` jit cache, the tile-layout cache and the shared scoring
+# program: toggling recompiles, never reuses. Set from the environment
+# via PHOTON_KERNEL_DTYPE.
+KERNEL_DTYPE = "f32"  # storage rung: "f32" (parity anchor) | "int8"
+KERNEL_DTYPES = ("f32", "int8")
 
 
 def validate_kernel_dtype(value) -> str:
@@ -229,16 +215,15 @@ class _Layout:
     single-DMA layout with 128-group steps is what made the stream cheap
     enough for the compute to be the limit again."""
 
-    packed: np.ndarray  # (M/GROUP, S, GROUP) storage-dtype streams; f32:
-    # S=3 int32 [write, read, val bits] (the pre-ladder layout verbatim),
-    # bf16: S=3 int16 [write off10, read off10, bf16 bits], int8: S=1
-    # int32 [write off10 | read off10 << 10 | symmetric q8 << 20]
+    packed: np.ndarray  # (M/GROUP, S, GROUP) int32 streams; f32: S=3
+    # [write, read, val bits], int8: S=1
+    # [write off10 | read off10 << 10 | symmetric q8 << 20]
     wslab: np.ndarray  # (M/(GROUP*GROUPS_PER_STEP),) int32: per-segment slab
     rslab: np.ndarray  # (M/GROUP,) int32 read slab id per group
     rrun: np.ndarray  # (M/(GROUP*GROUPS_PER_RUN),) int32: per-RUN read slab
     srun: np.ndarray  # (M/(GROUP*GROUPS_PER_RUN),) f32: per-RUN dequant
     # scale (each run is single-cell, so this carries the per-CELL int8
-    # symmetric scale; all-ones for the f32/bf16 rungs, never read there)
+    # symmetric scale; all-ones for the f32 rung, never read there)
 
 
 def detect_slab_runs(rslab: np.ndarray) -> np.ndarray:
@@ -279,9 +264,9 @@ def build_write_major_layout(
     layouts built after retuning the constant silently disagreed with
     the kernel consuming them (garbage outputs, caught by a parity
     probe). ``storage`` selects the packed-stream precision rung (see
-    KERNEL_DTYPE): the f32 layout is the pre-ladder layout verbatim;
-    bf16/int8 narrow the streams and must be consumed by a kernel
-    compiled for the same rung (the jit/layout caches key on it)."""
+    KERNEL_DTYPE): int8 narrows the streams and must be consumed by a
+    kernel compiled for the same rung (the jit/layout caches key on
+    it)."""
     if groups_per_step is None:
         groups_per_step = GROUPS_PER_STEP
     if groups_per_run is None:
@@ -290,13 +275,6 @@ def build_write_major_layout(
         storage = kernel_dtype()
     else:
         storage = validate_kernel_dtype(storage)
-    if storage == "bf16" and not _interpret():
-        raise NotImplementedError(
-            "PHOTON_KERNEL_DTYPE=bf16 does not compile for a TPU: its "
-            "(groups, 3, 128) int16 packed stream cannot be sliced for the "
-            "per-step DMA (Mosaic tiles 16-bit data (4, 128), so a 3-stream "
-            "block is not tile-aligned). Use f32 or int8."
-        )
     if groups_per_step % groups_per_run:
         raise ValueError(
             f"GROUPS_PER_RUN={groups_per_run} must divide "
@@ -384,23 +362,6 @@ def build_write_major_layout(
             ],
             axis=1,
         )
-    elif storage == "bf16":
-        import ml_dtypes
-
-        # the kernel consumes only the within-slab offset (lane + sublane
-        # = low 10 bits; slab ids ride the SMEM streams), so both index
-        # streams narrow to i16 and the value stream stores bf16 bits —
-        # the same three streams at exactly half width
-        packed = np.stack(
-            [
-                (out_w % SLAB).astype(np.int16).reshape(n_groups, GROUP),
-                (out_r % SLAB).astype(np.int16).reshape(n_groups, GROUP),
-                out_v.astype(ml_dtypes.bfloat16).view(np.int16).reshape(
-                    n_groups, GROUP
-                ),
-            ],
-            axis=1,
-        )
     else:  # int8: one i32 stream [w off10 | r off10 << 10 | q8 << 20]
         out_q = np.zeros(M_total, np.int64)
         if len(uniq):
@@ -433,28 +394,8 @@ def build_write_major_layout(
     )
 
 
-# r5 ablation on the A2 shapes (n=2^19, d=2^17, k=32; one chunk,
-# 21.2M padded nnz; round-5 chip session of 2026-07-31, ms/matvec):
-#   full 30.3 | single-matmul 25.7 | no-B_T-build 22.1 | no-A-staging
-#   20.4 | no-gather 31.2
-# i.e. the READ gather is fully hidden behind the scatter pipeline, and
-# the cost is the per-group staging of the one-hot operands (A ~33%,
-# B_T ~27%, Dekker's two extra matmuls ~15%). SEGMENT_BATCHED stages
-# whole segments instead: ONE relayout of the packed block to a
-# (1, seg_nnz) row per stream, one batched one-hot compare per segment,
-# matmul operands built as
-# VALUES (no a/bt VMEM scratch round-trip), one batched one-hot build
-# per segment instead of ``groups`` per-group ones. The r6 follow-up (the
-# retuned-state ablation's recorded lever) batches PHASE 1 the same way:
-# skeleton loads/bitcast hoist per segment and the source slab loads once
-# per GROUPS_PER_RUN-group run — see _tile_kernel_seg.
-SEGMENT_BATCHED = True
-
-
 def _decode_packed(load, storage):
-    """Phase 1's packed-stream decode, shared by BOTH kernels (one copy
-    of the per-rung bit layout — a drifted duplicate would let phase 1
-    and phase 2 disagree on offsets and produce silent garbage).
+    """Phase 1's packed-stream decode: the per-rung bit layout.
     ``load(stream)`` returns one packed stream's 2-D block, so each rung
     loads ONLY the streams it consumes; returns ``(rd, vals)`` — i32
     within-slab read offsets and f32 values (RAW q for int8: the per-run
@@ -466,28 +407,22 @@ def _decode_packed(load, storage):
         q = (pk >> 20) & 255
         return rd, (q - ((q & 128) << 1)).astype(jnp.float32)
     rd = load(1)
-    if storage == "bf16":
-        return rd.astype(jnp.int32), pltpu.bitcast(
-            load(2), jnp.bfloat16
-        ).astype(jnp.float32)
     return rd, pltpu.bitcast(load(2), jnp.float32)
 
 
 def _decode_write_offsets(wr, storage):
-    """Phase 2's write-stream decode, shared by both kernels: normalize
-    the per-rung storage to i32 within-slab write offsets."""
-    if storage == "bf16":
-        return wr.astype(jnp.int32)
+    """Phase 2's write-stream decode: normalize the per-rung storage to
+    i32 write offsets (only their low 10 bits are consumed)."""
     if storage == "int8":
         return wr & 1023  # low 10 bits of the single packed stream
     return wr
 
 
 def _run_segment_schedule(dma, phase1, phase2, *, n_steps, segs, pipeline):
-    """The per-step segment loop shared by BOTH kernels, expressed over
-    their ``dma(slot, t)`` / ``phase1(buf_slot, t, s2, p_slot)`` /
-    ``phase2(buf_slot, t, s2, p_slot)`` callables — one copy of the DMA
-    pairing and slot-parity logic, so the two kernels cannot diverge.
+    """The kernel's per-step segment loop over its ``dma(slot, t)`` /
+    ``phase1(buf_slot, t, s2, p_slot)`` / ``phase2(buf_slot, t, s2,
+    p_slot)`` closures: the DMA pairing and slot-parity logic of both
+    schedules.
 
     ``pipeline`` selects the skewed schedule (see PIPELINE_SEGMENTS):
     prologue runs segment 0's phase 1; each steady-state iteration issues
@@ -495,8 +430,8 @@ def _run_segment_schedule(dma, phase1, phase2, *, n_steps, segs, pipeline):
     (MXU contraction stream), crossing the DMA-step boundary at a step's
     last segment by waiting the already-in-flight next fetch mid-step.
     Every DMA semaphore is started and waited exactly once on either
-    schedule; the straight-line schedule is the pre-pipeline loop
-    verbatim (phase 1 then phase 2 per segment, slot 0 only)."""
+    schedule; the straight-line schedule runs phase 1 then phase 2 per
+    segment, slot 0 only."""
     dma(0, 0).start()
 
     if pipeline:
@@ -558,16 +493,22 @@ def _tile_kernel_seg(
     *, n_steps, step0, groups, segs, run_groups, square_vals, pipeline,
     storage,
 ):
-    """Segment-batched kernel with slab-RUN phase 1 (see SEGMENT_BATCHED
-    note): the per-group skeleton the r5 retuned-state ablation measured
-    as the floor (packed-buffer loads, value bitcast, p-scratch store,
-    ~135 ns per 128-nnz group) hoists to ONE batched load/bitcast per
+    """The tile-COO kernel: a ``fori_loop`` over DMA steps, each step
+    fetching ``segs * groups`` groups in ONE double-buffered DMA and
+    running ``segs`` segments, whose groups all write one output slab.
+
+    Phase 1 does per SEGMENT what costs most per group (packed-buffer
+    loads, value bitcast, p-scratch store): ONE batched load/bitcast per
     segment, and the source slab loads once per ``run_groups``-group RUN
     (the layout builder guarantees aligned runs are single-slab); the
     lane gather and sublane select then run per group of the run, the
     shape Mosaic's gather takes, and the product per run. Phase 2 is
     the whole-segment scatter staging + 3-term Dekker bf16 MXU
-    contraction.
+    contraction: the read gather hides behind it, and staging the one-hot
+    operands is the cost center, so they are staged once a segment — ONE
+    relayout of the packed block to a (1, seg_nnz) row per stream, one
+    batched one-hot compare, matmul operands built as VALUES (no VMEM
+    scratch round-trip).
 
     The call covers DMA steps ``[step0, step0 + n_steps)`` of the packed
     stream; the SMEM streams arrive sliced to that range, so only the DMA
@@ -584,11 +525,10 @@ def _tile_kernel_seg(
     BIT-IDENTICAL (asserted by the parity tests).
 
     ``storage`` selects the packed-stream precision rung (KERNEL_DTYPE):
-    only phase 1's stream decode changes — f32 reproduces the pre-ladder
-    decode verbatim (the bitwise anchor), bf16 widens i16 offsets and
-    bitcasts bf16 value bits, int8 unpacks the single i32 stream and
+    only the stream decode changes — f32 bitcasts the value stream (the
+    bitwise anchor), int8 unpacks the single i32 stream and
     dequantizes by the per-run SMEM scale (``srun_ref``, None on the
-    other rungs). Products land in f32 ``p_scratch`` either way, and
+    f32 rung). Products land in f32 ``p_scratch`` either way, and
     phase 2's Dekker-split f32 MXU accumulation is IDENTICAL across
     rungs."""
     step_groups = segs * groups
@@ -707,131 +647,17 @@ def _tile_kernel_seg(
     out_ref[...] = acc_scratch[...]
 
 
-def _tile_kernel(
-    wslab_ref, rslab_ref, srun_ref, packed_hbm, src_ref, out_ref,
-    acc_scratch, a_scratch, bt_scratch, p_scratch, pk_buf, dma_sem,
-    *, n_steps, step0, groups, segs, run_groups, square_vals, pipeline,
-    storage,
-):
-    """Single-launch kernel: a ``fori_loop`` over DMA steps, each step
-    fetching ``segs * groups`` groups in ONE double-buffered DMA and
-    running ``segs`` segment scatters (one batched MXU call per segment,
-    whose groups all write one output slab). This per-group variant reads
-    the per-group ``rslab_ref`` stream where the segment-batched kernel
-    reads the per-run one; ``srun_ref`` is None off the int8 rung.
-
-    The phase split mirrors ``_tile_kernel_seg``: phase 1 is the per-group
-    gather/select/product into ``p_scratch`` (two slots under
-    ``pipeline`` — see PIPELINE_SEGMENTS), phase 2 the per-group one-hot
-    staging + per-segment MXU contraction, so the same skewed schedule
-    overlaps adjacent segments' VPU and MXU streams here too. ``storage``
-    (KERNEL_DTYPE) changes only the per-group stream decode, exactly as in
-    the segment-batched kernel; accumulation stays f32 on every rung."""
-    step_groups = segs * groups
-    step_runs = step_groups // run_groups
-    iota8 = jax.lax.broadcasted_iota(jnp.int32, (8, GROUP), 0)
-    iota_sub = jax.lax.broadcasted_iota(jnp.int32, (GROUP, GROUP), 0)
-    acc_scratch[...] = jnp.zeros_like(acc_scratch)
-
-    def dma(slot, t):
-        return pltpu.make_async_copy(
-            packed_hbm.at[pl.ds((step0 + t) * step_groups, step_groups)],
-            pk_buf.at[slot],
-            dma_sem.at[slot],
-        )
-
-    def phase1(buf_slot, t, s2, p_slot):
-        """Per-group gather/sublane-select/product of segment (t, s2)
-        into ``p_scratch[p_slot]``."""
-        for gi in range(groups):
-            g = s2 * groups + gi
-            # (1, GROUP) 2-D blocks (Mosaic's bitcast scope), squeezed
-            # after the shared decode
-            rd, vals = _decode_packed(
-                lambda s: pk_buf[buf_slot, g:g + 1, s, :], storage
-            )
-            rd, vals = rd[0, :], vals[0, :]
-            lane_r = rd & 127
-            sub_r = (rd >> 7) & 7
-            rslab = rslab_ref[t * step_groups + g]
-            slab = src_ref[pl.ds(pl.multiple_of(rslab * 8, 8), 8), :]
-            gathered = jnp.take_along_axis(
-                slab, jnp.broadcast_to(lane_r[None, :], (8, GROUP)), axis=1
-            )
-            sel = (iota8 == sub_r[None, :]).astype(jnp.float32)
-            src_vals = jnp.sum(gathered * sel, axis=0)  # (GROUP,)
-            if storage == "int8":
-                vals = vals * srun_ref[t * step_runs + g // run_groups]
-            if square_vals:
-                # Hessian-diagonal contraction (rmatvec_sq) squares the
-                # values in-register — no second packed stream needed
-                # (int8: after dequantization, so the square carries s²)
-                vals = vals * vals
-            p_scratch[p_slot, gi, :] = vals * src_vals
-
-    def phase2(buf_slot, t, s2, p_slot):
-        """Per-group one-hot staging + one MXU scatter for segment
-        (t, s2), reading phase 1's products from ``p_scratch[p_slot]``."""
-        for gi in range(groups):
-            g = s2 * groups + gi
-            p = p_scratch[p_slot, gi, :]
-            wr = _decode_write_offsets(pk_buf[buf_slot, g, 0, :], storage)
-            lane_w = wr & 127
-            sub_w = (wr >> 7) & 7
-            cols = pl.ds(g * GROUP, GROUP)
-            a_scratch[:, cols] = jnp.where(
-                iota8 == sub_w[None, :], p[None, :], 0.0
-            )
-            # TRANSPOSED one-hot: lane indices stay in the lane dim
-            bt_scratch[:, cols] = (
-                iota_sub == lane_w[None, :]
-            ).astype(jnp.bfloat16)
-
-        # one MXU scatter per segment: contract over the nnz dimension.
-        # B_T is exact in bf16; A splits into hi+mid+lo bf16 terms
-        # (Dekker style, each residual exactly representable -> 24
-        # mantissa bits), so three bf16 passes reproduce the f32
-        # product (vs six for HIGHEST f32)
-        seg_cols = pl.ds(s2 * groups * GROUP, groups * GROUP)
-        a = a_scratch[:, seg_cols]
-        a_hi = a.astype(jnp.bfloat16)
-        rem = a - a_hi.astype(jnp.float32)
-        a_mid = rem.astype(jnp.bfloat16)
-        a_lo = (rem - a_mid.astype(jnp.float32)).astype(jnp.bfloat16)
-        bt = bt_scratch[:, seg_cols]
-        dims = (((1,), (1,)), ((), ()))
-        ms = (
-            jax.lax.dot_general(
-                a_hi, bt, dims, preferred_element_type=jnp.float32
-            )
-            + jax.lax.dot_general(
-                a_mid, bt, dims, preferred_element_type=jnp.float32
-            )
-            + jax.lax.dot_general(
-                a_lo, bt, dims, preferred_element_type=jnp.float32
-            )
-        )
-        ws = wslab_ref[t * segs + s2]
-        idx = pl.ds(pl.multiple_of(ws * 8, 8), 8)
-        acc_scratch[idx, :] = acc_scratch[idx, :] + ms
-
-    _run_segment_schedule(
-        dma, phase1, phase2, n_steps=n_steps, segs=segs, pipeline=pipeline
-    )
-    out_ref[...] = acc_scratch[...]
-
-
 @functools.partial(
     jax.jit,
     static_argnames=(
         "out_pad", "src_pad", "square_vals",
-        "groups", "segs", "run_groups", "seg_batched", "pipeline",
+        "groups", "segs", "run_groups", "pipeline",
         "storage", "interpret", "topology",
     ),
 )
 def _tiled_apply_jit(
     layout_arrays, src, out_pad, src_pad, square_vals,
-    groups, segs, run_groups, seg_batched, pipeline, storage, interpret,
+    groups, segs, run_groups, pipeline, storage, interpret,
     topology=None,
 ):
     packed, wslab, rslab, rrun, srun = layout_arrays
@@ -841,47 +667,35 @@ def _tiled_apply_jit(
     out_shape = (out_pad // 128, 128)
     src_mat = src.reshape(src_shape)
     if storage != "f32":
-        # the gathered operand carries bf16 VALUES under both reduced
-        # rungs (the source vector changes per call, so per-call int8
+        # the gathered operand carries bf16 VALUES under the reduced
+        # rung (the source vector changes per call, so per-call int8
         # quantization would buy nothing) but stays in a 32-bit table:
         # Mosaic's dynamic_gather needs operand and i32 indices of one
         # bitwidth, and the table is d*4 bytes once per call against
-        # 6 or 4 bytes per nonzero of packed stream
+        # 4 bytes per nonzero of packed stream
         src_mat = src_mat.astype(jnp.bfloat16).astype(jnp.float32)
-    # packed-stream shape/dtype per rung (must match the layout builder):
-    # f32 (.., 3, GROUP) i32 | bf16 (.., 3, GROUP) i16 | int8 (.., 1,
-    # GROUP) i32 — a layout built under one rung fails loudly under a
-    # kernel compiled for another (the caches key on the rung, so the
-    # only way there is hand-assembling mismatched pieces)
+    # packed-stream shape per rung (must match the layout builder):
+    # f32 (.., 3, GROUP) | int8 (.., 1, GROUP), both i32 — a layout
+    # built under one rung fails loudly under a kernel compiled for
+    # another (the caches key on the rung, so the only way there is
+    # hand-assembling mismatched pieces)
     n_streams = 1 if storage == "int8" else 3
-    buf_dtype = jnp.int16 if storage == "bf16" else jnp.int32
     # p_scratch: phase 1's per-segment products. The pipelined schedule
     # double-buffers it (segment s+1's phase 1 writes one slot while
     # segment s's phase 2 drains the other); straight-line needs one slot.
     p_slots = 2 if pipeline else 1
     p_scratch = pltpu.VMEM((p_slots, groups, GROUP), jnp.float32)
-    pk_buf = pltpu.VMEM((2, step_groups, n_streams, GROUP), buf_dtype)
-    if seg_batched:
-        kernel_fn = _tile_kernel_seg
-        scratch = [
-            pltpu.VMEM(out_shape, jnp.float32),
-            p_scratch, pk_buf, pltpu.SemaphoreType.DMA((2,)),
-        ]
-    else:
-        kernel_fn = _tile_kernel
-        scratch = [
-            pltpu.VMEM(out_shape, jnp.float32),
-            pltpu.VMEM((8, step_groups * GROUP), jnp.float32),
-            pltpu.VMEM((GROUP, step_groups * GROUP), jnp.bfloat16),
-            p_scratch, pk_buf, pltpu.SemaphoreType.DMA((2,)),
-        ]
+    pk_buf = pltpu.VMEM((2, step_groups, n_streams, GROUP), jnp.int32)
+    scratch = [
+        pltpu.VMEM(out_shape, jnp.float32),
+        p_scratch, pk_buf, pltpu.SemaphoreType.DMA((2,)),
+    ]
     # Scalar-prefetch operands live in SMEM (1 MiB on a v5e) for the whole
-    # call, so each kernel is handed only the streams it reads: the write
-    # slab per segment, ONE read-slab stream (per run for the
-    # segment-batched kernel, per group for the fallback), and the dequant
-    # scales on the int8 rung alone. Passing all four cost 8.1 B a group
-    # and put A2's own shape (n=2^19, ~166k groups) over the limit.
-    prefetch = [wslab, rrun if seg_batched else rslab]
+    # call, so the kernel is handed only the streams it reads: the write
+    # slab per segment, the read slab per run, and the dequant scales on
+    # the int8 rung alone. Passing all four cost 8.1 B a group
+    # and put a shape of n=2^19 rows (~166k groups) over the limit.
+    prefetch = [wslab, rrun]
     if storage == "int8":
         prefetch.append(srun)
     n_prefetch = len(prefetch)
@@ -891,7 +705,8 @@ def _tiled_apply_jit(
         whole packed stream stays in HBM (no slice copy; the kernel's DMA
         starts at ``step0``) and only the SMEM streams are sliced."""
         kernel = functools.partial(
-            kernel_fn, n_steps=steps, step0=step0, groups=groups, segs=segs,
+            _tile_kernel_seg, n_steps=steps, step0=step0, groups=groups,
+            segs=segs,
             run_groups=run_groups, square_vals=square_vals,
             pipeline=pipeline, storage=storage,
         )
@@ -980,7 +795,7 @@ def _tiled_apply(layout_arrays, src, out_pad, src_pad, square_vals=False):
 
     args = (
         layout_arrays, src, out_pad, src_pad, square_vals,
-        GROUPS_PER_STEP, SEGMENTS_PER_DMA, GROUPS_PER_RUN, SEGMENT_BATCHED,
+        GROUPS_PER_STEP, SEGMENTS_PER_DMA, GROUPS_PER_RUN,
         bool(PIPELINE_SEGMENTS), kernel_dtype(), _interpret(),
         # effective topology rides as a static key: a degrade-in-place
         # must never re-enter a pre-loss executable by shape coincidence,
@@ -1135,7 +950,7 @@ def _build_chunk(
 
 
 # bytes one packed slot of the tile-COO streams holds, by storage rung
-_SLOT_BYTES = {"f32": 12, "bf16": 6, "int8": 4}
+_SLOT_BYTES = {"f32": 12, "int8": 4}
 
 
 def _head_columns(counts: np.ndarray, num_rows: int,

@@ -1086,7 +1086,7 @@ def _score_matvec(b, wi):
     """The one scoring program, re-entered across objectives/visits. The
     tuned kernel constants ride along as a STATIC key: a nested jit's
     statics are resolved at the OUTER trace, so without this a
-    PIPELINE_SEGMENTS / SEGMENT_BATCHED toggle (which reshapes nothing)
+    PIPELINE_SEGMENTS toggle (which reshapes nothing)
     would silently re-enter the stale executable — the same
     never-by-luck rule as ``_tiled_apply`` itself. Analytic cost capture
     shadows the same key (constants are part of the signature), so a

@@ -16,7 +16,7 @@ This module is the one shared answer: a process-wide LRU keyed by
 
 where the fingerprint hashes the nonzero STRUCTURE (indices/values bytes,
 shape, feature count) and the tuned constants are the module-level
-GROUPS_PER_STEP / SEGMENTS_PER_DMA / GROUPS_PER_RUN / SEGMENT_BATCHED /
+GROUPS_PER_STEP / SEGMENTS_PER_DMA / GROUPS_PER_RUN /
 PIPELINE_SEGMENTS knobs read at call time — a retune invalidates by key,
 never by luck.
 Only the layout (the ``_TileChunk`` tuple, the dense head beside it and
@@ -75,7 +75,6 @@ def tuned_constants() -> tuple:
         st.GROUPS_PER_STEP,
         st.SEGMENTS_PER_DMA,
         st.GROUPS_PER_RUN,
-        bool(st.SEGMENT_BATCHED),
         # the dense head's rule decides which nonzeros the streams hold
         st.HEAD_MIN_FILL,
         # the pipeline schedule does not reshape the layout, but it keys
@@ -85,7 +84,7 @@ def tuned_constants() -> tuple:
         # future layout-coupled schedule would be silent garbage)
         bool(st.PIPELINE_SEGMENTS),
         # the precision rung RESHAPES the packed streams (f32 i32x3 /
-        # bf16 i16x3 / int8 i32x1 + scales): a stale hit across a toggle
+        # int8 i32x1 + scales): a stale hit across a toggle
         # would hand the kernel streams of the wrong width
         st.kernel_dtype(),
         # effective device topology: a degrade-in-place shrinks the

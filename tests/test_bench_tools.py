@@ -56,11 +56,11 @@ class TestQuickMode:
             "kernel_constants": {
                 "groups_per_run": 2,
                 "pipeline_segments": 1,
-                "kernel_dtype": "bf16",
+                "kernel_dtype": "int8",
             },
             "packed_stream_bytes_per_pass": 196608,
             "quality_parity": {
-                "kernel_dtype": "bf16",
+                "kernel_dtype": "int8",
                 "auc": 0.995066,
                 "auc_f32": 0.995074,
                 "auc_delta": -9e-06,
@@ -75,9 +75,9 @@ class TestQuickMode:
                     "counters": {}, "gauges": {}, "histograms": {},
                     "timers": {},
                 },
-                "knobs": {"kernel_dtype": "bf16", "groups_per_run": 2},
+                "knobs": {"kernel_dtype": "int8", "groups_per_run": 2},
                 "quality_parity": {
-                    "kernel_dtype": "bf16",
+                    "kernel_dtype": "int8",
                     "auc_delta": -9e-06,
                 },
             },
@@ -204,12 +204,12 @@ class TestQuickMode:
         # quality-parity block (AUC/loss deltas vs the f32 anchor) both
         # at top level and inside the telemetry block — a dtype sweep is
         # auditable (speed AND quality gate) from stdout alone
-        assert constants["kernel_dtype"] == "bf16"
+        assert constants["kernel_dtype"] == "int8"
         a2 = payload["configs"]["A2_sparse_highdim"]
         assert a2["packed_stream_bytes_per_pass"] == 196608
         assert a2["quality_parity"]["auc_delta"] == -9e-06
-        assert a2["quality_parity"]["kernel_dtype"] == "bf16"
-        assert a2["telemetry"]["knobs"]["kernel_dtype"] == "bf16"
+        assert a2["quality_parity"]["kernel_dtype"] == "int8"
+        assert a2["telemetry"]["knobs"]["kernel_dtype"] == "int8"
         assert a2["telemetry"]["quality_parity"]["auc_delta"] == -9e-06
         # the host-ingest pipeline knobs round-trip the same way: F's
         # prefetch depth + chunk-cache budget (and the measured host-pack

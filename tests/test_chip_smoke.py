@@ -151,8 +151,7 @@ def test_sparse_leg(tmp_path, monkeypatch):
     facts = chip_smoke.leg_sparse(str(tmp_path), n=2048, d=4096, k=8, iters=3)
     assert facts["tile_layout_packs"] >= 1 and facts["interpret"] is True
     assert facts["rel_diff"] <= 1e-4
-    # on CPU every rung runs (interpret mode); only a TPU refuses bf16
-    assert set(facts["rungs"]) == {"f32", "bf16", "int8"}
+    assert set(facts["rungs"]) == {"f32", "int8"}
     assert all(isinstance(r, dict) for r in facts["rungs"].values())
 
 

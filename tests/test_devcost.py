@@ -91,9 +91,9 @@ class TestCapture:
         r32 = devcost.capture("t.knob", _small_prog, (x,))
         assert r32 is not None and r32["knobs"]["kernel_dtype"] == "f32"
         assert devcost.capture("t.knob", _small_prog, (x,)) is None
-        monkeypatch.setenv("PHOTON_KERNEL_DTYPE", "bf16")
+        monkeypatch.setenv("PHOTON_KERNEL_DTYPE", "int8")
         rbf = devcost.capture("t.knob", _small_prog, (x,))
-        assert rbf is not None and rbf["knobs"]["kernel_dtype"] == "bf16"
+        assert rbf is not None and rbf["knobs"]["kernel_dtype"] == "int8"
 
     def test_knob_memo_invalidates_on_combine_and_replan_flips(
         self, telemetry, monkeypatch
@@ -294,7 +294,7 @@ class TestReportRoofline:
         from photon_ml_tpu.ops.sparse_tiled import kernel_dtype
 
         native = kernel_dtype()  # the run_start snapshot records this
-        other = "bf16" if native != "bf16" else "int8"
+        other = "int8" if native != "int8" else "f32"
         for rung, b in ((native, 1000.0), (other, 500.0)):
             emit_event(
                 "executable_cost", label="sparse_tiled.tiled_apply",

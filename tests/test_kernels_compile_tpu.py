@@ -81,16 +81,15 @@ def _lower_shipped(spec, specs, n, d, storage="f32"):
     return st._tiled_apply_jit.lower(
         specs, spec((d,), jnp.float32), n, d, False,
         st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA, st.GROUPS_PER_RUN,
-        True, True, storage, False, None,
+        True, storage, False, None,
     )
 
 
-def _compile_tile(spec, storage, *, pipeline, seg_batched=None, square=False):
+def _compile_tile(spec, storage, *, pipeline, square=False):
     specs, n, d = _layout_specs(spec, storage)
     return st._tiled_apply_jit.lower(
         specs, spec((d,), jnp.float32), n, d, square,
         st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA, st.GROUPS_PER_RUN,
-        st.SEGMENT_BATCHED if seg_batched is None else seg_batched,
         pipeline, storage, False, None,
     ).compile()
 
@@ -98,7 +97,7 @@ def _compile_tile(spec, storage, *, pipeline, seg_batched=None, square=False):
 class TestTileCooCompiles:
     def test_shipped_constants_are_the_ones_compiled(self):
         assert (st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA) == (32, 4)
-        assert st.GROUPS_PER_RUN == 2 and st.SEGMENT_BATCHED is True
+        assert st.GROUPS_PER_RUN == 2
 
     @pytest.mark.parametrize("pipeline", [True, False])
     @pytest.mark.parametrize("storage", ["f32", "int8"])
@@ -107,9 +106,6 @@ class TestTileCooCompiles:
 
     def test_hessian_diagonal_variant(self, topo):
         _compile_tile(_spec(topo), "f32", pipeline=True, square=True)
-
-    def test_per_group_fallback_kernel(self, topo):
-        _compile_tile(_spec(topo), "f32", pipeline=True, seg_batched=False)
 
     def test_a2_full_shape_fits_smem(self, topo):
         """A2's bench shape (n=2^19, d=2^17, 32 nonzeros a row, ~166k
@@ -165,13 +161,12 @@ class TestDenseHeadCompiles:
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-class TestBf16RungRefusesOnTpu:
-    def test_bf16_layout_build_raises_naming_itself(self, monkeypatch):
-        """Mosaic cannot slice the 3-stream int16 block for the per-step
-        DMA, so on a TPU the rung must refuse where the layout is built —
-        never a silent f32."""
-        monkeypatch.setattr(st, "_interpret", lambda: False)
-        with pytest.raises(NotImplementedError, match="PHOTON_KERNEL_DTYPE=bf16"):
+class TestBf16IsNoRung:
+    def test_bf16_layout_build_raises_naming_the_rungs(self):
+        """The bf16 rung never compiled for a TPU (Mosaic cannot slice a
+        3-stream int16 block for the per-step DMA) and is gone: a layout
+        asked for it fails the strict parse — never a silent f32."""
+        with pytest.raises(ValueError, match="valid rungs: f32, int8"):
             st.build_write_major_layout(
                 np.arange(8), np.arange(8), np.ones(8, np.float32),
                 st.SLAB, st.SLAB, storage="bf16",

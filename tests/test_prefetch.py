@@ -184,7 +184,7 @@ class TestDeviceChunkCache:
         s = prefetch.cache_stats()
         assert s["device_entries"] == 1 and s["evictions"] == 1
         prefetch.clear_cache()
-        monkeypatch.setenv("PHOTON_KERNEL_DTYPE", "bf16")
+        monkeypatch.setenv("PHOTON_KERNEL_DTYPE", "int8")
         for a in arrays:
             prefetch.cached_device_put({"values": a})
         s = prefetch.cache_stats()
@@ -225,7 +225,7 @@ class TestDeviceChunkCache:
         one device_put, no re-pack, correct values."""
         import ml_dtypes
 
-        monkeypatch.setenv("PHOTON_KERNEL_DTYPE", "bf16")
+        monkeypatch.setenv("PHOTON_KERNEL_DTYPE", "int8")
         vals = [np.full(256, i, np.float32) for i in range(3)]  # 512 B bf16
         labs = [np.full(128, i, np.float32) for i in range(3)]  # 512 B f32
         # fits exactly one (values, labels) pair on the device tier

@@ -515,114 +515,208 @@ class TestSlabRunBatching:
             assert (runs[:, 1] % R == 0).all()
 
 
+def _walk_layout(arrays, storage):
+    """A built layout read the plain way, with no kernel: every slot of every
+    group as ``(write index, read index, value)``, float64 values. A group's
+    write slab is its segment's ``wslab``, its read slab its own ``rslab``
+    (and its run's ``rrun``: they must agree), its within-slab offsets and
+    value come from ``packed`` (int8: the value is q times the run's
+    ``srun``). Reads the module's constants, as the kernel does."""
+    import photon_ml_tpu.ops.sparse_tiled as st
+
+    packed, wslab, rslab, rrun, srun = (np.asarray(a) for a in arrays)
+    n_groups = packed.shape[0]
+    np.testing.assert_array_equal(np.repeat(rrun, st.GROUPS_PER_RUN), rslab)
+    # one slab id a group, spread over the group's 128 slots
+    ws = np.repeat(wslab.astype(np.int64), st.GROUPS_PER_STEP)
+    assert len(ws) == len(rslab) == n_groups
+    ws = np.broadcast_to(ws[:, None], (n_groups, st.GROUP))
+    rs = np.broadcast_to(rslab.astype(np.int64)[:, None], (n_groups, st.GROUP))
+    if storage == "int8":
+        pk = packed[:, 0, :].astype(np.int64)
+        w_off, r_off = pk & 1023, (pk >> 10) & 1023
+        q = (pk >> 20) & 255
+        q = q - ((q & 128) << 1)
+        scale = np.repeat(srun.astype(np.float64), st.GROUPS_PER_RUN)
+        vals = q * scale[:, None]
+    else:
+        w_off = packed[:, 0, :].astype(np.int64) % SLAB
+        r_off = packed[:, 1, :].astype(np.int64) % SLAB
+        vals = np.ascontiguousarray(packed[:, 2, :]).view(np.float32).astype(
+            np.float64
+        )
+        # the f32 rung stores whole indices: their slab is the stream's
+        # (a filler's read index is 0 whatever slab its group reads)
+        np.testing.assert_array_equal(packed[:, 0, :] // SLAB, ws)
+        live = vals != 0
+        np.testing.assert_array_equal((packed[:, 1, :] // SLAB)[live], rs[live])
+    return (
+        (ws * SLAB + w_off).reshape(-1),
+        (rs * SLAB + r_off).reshape(-1),
+        vals.reshape(-1),
+    )
+
+
+def _walk_apply(arrays, src, out_pad, storage, square=False):
+    """``out[write] += value * src[read]`` over the walked slots, float64.
+    On the int8 rung the kernel gathers from a bfloat16-rounded source."""
+    import ml_dtypes
+
+    write, read, vals = _walk_layout(arrays, storage)
+    src = np.asarray(src, np.float32)
+    if storage != "f32":
+        src = src.astype(ml_dtypes.bfloat16)
+    out = np.zeros(out_pad, np.float64)
+    np.add.at(out, write, (vals * vals if square else vals)
+              * src.astype(np.float64)[read])
+    return out
+
+
+def _entries(rows, cols, vals, width):
+    """Sorted (row * width + col) keys and float64 sums of the nonzero
+    entries: a matrix, whatever order and padding it was stored in."""
+    keys, inv = np.unique(rows.astype(np.int64) * width + cols,
+                          return_inverse=True)
+    sums = np.bincount(inv.reshape(-1), weights=vals, minlength=len(keys))
+    return keys[sums != 0], sums[sums != 0]
+
+
+# the schedule's edge shapes: (GROUPS_PER_STEP, SEGMENTS_PER_DMA,
+# GROUPS_PER_RUN, n, d, k)
+_EDGE_SHAPES = {
+    # >=2 DMA steps: the last segment of step t hands its phase-2 MXU
+    # stream to step t+1's first-segment gather
+    "cross_step_boundary": (8, 2, 2, 2048, 4096, 4),
+    # the whole stream is ONE DMA step: the cross-step pl.when never
+    # fires — prologue + epilogue only
+    "one_step_stream": (8, 2, 2, 1024, 1024, 1),
+    # SEGMENTS_PER_DMA=1: EVERY step (the last included) holds a single
+    # segment, so every skew crosses the DMA-step boundary
+    "single_segment_steps": (8, 1, 2, 2048, 4096, 4),
+    # GROUPS_PER_STEP == GROUPS_PER_RUN: each segment is ONE slab run,
+    # so phase 1 is a single batched gather per segment
+    "single_run_segments": (2, 2, 2, 1500, 4096, 3),
+}
+
+
+def _retune(monkeypatch, step=8, dma=2, run=2):
+    import photon_ml_tpu.ops.sparse_tiled as st
+
+    monkeypatch.setattr(st, "GROUPS_PER_STEP", step)
+    monkeypatch.setattr(st, "SEGMENTS_PER_DMA", dma)
+    monkeypatch.setattr(st, "GROUPS_PER_RUN", run)
+
+
+def _plain_batch(rng, n, d, k):
+    idx = rng.integers(0, d, size=(n, k)).astype(np.int32)
+    val = rng.normal(size=(n, k)).astype(np.float32)
+    return SparseBatch(
+        indices=jnp.asarray(idx), values=jnp.asarray(val),
+        labels=jnp.zeros(n, jnp.float32),
+        offsets=jnp.zeros(n, jnp.float32),
+        weights=jnp.ones(n, jnp.float32), num_features=d,
+    )
+
+
+def _edge_batch(rng, monkeypatch, shape):
+    step, dma, run, n, d, k = _EDGE_SHAPES[shape]
+    _retune(monkeypatch, step, dma, run)
+    return _plain_batch(rng, n, d, k)
+
+
+def _n_steps(tb):
+    import photon_ml_tpu.ops.sparse_tiled as st
+
+    step_groups = st.GROUPS_PER_STEP * st.SEGMENTS_PER_DMA
+    return [int(c.m_arrays[0].shape[0]) // step_groups for c in tb.chunks]
+
+
+class TestLayoutWalk:
+    """The layout ALONE encodes the matrix: walked with plain numpy
+    (``_walk_layout``), a direction's five streams give back every entry of
+    the batch at its (row, column), exactly on the f32 rung and to half a
+    quantisation step of its cell on int8 — no kernel involved."""
+
+    @pytest.mark.parametrize("side", ["m_arrays", "g_arrays"])
+    @pytest.mark.parametrize("storage", ["f32", "int8"])
+    @pytest.mark.parametrize("shape", list(_EDGE_SHAPES))
+    def test_streams_decode_to_the_matrix(
+        self, rng, monkeypatch, shape, storage, side
+    ):
+        monkeypatch.setenv("PHOTON_KERNEL_DTYPE", storage)
+        batch = _edge_batch(rng, monkeypatch, shape)
+        (chunk,) = tile_sparse_batch(batch).chunks
+        idx = np.asarray(batch.indices).astype(np.int64)
+        val = np.asarray(batch.values).astype(np.float64)
+        rows = np.repeat(np.arange(idx.shape[0]), idx.shape[1])
+        width = max(chunk.n_pad, chunk.d_pad)
+        want_keys, want = _entries(rows, idx.reshape(-1), val.reshape(-1), width)
+        write, read, vals = _walk_layout(getattr(chunk, side), storage)
+        # margins write rows and read columns; the gradient the reverse
+        r, c = (write, read) if side == "m_arrays" else (read, write)
+        got_keys, got = _entries(r, c, vals, width)
+        if storage == "f32":
+            np.testing.assert_array_equal(got_keys, want_keys)
+            np.testing.assert_allclose(got, want, rtol=1e-6, atol=0)
+        else:
+            # an entry that quantises to 0 leaves the walk: it reads 0
+            at = np.searchsorted(want_keys, got_keys)
+            np.testing.assert_array_equal(want_keys[at], got_keys)
+            got_all = np.zeros(len(want))
+            got_all[at] = got
+            half_step = np.max(np.abs(val)) / 127.0 / 2.0
+            assert np.max(np.abs(got_all - want)) <= half_step * (1 + 1e-6)
+
+
 @pytest.mark.kernel
 class TestPipelinedKernel:
     """Software-pipelined segment schedule (PIPELINE_SEGMENTS): the skewed
     loop must produce BIT-IDENTICAL outputs to the straight-line schedule
     in interpret mode — same per-phase math, same accumulation order, only
     the instruction interleave differs — across the pipeline's epilogue
-    edge cases (single-segment DMA steps, single-run segments, the
-    cross-step overlap boundary, a one-step stream) and the non-batched
-    fallback kernel. Retuned-down constants throughout (tier-1 runtime
-    budget)."""
-
-    def _small(self, monkeypatch, step=8, dma=2, run=2):
-        import photon_ml_tpu.ops.sparse_tiled as st
-
-        monkeypatch.setattr(st, "GROUPS_PER_STEP", step)
-        monkeypatch.setattr(st, "SEGMENTS_PER_DMA", dma)
-        monkeypatch.setattr(st, "GROUPS_PER_RUN", run)
-
-    def _batch(self, rng, n, d, k):
-        idx = rng.integers(0, d, size=(n, k)).astype(np.int32)
-        val = rng.normal(size=(n, k)).astype(np.float32)
-        return SparseBatch(
-            indices=jnp.asarray(idx), values=jnp.asarray(val),
-            labels=jnp.zeros(n, jnp.float32),
-            offsets=jnp.zeros(n, jnp.float32),
-            weights=jnp.ones(n, jnp.float32), num_features=d,
-        )
+    edge cases (``_EDGE_SHAPES``), and both must agree with the plain walk
+    of the layout they read. Retuned-down constants throughout (tier-1
+    runtime budget)."""
 
     def _bitwise_both_schedules(self, batch, rng, monkeypatch):
         """All three kernel directions under both schedules: pipelined and
-        straight-line must agree BITWISE; returns the pipelined outputs
-        for the XLA parity check."""
+        straight-line must agree BITWISE, and with the float64 walk of
+        the same streams to float32 accumulation's error."""
         import photon_ml_tpu.ops.sparse_tiled as st
 
-        w = jnp.asarray(rng.normal(size=batch.num_features).astype(np.float32))
-        r = jnp.asarray(rng.normal(size=batch.num_rows).astype(np.float32))
+        w = rng.normal(size=batch.num_features).astype(np.float32)
+        r = rng.normal(size=batch.num_rows).astype(np.float32)
         outs = {}
         for flag in (1, 0):
             monkeypatch.setattr(st, "PIPELINE_SEGMENTS", flag)
             tb = tile_sparse_batch(batch)
             outs[flag] = (
-                np.asarray(tb.matvec(w)),
-                np.asarray(tb.rmatvec(r)),
-                np.asarray(tb.rmatvec_sq(r)),
+                np.asarray(tb.matvec(jnp.asarray(w))),
+                np.asarray(tb.rmatvec(jnp.asarray(r))),
+                np.asarray(tb.rmatvec_sq(jnp.asarray(r))),
             )
         for pipelined, straight in zip(outs[1], outs[0]):
             np.testing.assert_array_equal(pipelined, straight)
-        np.testing.assert_allclose(
-            outs[1][0], np.asarray(batch.matvec(w)), rtol=2e-3, atol=2e-3
+        (chunk,) = tb.chunks
+        w_pad = np.pad(w, (0, chunk.d_pad - len(w)))
+        r_pad = np.pad(r, (0, chunk.n_pad - len(r)))
+        n, d = batch.num_rows, batch.num_features
+        walked = (
+            _walk_apply(chunk.m_arrays, w_pad, chunk.n_pad, "f32")[:n],
+            _walk_apply(chunk.g_arrays, r_pad, chunk.d_pad, "f32")[:d],
+            _walk_apply(chunk.g_arrays, r_pad, chunk.d_pad, "f32", True)[:d],
         )
-        np.testing.assert_allclose(
-            outs[1][1], np.asarray(batch.rmatvec(r)), rtol=2e-3, atol=2e-3
-        )
-        return outs[1]
+        for got, want in zip(outs[1], walked):
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
-    def _n_steps(self, batch):
-        import photon_ml_tpu.ops.sparse_tiled as st
-
-        tb = tile_sparse_batch(batch)
-        step_groups = st.GROUPS_PER_STEP * st.SEGMENTS_PER_DMA
-        return [
-            int(c.m_arrays[0].shape[0]) // step_groups for c in tb.chunks
-        ]
-
-    def test_cross_step_overlap_boundary(self, rng, monkeypatch):
-        # ≥2 DMA steps: the last segment of step t hands its phase-2 MXU
-        # stream to step t+1's first-segment gather (the composed
-        # DMA+segment pipeline under test, not an accident of the shapes)
-        self._small(monkeypatch)
-        batch = self._batch(rng, n=2048, d=4096, k=4)
-        assert min(self._n_steps(batch)) >= 2
-        self._bitwise_both_schedules(batch, rng, monkeypatch)
-
-    def test_single_dma_step_stream(self, rng, monkeypatch):
-        # the whole stream is ONE DMA step: the cross-step pl.when never
-        # fires — prologue + epilogue only
-        self._small(monkeypatch)
-        batch = self._batch(rng, n=1024, d=1024, k=1)
-        assert self._n_steps(batch) == [1]
-        self._bitwise_both_schedules(batch, rng, monkeypatch)
-
-    def test_single_segment_dma_steps(self, rng, monkeypatch):
-        # SEGMENTS_PER_DMA=1: EVERY step (the last included) holds a
-        # single segment, so every skew crosses the DMA-step boundary
-        self._small(monkeypatch, step=8, dma=1)
-        batch = self._batch(rng, n=2048, d=4096, k=4)
-        assert min(self._n_steps(batch)) >= 2
-        self._bitwise_both_schedules(batch, rng, monkeypatch)
-
-    def test_single_run_segments(self, rng, monkeypatch):
-        # GROUPS_PER_STEP == GROUPS_PER_RUN: each segment is ONE slab run,
-        # so phase 1 is a single batched gather per segment
-        self._small(monkeypatch, step=2, dma=2, run=2)
-        batch = self._batch(rng, n=1500, d=4096, k=3)
-        self._bitwise_both_schedules(batch, rng, monkeypatch)
-
-    def test_fallback_kernel_pipelines_too(self, rng, monkeypatch):
-        # the non-batched per-group kernel gets the same skewed schedule
-        # through its own (new) double-buffered p_scratch. Extra-small
-        # constants: this kernel unrolls per GROUP, so its interpret-mode
-        # trace cost scales with GROUPS_PER_STEP (tier-1 runtime budget)
-        import photon_ml_tpu.ops.sparse_tiled as st
-
-        self._small(monkeypatch, step=4, dma=2, run=2)
-        monkeypatch.setattr(st, "SEGMENT_BATCHED", False)
-        # schedule-bitwise parity is row-count-independent; 640 rows keep
-        # multiple steps under the extra-small constants
-        batch = self._batch(rng, n=640, d=2048, k=2)
+    @pytest.mark.parametrize("shape", list(_EDGE_SHAPES))
+    def test_edge_shape(self, rng, monkeypatch, shape):
+        batch = _edge_batch(rng, monkeypatch, shape)
+        steps = _n_steps(tile_sparse_batch(batch))
+        if shape == "one_step_stream":
+            assert steps == [1]
+        elif shape != "single_run_segments":
+            assert min(steps) >= 2
         self._bitwise_both_schedules(batch, rng, monkeypatch)
 
     def test_toggle_recompiles_never_reuses(self, rng, monkeypatch):
@@ -632,8 +726,8 @@ class TestPipelinedKernel:
         stale compile whose argument shapes happen to coincide."""
         import photon_ml_tpu.ops.sparse_tiled as st
 
-        self._small(monkeypatch)
-        batch = self._batch(rng, n=1024, d=2048, k=2)
+        _retune(monkeypatch)
+        batch = _plain_batch(rng, n=1024, d=2048, k=2)
         w = jnp.asarray(rng.normal(size=batch.num_features).astype(np.float32))
         monkeypatch.setattr(st, "PIPELINE_SEGMENTS", 1)
         tb = tile_sparse_batch(batch)
@@ -652,7 +746,7 @@ class TestPipelinedKernel:
         from photon_ml_tpu.ops import tile_cache
 
         tile_cache.clear()
-        batch = self._batch(rng, n=2048, d=4096, k=4)
+        batch = _plain_batch(rng, n=2048, d=4096, k=4)
         monkeypatch.setattr(st, "PIPELINE_SEGMENTS", 1)
         tile_cache.tiled_layout_for(batch)
         monkeypatch.setattr(st, "PIPELINE_SEGMENTS", 0)
@@ -994,6 +1088,39 @@ class TestTopologyKeyedCaches:
         t2 = tile_cache.tuned_constants()
         assert t2[:-1] == t1[:-1]
         assert t2[-1][2] == 2 and t2 != t1
+
+    def test_keys_hold_exactly_the_constants_that_remain(self, monkeypatch):
+        """One kernel, two rungs: the layout cache's key and the kernel
+        executable's static arguments name the constants the module still
+        has, and nothing that is gone."""
+        import inspect
+
+        import photon_ml_tpu.ops.sparse_tiled as st
+        from photon_ml_tpu.ops import tile_cache
+        from photon_ml_tpu.parallel.multihost import effective_topology
+
+        monkeypatch.delenv("PHOTON_KERNEL_DTYPE", raising=False)
+        topology = effective_topology()
+        assert tile_cache.tuned_constants() == (
+            st.GROUP, st.SLAB, st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA,
+            st.GROUPS_PER_RUN, st.HEAD_MIN_FILL, bool(st.PIPELINE_SEGMENTS),
+            "f32", topology,
+        )
+        params = list(inspect.signature(st._tiled_apply_jit).parameters)
+        assert params == [
+            "layout_arrays", "src", "out_pad", "src_pad", "square_vals",
+            "groups", "segs", "run_groups", "pipeline", "storage",
+            "interpret", "topology",
+        ]
+        # _tiled_apply hands the jitted call exactly those, in that order
+        monkeypatch.setattr(st, "_tiled_apply_jit", lambda *args: args)
+        args = st._tiled_apply(("streams",), "src", 2048, 1024, True)
+        assert args == (
+            ("streams",), "src", 2048, 1024, True,
+            st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA, st.GROUPS_PER_RUN,
+            bool(st.PIPELINE_SEGMENTS), "f32", st._interpret(), topology,
+        )
+        assert st.KERNEL_DTYPES == ("f32", "int8")
 
     def test_tiled_apply_zero_growth_then_topology_miss(
         self, rng, monkeypatch

@@ -443,10 +443,10 @@ class TestExportAndReport:
         run) surfaces in summarize, format_summary and diff — the
         precision ladder's quality gate reads from the same report as the
         wall numbers."""
-        monkeypatch.setenv("PHOTON_KERNEL_DTYPE", "bf16")
-        path_b = obs.configure(str(tmp_path / "b"), run_id="runBF16")
+        monkeypatch.setenv("PHOTON_KERNEL_DTYPE", "int8")
+        path_b = obs.configure(str(tmp_path / "b"), run_id="runINT8")
         obs.emit_event(
-            "quality_parity", kernel_dtype="bf16",
+            "quality_parity", kernel_dtype="int8",
             auc=0.9951, auc_f32=0.9950, auc_delta=0.0001,
             final_loss=983.32, final_loss_f32=983.28,
             loss_rel_delta=4.4e-05, margins_rmse_vs_f32=0.0035,
@@ -456,18 +456,18 @@ class TestExportAndReport:
         path_a = obs.configure(str(tmp_path / "a"), run_id="runF32")
         obs.shutdown()
         b = summarize_run(path_b)
-        assert b["quality_parity"]["kernel_dtype"] == "bf16"
+        assert b["quality_parity"]["kernel_dtype"] == "int8"
         assert b["quality_parity"]["auc_delta"] == 0.0001
-        assert b["knobs"]["kernel_dtype"] == "bf16"
+        assert b["knobs"]["kernel_dtype"] == "int8"
         text = format_summary(b)
-        assert "quality-parity" in text and "kernel_dtype=bf16" in text
+        assert "quality-parity" in text and "kernel_dtype=int8" in text
         assert "auc_delta=+0.000100" in text
         a = summarize_run(path_a)
         assert a["quality_parity"] is None
         d = diff_summaries(a, b)
         assert "quality-parity" in d
         assert "(unrecorded)" in d  # run A recorded no parity block
-        assert "kernel_dtype: 'f32' -> 'bf16'" in d  # the knob delta too
+        assert "kernel_dtype: 'f32' -> 'int8'" in d  # the knob delta too
 
     def test_report_diff_renders_asymmetric_retune_knobs(self, tmp_path):
         """A RETUNE knob recorded by only ONE run (an older-schema run,
