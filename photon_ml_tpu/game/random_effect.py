@@ -316,7 +316,10 @@ class PreparedBucket:
     entity_ids: np.ndarray  # (k,) original entity ids (host)
     ids: Array | None  # (k,) the same ids staged to device (W scatter key)
     static: Batch | None  # (k_pad, C, …) features/labels/weights
-    row_idx: Array | None  # (k_pad, C) int32 device, clipped to >= 0
+    # where the bucket's slots sit in the residual offsets, read by
+    # ``_bucket_offsets``: (k_pad, C) int32 row numbers clipped to >= 0, or,
+    # where every lane's rows are consecutive, its (k_pad,) int32 run starts
+    row_idx: Array | None
     mask: Array | None  # (k_pad, C) 1.0 where the slot holds a real sample
     num_real: int  # k (before device-count padding)
     columns: Array | None = None  # (k_pad, p) int32 per-entity column map
@@ -418,6 +421,7 @@ def prepare_buckets(
         re_project_mode,
         subspace_columns,
     )
+    from photon_ml_tpu.obs.metrics import REGISTRY
     from photon_ml_tpu.parallel.placement import (
         re_shard_enabled,
         re_split_factor,
@@ -508,6 +512,11 @@ def prepare_buckets(
 
     own_pid = effective_process_index()
     zeros_off = np.zeros_like(np.asarray(labels))
+    starts = [_run_starts(r) for r in buckets.row_indices]
+    if parents is not None:
+        # the sub-buckets of one parent are concatenated again: one form a parent
+        scalar = {p for p, s in zip(parents, starts) if s is None}
+        starts = [None if p in scalar else s for p, s in zip(parents, starts)]
     prepared: list[PreparedBucket] = []
     for bi, (ent_ids, row_idx) in enumerate(
         zip(buckets.entity_ids, buckets.row_indices)
@@ -536,7 +545,10 @@ def prepare_buckets(
         static = gather_bucket(
             features, labels, zeros_off, weights, row_idx, columns=cols
         )
-        idx = jnp.asarray(np.maximum(row_idx, 0), jnp.int32)
+        runs = starts[bi] is not None
+        REGISTRY.counter_inc("re_offsets.slots", float(row_idx.size))
+        REGISTRY.counter_inc("re_offsets.run_slots", float(row_idx.size) if runs else 0.0)
+        idx = jnp.asarray(starts[bi] if runs else np.maximum(row_idx, 0), jnp.int32)
         mask = jnp.asarray((row_idx >= 0).astype(np.float32))
         columns = None if cols is None else jnp.asarray(cols)
         hash_S = None
@@ -661,6 +673,17 @@ def prepare_buckets(
             ],
         )
     return prepared
+
+
+def _run_starts(row_idx: np.ndarray) -> np.ndarray | None:
+    """The (k,) first rows of a bucket whose every lane holds one run of
+    consecutive rows (slot ``j`` of lane ``i`` holds row ``s_i + j`` wherever it
+    holds a row: an input sorted by the effect's id, grouped stably), or
+    None where one lane does not. A lane without rows starts at 0. Read
+    from the data, bucket by bucket; ``_bucket_offsets`` reads either form."""
+    first = np.maximum(row_idx[:, 0], 0)
+    runs = first[:, None] + np.arange(row_idx.shape[1], dtype=row_idx.dtype)
+    return first if np.array_equal(row_idx >= 0, row_idx == runs) else None
 
 
 # Lanes of one (capacity, width) class that are densified and solved at a
@@ -1255,7 +1278,8 @@ def _bucket_geometry(pb: PreparedBucket):
     return (
         jax.tree.structure(pb.static),
         static_leaves,
-        pb.row_idx.shape[1:],
+        pb.mask.shape[1:],  # the capacity C
+        pb.row_idx.ndim,  # run starts (1) never fuse with slot indices (2)
         None if pb.columns is None else pb.columns.shape[1],
         # hash-fold width (PHOTON_RE_PROJECT=hash): same capacity class
         # ⇒ same fold matrix, so equal keys still share one S — this
@@ -2158,6 +2182,40 @@ def _combine_owned_segments(
     return W, V, diag
 
 
+_ROW = 128  # the lane width an aligned row of the offsets is gathered at
+
+
+def _bucket_offsets(offsets: Array, row_idx: Array, mask: Array) -> Array:
+    """A bucket's (k_pad, C) residual offsets, zero in the padded slots.
+    SHARED by ``_bucket_step`` and ``_lane_prologue``, in either form
+    ``prepare_buckets`` staged: ``offsets[row_idx]`` by (k_pad, C) slot
+    indices (one scalar gather an index, 7 ns each on a v5e whatever it
+    points at), or, from (k_pad,) run starts, lane ``i`` as
+    ``offsets[s_i : s_i + C]``. The slice is one gather of whole aligned
+    128-wide rows, ``ceil(C / 128) + 1`` from row ``s_i // 128``, moved left
+    by ``s_i % 128`` in seven fixed-distance selects: no loop over lanes
+    (``vmap`` of ``dynamic_slice`` lowers to one trip a lane). Bitwise one
+    result: a real slot holds the same row's offset, and a padded slot reads
+    ``offsets[0]`` in both forms before the zero mask, so zero signs agree."""
+    if row_idx.ndim == 2:
+        return offsets[row_idx] * mask
+    k, C = mask.shape
+    rows = -(-C // _ROW) + 1
+    nrow = -(-offsets.shape[0] // _ROW)
+    # the same array for every bucket of a visit: formed once a program
+    table = jnp.pad(offsets, (0, nrow * _ROW - offsets.shape[0])).reshape(nrow, _ROW)
+    q, r = row_idx // _ROW, row_idx % _ROW
+    # rows past the table's end repeat its last: only padded slots see them
+    at = jnp.minimum(q[:, None] + jnp.arange(rows, dtype=q.dtype)[None, :], nrow - 1)
+    win = table[at].reshape(k, rows * _ROW)
+    for b in range(7):
+        moved = jnp.concatenate(
+            [win[:, 1 << b:], jnp.zeros((k, 1 << b), win.dtype)], axis=1
+        )
+        win = jnp.where((((r >> b) & 1) == 1)[:, None], moved, win)
+    return jnp.where(mask != 0, win[:, :C], offsets[0]) * mask
+
+
 def _extract_lanes(M, ids, columns, k, k_pad, d, pad_value=0.0, sharding=None):
     """Extract, pad, project, and (optionally) shard one bucket's rows of
     an (E, d) matrix — the warm-start/prior lane convention. SHARED by the
@@ -2266,14 +2324,12 @@ def _bucket_step(
     sharding: Any,
     **minimize_kwargs,
 ):
-    """ONE device dispatch per bucket per descent iteration: offset gather,
-    warm-start extraction, the vmapped solve, and the (E, d) scatter update
-    all fuse into a single compiled program. The previous eager sequence
-    cost ~6 host→device dispatches per bucket — pure latency on remote-
-    attached accelerators (SURVEY.md §7 / VERDICT weak #6)."""
+    """One bucket's whole step (offsets, warm-start lanes, the vmapped solve,
+    the (E, d) scatter) as one program: the fused visit traces it inline, and
+    an eager caller queues every bucket with no host sync between them."""
     d = W.shape[1]
     with stage(RE_OFFSETS):
-        off_b = offsets[row_idx] * mask
+        off_b = _bucket_offsets(offsets, row_idx, mask)
     with stage(RE_SOLVE):
         bucket_batch = dataclasses.replace(static_batch, offsets=off_b)
         k_pad = static_batch.labels.shape[0]
@@ -2337,7 +2393,7 @@ def _lane_prologue(
     ``sharding=None`` — identical values."""
     d = W.shape[1]
     with stage(RE_OFFSETS):
-        off_b = offsets[row_idx] * mask
+        off_b = _bucket_offsets(offsets, row_idx, mask)
     with stage(RE_SOLVE):
         bucket_batch = dataclasses.replace(static_batch, offsets=off_b)
         k_pad = static_batch.labels.shape[0]

@@ -395,6 +395,19 @@ def summarize_run(path: str, records: list[dict] | None = None) -> dict:
             "width_classes": counter_v("re_subspace.width_classes"),
             "build_s": timer_s("re_subspace.build"),
         }
+    # residual offsets of random-effect buckets (re_offsets.*,
+    # game/random_effect.prepare_buckets): the slots (lanes x capacity) of
+    # every staged bucket, and those of the buckets whose lanes are runs of
+    # consecutive rows, read by one run start a lane in place of one index
+    # a slot. Present only on runs that prepared a random effect.
+    if "re_offsets.slots" in counters or "re_offsets.slots" in base_counters:
+        slots = counter_v("re_offsets.slots")
+        run_slots = counter_v("re_offsets.run_slots")
+        out["re_offsets"] = {
+            "slots": slots,
+            "run_slots": run_slots,
+            "run_slot_share": run_slots / slots if slots > 0 else None,
+        }
     # tile-COO layout builds (tile_layout.*, ops/sparse_tiled.
     # tile_sparse_batch): the stored nonzeros each build left to the
     # kernels' streams (the tail) and those it moved into the dense head of
@@ -681,6 +694,13 @@ def format_summary(s: dict) -> str:
             + (f" solved at {pad:.2f}x" if pad else "")
             + f" in {int(sub['width_classes'])} width classes, "
             f"index maps built in {_fmt_s(sub['build_s'])}"
+        )
+    ofs = s.get("re_offsets") or {}
+    if ofs.get("run_slot_share") is not None:
+        lines.append(
+            f"  re-offsets: {_fmt_qty(ofs['slots'])} bucket slots, "
+            f"{_fmt_qty(ofs['run_slots'])} "
+            f"({100.0 * ofs['run_slot_share']:.1f}%) read by run-start slices"
         )
     til = s.get("tile_layout") or {}
     if til.get("head_nonzero_share") is not None:

@@ -413,3 +413,82 @@ class TestBucketMerging:
         g = group_by_entity(ids)
         b = bucket_entities(g, capacities=(4, 8))
         assert b.capacities == (4,)  # all entities have 3 samples
+
+
+# ---------------------------------------------------------------------------
+# residual offsets: run starts (rows sorted by the effect's id) beside slot
+# indices (rows scattered), one descent, bit for bit the all-slot-index one
+# ---------------------------------------------------------------------------
+def _blocks_and_shuffled_run(path, monkeypatch, refuse):
+    """Fixed + per-user (the file sorted by user: blocks) + per-item
+    (scattered) through ``CoordinateDescent.run``; ``refuse`` takes the
+    run-start form away, as the parent commit had it."""
+    from photon_ml_tpu.game import random_effect as re_mod
+    from photon_ml_tpu.obs.metrics import REGISTRY
+
+    monkeypatch.setenv("PHOTON_RE_COMPACT_EVERY", "2" if path == "compacted" else "0")
+    if refuse:
+        monkeypatch.setattr(re_mod, "_run_starts", lambda rows: None)
+    task = TaskType.LOGISTIC_REGRESSION
+    effects = {"userId": (25, 3), "itemId": (14, 3)}
+    data = synthetic_game_data(np.random.default_rng(21), 900, 4, effects, task=task)
+    order = np.argsort(data.entity_ids["userId"], kind="stable")
+    batch = make_game_batch(
+        data.y[order],
+        {"global": data.X[order],
+         **{f"shard_{t}": data.entity_X[t][order] for t in effects}},
+        id_tags={t: data.entity_ids[t][order] for t in effects},
+    )
+    config = OptimizationConfig(
+        optimizer=CFG, regularization=RegularizationContext(RegularizationType.L2),
+        regularization_weight=1.0,
+    )
+    coords = {"fixed": FixedEffectCoordinate(
+        coordinate_id="fixed", batch=batch, feature_shard_id="global",
+        config=config, task_type=task, intercept_index=data.intercept_index,
+    )}
+    slots = {}
+    for tag, (entities, _) in effects.items():
+        g = group_by_entity(data.entity_ids[tag][order], num_entities=entities)
+        buckets = bucket_entities(g)
+        slots[tag] = sum(r.size for r in buckets.row_indices)
+        coords[f"per_{tag}"] = RandomEffectCoordinate(
+            coordinate_id=f"per_{tag}", batch=batch, feature_shard_id=f"shard_{tag}",
+            random_effect_type=tag, config=config, grouping=g, buckets=buckets,
+            task_type=task, num_entities=entities,
+        )
+    compacted_steps, step = [], re_mod._bucket_step_compacted
+
+    def counted_step(*args, **kwargs):
+        compacted_steps.append(1)
+        return step(*args, **kwargs)
+
+    monkeypatch.setattr(re_mod, "_bucket_step_compacted", counted_step)
+    REGISTRY.reset(prefix="re_offsets")
+    res = CoordinateDescent(coords, batch, task).run(list(coords), num_iterations=2)
+    assert bool(compacted_steps) == (path == "compacted")  # ``_lane_prologue``'s path
+    counters = {k: v["value"] for k, v in
+                REGISTRY.snapshot("re_offsets.")["counters"].items()}
+    out = {f"scores.{cid}": np.asarray(s) for cid, s in res.training_scores.items()}
+    for cid in coords:
+        out[f"w.{cid}"] = np.asarray(res.model[cid].coefficient_means)
+        if cid != "fixed":
+            out[f"iterations.{cid}"] = np.asarray(res.trackers[cid][-1].iterations)
+    return out, counters, slots
+
+
+@pytest.mark.parametrize("path", ["fused", "compacted"])
+def test_run_start_offsets_leave_the_descent_bitwise(path, monkeypatch):
+    got, counters, slots = _blocks_and_shuffled_run(path, monkeypatch, refuse=False)
+    assert counters == {
+        "re_offsets.slots": slots["userId"] + slots["itemId"],
+        "re_offsets.run_slots": slots["userId"],
+    }
+    want, refused, _ = _blocks_and_shuffled_run(path, monkeypatch, refuse=True)
+    assert refused["re_offsets.run_slots"] == 0
+    assert got.keys() == want.keys() and len(got) == 8
+    for name in got:
+        np.testing.assert_array_equal(
+            got[name].view(np.uint32), want[name].view(np.uint32), err_msg=name
+        )
+    assert got["iterations.per_userId"].max() > 1

@@ -489,3 +489,82 @@ def test_the_run_report_renders_the_subspace_counters(tmp_path, prepared):
     assert summary["re_subspace"]["build_s"] == 0.25
     assert "re-subspace: 10 entities" in format_summary(summary)
     assert "solved at 1.50x in 2 width classes" in format_summary(summary)
+
+
+# ---------------------------------------------------------------------------
+# residual offsets by run starts: a sparse per-user effect over rows sorted
+# by user (width classes, chunk padding; every lane still one run) beside a
+# dense per-item effect over scattered rows
+# ---------------------------------------------------------------------------
+def _blocks_and_shuffled_sparse_run(path, monkeypatch, refuse):
+    from photon_ml_tpu.game import random_effect as re_mod
+    from photon_ml_tpu.obs.metrics import REGISTRY
+
+    monkeypatch.setenv("PHOTON_RE_COMPACT_EVERY", "2" if path == "compacted" else "0")
+    if refuse:
+        monkeypatch.setattr(re_mod, "_run_starts", lambda rows: None)
+    d, users, items = 400, 18, 9
+    idx, val, ids, y = _sparse_problem(9, n=700, d=d, entities=users)
+    order = np.argsort(ids, kind="stable")
+    idx, val, ids, y = idx[order], val[order], ids[order], y[order]
+    rng = np.random.default_rng(10)
+    item_ids = rng.integers(0, items, size=len(y)).astype(np.int32)
+    batch = make_game_batch(
+        y,
+        {"global": rng.normal(size=(len(y), 4)).astype(np.float32),
+         "per_user": SparseFeatures(indices=jnp.asarray(idx), values=jnp.asarray(val),
+                                    num_features=d),
+         "per_item": rng.normal(size=(len(y), 3)).astype(np.float32)},
+        id_tags={"userId": ids, "itemId": item_ids},
+    )
+    config = _opt(OptimizerConfig(max_iterations=40, tolerance=1e-8))
+    coords = {"fixed": FixedEffectCoordinate(
+        coordinate_id="fixed", batch=batch, feature_shard_id="global",
+        config=config, task_type=TASK,
+    )}
+    for cid, tag, column, entities in (("per_user", "userId", ids, users),
+                                       ("per_item", "itemId", item_ids, items)):
+        g = group_by_entity(column, num_entities=entities)
+        coords[cid] = RandomEffectCoordinate(
+            coordinate_id=cid, batch=batch, feature_shard_id=cid,
+            random_effect_type=tag, config=config, grouping=g,
+            buckets=bucket_entities(g), task_type=TASK, num_entities=entities,
+        )
+    REGISTRY.reset(prefix="re_offsets")
+    res = CoordinateDescent(coords, batch, TASK).run(list(coords), 2)
+    counters = {k: v["value"] for k, v in
+                REGISTRY.snapshot("re_offsets.")["counters"].items()}
+    out = {f"scores.{cid}": np.asarray(s) for cid, s in res.training_scores.items()}
+    for cid in coords:
+        out[f"w.{cid}"] = np.asarray(res.model[cid].coefficient_means)
+        if cid != "fixed":
+            out[f"iterations.{cid}"] = np.asarray(res.trackers[cid][-1].iterations)
+    # the classes that solve (by capacity and width), not the coordinate's buckets
+    slots = {cid: sum(pb.num_real * pb.mask.shape[1] for pb in coords[cid]._prepared)
+             for cid in ("per_user", "per_item")}
+    forms = {cid: {pb.row_idx.ndim for pb in coords[cid]._prepared}
+             for cid in ("per_user", "per_item")}
+    return out, counters, slots, forms
+
+
+@pytest.mark.parametrize("path", ["fused", "compacted"])
+def test_run_start_offsets_leave_the_sparse_descent_bitwise(path, monkeypatch):
+    got, counters, slots, forms = _blocks_and_shuffled_sparse_run(
+        path, monkeypatch, refuse=False
+    )
+    assert forms == {"per_user": {1}, "per_item": {2}}
+    assert counters == {
+        "re_offsets.slots": slots["per_user"] + slots["per_item"],
+        "re_offsets.run_slots": slots["per_user"],
+    }
+    want, refused, _, forms = _blocks_and_shuffled_sparse_run(
+        path, monkeypatch, refuse=True
+    )
+    assert forms == {"per_user": {2}, "per_item": {2}}
+    assert refused["re_offsets.run_slots"] == 0
+    assert got.keys() == want.keys() and len(got) == 8
+    for name in got:
+        np.testing.assert_array_equal(
+            got[name].view(np.uint32), want[name].view(np.uint32), err_msg=name
+        )
+    assert got["iterations.per_user"].max() > 1
