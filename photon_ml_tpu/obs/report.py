@@ -411,17 +411,25 @@ def summarize_run(path: str, records: list[dict] | None = None) -> dict:
     # tile-COO layout builds (tile_layout.*, ops/sparse_tiled.
     # tile_sparse_batch): the stored nonzeros each build left to the
     # kernels' streams (the tail) and those it moved into the dense head of
-    # popular columns, with the head's width. Present only on runs that
+    # popular columns, with the head's width; the non-empty cells of the
+    # streams (one direction) and their slots (both), so the nonzeros a
+    # cell holds and the streams' padding. Present only on runs that
     # built a tile-COO layout.
     if "tile_layout.tail_nonzeros" in counters or \
             "tile_layout.tail_nonzeros" in base_counters:
         head = counter_v("tile_layout.head_nonzeros")
         tail = counter_v("tile_layout.tail_nonzeros")
+        cells = counter_v("tile_layout.tail_cells")
+        slots = counter_v("tile_layout.tail_slots")
         out["tile_layout"] = {
             "head_columns": counter_v("tile_layout.head_columns"),
             "head_nonzeros": head,
             "tail_nonzeros": tail,
             "head_nonzero_share": head / (head + tail) if head + tail > 0 else None,
+            "tail_cells": cells,
+            "tail_slots": slots,
+            "tail_cell_fill": tail / cells if cells > 0 else None,
+            "tail_pad_ratio": slots / (2.0 * tail) if tail > 0 else None,
         }
     # per-entity feature projection (re_project.*, game/projector): the
     # mean solved-width ratio and the per-lane bytes the subspace solves

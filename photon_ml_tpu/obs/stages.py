@@ -36,6 +36,8 @@ RE_SUBSPACE = "re.subspace"  # lanes through a sparse shard's column maps
 RE_SPARSE_PASS = "re.sparse_pass"  # a subspace lane's passes over its rows
 RE_SCORE = "re.score"  # the W[ids] gathers and (n, d_e) work of scoring
 GLM_OBJECTIVE = "glm.objective"  # every pass over the data (ops/glm)
+GLM_HEAD = "glm.head"  # inside it: the dense head's multiply-reduces
+GLM_TAIL = "glm.tail"  # inside it: the tile-COO kernels (ops/sparse_tiled)
 LBFGS_TWO_LOOP = "lbfgs.two_loop"  # the search direction (optim/lbfgs)
 LBFGS_LINE_SEARCH = "lbfgs.line_search"  # trial points and their loops
 LBFGS_UPDATE = "lbfgs.update"  # acceptance, ring buffers, next state
@@ -47,7 +49,7 @@ COORD_PREFIX = "coord."  # + the coordinate id: which coordinate's visit
 # cache with the names it was compiled with: after a scope moves with no
 # instruction changing, a warm cache would keep serving the old names to
 # every profile. Raise this when a site or a name of this module changes.
-VERSION = 2
+VERSION = 3
 
 _NOT_SEGMENT = re.compile(r"[^A-Za-z0-9_.\-]")
 

@@ -262,7 +262,12 @@ def optimize_batch_layout(
     far as head, tail, the tail's relayout copy and the input batch fit
     ``hbm_budget_bytes``; the kernels then scatter the tail only. A matrix
     without popular columns is tiled whole, as before. Either way a row's
-    repeated draws of a column are merged into one entry."""
+    repeated draws of a column are merged into one entry, and each chunk's
+    streams take the form its cells' occupancy asks for
+    (``sparse_tiled._cell_form``): whole runs where the 1,024 x 1,024 cells
+    are full, granules of 16 slots with eight source slabs a group where
+    they are near empty, as at 10^6 columns. Nothing here is a knob: the
+    build reads the column counts, the cell counts and the budget."""
     out = maybe_densify(batch, hbm_budget_bytes, dtype)
     if isinstance(out, SparseBatch):
         from photon_ml_tpu.ops import tile_cache

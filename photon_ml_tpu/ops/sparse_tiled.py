@@ -64,6 +64,27 @@ Design — write-slab-major tile-COO, built ONCE at ingest:
   had; streamed chunks, per-device shards and feature-range slices pass no
   budget (their pytrees must share one structure) and are never asked.
 
+- TWO FORMS OF A STREAM, chosen by the build from the cells it finds
+  (PR 31). The nonzeros a cell holds fall as 1 over the matrix's width:
+  711 at 47,236 columns, 20 at 10^6. A run of ``GROUPS_PER_RUN`` groups
+  pads the first by 9% and the second 12.5 times over. The resident build
+  (the one that is handed the budget, as for the head) therefore counts
+  every chunk's cells (``_cell_form``: the ``np.unique`` counts the sort
+  returns anyway) and, where whole runs would hold more than
+  ``SUB_GROUP_COST`` times the slots of whole granules, lays the chunk
+  out in the SPARSE-CELL form: a cell pads to a granule of ``GROUP //
+  SUB_SLABS`` = 16 slots, ``rrun`` holds one read slab a granule, and the
+  kernel's phase 1 loads and lane-gathers ``SUB_SLABS`` slabs a group,
+  keeping from each the lanes of its granule (the slab ids stay in HBM and
+  ride each DMA step into SMEM: one kernel call a stream of any length).
+  Phase 2, the packed streams and the write-slab segments are the same in
+  both forms; which form a stream has is read off ``rrun``'s length. A
+  matrix whose cells are full gets the runs, the streams and the kernel
+  it always had, byte for byte; shards, streamed chunks, feature-range
+  slices and the int8 rung keep the run form (one structure to stack, a
+  per-cell scale a run). The head's budget counts the tail at the padding
+  its form will have (``_tail_padding``), not at a fixed quarter.
+
 - ONE ENTRY A (ROW, COLUMN). A padded-sparse row may name a column twice,
   and such entries add. The build merges them (``_merge_repeats``: the
   first draw keeps the sum), so head and tail alike hold the MATRIX's
@@ -97,6 +118,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from photon_ml_tpu.obs.stages import GLM_HEAD, GLM_TAIL, stage
+
 Array = jnp.ndarray
 
 GROUP = 128  # nonzeros per group: one vreg row, shares one (write, read) cell
@@ -117,6 +140,17 @@ SEGMENTS_PER_DMA = 4  # segments per DMA step (128 groups = 16K nnz per fetch)
 # padding-neutral default — retune per workload like the two constants
 # above (must divide GROUPS_PER_STEP).
 GROUPS_PER_RUN = 2  # groups per slab RUN: all read ONE source slab
+# The SPARSE-CELL form of a stream (module docstring, "TWO FORMS"): a cell
+# pads to a granule of GROUP // SUB_SLABS slots and a group reads up to
+# SUB_SLABS source slabs. 8 won the chip's timing of 2 / 4 / 8 on cells of
+# 20 nonzeros (a lane gather costs under 1 ns a group; 16 would pad 1.2
+# against 1.39 at +7 ns a group and twice the code), and 128 groups x 8 ids
+# fill the 1,024-word tile Mosaic slices a DMA step's ids by. A group then
+# takes 44.2 ns against a run group's 36.8 (criteo_fit traced, and rcv1_fit
+# with the form forced on its full cells: PERF.md, PR 31): the ratio is
+# the weight by which ``_cell_form`` compares the two forms' padded slots.
+SUB_SLABS = 8  # source slabs a group of the sparse-cell form may read
+SUB_GROUP_COST = 1.2  # a sparse-cell group's kernel time over a run group's
 # The dense head's rule (see _head_columns): a column joins the head when it
 # stores a nonzero in at least this share of the rows. Origin: a dense
 # float32 column costs 8 B a row a pass (read once in each direction) at
@@ -219,11 +253,15 @@ class _Layout:
     # [write, read, val bits], int8: S=1
     # [write off10 | read off10 << 10 | symmetric q8 << 20]
     wslab: np.ndarray  # (M/(GROUP*GROUPS_PER_STEP),) int32: per-segment slab
-    rslab: np.ndarray  # (M/GROUP,) int32 read slab id per group
-    rrun: np.ndarray  # (M/(GROUP*GROUPS_PER_RUN),) int32: per-RUN read slab
+    rslab: np.ndarray  # (M/GROUP,) int32 read slab id per group (the
+    # sparse-cell form: of the group's first granule; no kernel reads it)
+    rrun: np.ndarray  # (M/(GROUP*GROUPS_PER_RUN),) int32: per-RUN read slab;
+    # the sparse-cell form: (M/GROUP*SUB_SLABS,), per GRANULE. Which form a
+    # stream has is read off this length, by the kernel too
     srun: np.ndarray  # (M/(GROUP*GROUPS_PER_RUN),) f32: per-RUN dequant
     # scale (each run is single-cell, so this carries the per-CELL int8
     # symmetric scale; all-ones for the f32 rung, never read there)
+    cells: int = 0  # non-empty (write-slab, read-slab) cells of the stream
 
 
 def detect_slab_runs(rslab: np.ndarray) -> np.ndarray:
@@ -241,6 +279,33 @@ def detect_slab_runs(rslab: np.ndarray) -> np.ndarray:
     return np.stack([starts, lengths, r[starts]], axis=1)
 
 
+def _cell_form(counts: np.ndarray, groups_per_run: int,
+               storage: str) -> tuple[int, int]:
+    """The form that serves cells holding ``counts`` nonzeros cheaper, as
+    ``(sub_slabs, slots)``: 0 for the run form or ``SUB_SLABS`` for the
+    sparse-cell form, and the slots its cells pad to. A cell pads to whole
+    runs in the one and to whole granules in the other, and a group of the
+    sparse-cell form costs ``SUB_GROUP_COST`` run groups, so the sparse-cell
+    form wins where the run form's padded slots exceed that many times its
+    own: at 20 nonzeros a cell (12.5 against 1.7) and not at 711 (1.09
+    against 1.02). The int8 rung keeps the run form (its per-cell scale
+    rides the run), and so does a carve whose DMA step does not fill whole
+    1,024-word tiles with slab ids, which Mosaic could not slice (the
+    shipped carve does: 128 groups x 8; the interpreter takes any)."""
+    run_nnz = GROUP * groups_per_run
+    granule = GROUP // SUB_SLABS
+    run_slots = int((-(-counts // run_nnz) * run_nnz).sum())
+    sub_slots = int((-(-counts // granule) * granule).sum())
+    step_ids = GROUPS_PER_STEP * SEGMENTS_PER_DMA * SUB_SLABS
+    if (
+        storage == "f32"
+        and (_interpret() or step_ids % 1024 == 0)
+        and sub_slots * SUB_GROUP_COST < run_slots
+    ):
+        return SUB_SLABS, sub_slots
+    return 0, run_slots
+
+
 def build_write_major_layout(
     write_idx: np.ndarray,
     read_idx: np.ndarray,
@@ -250,6 +315,7 @@ def build_write_major_layout(
     groups_per_step: int | None = None,
     groups_per_run: int | None = None,
     storage: str | None = None,
+    by_occupancy: bool = False,
 ) -> _Layout:
     """Sort nonzeros by (write-slab, read-slab) cell, pad each cell to a
     whole number of ``groups_per_run``-group RUNS (every group of a cell
@@ -266,7 +332,14 @@ def build_write_major_layout(
     probe). ``storage`` selects the packed-stream precision rung (see
     KERNEL_DTYPE): int8 narrows the streams and must be consumed by a
     kernel compiled for the same rung (the jit/layout caches key on
-    it)."""
+    it).
+
+    ``by_occupancy`` lets the build choose the form from the cells it
+    finds (``_cell_form``): where they are near empty each pads to a
+    granule of ``GROUP // SUB_SLABS`` slots instead of a run, ``rrun``
+    holds one read slab a GRANULE, and the kernel loads ``SUB_SLABS`` slabs
+    a group. Without it (shards and streamed chunks, whose streams must
+    share one structure) every stream has the run form."""
     if groups_per_step is None:
         groups_per_step = GROUPS_PER_STEP
     if groups_per_run is None:
@@ -292,7 +365,11 @@ def build_write_major_layout(
     w, r, v, cell = w[order], r[order], v[order], cell[order]
 
     uniq, start, counts = np.unique(cell, return_index=True, return_counts=True)
-    run_nnz = GROUP * groups_per_run
+    sub_slabs = (
+        _cell_form(counts, groups_per_run, storage)[0] if by_occupancy else 0
+    )
+    # the slots that share one read slab: a run, or a granule of a group
+    run_nnz = GROUP // sub_slabs if sub_slabs else GROUP * groups_per_run
     pc = (-(-counts // run_nnz) * run_nnz).astype(np.int64)  # padded cell nnz
     cell_ws = (uniq // nrs).astype(np.int64)
     cell_rs = (uniq % nrs).astype(np.int32)
@@ -331,28 +408,30 @@ def build_write_major_layout(
     out_r[pos] = r
     out_v[pos] = v
 
-    # per-group read slab: a cell's groups all read its slab; filler groups
-    # (write-slab/tail padding) read slab 0 — their values are all 0
+    # per-run read slab: cells pad to whole runs (granules) and
+    # write-slab/tail fillers start run-aligned, so every aligned run is
+    # single-slab by construction — the invariant the kernel's one load a
+    # run (a granule) rests on. Filler runs read slab 0: their values are 0
     n_groups = M_total // GROUP
-    rslab = np.zeros(n_groups, np.int32)
-    gc = (pc // GROUP).astype(np.int64)  # groups per cell
-    gc_excl = np.cumsum(gc) - gc
-    gpos = (
-        np.repeat(cell_out // GROUP, gc)
-        + np.arange(int(gc.sum()), dtype=np.int64)
-        - np.repeat(gc_excl, gc)
+    n_runs = M_total // run_nnz
+    rrun = np.zeros(n_runs, np.int32)
+    runs_per_cell = (pc // run_nnz).astype(np.int64)
+    rpc_excl = np.cumsum(runs_per_cell) - runs_per_cell
+    rpos = (
+        np.repeat(cell_out // run_nnz, runs_per_cell)
+        + np.arange(int(runs_per_cell.sum()), dtype=np.int64)
+        - np.repeat(rpc_excl, runs_per_cell)
     )
-    rslab[gpos] = np.repeat(cell_rs, gc)
+    rrun[rpos] = np.repeat(cell_rs, runs_per_cell)
+    # per-group read slab: every group of a run reads the run's; in the
+    # sparse-cell form the first granule's (no kernel reads it)
+    rslab = (
+        np.ascontiguousarray(rrun[::sub_slabs]) if sub_slabs
+        else np.repeat(rrun, groups_per_run)
+    )
 
     wslab = (out_w[::step_nnz] // SLAB).astype(np.int32)
-    # per-run read slab: cells pad to whole runs and write-slab/tail
-    # fillers (rslab 0) start run-aligned, so every aligned block is
-    # single-slab — the invariant the kernel's once-per-run load rests on
-    blocks = rslab.reshape(-1, groups_per_run)
-    assert (blocks == blocks[:, :1]).all(), "slab run crosses a run block"
-    rrun = np.ascontiguousarray(blocks[:, 0])
-    n_runs = n_groups // groups_per_run
-    srun = np.ones(n_runs, np.float32)
+    srun = np.ones(len(rrun), np.float32)
     if storage == "f32":
         packed = np.stack(
             [
@@ -376,13 +455,6 @@ def build_write_major_layout(
                 np.rint(v / np.repeat(cell_scale, counts)), -127, 127
             ).astype(np.int64)
             out_q[pos] = q
-            runs_per_cell = (pc // run_nnz).astype(np.int64)
-            rpc_excl = np.cumsum(runs_per_cell) - runs_per_cell
-            rpos = (
-                np.repeat(cell_out // run_nnz, runs_per_cell)
-                + np.arange(int(runs_per_cell.sum()), dtype=np.int64)
-                - np.repeat(rpc_excl, runs_per_cell)
-            )
             srun[rpos] = np.repeat(cell_scale, runs_per_cell)
         packed = (
             (out_w.astype(np.int64) % SLAB)
@@ -390,7 +462,8 @@ def build_write_major_layout(
             | ((out_q & 0xFF) << 20)
         ).astype(np.int32).reshape(n_groups, 1, GROUP)
     return _Layout(
-        packed=packed, wslab=wslab, rslab=rslab, rrun=rrun, srun=srun
+        packed=packed, wslab=wslab, rslab=rslab, rrun=rrun, srun=srun,
+        cells=len(uniq),
     )
 
 
@@ -487,11 +560,26 @@ def _run_segment_schedule(dma, phase1, phase2, *, n_steps, segs, pipeline):
     jax.lax.fori_loop(0, n_steps, step, 0)
 
 
+class _Copies:
+    """Async copies started and waited together."""
+
+    def __init__(self, *copies):
+        self.copies = copies
+
+    def start(self):
+        for c in self.copies:
+            c.start()
+
+    def wait(self):
+        for c in self.copies:
+            c.wait()
+
+
 def _tile_kernel_seg(
     wslab_ref, rrun_ref, srun_ref, packed_hbm, src_ref, out_ref,
-    acc_scratch, p_scratch, pk_buf, dma_sem,
+    acc_scratch, p_scratch, pk_buf, dma_sem, ids_buf=None, ids_sem=None,
     *, n_steps, step0, groups, segs, run_groups, square_vals, pipeline,
-    storage,
+    storage, sub_slabs=0,
 ):
     """The tile-COO kernel: a ``fori_loop`` over DMA steps, each step
     fetching ``segs * groups`` groups in ONE double-buffered DMA and
@@ -530,7 +618,20 @@ def _tile_kernel_seg(
     dequantizes by the per-run SMEM scale (``srun_ref``, None on the
     f32 rung). Products land in f32 ``p_scratch`` either way, and
     phase 2's Dekker-split f32 MXU accumulation is IDENTICAL across
-    rungs."""
+    rungs.
+
+    ``sub_slabs`` selects the SPARSE-CELL form's phase 1 (see SUB_SLABS):
+    ``rrun_ref`` then holds a read slab a granule of ``GROUP // sub_slabs``
+    lanes, and a group loads and lane-gathers each of its ``sub_slabs``
+    slabs and keeps, lane range by lane range, the granule's own. At
+    ``sub_slabs`` words a group that stream would not fit SMEM as a
+    prefetch operand (32 B a group: 16 MB at 10^6 columns, eighteen kernel
+    calls and as many compiles), so it stays in HBM and each DMA step
+    brings its own words into the double-buffered ``ids_buf`` beside the
+    packed block: one call a stream whatever its length. Mosaic tiles a
+    1-D int32 array in HBM by 1,024, so a step's words must be a multiple
+    of that: 128 groups x 8 slabs, the shipped carve. Phase 2 does not
+    know the form."""
     step_groups = segs * groups
     seg_nnz = groups * GROUP
     seg_runs = groups // run_groups
@@ -540,14 +641,30 @@ def _tile_kernel_seg(
     iota8 = jax.lax.broadcasted_iota(jnp.int32, (8, GROUP), 0)
     iota8_seg = jax.lax.broadcasted_iota(jnp.int32, (8, seg_nnz), 0)
     iota_sub_seg = jax.lax.broadcasted_iota(jnp.int32, (GROUP, seg_nnz), 0)
+    if sub_slabs:
+        # lanes from granule u on: where slab u's gather replaces the others'
+        lane8 = jax.lax.broadcasted_iota(jnp.int32, (8, GROUP), 1)
+        from_granule = [lane8 >= u * (GROUP // sub_slabs) for u in range(sub_slabs)]
     acc_scratch[...] = jnp.zeros_like(acc_scratch)
 
     def dma(slot, t):
-        return pltpu.make_async_copy(
+        packed = pltpu.make_async_copy(
             packed_hbm.at[pl.ds((step0 + t) * step_groups, step_groups)],
             pk_buf.at[slot],
             dma_sem.at[slot],
         )
+        if not sub_slabs:
+            return packed
+        step_words = step_groups * sub_slabs
+        return _Copies(packed, pltpu.make_async_copy(
+            rrun_ref.at[pl.ds(
+                pl.multiple_of((step0 + t) * step_words, step_words), step_words
+            )],
+            ids_buf.at[pl.ds(
+                pl.multiple_of(slot * step_words, step_words), step_words
+            )],
+            ids_sem.at[slot],
+        ))
 
     def phase1(buf_slot, t, s2, p_slot):
         """Batched gather/sublane-select/product of segment (t, s2) from
@@ -597,6 +714,69 @@ def _tile_kernel_seg(
                 v * src_vals
             )
 
+    def phase1_sub(buf_slot, t, s2, p_slot):
+        """Phase 1 of the sparse-cell form: a slab a GRANULE. Each group
+        gathers its lanes from all ``sub_slabs`` of its slabs at once (one
+        lane gather over the slabs stacked along sublanes) and keeps from
+        slab u the lanes of granule u. Unrolled over the segment like the
+        run form's: rolled into a ``fori_loop`` over blocks of 8 groups the
+        kernel took 1.46 times as long (``criteo_fit`` ``fit_s`` 1.804
+        against 1.239 s; my chip runs, PR 31). ``lax`` primitives where a
+        ``jnp`` wrapper would do: at 5,000 sites a kernel each wrapper's
+        own jit lookup is most of a minute of tracing a process."""
+        g0 = s2 * groups
+        rd_all, vals_all = _decode_packed(
+            lambda s: pk_buf[buf_slot, g0:g0 + groups, s, :], storage
+        )  # (groups, GROUP) each
+        lane_all = rd_all & 127
+        sub_all = (rd_all >> 7) & 7
+        if square_vals:
+            vals_all = vals_all * vals_all
+        ids0 = (buf_slot * step_groups + g0) * sub_slabs
+        stacked = (sub_slabs * 8, GROUP)
+
+        def slab(k):
+            """The source slab of granule ``k`` of the segment."""
+            at = ids0 + k if isinstance(ids0, int) else jax.lax.add(
+                ids0, np.asarray(k, ids0.dtype)
+            )
+            row = jax.lax.mul(ids_buf[at], np.int32(8))
+            return src_ref[pl.ds(pl.multiple_of(row, 8), 8), :]
+
+        for gb in range(0, groups, run_groups):
+            rows = []
+            for j in range(gb, gb + run_groups):
+                lane_j = jax.lax.slice_in_dim(lane_all, j, j + 1, axis=0)
+                sub_j = jax.lax.slice_in_dim(sub_all, j, j + 1, axis=0)
+                slabs = jax.lax.concatenate(
+                    [slab(j * sub_slabs + u) for u in range(sub_slabs)], 0
+                )
+                got = jnp.take_along_axis(
+                    slabs, jax.lax.broadcast_in_dim(lane_j, stacked, (0, 1)),
+                    axis=1,
+                )
+                gathered = jax.lax.slice_in_dim(got, 0, 8, axis=0)
+                for u in range(1, sub_slabs):
+                    gathered = jax.lax.select(
+                        from_granule[u],
+                        jax.lax.slice_in_dim(got, u * 8, u * 8 + 8, axis=0),
+                        gathered,
+                    )
+                sel = jax.lax.convert_element_type(
+                    jax.lax.eq(
+                        iota8,
+                        jax.lax.broadcast_in_dim(sub_j, (8, GROUP), (0, 1)),
+                    ),
+                    jnp.float32,
+                )
+                rows.append(jnp.sum(
+                    jax.lax.mul(gathered, sel), axis=0, keepdims=True
+                ))
+            p_scratch[p_slot, gb:gb + run_groups, :] = jax.lax.mul(
+                jax.lax.slice_in_dim(vals_all, gb, gb + run_groups, axis=0),
+                jax.lax.concatenate(rows, 0),
+            )
+
     def phase2(buf_slot, t, s2, p_slot):
         """Whole-segment scatter staging + MXU contraction of segment
         (t, s2), reading phase 1's products from ``p_scratch[p_slot]``:
@@ -642,7 +822,8 @@ def _tile_kernel_seg(
         acc_scratch[idx, :] = acc_scratch[idx, :] + ms
 
     _run_segment_schedule(
-        dma, phase1, phase2, n_steps=n_steps, segs=segs, pipeline=pipeline
+        dma, phase1_sub if sub_slabs else phase1, phase2,
+        n_steps=n_steps, segs=segs, pipeline=pipeline,
     )
     out_ref[...] = acc_scratch[...]
 
@@ -663,6 +844,18 @@ def _tiled_apply_jit(
     packed, wslab, rslab, rrun, srun = layout_arrays
     step_groups = segs * groups
     n_steps = int(packed.shape[0]) // step_groups
+    # the stream's form is in its own shape: a read slab a run of
+    # run_groups groups, or (the sparse-cell form) several a group
+    n_groups, n_slab_ids = int(packed.shape[0]), int(rrun.shape[0])
+    sub_slabs = 0 if n_slab_ids * run_groups == n_groups else n_slab_ids // n_groups
+    if sub_slabs and (
+        sub_slabs * n_groups != n_slab_ids or GROUP % sub_slabs
+        or storage != "f32"
+    ):
+        raise ValueError(
+            f"a stream of {n_groups} groups with {n_slab_ids} read slabs on "
+            f"the {storage} rung is no form this kernel reads"
+        )
     src_shape = (src_pad // 128, 128)
     out_shape = (out_pad // 128, 128)
     src_mat = src.reshape(src_shape)
@@ -698,6 +891,16 @@ def _tiled_apply_jit(
     prefetch = [wslab, rrun]
     if storage == "int8":
         prefetch.append(srun)
+    hbm_inputs = [packed]
+    if sub_slabs:
+        # the sparse-cell form's slab ids stay in HBM: the kernel brings
+        # each DMA step's words into SMEM itself (see _tile_kernel_seg)
+        prefetch = [wslab]
+        hbm_inputs.append(rrun)
+        scratch = scratch + [
+            pltpu.SMEM((2 * step_groups * sub_slabs,), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ]
     n_prefetch = len(prefetch)
 
     def piece(step0, steps):
@@ -708,10 +911,12 @@ def _tiled_apply_jit(
             _tile_kernel_seg, n_steps=steps, step0=step0, groups=groups,
             segs=segs,
             run_groups=run_groups, square_vals=square_vals,
-            pipeline=pipeline, storage=storage,
+            pipeline=pipeline, storage=storage, sub_slabs=sub_slabs,
         )
 
         def body(*refs):
+            if sub_slabs:  # wslab | packed, slab ids | src, out, scratch
+                return kernel(refs[0], refs[2], None, refs[1], *refs[3:])
             srun_ref = refs[2] if storage == "int8" else None
             return kernel(refs[0], refs[1], srun_ref, *refs[n_prefetch:])
 
@@ -721,9 +926,9 @@ def _tiled_apply_jit(
                 num_scalar_prefetch=n_prefetch,
                 grid=(1,),
                 in_specs=[
-                    pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-                    pl.BlockSpec(src_shape, lambda i, *_: (0, 0)),
-                ],
+                    pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+                    for _ in hbm_inputs
+                ] + [pl.BlockSpec(src_shape, lambda i, *_: (0, 0))],
                 out_specs=pl.BlockSpec(out_shape, lambda i, *_: (0, 0)),
                 scratch_shapes=scratch,
             ),
@@ -738,7 +943,7 @@ def _tiled_apply_jit(
         sliced = [
             a[step0 * c:(step0 + steps) * c] for a, c in zip(prefetch, per_step)
         ]
-        return f(*sliced, packed, src_mat)
+        return f(*sliced, *hbm_inputs, src_mat)
 
     # A stream whose prefetch operands exceed SMEM runs as several calls
     # over consecutive step ranges, partial outputs summed. The split is
@@ -882,35 +1087,39 @@ class TiledSparseBatch:
         d = self.num_features
         w_pad = w if d == self.d_pad_total else jnp.pad(w, (0, self.d_pad_total - d))
         m = jnp.zeros((self.n_pad_total,), jnp.float32)
-        for c in self.chunks:
-            m = jax.lax.dynamic_update_slice(
-                m,
-                jax.lax.dynamic_slice(m, (c.row_start,), (c.n_pad,))
-                + c.matvec_part(w_pad),
-                (c.row_start,),
-            )
+        with stage(GLM_TAIL):
+            for c in self.chunks:
+                m = jax.lax.dynamic_update_slice(
+                    m,
+                    jax.lax.dynamic_slice(m, (c.row_start,), (c.n_pad,))
+                    + c.matvec_part(w_pad),
+                    (c.row_start,),
+                )
         m = m[: self.num_rows]
         if self.head_X is not None:
             # float32 multiply-reduces, not matmuls (which a TPU rounds to
             # bfloat16 by default): exact, and read at the HBM's rate
-            m = m + jnp.sum(self.head_X * w[self.head_cols], axis=1)
+            with stage(GLM_HEAD):
+                m = m + jnp.sum(self.head_X * w[self.head_cols], axis=1)
         return m
 
     def _rmatvec(self, r: Array, squared: bool) -> Array:
         n = self.num_rows
         r_pad = r if n == self.n_pad_total else jnp.pad(r, (0, self.n_pad_total - n))
         g = jnp.zeros((self.d_pad_total,), jnp.float32)
-        for c in self.chunks:
-            g = jax.lax.dynamic_update_slice(
-                g,
-                jax.lax.dynamic_slice(g, (c.col_start,), (c.d_pad,))
-                + c.rmatvec_part(r_pad, squared),
-                (c.col_start,),
-            )
+        with stage(GLM_TAIL):
+            for c in self.chunks:
+                g = jax.lax.dynamic_update_slice(
+                    g,
+                    jax.lax.dynamic_slice(g, (c.col_start,), (c.d_pad,))
+                    + c.rmatvec_part(r_pad, squared),
+                    (c.col_start,),
+                )
         g = g[: self.num_features]
         if self.head_X is not None:
-            X = self.head_X * self.head_X if squared else self.head_X
-            g = g.at[self.head_cols].add(jnp.sum(X * r[:, None], axis=0))
+            with stage(GLM_HEAD):
+                X = self.head_X * self.head_X if squared else self.head_X
+                g = g.at[self.head_cols].add(jnp.sum(X * r[:, None], axis=0))
         return g
 
     def rmatvec(self, r: Array) -> Array:
@@ -929,17 +1138,20 @@ _MAX_TABLE_COLS = 1 << 21  # 2M cols -> 2 x 8 MB
 def _build_chunk(
     rows: np.ndarray, cols: np.ndarray, vals: np.ndarray,
     row_start: int, col_start: int, n_pad: int, d_pad: int,
-) -> _TileChunk:
+    by_occupancy: bool = False,
+) -> tuple[_TileChunk, int]:
+    """One chunk's two layouts, and how many cells hold a nonzero (the same
+    cells in both directions, so both choose one form)."""
     storage = kernel_dtype()  # ONE call-time read for both directions
     m = build_write_major_layout(rows, cols, vals, n_pad, d_pad,
-                                 storage=storage)
+                                 storage=storage, by_occupancy=by_occupancy)
     g = build_write_major_layout(cols, rows, vals, d_pad, n_pad,
-                                 storage=storage)
+                                 storage=storage, by_occupancy=by_occupancy)
     as_j = lambda lay: tuple(
         jnp.asarray(a)
         for a in (lay.packed, lay.wslab, lay.rslab, lay.rrun, lay.srun)
     )
-    return _TileChunk(
+    chunk = _TileChunk(
         m_arrays=as_j(m),
         g_arrays=as_j(g),
         row_start=row_start,
@@ -947,14 +1159,15 @@ def _build_chunk(
         n_pad=n_pad,
         d_pad=d_pad,
     )
+    return chunk, m.cells
 
 
 # bytes one packed slot of the tile-COO streams holds, by storage rung
 _SLOT_BYTES = {"f32": 12, "int8": 4}
 
 
-def _head_columns(counts: np.ndarray, num_rows: int,
-                  free_bytes: float) -> np.ndarray | None:
+def _head_columns(counts: np.ndarray, num_rows: int, free_bytes: float,
+                  tail_padding) -> np.ndarray | None:
     """The dense head's columns for a matrix whose column c stores
     ``counts[c]`` nonzeros in ``num_rows`` rows: int64 ids by descending
     count, a multiple of ``HEAD_LANES`` of them, or None for no head.
@@ -964,9 +1177,13 @@ def _head_columns(counts: np.ndarray, num_rows: int,
     knows fills only, not sizes. The head then shrinks until what the
     layout will hold at a fit fits ``free_bytes``: the head's float32, and
     for every nonzero left to the tail a packed slot in each direction,
-    a quarter of padding on top (the padding is known only once built;
-    1.09-1.22 measured, PERF.md, PR 28) and the same again for the copy
-    into which XLA relayouts the streams once a fit (PERF.md finding 4).
+    times the padding the tail's form will have, and the same again for
+    the copy into which XLA relayouts the streams once a fit (PERF.md
+    finding 4). ``tail_padding(head_cols)`` gives that padding, slots over
+    nonzeros, for the tail that a head of ``head_cols`` leaves; it is asked
+    once, at the width the fills choose (a narrower head leaves the same
+    cells fuller, so the figure errs high). 1.09-1.22 on cells of 700
+    nonzeros (PERF.md, PR 28), 1.42 on cells of 20 (PR 31).
     A head that would hold under an eighth of the nonzeros is not worth
     its second code path: None."""
     blocks = len(counts) // HEAD_LANES
@@ -980,8 +1197,13 @@ def _head_columns(counts: np.ndarray, num_rows: int,
         block_nnz >= HEAD_MIN_FILL * HEAD_LANES * num_rows
     ))
     head_nnz = np.concatenate([[0], np.cumsum(block_nnz)])
-    # a tail nonzero: two slots, a quarter of padding, the relayout's copy
-    tail_bytes = 2 * _SLOT_BYTES[kernel_dtype()] * 1.25 * 2
+    if not width:
+        return None
+    # a tail nonzero: two slots, their padding, the relayout's copy
+    tail_bytes = (
+        2 * _SLOT_BYTES[kernel_dtype()]
+        * tail_padding(order[: width * HEAD_LANES]) * 2
+    )
     while width and (
         4.0 * num_rows * width * HEAD_LANES
         + tail_bytes * (total - head_nnz[width]) > free_bytes
@@ -990,6 +1212,22 @@ def _head_columns(counts: np.ndarray, num_rows: int,
     if head_nnz[width] * 8 < total:
         return None
     return order[: width * HEAD_LANES]
+
+
+def _tail_padding(indices: np.ndarray, live: np.ndarray, num_features: int,
+                  head_cols: np.ndarray) -> float:
+    """Slots over nonzeros of the tail that a head of ``head_cols`` leaves
+    of the ``live`` entries, in the form its cells will get (``_cell_form``;
+    row and column chunks are cut at slab boundaries, so the whole matrix's
+    cells are the chunks'). One count over the entries, no sort."""
+    in_tail = np.ones(num_features, bool)
+    in_tail[head_cols] = False
+    at = live & in_tail[indices]
+    col_slabs = -(-num_features // SLAB)
+    row_slab = np.arange(indices.shape[0], dtype=np.int64) // SLAB
+    cells = np.bincount(((row_slab * col_slabs)[:, None] + indices // SLAB)[at])
+    _, slots = _cell_form(cells[cells > 0], GROUPS_PER_RUN, kernel_dtype())
+    return slots / max(int(np.count_nonzero(at)), 1)
 
 
 @functools.partial(jax.jit, static_argnames=("num_features",))
@@ -1061,9 +1299,14 @@ def tile_sparse_batch(batch, keep_empty_chunks: bool = False,
     nonzero is tiled. It cannot be combined with ``keep_empty_chunks`` or
     ``fe_range``: shards and streamed chunks must share one pytree
     structure, and a head under a feature range is not built.
+    With it, too, each chunk's streams take the form their cells'
+    occupancy asks for (``_cell_form``): runs where cells are full, the
+    sparse-cell form where they are near empty.
     ``tile_layout.{head_columns, head_nonzeros, tail_nonzeros}`` in the
     registry count where every build put the input's stored nonzeros (the
-    streams hold ``tail_nonzeros`` less the repeats merged away).
+    streams hold ``tail_nonzeros`` less the repeats merged away),
+    ``tile_layout.tail_cells`` the non-empty cells of the streams (one
+    direction) and ``tile_layout.tail_slots`` their slots (both).
     """
     from photon_ml_tpu.obs.metrics import REGISTRY
 
@@ -1083,6 +1326,7 @@ def tile_sparse_batch(batch, keep_empty_chunks: bool = False,
         head_cols = _head_columns(
             np.bincount(indices[live], minlength=d), n,
             hbm_budget_bytes - indices.nbytes - values.nbytes,
+            functools.partial(_tail_padding, indices, live, d),
         )
     if head_cols is not None:
         in_tail = np.ones(d, bool)
@@ -1108,7 +1352,7 @@ def tile_sparse_batch(batch, keep_empty_chunks: bool = False,
     d_pad_total = -(-d // SLAB) * SLAB
     n_row_chunks = -(-n_pad_total // _MAX_TABLE_ROWS)
     n_col_chunks = -(-d_pad_total // _MAX_TABLE_COLS)
-    chunks = []
+    chunks, tail_cells = [], 0
     for rc in range(n_row_chunks):
         r0 = rc * _MAX_TABLE_ROWS
         r1 = min(r0 + _MAX_TABLE_ROWS, n_pad_total)
@@ -1123,13 +1367,21 @@ def tile_sparse_batch(batch, keep_empty_chunks: bool = False,
                 and not m.any()
             ):
                 continue
-            chunks.append(
-                _build_chunk(
-                    rows[m] - r0, cols[m] - c0, vals[m],
-                    row_start=r0, col_start=c0,
-                    n_pad=r1 - r0, d_pad=c1 - c0,
-                )
+            chunk, cells = _build_chunk(
+                rows[m] - r0, cols[m] - c0, vals[m],
+                row_start=r0, col_start=c0,
+                n_pad=r1 - r0, d_pad=c1 - c0,
+                # the resident layout alone: shards and streamed chunks
+                # must share one stream structure
+                by_occupancy=hbm_budget_bytes is not None,
             )
+            chunks.append(chunk)
+            tail_cells += cells
+    REGISTRY.counter_inc("tile_layout.tail_cells", float(tail_cells))
+    REGISTRY.counter_inc("tile_layout.tail_slots", float(sum(
+        int(arrays[0].shape[0]) * GROUP
+        for c in chunks for arrays in (c.m_arrays, c.g_arrays)
+    )))
     return TiledSparseBatch(
         chunks=tuple(chunks),
         labels=batch.labels,
@@ -1155,7 +1407,14 @@ def tiling_economical_features(num_features: int) -> bool:
     """The feature-dimension half of the tiling gate, shared with the
     streamed objective's auto rule (one decision, two ingest paths —
     duplicating it let the streamed rule drop the upper cap): genuinely
-    high-dimensional, but within the chunk-count economy ceiling."""
+    high-dimensional, but within the chunk-count ceiling (each column
+    chunk is two more kernel compiles). This bounds what CAN be tiled, not
+    what tiling costs: a cell's occupancy falls as 1 over the width, and
+    only the resident build adapts to it (``_cell_form``; 10^6 columns
+    measured, PERF.md PR 31). A streamed or sharded build keeps the run
+    form at any width and pads a 10^6-column chunk 12 times over; beyond
+    about 2 x 10^7 columns a cell holds 2 nonzeros and no form here is
+    economical (PERF.md, section 7)."""
     return 4096 <= num_features <= _MAX_TOTAL_COLS
 
 
@@ -1174,10 +1433,12 @@ def auto_tile_streaming(sparse: bool, num_features: int | None) -> bool:
 
 
 def supports_tiling(batch) -> bool:
-    """Static gate: shapes the tile-COO path handles well — a genuinely
+    """Static gate: shapes the tile-COO path can lay out — a genuinely
     sparse high-dimensional problem (the dense path beats it otherwise).
     Shapes beyond one kernel's VMEM bounds are row/col-chunked, so the
-    ceiling here is the chunk-count economy, not VMEM."""
+    ceiling here is the chunk count, not VMEM. What the layout then costs
+    depends on how full its cells are, which the resident build observes
+    and this gate does not (``tiling_economical_features``)."""
     from photon_ml_tpu.ops.batch import SparseBatch
 
     return (

@@ -17,7 +17,8 @@ This module is the one shared answer: a process-wide LRU keyed by
 where the fingerprint hashes the nonzero STRUCTURE (indices/values bytes,
 shape, feature count) and the tuned constants are the module-level
 GROUPS_PER_STEP / SEGMENTS_PER_DMA / GROUPS_PER_RUN /
-PIPELINE_SEGMENTS knobs read at call time — a retune invalidates by key,
+PIPELINE_SEGMENTS knobs (and SUB_SLABS / SUB_GROUP_COST, by which the
+resident build chooses a stream's form) read at call time — a retune invalidates by key,
 never by luck.
 Only the layout (the ``_TileChunk`` tuple, the dense head beside it and
 the pad metadata) is cached;
@@ -77,6 +78,11 @@ def tuned_constants() -> tuple:
         st.GROUPS_PER_RUN,
         # the dense head's rule decides which nonzeros the streams hold
         st.HEAD_MIN_FILL,
+        # the sparse-cell form's granule and the cost at which the resident
+        # build prefers it decide a stream's FORM (whether it is asked at
+        # all rides the key as ``hbm_budget_bytes``)
+        st.SUB_SLABS,
+        st.SUB_GROUP_COST,
         # the pipeline schedule does not reshape the layout, but it keys
         # here anyway so a toggle can NEVER reuse a stale entry (the same
         # never-by-luck rule as the stream-shaping constants; the cost of
@@ -199,7 +205,8 @@ def tiled_layout_for(batch, keep_empty_chunks: bool = False,
     P change invalidates by key, never by luck) and rides the built
     batch as its static ``fe_range`` meta field. ``hbm_budget_bytes``
     asks for the resident single-device layout, which may carry a dense
-    head inside that budget (``tile_sparse_batch``), so it joins the key
+    head inside that budget and gives each chunk's streams the form their
+    cells' occupancy asks for (``tile_sparse_batch``), so it joins the key
     too."""
     import photon_ml_tpu.ops.sparse_tiled as st
 

@@ -38,8 +38,11 @@ SPARSE_DESCENT_STAGES = (
     "re.solve", "re.subspace", "re.sparse_pass", "re.score", "glm.objective",
     "lbfgs.two_loop", "lbfgs.line_search", "lbfgs.update",
 )
+# a fit over a tile-COO layout with a dense head: the two parts of a pass
+TILE_FIT_STAGES = FIT_STAGES + ("glm.head", "glm.tail")
 PROGRAM_STAGES = {
-    "descent": DESCENT_STAGES, "tile_fit": FIT_STAGES, "sharded": FIT_STAGES,
+    "descent": DESCENT_STAGES, "tile_fit": TILE_FIT_STAGES,
+    "sharded": FIT_STAGES,
     "sparse_descent": SPARSE_DESCENT_STAGES,
 }
 
@@ -139,15 +142,19 @@ def tile_fit_program():
 
     rng = np.random.default_rng(0)
     n, d, k = 1 << 12, 1 << 13, 8
+    # Zipf(1.0) columns, so that the resident build finds a dense head
+    rank = np.floor(np.exp(rng.random((n, k)) * np.log(d + 1.0)) - 1.0)
     batch = SparseBatch(
-        indices=jnp.asarray(rng.integers(0, d, (n, k)), jnp.int32),
+        indices=jnp.asarray(
+            (np.clip(rank, 0, d - 1).astype(np.int64) * 3571 + 17) % d, jnp.int32
+        ),
         values=jnp.asarray(rng.normal(size=(n, k)), jnp.float32),
         labels=jnp.asarray(rng.random(n) < 0.5, jnp.float32),
         offsets=jnp.zeros((n,), jnp.float32),
         weights=jnp.ones((n,), jnp.float32), num_features=d,
     )
     objective = make_objective(
-        tile_sparse_batch(batch),
+        tile_sparse_batch(batch, hbm_budget_bytes=1e9),
         loss_for_task(TaskType.LOGISTIC_REGRESSION), l2_weight=1.0,
     )
     config = OptimizerConfig(max_iterations=3, tolerance=0.0)

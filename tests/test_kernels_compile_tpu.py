@@ -130,6 +130,57 @@ class TestTileCooCompiles:
         )
 
 
+class TestSparseCellFormCompiles:
+    """The tail's sparse-cell form (PR 31) at ``criteo_fit``'s table sizes
+    (2,292,031 rows x 10^6 columns) and about its stream length: ``SUB_SLABS``
+    slab ids a group stay in HBM and each DMA step brings its 1,024 words
+    into SMEM, so a stream of any length is ONE kernel call (as a prefetch
+    operand it would be 16 MB and eighteen calls). Mosaic tiles a 1-D int32
+    array in HBM by 1,024: the shipped carve's 128 groups x 8 slabs."""
+
+    N_PAD, D_PAD, GROUPS = 2_293_760, 1_000_448, 494_464
+
+    def _specs(self, spec):
+        g = self.GROUPS
+        return (
+            spec((g, 3, st.GROUP), jnp.int32),
+            spec((g // st.GROUPS_PER_STEP,), jnp.int32),
+            spec((g,), jnp.int32),
+            spec((g * st.SUB_SLABS,), jnp.int32),
+            spec((g * st.SUB_SLABS,), jnp.float32),
+        )
+
+    def test_a_steps_slab_ids_fill_whole_hbm_tiles(self):
+        step_groups = st.GROUPS_PER_STEP * st.SEGMENTS_PER_DMA
+        assert step_groups * st.SUB_SLABS % 1024 == 0
+
+    @pytest.mark.parametrize("pipeline", [True, False])
+    @pytest.mark.parametrize("direction", ["margins", "gradient", "gradient_sq"])
+    def test_one_call_a_stream_at_criteo_fit(self, topo, direction, pipeline):
+        spec = _spec(topo)
+        out, src = (
+            (self.N_PAD, self.D_PAD) if direction == "margins"
+            else (self.D_PAD, self.N_PAD)
+        )
+        text = st._tiled_apply_jit.lower(
+            self._specs(spec), spec((src,), jnp.float32), out, src,
+            direction == "gradient_sq",
+            st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA, st.GROUPS_PER_RUN,
+            pipeline, "f32", False, None,
+        ).compile().as_text()
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+
+    def test_the_int8_rung_has_no_sparse_cell_form(self, topo):
+        spec = _spec(topo)
+        with pytest.raises(ValueError, match="no form this kernel reads"):
+            st._tiled_apply_jit.lower(
+                self._specs(spec), spec((self.D_PAD,), jnp.float32),
+                self.N_PAD, self.D_PAD, False,
+                st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA, st.GROUPS_PER_RUN,
+                True, "int8", False, None,
+            )
+
+
 class TestDenseHeadCompiles:
     """The dense head beside the tile-COO tail (PR 28) at ``rcv1_fit``'s
     shape and the width its rule picks there: each direction must stay one

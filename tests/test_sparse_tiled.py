@@ -526,12 +526,24 @@ def _walk_layout(arrays, storage):
 
     packed, wslab, rslab, rrun, srun = (np.asarray(a) for a in arrays)
     n_groups = packed.shape[0]
-    np.testing.assert_array_equal(np.repeat(rrun, st.GROUPS_PER_RUN), rslab)
     # one slab id a group, spread over the group's 128 slots
     ws = np.repeat(wslab.astype(np.int64), st.GROUPS_PER_STEP)
     assert len(ws) == len(rslab) == n_groups
     ws = np.broadcast_to(ws[:, None], (n_groups, st.GROUP))
-    rs = np.broadcast_to(rslab.astype(np.int64)[:, None], (n_groups, st.GROUP))
+    if len(rrun) > n_groups:
+        # the sparse-cell form: a read slab a GRANULE of a group's lanes
+        # (``rslab`` keeps each group's first)
+        sub = len(rrun) // n_groups
+        assert storage == "f32" and sub == st.SUB_SLABS
+        np.testing.assert_array_equal(rrun[::sub], rslab)
+        rs = np.repeat(
+            rrun.astype(np.int64).reshape(n_groups, sub), st.GROUP // sub, axis=1
+        )
+    else:
+        np.testing.assert_array_equal(np.repeat(rrun, st.GROUPS_PER_RUN), rslab)
+        rs = np.broadcast_to(
+            rslab.astype(np.int64)[:, None], (n_groups, st.GROUP)
+        )
     if storage == "int8":
         pk = packed[:, 0, :].astype(np.int64)
         w_off, r_off = pk & 1023, (pk >> 10) & 1023
@@ -1103,9 +1115,11 @@ class TestTopologyKeyedCaches:
         topology = effective_topology()
         assert tile_cache.tuned_constants() == (
             st.GROUP, st.SLAB, st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA,
-            st.GROUPS_PER_RUN, st.HEAD_MIN_FILL, bool(st.PIPELINE_SEGMENTS),
-            "f32", topology,
+            st.GROUPS_PER_RUN, st.HEAD_MIN_FILL, st.SUB_SLABS,
+            st.SUB_GROUP_COST, bool(st.PIPELINE_SEGMENTS), "f32", topology,
         )
+        # a stream's FORM is no static argument: the kernel reads it off the
+        # stream's own shape (one read slab a run, or SUB_SLABS a group)
         params = list(inspect.signature(st._tiled_apply_jit).parameters)
         assert params == [
             "layout_arrays", "src", "out_pad", "src_pad", "square_vals",
@@ -1202,6 +1216,10 @@ def _later_draws(idx):
     return np.tril(same, k=-1).any(axis=2)
 
 
+# the tail's padding these tests' byte arithmetic assumes, whatever head
+_QUARTER = lambda head_cols: 1.25
+
+
 def _without_repeats(batch):
     val = np.asarray(batch.values).copy()
     val[_later_draws(np.asarray(batch.indices))] = 0.0
@@ -1218,12 +1236,13 @@ def _parents_chunk(batch):
     idx, val = np.asarray(batch.indices), np.asarray(batch.values)
     n, k = idx.shape
     keep = val.reshape(-1) != 0.0
-    return st._build_chunk(
+    chunk, _ = st._build_chunk(
         np.repeat(np.arange(n, dtype=np.int64), k)[keep],
         idx.reshape(-1).astype(np.int64)[keep], val.reshape(-1)[keep],
         row_start=0, col_start=0,
         n_pad=-(-n // SLAB) * SLAB, d_pad=-(-batch.num_features // SLAB) * SLAB,
     )
+    return chunk
 
 
 def _assert_chunks_equal(chunk, parent):
@@ -1344,10 +1363,12 @@ class TestDenseHead:
     ):
         """Uniform columns find no head; Zipf columns without a budget are
         not asked. Either way, rows that repeat no column are laid out
-        value for value as the builder laid them out before PR 28."""
+        value for value as the builder laid them out before PR 28 (the
+        budgeted build's cells are full, 1,100 nonzeros each, so it keeps
+        the run form too: ``TestSparseCellForm`` has the other side)."""
         batch = (
             _zipf_problem(rng, repeats=False) if popular
-            else _without_repeats(_sparse_problem(rng))
+            else _without_repeats(_sparse_problem(rng, n=2 * SLAB))
         )
         tiled = tile_sparse_batch(
             batch, **({} if popular else dict(hbm_budget_bytes=8e9))
@@ -1383,7 +1404,7 @@ class TestDenseHead:
         # the rule knows fills, not sizes: the same matrix at another
         # number of rows (a streamed chunk's 2^20 too) gets the same head
         counts = self._zipf_counts(nnz=73 * rows)
-        head = st._head_columns(counts, rows, 1e12)
+        head = st._head_columns(counts, rows, 1e12, _QUARTER)
         assert len(head) == 384
         np.testing.assert_array_equal(
             head, np.argsort(-counts, kind="stable")[: len(head)]
@@ -1400,18 +1421,18 @@ class TestDenseHead:
 
         counts = self._zipf_counts()
         rows = 1_354_798
-        free = st._head_columns(counts, rows, 1e12)
+        free = st._head_columns(counts, rows, 1e12, _QUARTER)
         by_count = np.sort(counts)[::-1]
         # what ``fits_blocks`` blocks of head pin beside their tail: the
         # tail's two slots a nonzero, a quarter of padding, and the
         # relayout's copy of the streams
         width = 128 * fits_blocks
         need = 4.0 * rows * width + 2 * 12 * 1.25 * 2 * by_count[width:].sum()
-        capped = st._head_columns(counts, rows, need)
+        capped = st._head_columns(counts, rows, need, _QUARTER)
         assert len(capped) == width < len(free)
         np.testing.assert_array_equal(capped, free[:width])
         # a byte less and the last block does not fit any more
-        fewer = st._head_columns(counts, rows, need - 1.0)
+        fewer = st._head_columns(counts, rows, need - 1.0, _QUARTER)
         assert (0 if fewer is None else len(fewer)) == width - 128
 
     def test_cap_counts_the_input_batch(self, rng):
@@ -1420,9 +1441,16 @@ class TestDenseHead:
         batch = _zipf_problem(rng)
         n, k = batch.indices.shape
         idx, val = np.asarray(batch.indices), np.asarray(batch.values)
+        import photon_ml_tpu.ops.sparse_tiled as st
+
         counts = np.bincount(idx[val != 0.0], minlength=batch.num_features)
         by_count = np.sort(counts)[::-1]
-        need = 4.0 * n * 128 + 2 * 12 * 1.25 * 2 * by_count[128:].sum()
+        # the tail's padding is the build's own figure for the tail that the
+        # one block the fills choose leaves, in the form its cells will get
+        block = np.argsort(-counts, kind="stable")[:128]
+        padding = st._tail_padding(idx, val != 0.0, batch.num_features, block)
+        assert padding > 1.0
+        need = 4.0 * n * 128 + 2 * 12 * padding * 2 * by_count[128:].sum()
         at_edge = tile_sparse_batch(batch, hbm_budget_bytes=need + 8 * n * k)
         assert at_edge.head_X is not None
         short = tile_sparse_batch(batch, hbm_budget_bytes=need + 8 * n * k - 1.0)
@@ -1431,7 +1459,7 @@ class TestDenseHead:
     def test_no_budget_for_any_block_means_no_head(self):
         import photon_ml_tpu.ops.sparse_tiled as st
 
-        assert st._head_columns(self._zipf_counts(), 1_354_798, 1.0) is None
+        assert st._head_columns(self._zipf_counts(), 1_354_798, 1.0, _QUARTER) is None
 
     @pytest.mark.parametrize("case", ["uniform", "one_full_column", "empty"])
     def test_matrix_without_popular_columns_gets_no_head(self, case):
@@ -1446,7 +1474,7 @@ class TestDenseHead:
             # but holds a thirtieth of the nonzeros, under the eighth
             counts[:] = rows * 31 // d
             counts[5] = rows
-        assert st._head_columns(counts, rows, 1e12) is None
+        assert st._head_columns(counts, rows, 1e12, _QUARTER) is None
 
     @pytest.mark.parametrize(
         "kwargs", [dict(keep_empty_chunks=True), dict(fe_range=(0, 0, 4608, 1))]
@@ -1514,10 +1542,20 @@ class TestDenseHead:
         head_cols = [] if tiled.head_cols is None else np.asarray(tiled.head_cols)
         in_head = int((np.isin(idx, head_cols) & (val != 0.0)).sum())
         assert (in_head > 0) == popular
+        # the tail's cells (one direction) and slots (both), as built
+        tail = ~np.isin(idx, head_cols) & (val != 0.0)
+        cell = (np.arange(idx.shape[0])[:, None] // SLAB) * (1 << 20) + idx // SLAB
+        cells = np.unique(cell[tail])
+        slots = sum(
+            a[0].shape[0] * a[0].shape[-1]
+            for c in tiled.chunks for a in (c.m_arrays, c.g_arrays)
+        )
         assert got == {
             "tile_layout.head_columns": float(len(head_cols)),
             "tile_layout.head_nonzeros": float(in_head),
             "tile_layout.tail_nonzeros": float(np.count_nonzero(val) - in_head),
+            "tile_layout.tail_cells": float(len(cells)),
+            "tile_layout.tail_slots": float(slots),
         }
 
     @pytest.mark.parametrize("built", ["head", "no_head", "never"])
@@ -1601,3 +1639,234 @@ class TestDenseHead:
             return
         assert summary["tile_layout"]["head_nonzero_share"] == 0.75
         assert "(75.0%) in a dense head of 384 columns" in format_summary(summary)
+
+
+def _cell_batch(rng, n, d, cells_nnz):
+    """A batch whose cell (row slab i, column slab j) holds
+    ``cells_nnz[i][j]`` nonzeros at random places, padded-sparse rows as
+    wide as the fullest row needs."""
+    rows, cols = [], []
+    for i, per_col_slab in enumerate(cells_nnz):
+        for j, count in enumerate(per_col_slab):
+            at = rng.choice(SLAB * SLAB, size=count, replace=False)
+            rows.append(i * SLAB + at // SLAB)
+            cols.append(j * SLAB + at % SLAB)
+    rows, cols = np.concatenate(rows), np.concatenate(cols)
+    keep = (rows < n) & (cols < d)
+    rows, cols = rows[keep], cols[keep]
+    k = int(np.bincount(rows, minlength=n).max())
+    idx = np.zeros((n, k), np.int32)
+    val = np.zeros((n, k), np.float32)
+    order = np.argsort(rows, kind="stable")
+    rows, cols = rows[order], cols[order]
+    slot = np.arange(len(rows)) - np.searchsorted(rows, rows)
+    idx[rows, slot] = cols
+    val[rows, slot] = rng.normal(size=len(rows)).astype(np.float32)
+    return SparseBatch(
+        indices=jnp.asarray(idx), values=jnp.asarray(val),
+        labels=jnp.zeros(n, jnp.float32), offsets=jnp.zeros(n, jnp.float32),
+        weights=jnp.ones(n, jnp.float32), num_features=d,
+    )
+
+
+# cell occupancies (row slabs x column slabs) and the form the build gives
+# them: "runs" where the cells are full, "sub" where they are near empty
+_FORM_CASES = {
+    # rcv1_fit's tail: 711 nonzeros a cell
+    "full_cells": ([[711] * 4] * 2, "runs"),
+    # glm_sparse_criteo's tail: 20 nonzeros a cell
+    "near_empty_cells": ([[20] * 24] * 2, "sub"),
+    "one_nonzero_a_cell": ([[1] * 24] * 2, "sub"),
+    # the middle row slab holds nothing: no segment writes it
+    "empty_write_slab": ([[20] * 24, [0] * 24, [20] * 24], "sub"),
+    # just under and just over a whole run: the sums decide, cell by cell
+    "just_under_a_run": ([[250] * 8] * 2, "runs"),
+    "just_over_a_run": ([[260] * 8] * 2, "sub"),
+}
+
+
+class TestSparseCellForm:
+    """The tail's second form (PR 31): where cells are near empty the
+    resident build pads a cell to a granule of a group's lanes, not to a run
+    of 256 slots, and the kernel reads ``SUB_SLABS`` source slabs a group.
+    The build chooses from the occupancy it observes; a matrix whose cells
+    are full gets the streams it always had."""
+
+    def _built(self, rng, monkeypatch, case, n=None, d=None):
+        cells, form = _FORM_CASES[case]
+        _retune(monkeypatch)
+        n = n or len(cells) * SLAB
+        d = d or len(cells[0]) * SLAB
+        batch = _cell_batch(rng, n, d, cells)
+        return batch, tile_sparse_batch(batch, hbm_budget_bytes=1e12), form
+
+    @pytest.mark.parametrize("case", list(_FORM_CASES))
+    def test_build_chooses_the_form_by_occupancy(self, rng, monkeypatch, case):
+        import photon_ml_tpu.ops.sparse_tiled as st
+
+        batch, tb, form = self._built(rng, monkeypatch, case)
+        assert tb.head_X is None  # uniform columns: no head
+        (chunk,) = tb.chunks
+        for arrays in (chunk.m_arrays, chunk.g_arrays):
+            groups, ids = arrays[0].shape[0], arrays[3].shape[0]
+            if form == "sub":
+                assert ids == groups * st.SUB_SLABS
+            else:
+                assert ids * st.GROUPS_PER_RUN == groups
+        # without the budget (shards, streamed chunks): runs, whatever
+        (plain,) = tile_sparse_batch(batch).chunks
+        for arrays in (plain.m_arrays, plain.g_arrays):
+            assert arrays[3].shape[0] * st.GROUPS_PER_RUN == arrays[0].shape[0]
+        if form == "runs":
+            _assert_chunks_equal(chunk, plain)
+            _assert_chunks_equal(chunk, _parents_chunk(batch))
+
+    @pytest.mark.parametrize("case", list(_FORM_CASES))
+    def test_streams_decode_to_the_matrix(self, rng, monkeypatch, case):
+        batch, tb, _ = self._built(rng, monkeypatch, case)
+        (chunk,) = tb.chunks
+        idx = np.asarray(batch.indices).astype(np.int64)
+        val = np.asarray(batch.values).astype(np.float64)
+        rows = np.repeat(np.arange(idx.shape[0]), idx.shape[1])
+        width = max(chunk.n_pad, chunk.d_pad)
+        want_keys, want = _entries(rows, idx.reshape(-1), val.reshape(-1), width)
+        for side in ("m_arrays", "g_arrays"):
+            write, read, vals = _walk_layout(getattr(chunk, side), "f32")
+            r, c = (write, read) if side == "m_arrays" else (read, write)
+            got_keys, got = _entries(r, c, vals, width)
+            np.testing.assert_array_equal(got_keys, want_keys)
+            np.testing.assert_allclose(got, want, rtol=1e-6, atol=0)
+
+    def test_padding_stays_near_the_nonzeros_where_cells_are_near_empty(
+        self, rng, monkeypatch
+    ):
+        """20 nonzeros a cell: runs would pad 12.8x, granules of 16 lanes
+        pad a cell to 32 slots at the most and a write slab to a segment."""
+        import photon_ml_tpu.ops.sparse_tiled as st
+        from photon_ml_tpu.obs.metrics import REGISTRY
+
+        REGISTRY.reset(prefix="tile_layout.")
+        batch, tb, _ = self._built(rng, monkeypatch, "near_empty_cells")
+        counters = {
+            k: v["value"]
+            for k, v in REGISTRY.snapshot("tile_layout.")["counters"].items()
+        }
+        nnz = int(np.count_nonzero(np.asarray(batch.values)))
+        assert counters["tile_layout.tail_nonzeros"] == nnz == 2 * 24 * 20
+        assert counters["tile_layout.tail_cells"] == 2 * 24
+        (chunk,) = tb.chunks
+        slots = sum(
+            a[0].shape[0] * st.GROUP for a in (chunk.m_arrays, chunk.g_arrays)
+        )
+        assert counters["tile_layout.tail_slots"] == slots
+        # a cell of 20 pads to 32; the margins' 2 write slabs of 768 slots
+        # fill a segment of 1,024 each, the gradient's 24 of 64 slots too
+        assert slots == 2 * 1024 + 24 * 1024
+        run_nnz = st.GROUP * st.GROUPS_PER_RUN
+        assert st._cell_form(np.full(48, 20), st.GROUPS_PER_RUN, "f32") == (
+            st.SUB_SLABS, 48 * 32
+        )
+        assert st._cell_form(np.full(48, 20), st.GROUPS_PER_RUN, "int8") == (
+            0, 48 * run_nnz
+        )
+
+    @pytest.mark.kernel
+    @pytest.mark.parametrize("pipeline", [1, 0])
+    @pytest.mark.parametrize("case", list(_FORM_CASES))
+    def test_kernels_match_the_walk_and_the_dense_matrix(
+        self, rng, monkeypatch, case, pipeline
+    ):
+        import photon_ml_tpu.ops.sparse_tiled as st
+
+        monkeypatch.setattr(st, "PIPELINE_SEGMENTS", pipeline)
+        batch, tb, _ = self._built(rng, monkeypatch, case)
+        self._check_against_walk_and_dense(batch, tb, rng)
+
+    @pytest.mark.kernel
+    def test_a_row_naming_a_column_twice_and_ragged_edges(self, rng, monkeypatch):
+        """n and d off the slab grid, and rows that name a column twice
+        (merged into one entry, so ``rmatvec_sq`` squares the sum)."""
+        batch, _, form = self._built(
+            rng, monkeypatch, "near_empty_cells", n=2 * SLAB - 77,
+            d=24 * SLAB - 13,
+        )
+        idx = np.asarray(batch.indices).copy()
+        val = np.asarray(batch.values).copy()
+        wide = np.flatnonzero((val != 0).sum(axis=1) >= 2)[:50]
+        idx[wide, 1] = idx[wide, 0]  # the second entry names the first's column
+        import dataclasses
+
+        batch = dataclasses.replace(
+            batch, indices=jnp.asarray(idx), values=jnp.asarray(val)
+        )
+        tb = tile_sparse_batch(batch, hbm_budget_bytes=1e12)
+        (chunk,) = tb.chunks
+        assert chunk.m_arrays[3].shape[0] > chunk.m_arrays[0].shape[0]
+        self._check_against_walk_and_dense(batch, tb, rng)
+
+    @staticmethod
+    def _check_against_walk_and_dense(batch, tb, rng):
+        n, d = batch.num_rows, batch.num_features
+        w = rng.normal(size=d).astype(np.float32)
+        r = rng.normal(size=n).astype(np.float32)
+        got = (
+            np.asarray(tb.matvec(jnp.asarray(w))),
+            np.asarray(tb.rmatvec(jnp.asarray(r))),
+            np.asarray(tb.rmatvec_sq(jnp.asarray(r))),
+        )
+        (chunk,) = tb.chunks
+        w_pad = np.pad(w, (0, chunk.d_pad - d))
+        r_pad = np.pad(r, (0, chunk.n_pad - n))
+        walked = (
+            _walk_apply(chunk.m_arrays, w_pad, chunk.n_pad, "f32")[:n],
+            _walk_apply(chunk.g_arrays, r_pad, chunk.d_pad, "f32")[:d],
+            _walk_apply(chunk.g_arrays, r_pad, chunk.d_pad, "f32", True)[:d],
+        )
+        dense = densify(batch)
+        want = (
+            np.asarray(dense.matvec(jnp.asarray(w))),
+            np.asarray(dense.rmatvec(jnp.asarray(r))),
+            np.asarray(dense.rmatvec_sq(jnp.asarray(r))),
+        )
+        for g, by_walk, by_dense in zip(got, walked, want):
+            np.testing.assert_allclose(g, by_walk, rtol=1e-5, atol=1e-5)
+            np.testing.assert_allclose(g, by_dense, rtol=1e-4, atol=1e-4)
+
+    def test_head_budget_counts_the_tail_at_its_own_padding(self, rng, monkeypatch):
+        """The head's budget arithmetic takes the tail's padding from the
+        cells the head would leave: popular columns over near-empty cells
+        get a tail budgeted at the sparse-cell form's 1.6-2, not at runs'
+        12.8, and not at a quarter whatever the cells hold."""
+        import photon_ml_tpu.ops.sparse_tiled as st
+
+        _retune(monkeypatch)
+        n, d = 2 * SLAB, 24 * SLAB
+        sparse = _cell_batch(rng, n, d, [[20] * 24] * 2)
+        k = sparse.indices.shape[1]
+        # 128 columns every row names, beside the sparse ones
+        idx = np.concatenate(
+            [np.asarray(sparse.indices),
+             np.broadcast_to(np.arange(128, dtype=np.int32) * 7, (n, 128))], axis=1
+        )
+        val = np.concatenate(
+            [np.asarray(sparse.values), np.ones((n, 128), np.float32)], axis=1
+        )
+        import dataclasses
+
+        batch = dataclasses.replace(
+            sparse, indices=jnp.asarray(idx), values=jnp.asarray(val)
+        )
+        live = val != 0.0
+        head = np.arange(128) * 7
+        padding = st._tail_padding(idx, live, d, head)
+        in_tail = np.ones(d, bool)
+        in_tail[head] = False
+        tail = int(np.count_nonzero(live & in_tail[idx]))
+        assert 1.5 < padding < 2.1
+        need = 4.0 * n * 128 + 2 * 12 * padding * 2 * tail
+        at_edge = tile_sparse_batch(batch, hbm_budget_bytes=need + idx.nbytes + val.nbytes)
+        assert at_edge.head_X is not None and at_edge.head_X.shape == (n, 128)
+        short = tile_sparse_batch(
+            batch, hbm_budget_bytes=need + idx.nbytes + val.nbytes - 1.0
+        )
+        assert short.head_X is None
