@@ -67,9 +67,6 @@ RETUNE_ENV = {
     "PHOTON_GROUPS_PER_STEP": "GROUPS_PER_STEP",
     "PHOTON_SEGMENTS_PER_DMA": "SEGMENTS_PER_DMA",
     "PHOTON_GROUPS_PER_RUN": "GROUPS_PER_RUN",
-    # 1 = software-pipelined segment schedule (phase 1 of segment s+1
-    # overlaps phase 2 of segment s), 0 = straight-line reference
-    "PHOTON_PIPELINE_SEGMENTS": "PIPELINE_SEGMENTS",
     # storage precision rung for the packed slabs + gathered operands
     # (f32 = bitwise anchor | int8 with per-tile scales); the ONE
     # string-valued knob — parsed strictly by validate_kernel_dtype, so a
@@ -721,7 +718,6 @@ def _sparse_logistic_bench(jax, jnp, n, d, k, iters, densify_dtype,
             "groups_per_step": st.GROUPS_PER_STEP,
             "segments_per_dma": st.SEGMENTS_PER_DMA,
             "groups_per_run": st.GROUPS_PER_RUN,
-            "pipeline_segments": int(st.PIPELINE_SEGMENTS),
             "kernel_dtype": st.kernel_dtype(),
         }
         # the streamed bytes at the active rung: what a dtype sweep diffs

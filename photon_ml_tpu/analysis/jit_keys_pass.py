@@ -6,7 +6,7 @@ recompiles instead of silently reusing a stale executable. The violation
 this pass hunts is the inverse: a function that enters ``jax.jit`` whose
 BODY calls a knob accessor (``kernel_dtype()``, ``prefetch_depth()``, …)
 or reads a retune-mutable module global (``GROUPS_PER_RUN``,
-``PIPELINE_SEGMENTS``, …) or the environment directly. Values read inside
+``SEGMENTS_PER_DMA``, …) or the environment directly. Values read inside
 a traced body are baked into the executable at first trace — the jit
 cache keys only on argument shapes/statics, so a later knob flip REUSES
 the stale program (PR 2's missing-static bug, found by hand then;

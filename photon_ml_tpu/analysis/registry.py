@@ -133,13 +133,6 @@ KNOBS: tuple[Knob, ...] = (
         sink_key="groups_per_run",
     ),
     Knob(
-        name="PHOTON_PIPELINE_SEGMENTS", kind="flag", parse="strict_int",
-        default="1", owner="photon_ml_tpu/ops/sparse_tiled.py",
-        doc="1 = software-pipelined segment schedule, 0 = straight-line",
-        retune_global="PIPELINE_SEGMENTS", retune_table="RETUNE_ENV",
-        sink_key="pipeline_segments",
-    ),
-    Knob(
         name="PHOTON_KERNEL_DTYPE", kind="enum", parse="enum",
         default="f32", owner="photon_ml_tpu/ops/sparse_tiled.py",
         doc="storage precision rung: f32 (bitwise anchor) | int8",

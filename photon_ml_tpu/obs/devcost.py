@@ -1,7 +1,7 @@
 """Analytic device-cost capture: per-executable XLA cost/memory analysis.
 
-Every perf knob so far (pipeline segments, groups-per-run, prefetch depth,
-compaction, kernel dtype) shipped bitwise-parity-tested but BLIND — no
+Every perf knob so far (groups-per-run, prefetch depth, compaction,
+kernel dtype) shipped bitwise-parity-tested but BLIND — no
 session has had a TPU attached, so no on-device cost number exists for any
 of them. XLA's AOT surface closes the gap on any backend: for a jitted
 callable, ``fn.lower(*args).compile()`` yields ``cost_analysis()`` (flops,
@@ -17,8 +17,7 @@ Capture discipline (the whole point is to never touch the hot path):
 
 - **Cache-miss only.** A process-wide seen-set keyed by ``(label, knob
   tuple, argument signature)`` mirrors the jit caches it shadows: the
-  knob tuple is the retune surface (dtype rung, pipeline segments,
-  groups-per-run, …) and the signature is tree structure + shape/dtype
+  knob tuple is the retune surface (dtype rung, groups-per-run, …) and the signature is tree structure + shape/dtype
   of every array leaf + repr of every static. A repeat call emits
   NOTHING and costs one tree flatten + the signature-tuple build + a
   set lookup (the knob snapshot is memoized on its raw env/global
@@ -230,7 +229,7 @@ def _knob_raw_state() -> tuple:
         pf.PREFETCH_DEPTH, pf.CHUNK_CACHE_BUDGET,
         len(pf._device_budget_memo),
         st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA,
-        st.GROUPS_PER_RUN, st.PIPELINE_SEGMENTS, st.KERNEL_DTYPE,
+        st.GROUPS_PER_RUN, st.KERNEL_DTYPE,
         re_state,
         shard_state,
         project_state,
@@ -256,9 +255,9 @@ def _knob_items() -> tuple:
 
 def knob_key() -> dict:
     """The retune surface an executable was compiled under — the same
-    knob snapshot a run's ``run_start`` records (dtype rung, pipeline
-    segments, groups-per-run, prefetch depth, compaction knobs), so cost
-    records key by CONFIGURATION, not by luck."""
+    knob snapshot a run's ``run_start`` records (dtype rung,
+    groups-per-run, prefetch depth, compaction knobs), so cost records
+    key by CONFIGURATION, not by luck."""
     return dict(_knob_items())
 
 

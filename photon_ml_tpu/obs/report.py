@@ -5,7 +5,7 @@ every on-chip sweep needs answered per run — where did the wall go
 (per-phase span seconds), how much was XLA compile, how much was
 host→device transfer, what did the optimizers do — and ``diff`` lines two
 runs up so a knob sweep (``PHOTON_PREFETCH_DEPTH``,
-``PHOTON_PIPELINE_SEGMENTS``, …) reads as a table instead of two log
+``PHOTON_GROUPS_PER_RUN``, …) reads as a table instead of two log
 greps. Phases are the first ``/`` segment of span names (``descent/iter``
 → ``descent``); a phase's wall is the UNION of its phase-entry spans'
 time intervals (entry = parent outside the phase), so neither nesting

@@ -355,7 +355,6 @@ def _knob_snapshot() -> dict:
         knobs["groups_per_step"] = int(st.GROUPS_PER_STEP)
         knobs["segments_per_dma"] = int(st.SEGMENTS_PER_DMA)
         knobs["groups_per_run"] = int(st.GROUPS_PER_RUN)
-        knobs["pipeline_segments"] = int(st.PIPELINE_SEGMENTS)
         knobs["kernel_dtype"] = st.kernel_dtype()
     except Exception:
         pass

@@ -127,7 +127,7 @@ GROUP = 128  # nonzeros per group: one vreg row, shares one (write, read) cell
 # accumulate) steps chained onto each write slab, which bound the
 # gradient direction (the margins direction is insensitive), at +1.4%
 # stream padding. Chosen on a remote-attached chip and never swept on the
-# v5e (ROADMAP S3). The DMA step stays at 128 groups (16K nnz per fetch).
+# v5e (ROADMAP S2b). The DMA step stays at 128 groups (16K nnz per fetch).
 GROUPS_PER_STEP = 32  # groups per SEGMENT: all share ONE write slab
 SEGMENTS_PER_DMA = 4  # segments per DMA step (128 groups = 16K nnz per fetch)
 # Slab-RUN batching: consecutive groups of one cell read the SAME source
@@ -163,22 +163,6 @@ SUB_GROUP_COST = 1.2  # a sparse-cell group's kernel time over a run group's
 # (PERF.md, PR 28): the threshold needs to be right to a factor of two.
 HEAD_MIN_FILL = 0.017
 HEAD_LANES = 128  # the head grows by whole lane blocks of columns
-# Software pipeline across SEGMENTS: phase 1 (VPU gather/select/product)
-# and phase 2 (scatter staging + MXU contraction) of one segment touch
-# disjoint scratch, so the
-# kernel double-buffers ``p_scratch`` (two segment slots) and issues
-# segment s+1's phase 1 BEFORE segment s's phase 2 — the VPU gather stream
-# of one segment overlaps the MXU dots of the previous one, hiding
-# whichever side is shorter. The skew carries across the DMA-step
-# boundary too (the last segment of step t overlaps the first segment of
-# step t+1, composing with the double-buffered DMA). 0 is the
-# straight-line schedule: the same per-phase math in the same
-# accumulation order, so the two are bitwise equal (tests). On the v5e
-# the straight-line schedule is the FASTER one (PERF.md, PR 29: fit_s
-# 0.748 against 0.787 s in rcv1_fit); the default is the next perf_opt
-# PR's to flip (ROADMAP S3). The package reads no environment variable
-# for it: only bench.py's RETUNE_ENV sets the global.
-PIPELINE_SEGMENTS = 1  # 1 = skewed segment schedule, 0 = straight-line
 SLAB = 1024  # outputs/inputs per slab: an (8, 128) block of a table
 # Storage rungs of the PACKED SLAB STREAMS and the gathered source
 # operand: the reduced rung holds and moves a third of the bytes (on the
@@ -491,75 +475,6 @@ def _decode_write_offsets(wr, storage):
     return wr
 
 
-def _run_segment_schedule(dma, phase1, phase2, *, n_steps, segs, pipeline):
-    """The kernel's per-step segment loop over its ``dma(slot, t)`` /
-    ``phase1(buf_slot, t, s2, p_slot)`` / ``phase2(buf_slot, t, s2,
-    p_slot)`` closures: the DMA pairing and slot-parity logic of both
-    schedules.
-
-    ``pipeline`` selects the skewed schedule (see PIPELINE_SEGMENTS):
-    prologue runs segment 0's phase 1; each steady-state iteration issues
-    segment s+1's phase 1 (VPU gather stream) before segment s's phase 2
-    (MXU contraction stream), crossing the DMA-step boundary at a step's
-    last segment by waiting the already-in-flight next fetch mid-step.
-    Every DMA semaphore is started and waited exactly once on either
-    schedule; the straight-line schedule runs phase 1 then phase 2 per
-    segment, slot 0 only."""
-    dma(0, 0).start()
-
-    if pipeline:
-        dma(0, 0).wait()
-        phase1(0, 0, 0, 0)
-
-        def step(t, carry):
-            slot = jax.lax.rem(t, 2)
-            nxt = jax.lax.rem(t + 1, 2)
-
-            # start the next fetch first (its pk_buf slot was last read by
-            # the previous iteration's trailing phase 2, already issued by
-            # this sequential core), so it overlaps this whole step
-            @pl.when(t + 1 < n_steps)
-            def _():
-                dma(nxt, t + 1).start()
-
-            for s2 in range(segs):
-                sg = t * segs + s2  # global segment index
-                cur_p = jax.lax.rem(sg, 2)
-                nxt_p = jax.lax.rem(sg + 1, 2)
-                # skew: the NEXT segment's phase 1 issues before THIS
-                # segment's phase 2 — disjoint p_scratch slots, so the
-                # gather stream and the MXU stream have no dependency
-                if s2 + 1 < segs:
-                    phase1(slot, t, s2 + 1, nxt_p)
-                else:
-                    @pl.when(t + 1 < n_steps)
-                    def _():
-                        # cross-step handoff: wait the already-in-flight
-                        # next fetch and pipeline its first segment
-                        # against this step's last contraction
-                        dma(nxt, t + 1).wait()
-                        phase1(nxt, t + 1, 0, nxt_p)
-                phase2(slot, t, s2, cur_p)
-            return carry
-    else:
-        def step(t, carry):
-            slot = jax.lax.rem(t, 2)
-            nxt = jax.lax.rem(t + 1, 2)
-
-            @pl.when(t + 1 < n_steps)
-            def _():
-                dma(nxt, t + 1).start()
-
-            dma(slot, t).wait()
-
-            for s2 in range(segs):
-                phase1(slot, t, s2, 0)
-                phase2(slot, t, s2, 0)
-            return carry
-
-    jax.lax.fori_loop(0, n_steps, step, 0)
-
-
 class _Copies:
     """Async copies started and waited together."""
 
@@ -578,8 +493,8 @@ class _Copies:
 def _tile_kernel_seg(
     wslab_ref, rrun_ref, srun_ref, packed_hbm, src_ref, out_ref,
     acc_scratch, p_scratch, pk_buf, dma_sem, ids_buf=None, ids_sem=None,
-    *, n_steps, step0, groups, segs, run_groups, square_vals, pipeline,
-    storage, sub_slabs=0,
+    *, n_steps, step0, groups, segs, run_groups, square_vals, storage,
+    sub_slabs=0,
 ):
     """The tile-COO kernel: a ``fori_loop`` over DMA steps, each step
     fetching ``segs * groups`` groups in ONE double-buffered DMA and
@@ -602,15 +517,10 @@ def _tile_kernel_seg(
     stream; the SMEM streams arrive sliced to that range, so only the DMA
     adds ``step0``.
 
-    ``pipeline`` selects the SOFTWARE-PIPELINED segment schedule (see
-    PIPELINE_SEGMENTS): ``p_scratch`` carries two segment slots and the
-    loop is skewed — prologue runs segment 0's phase 1, each steady-state
-    iteration issues segment s+1's phase 1 (VPU gather stream) before
-    segment s's phase 2 (MXU contraction stream), and at the step boundary
-    the NEXT step's DMA is waited mid-step so its first segment's phase 1
-    overlaps the last segment's phase 2. Both schedules run identical
-    per-phase math in identical accumulation order, so outputs are
-    BIT-IDENTICAL (asserted by the parity tests).
+    A step starts the next fetch, waits its own, and runs phase 1 then
+    phase 2 of each segment in turn. There is no skew of segment s+1's
+    phase 1 over segment s's phase 2: on the v5e it read 5% slower in
+    ``rcv1_fit`` and 11% in ``criteo_fit`` (PERF.md section 6, PRs 29 and 33).
 
     ``storage`` selects the packed-stream precision rung (KERNEL_DTYPE):
     only the stream decode changes — f32 bitcasts the value stream (the
@@ -666,9 +576,9 @@ def _tile_kernel_seg(
             ids_sem.at[slot],
         ))
 
-    def phase1(buf_slot, t, s2, p_slot):
+    def phase1(buf_slot, t, s2):
         """Batched gather/sublane-select/product of segment (t, s2) from
-        ``pk_buf[buf_slot]`` into ``p_scratch[p_slot]``."""
+        ``pk_buf[buf_slot]`` into ``p_scratch``."""
         g0 = s2 * groups
         # per-group skeleton, hoisted: one packed-buffer load per
         # stream and one value decode for the WHOLE segment
@@ -710,11 +620,9 @@ def _tile_kernel_seg(
                 v = v * srun_ref[t * step_runs + s2 * seg_runs + b]
                 if square_vals:
                     v = v * v
-            p_scratch[p_slot, gb:gb + run_groups, :] = (
-                v * src_vals
-            )
+            p_scratch[gb:gb + run_groups, :] = v * src_vals
 
-    def phase1_sub(buf_slot, t, s2, p_slot):
+    def phase1_sub(buf_slot, t, s2):
         """Phase 1 of the sparse-cell form: a slab a GRANULE. Each group
         gathers its lanes from all ``sub_slabs`` of its slabs at once (one
         lane gather over the slabs stacked along sublanes) and keeps from
@@ -772,14 +680,14 @@ def _tile_kernel_seg(
                 rows.append(jnp.sum(
                     jax.lax.mul(gathered, sel), axis=0, keepdims=True
                 ))
-            p_scratch[p_slot, gb:gb + run_groups, :] = jax.lax.mul(
+            p_scratch[gb:gb + run_groups, :] = jax.lax.mul(
                 jax.lax.slice_in_dim(vals_all, gb, gb + run_groups, axis=0),
                 jax.lax.concatenate(rows, 0),
             )
 
-    def phase2(buf_slot, t, s2, p_slot):
+    def phase2(buf_slot, t, s2):
         """Whole-segment scatter staging + MXU contraction of segment
-        (t, s2), reading phase 1's products from ``p_scratch[p_slot]``:
+        (t, s2), reading phase 1's products from ``p_scratch``:
         one relayout per stream, int8 one-hot compares, operands as
         values."""
         g0 = s2 * groups
@@ -789,7 +697,7 @@ def _tile_kernel_seg(
         wr_row = wr.reshape(1, seg_nnz)
         lane_w = wr_row & 127
         sub_w = (wr_row >> 7) & 7
-        p_row = p_scratch[p_slot].reshape(1, seg_nnz)
+        p_row = p_scratch[...].reshape(1, seg_nnz)
         # explicit broadcasts + mask-multiply: the implicit (1, n) ->
         # (8, n) broadcast inside compare/select trips a Mosaic
         # "invalid relayout" on the i1 mask
@@ -821,10 +729,26 @@ def _tile_kernel_seg(
         idx = pl.ds(pl.multiple_of(ws * 8, 8), 8)
         acc_scratch[idx, :] = acc_scratch[idx, :] + ms
 
-    _run_segment_schedule(
-        dma, phase1_sub if sub_slabs else phase1, phase2,
-        n_steps=n_steps, segs=segs, pipeline=pipeline,
-    )
+    phase1_of_form = phase1_sub if sub_slabs else phase1
+
+    def step(t, carry):
+        slot = jax.lax.rem(t, 2)
+        nxt = jax.lax.rem(t + 1, 2)
+
+        # start the next fetch first (its pk_buf slot was last read by the
+        # previous step), so it overlaps this whole step
+        @pl.when(t + 1 < n_steps)
+        def _():
+            dma(nxt, t + 1).start()
+
+        dma(slot, t).wait()
+        for s2 in range(segs):
+            phase1_of_form(slot, t, s2)
+            phase2(slot, t, s2)
+        return carry
+
+    dma(0, 0).start()
+    jax.lax.fori_loop(0, n_steps, step, 0)
     out_ref[...] = acc_scratch[...]
 
 
@@ -832,13 +756,13 @@ def _tile_kernel_seg(
     jax.jit,
     static_argnames=(
         "out_pad", "src_pad", "square_vals",
-        "groups", "segs", "run_groups", "pipeline",
+        "groups", "segs", "run_groups",
         "storage", "interpret", "topology",
     ),
 )
 def _tiled_apply_jit(
     layout_arrays, src, out_pad, src_pad, square_vals,
-    groups, segs, run_groups, pipeline, storage, interpret,
+    groups, segs, run_groups, storage, interpret,
     topology=None,
 ):
     packed, wslab, rslab, rrun, srun = layout_arrays
@@ -873,11 +797,8 @@ def _tiled_apply_jit(
     # another (the caches key on the rung, so the only way there is
     # hand-assembling mismatched pieces)
     n_streams = 1 if storage == "int8" else 3
-    # p_scratch: phase 1's per-segment products. The pipelined schedule
-    # double-buffers it (segment s+1's phase 1 writes one slot while
-    # segment s's phase 2 drains the other); straight-line needs one slot.
-    p_slots = 2 if pipeline else 1
-    p_scratch = pltpu.VMEM((p_slots, groups, GROUP), jnp.float32)
+    # p_scratch: phase 1's products of one segment, drained by its phase 2
+    p_scratch = pltpu.VMEM((groups, GROUP), jnp.float32)
     pk_buf = pltpu.VMEM((2, step_groups, n_streams, GROUP), jnp.int32)
     scratch = [
         pltpu.VMEM(out_shape, jnp.float32),
@@ -911,7 +832,7 @@ def _tiled_apply_jit(
             _tile_kernel_seg, n_steps=steps, step0=step0, groups=groups,
             segs=segs,
             run_groups=run_groups, square_vals=square_vals,
-            pipeline=pipeline, storage=storage, sub_slabs=sub_slabs,
+            storage=storage, sub_slabs=sub_slabs,
         )
 
         def body(*refs):
@@ -987,9 +908,8 @@ def _tiled_apply(layout_arrays, src, out_pad, src_pad, square_vals=False):
     also what makes the compiled kernel a PROCESS-WIDE executable cache:
     any layout with the same stream shapes and constants — across
     streaming chunks, GAME visits and CV folds — re-enters the same
-    compiled program. PIPELINE_SEGMENTS and the KERNEL_DTYPE storage rung
-    are part of the same static key: toggling either mid-process
-    recompiles, never reuses.
+    compiled program. The KERNEL_DTYPE storage rung is part of the same
+    static key: toggling it mid-process recompiles, never reuses.
 
     Analytic cost capture (``obs/devcost``) shadows the same key: an
     eager call whose (knob tuple, stream signature) is fresh captures the
@@ -1001,7 +921,7 @@ def _tiled_apply(layout_arrays, src, out_pad, src_pad, square_vals=False):
     args = (
         layout_arrays, src, out_pad, src_pad, square_vals,
         GROUPS_PER_STEP, SEGMENTS_PER_DMA, GROUPS_PER_RUN,
-        bool(PIPELINE_SEGMENTS), kernel_dtype(), _interpret(),
+        kernel_dtype(), _interpret(),
         # effective topology rides as a static key: a degrade-in-place
         # must never re-enter a pre-loss executable by shape coincidence,
         # and a same-topology re-entry compiles nothing new

@@ -1085,9 +1085,9 @@ def _score_matvec_keyed(b, wi, constants):
 def _score_matvec(b, wi):
     """The one scoring program, re-entered across objectives/visits. The
     tuned kernel constants ride along as a STATIC key: a nested jit's
-    statics are resolved at the OUTER trace, so without this a
-    PIPELINE_SEGMENTS toggle (which reshapes nothing)
-    would silently re-enter the stale executable — the same
+    statics are resolved at the OUTER trace, so without this a retune
+    that reshapes nothing (GROUPS_PER_STEP 32 / SEGMENTS_PER_DMA 4 to
+    16 / 8) would silently re-enter the stale executable — the same
     never-by-luck rule as ``_tiled_apply`` itself. Analytic cost capture
     shadows the same key (constants are part of the signature), so a
     fresh scoring executable's flops/bytes land in telemetry once."""

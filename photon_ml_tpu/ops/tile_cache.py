@@ -16,9 +16,9 @@ This module is the one shared answer: a process-wide LRU keyed by
 
 where the fingerprint hashes the nonzero STRUCTURE (indices/values bytes,
 shape, feature count) and the tuned constants are the module-level
-GROUPS_PER_STEP / SEGMENTS_PER_DMA / GROUPS_PER_RUN /
-PIPELINE_SEGMENTS knobs (and SUB_SLABS / SUB_GROUP_COST, by which the
-resident build chooses a stream's form) read at call time — a retune invalidates by key,
+GROUPS_PER_STEP / SEGMENTS_PER_DMA / GROUPS_PER_RUN knobs (and
+SUB_SLABS / SUB_GROUP_COST, by which the resident build chooses a
+stream's form) read at call time — a retune invalidates by key,
 never by luck.
 Only the layout (the ``_TileChunk`` tuple, the dense head beside it and
 the pad metadata) is cached;
@@ -83,12 +83,6 @@ def tuned_constants() -> tuple:
         # all rides the key as ``hbm_budget_bytes``)
         st.SUB_SLABS,
         st.SUB_GROUP_COST,
-        # the pipeline schedule does not reshape the layout, but it keys
-        # here anyway so a toggle can NEVER reuse a stale entry (the same
-        # never-by-luck rule as the stream-shaping constants; the cost of
-        # a spurious miss is one re-pack, the cost of a stale hit under a
-        # future layout-coupled schedule would be silent garbage)
-        bool(st.PIPELINE_SEGMENTS),
         # the precision rung RESHAPES the packed streams (f32 i32x3 /
         # int8 i32x1 + scales): a stale hit across a toggle
         # would hand the kernel streams of the wrong width

@@ -55,7 +55,7 @@ class TestQuickMode:
             "implied_hbm_fraction": 0.1,
             "kernel_constants": {
                 "groups_per_run": 2,
-                "pipeline_segments": 1,
+                "segments_per_dma": 4,
                 "kernel_dtype": "int8",
             },
             "packed_stream_bytes_per_pass": 196608,
@@ -193,11 +193,10 @@ class TestQuickMode:
         assert [c for c, _ in calls] == list(bench.QUICK_CONFIGS)
         assert all(q for _, q in calls)
         # the retune surface round-trips through the contract: A2's
-        # kernel_constants (incl. the pipeline-schedule knob) appear
-        # verbatim in the single JSON line, so a sweep is auditable from
-        # stdout alone
+        # kernel_constants appear verbatim in the single JSON line, so a
+        # sweep is auditable from stdout alone
         constants = payload["configs"]["A2_sparse_highdim"]["kernel_constants"]
-        assert constants["pipeline_segments"] == 1
+        assert constants["segments_per_dma"] == 4
         assert constants["groups_per_run"] == 2
         # the precision-ladder knob rides the same contract: kernel_dtype
         # in kernel_constants, the per-rung streamed bytes, and the
@@ -358,16 +357,16 @@ class TestQuickMode:
 
         monkeypatch.setattr(st, "GROUPS_PER_RUN", 2)
         monkeypatch.setattr(st, "GROUPS_PER_STEP", 32)
-        monkeypatch.setattr(st, "PIPELINE_SEGMENTS", 1)
+        monkeypatch.setattr(st, "SEGMENTS_PER_DMA", 4)
         monkeypatch.setattr(st, "KERNEL_DTYPE", "f32")
         monkeypatch.setenv("PHOTON_GROUPS_PER_RUN", "4")
         monkeypatch.setenv("PHOTON_GROUPS_PER_STEP", "16")
-        monkeypatch.setenv("PHOTON_PIPELINE_SEGMENTS", "0")
+        monkeypatch.setenv("PHOTON_SEGMENTS_PER_DMA", "2")
         monkeypatch.setenv("PHOTON_KERNEL_DTYPE", "int8")
         bench._apply_retune_env()
         assert st.GROUPS_PER_RUN == 4
         assert st.GROUPS_PER_STEP == 16
-        assert st.PIPELINE_SEGMENTS == 0
+        assert st.SEGMENTS_PER_DMA == 2
         # the one string knob parses as a validated string, not an int
         assert st.KERNEL_DTYPE == "int8"
         # knob snapshot (telemetry block / run_start) reflects it

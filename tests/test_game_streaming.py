@@ -869,14 +869,12 @@ def test_grouped_metric_dropped_sentinel_fraction_logged(rng):
 
 
 @pytest.mark.kernel
-def test_game_visit_scoring_pipelined_bit_identical(rng, monkeypatch):
-    """PIPELINE_SEGMENTS on/off through the GAME visit-scoring consumer:
-    ``ops.streaming.stream_scores`` with tile-COO layouts (the per-visit
-    validation/coordinate scorer's kernel path, riding the process-wide
-    layout cache) must be BIT-IDENTICAL between the skewed and
-    straight-line schedules (interpret mode, retuned-down constants)."""
-    import jax.numpy as jnp
-
+def test_game_visit_scoring_on_tile_coo_matches_the_untiled_chunks(rng, monkeypatch):
+    """The GAME visit-scoring consumer: ``ops.streaming.stream_scores`` with
+    tile-COO layouts (the per-visit validation/coordinate scorer's kernel
+    path, riding the process-wide layout cache) against the same chunks on
+    the XLA path (interpret mode, retuned-down constants); the second visit
+    packs nothing and scores bit for bit the same."""
     import photon_ml_tpu.ops.sparse_tiled as st_mod
     from photon_ml_tpu.ops import tile_cache
     from photon_ml_tpu.ops.streaming import sparse_chunks, stream_scores
@@ -890,17 +888,16 @@ def test_game_visit_scoring_pipelined_bit_identical(rng, monkeypatch):
     y = (rng.uniform(size=n) < 0.5).astype(np.float32)
     chunks = sparse_chunks(idx, val, y, chunk_rows=1024)
     w = rng.normal(size=d).astype(np.float32)
-    outs = {}
-    for flag in (1, 0):
-        monkeypatch.setattr(st_mod, "PIPELINE_SEGMENTS", flag)
-        outs[flag] = stream_scores(
-            chunks, w, num_rows=n, num_features=d, tile_sparse=True
-        )
-    np.testing.assert_array_equal(outs[1], outs[0])
-    # the XLA path agrees too (the kernel is correct, not just consistent)
+    got = stream_scores(chunks, w, num_rows=n, num_features=d, tile_sparse=True)
+    misses = tile_cache.stats()["misses"]
+    assert misses == 2  # one layout a chunk
     ref = stream_scores(chunks, w, num_rows=n, num_features=d,
                         tile_sparse=False)
-    np.testing.assert_allclose(outs[1], ref, rtol=2e-3, atol=2e-3)
+    np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
+    again = stream_scores(chunks, w, num_rows=n, num_features=d, tile_sparse=True)
+    np.testing.assert_array_equal(again, got)
+    s = tile_cache.stats()
+    assert (s["hits"], s["misses"]) == (2, misses)
     tile_cache.clear()
 
 

@@ -81,16 +81,16 @@ def _lower_shipped(spec, specs, n, d, storage="f32"):
     return st._tiled_apply_jit.lower(
         specs, spec((d,), jnp.float32), n, d, False,
         st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA, st.GROUPS_PER_RUN,
-        True, storage, False, None,
+        storage, False, None,
     )
 
 
-def _compile_tile(spec, storage, *, pipeline, square=False):
+def _compile_tile(spec, storage, *, square=False):
     specs, n, d = _layout_specs(spec, storage)
     return st._tiled_apply_jit.lower(
         specs, spec((d,), jnp.float32), n, d, square,
         st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA, st.GROUPS_PER_RUN,
-        pipeline, storage, False, None,
+        storage, False, None,
     ).compile()
 
 
@@ -99,13 +99,12 @@ class TestTileCooCompiles:
         assert (st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA) == (32, 4)
         assert st.GROUPS_PER_RUN == 2
 
-    @pytest.mark.parametrize("pipeline", [True, False])
     @pytest.mark.parametrize("storage", ["f32", "int8"])
-    def test_shipped_kernel_both_schedules(self, topo, storage, pipeline):
-        _compile_tile(_spec(topo), storage, pipeline=pipeline)
+    def test_shipped_kernel_on_both_rungs(self, topo, storage):
+        _compile_tile(_spec(topo), storage)
 
     def test_hessian_diagonal_variant(self, topo):
-        _compile_tile(_spec(topo), "f32", pipeline=True, square=True)
+        _compile_tile(_spec(topo), "f32", square=True)
 
     def test_a2_full_shape_fits_smem(self, topo):
         """A2's bench shape (n=2^19, d=2^17, 32 nonzeros a row, ~166k
@@ -154,9 +153,8 @@ class TestSparseCellFormCompiles:
         step_groups = st.GROUPS_PER_STEP * st.SEGMENTS_PER_DMA
         assert step_groups * st.SUB_SLABS % 1024 == 0
 
-    @pytest.mark.parametrize("pipeline", [True, False])
     @pytest.mark.parametrize("direction", ["margins", "gradient", "gradient_sq"])
-    def test_one_call_a_stream_at_criteo_fit(self, topo, direction, pipeline):
+    def test_one_call_a_stream_at_criteo_fit(self, topo, direction):
         spec = _spec(topo)
         out, src = (
             (self.N_PAD, self.D_PAD) if direction == "margins"
@@ -166,7 +164,7 @@ class TestSparseCellFormCompiles:
             self._specs(spec), spec((src,), jnp.float32), out, src,
             direction == "gradient_sq",
             st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA, st.GROUPS_PER_RUN,
-            pipeline, "f32", False, None,
+            "f32", False, None,
         ).compile().as_text()
         assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
 
@@ -177,7 +175,7 @@ class TestSparseCellFormCompiles:
                 self._specs(spec), spec((self.D_PAD,), jnp.float32),
                 self.N_PAD, self.D_PAD, False,
                 st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA, st.GROUPS_PER_RUN,
-                True, "int8", False, None,
+                "int8", False, None,
             )
 
 

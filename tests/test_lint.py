@@ -459,6 +459,20 @@ class TestRegistry:
         for k in KNOBS:
             assert f"`{k.name}`" in table, k.name
 
+    def test_46_knobs_none_for_a_segment_schedule_and_the_readme_has_them(self):
+        """The tile-COO kernel has one schedule (PR 33): no knob, retune
+        global or snapshot key selects one, and the README's table is the
+        registry's, row for row."""
+        assert len(KNOBS) == 46
+        for k in KNOBS:
+            for text in (k.name, k.retune_global, k.sink_key, k.doc):
+                assert "pipeline_segments" not in (text or "").lower(), k.name
+        readme = os.path.join(
+            discover_root(os.path.dirname(__file__)), "README.md"
+        )
+        with open(readme) as f:
+            assert render_knob_table() in f.read()
+
     def test_check_retune_tables_raises_on_drift(self):
         good = {
             t: {k.name: k.retune_global for k in KNOBS

@@ -596,14 +596,13 @@ def _entries(rows, cols, vals, width):
 # the schedule's edge shapes: (GROUPS_PER_STEP, SEGMENTS_PER_DMA,
 # GROUPS_PER_RUN, n, d, k)
 _EDGE_SHAPES = {
-    # >=2 DMA steps: the last segment of step t hands its phase-2 MXU
-    # stream to step t+1's first-segment gather
+    # >=2 DMA steps: step t starts step t+1's fetch into the other buffer
+    # slot before it waits its own
     "cross_step_boundary": (8, 2, 2, 2048, 4096, 4),
-    # the whole stream is ONE DMA step: the cross-step pl.when never
-    # fires — prologue + epilogue only
+    # the whole stream is ONE DMA step: no next fetch is ever started
     "one_step_stream": (8, 2, 2, 1024, 1024, 1),
     # SEGMENTS_PER_DMA=1: EVERY step (the last included) holds a single
-    # segment, so every skew crosses the DMA-step boundary
+    # segment, so every segment follows a wait
     "single_segment_steps": (8, 1, 2, 2048, 4096, 4),
     # GROUPS_PER_STEP == GROUPS_PER_RUN: each segment is ONE slab run,
     # so phase 1 is a single batched gather per segment
@@ -634,13 +633,6 @@ def _edge_batch(rng, monkeypatch, shape):
     step, dma, run, n, d, k = _EDGE_SHAPES[shape]
     _retune(monkeypatch, step, dma, run)
     return _plain_batch(rng, n, d, k)
-
-
-def _n_steps(tb):
-    import photon_ml_tpu.ops.sparse_tiled as st
-
-    step_groups = st.GROUPS_PER_STEP * st.SEGMENTS_PER_DMA
-    return [int(c.m_arrays[0].shape[0]) // step_groups for c in tb.chunks]
 
 
 class TestLayoutWalk:
@@ -680,92 +672,132 @@ class TestLayoutWalk:
             assert np.max(np.abs(got_all - want)) <= half_step * (1 + 1e-6)
 
 
+# near-empty cells (20 nonzeros each; row slabs x column slabs) that take the
+# sparse-cell form under each carve of ``_EDGE_SHAPES``
+_EDGE_CELLS = {
+    "cross_step_boundary": [[20] * 24] * 4,
+    # 2 x 2 cells: two write slabs a direction, one segment each
+    "one_step_stream": [[20] * 2] * 2,
+    "single_segment_steps": [[20] * 24] * 4,
+    "single_run_segments": [[20] * 24] * 2,
+}
+_DIRECTIONS = ("margins", "gradient", "gradient_sq")
+
+
+def _apply_and_walk(tb, direction, rng):
+    """One direction of a one-chunk tiled batch through the kernel, and the
+    float64 walk of the same streams: ``(got, want, src)``."""
+    (chunk,) = tb.chunks
+    n, d = tb.num_rows, tb.num_features
+    if direction == "margins":
+        src = rng.normal(size=d).astype(np.float32)
+        got = tb.matvec(jnp.asarray(src))
+        want = _walk_apply(
+            chunk.m_arrays, np.pad(src, (0, chunk.d_pad - d)), chunk.n_pad, "f32"
+        )[:n]
+    else:
+        sq = direction == "gradient_sq"
+        src = rng.normal(size=n).astype(np.float32)
+        got = (tb.rmatvec_sq if sq else tb.rmatvec)(jnp.asarray(src))
+        want = _walk_apply(
+            chunk.g_arrays, np.pad(src, (0, chunk.n_pad - n)), chunk.d_pad,
+            "f32", sq,
+        )[:d]
+    return np.asarray(got), want, src
+
+
+def _direction_steps(tb, direction):
+    import photon_ml_tpu.ops.sparse_tiled as st
+
+    (chunk,) = tb.chunks
+    arrays = chunk.m_arrays if direction == "margins" else chunk.g_arrays
+    return int(arrays[0].shape[0]) // (st.GROUPS_PER_STEP * st.SEGMENTS_PER_DMA)
+
+
 @pytest.mark.kernel
-class TestPipelinedKernel:
-    """Software-pipelined segment schedule (PIPELINE_SEGMENTS): the skewed
-    loop must produce BIT-IDENTICAL outputs to the straight-line schedule
-    in interpret mode — same per-phase math, same accumulation order, only
-    the instruction interleave differs — across the pipeline's epilogue
-    edge cases (``_EDGE_SHAPES``), and both must agree with the plain walk
-    of the layout they read. Retuned-down constants throughout (tier-1
-    runtime budget)."""
-
-    def _bitwise_both_schedules(self, batch, rng, monkeypatch):
-        """All three kernel directions under both schedules: pipelined and
-        straight-line must agree BITWISE, and with the float64 walk of
-        the same streams to float32 accumulation's error."""
-        import photon_ml_tpu.ops.sparse_tiled as st
-
-        w = rng.normal(size=batch.num_features).astype(np.float32)
-        r = rng.normal(size=batch.num_rows).astype(np.float32)
-        outs = {}
-        for flag in (1, 0):
-            monkeypatch.setattr(st, "PIPELINE_SEGMENTS", flag)
-            tb = tile_sparse_batch(batch)
-            outs[flag] = (
-                np.asarray(tb.matvec(jnp.asarray(w))),
-                np.asarray(tb.rmatvec(jnp.asarray(r))),
-                np.asarray(tb.rmatvec_sq(jnp.asarray(r))),
-            )
-        for pipelined, straight in zip(outs[1], outs[0]):
-            np.testing.assert_array_equal(pipelined, straight)
-        (chunk,) = tb.chunks
-        w_pad = np.pad(w, (0, chunk.d_pad - len(w)))
-        r_pad = np.pad(r, (0, chunk.n_pad - len(r)))
-        n, d = batch.num_rows, batch.num_features
-        walked = (
-            _walk_apply(chunk.m_arrays, w_pad, chunk.n_pad, "f32")[:n],
-            _walk_apply(chunk.g_arrays, r_pad, chunk.d_pad, "f32")[:d],
-            _walk_apply(chunk.g_arrays, r_pad, chunk.d_pad, "f32", True)[:d],
-        )
-        for got, want in zip(outs[1], walked):
-            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+class TestSegmentSchedule:
+    """The kernel's one schedule (a step starts the next fetch, waits its
+    own, runs phase 1 then phase 2 of each segment) against the float64
+    walk of the streams it reads, at the carves where a schedule can go
+    wrong (``_EDGE_SHAPES``): a stream of one DMA step, steps of one
+    segment, a boundary between steps, segments of one run. Both forms of
+    a stream, and a stream run as several kernel calls. Retuned-down
+    constants throughout (tier-1 runtime budget)."""
 
     @pytest.mark.parametrize("shape", list(_EDGE_SHAPES))
     def test_edge_shape(self, rng, monkeypatch, shape):
         batch = _edge_batch(rng, monkeypatch, shape)
-        steps = _n_steps(tile_sparse_batch(batch))
+        tb = tile_sparse_batch(batch)
+        steps = _direction_steps(tb, "margins")
         if shape == "one_step_stream":
-            assert steps == [1]
+            assert steps == 1
         elif shape != "single_run_segments":
-            assert min(steps) >= 2
-        self._bitwise_both_schedules(batch, rng, monkeypatch)
+            assert steps >= 2
+        for direction in _DIRECTIONS:
+            got, want, _ = _apply_and_walk(tb, direction, rng)
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
-    def test_toggle_recompiles_never_reuses(self, rng, monkeypatch):
-        """PIPELINE_SEGMENTS is a static jit key of _tiled_apply: toggling
-        mid-process compiles a NEW executable (and re-entering a seen
-        value re-enters the cached one) — a toggle can never reuse a
-        stale compile whose argument shapes happen to coincide."""
+    @pytest.mark.parametrize("direction", _DIRECTIONS)
+    @pytest.mark.parametrize("shape", list(_EDGE_SHAPES))
+    def test_edge_shape_in_the_sparse_cell_form(
+        self, rng, monkeypatch, shape, direction
+    ):
+        import photon_ml_tpu.ops.sparse_tiled as st
+
+        step, dma, run = _EDGE_SHAPES[shape][:3]
+        _retune(monkeypatch, step, dma, run)
+        cells = _EDGE_CELLS[shape]
+        batch = _cell_batch(rng, len(cells) * SLAB, len(cells[0]) * SLAB, cells)
+        tb = tile_sparse_batch(batch, hbm_budget_bytes=1e12)
+        (chunk,) = tb.chunks
+        for arrays in (chunk.m_arrays, chunk.g_arrays):
+            assert arrays[3].shape[0] == arrays[0].shape[0] * st.SUB_SLABS
+        steps = _direction_steps(tb, direction)
+        if shape == "one_step_stream":
+            assert steps == 1
+        else:
+            assert steps >= 2
+        got, want, _ = _apply_and_walk(tb, direction, rng)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("form", ["runs", "sparse_cell"])
+    def test_a_stream_run_as_several_kernel_calls(self, rng, monkeypatch, form):
+        """With the SMEM budget cut to one DMA step's prefetch words a
+        stream runs as one call a step, each starting its own fetch at its
+        own ``step0``; their summed outputs are the walk's."""
         import photon_ml_tpu.ops.sparse_tiled as st
 
         _retune(monkeypatch)
-        batch = _plain_batch(rng, n=1024, d=2048, k=2)
-        w = jnp.asarray(rng.normal(size=batch.num_features).astype(np.float32))
-        monkeypatch.setattr(st, "PIPELINE_SEGMENTS", 1)
-        tb = tile_sparse_batch(batch)
-        tb.matvec(w)
-        size0 = st._tiled_apply_jit._cache_size()
-        tb.matvec(w)  # same schedule: cache re-entered
-        assert st._tiled_apply_jit._cache_size() == size0
-        monkeypatch.setattr(st, "PIPELINE_SEGMENTS", 0)
-        tb.matvec(w)  # toggled: new static key, new executable
-        assert st._tiled_apply_jit._cache_size() > size0
-
-    def test_toggle_misses_layout_cache(self, rng, monkeypatch):
-        """The tile-cache key carries PIPELINE_SEGMENTS: a toggle can
-        never reuse a stale cached layout either."""
-        import photon_ml_tpu.ops.sparse_tiled as st
-        from photon_ml_tpu.ops import tile_cache
-
-        tile_cache.clear()
-        batch = _plain_batch(rng, n=2048, d=4096, k=4)
-        monkeypatch.setattr(st, "PIPELINE_SEGMENTS", 1)
-        tile_cache.tiled_layout_for(batch)
-        monkeypatch.setattr(st, "PIPELINE_SEGMENTS", 0)
-        tile_cache.tiled_layout_for(batch)
-        s = tile_cache.stats()
-        assert (s["hits"], s["misses"]) == (0, 2)
-        tile_cache.clear()
+        step_groups = st.GROUPS_PER_STEP * st.SEGMENTS_PER_DMA
+        if form == "runs":
+            batch = _plain_batch(rng, n=2048, d=4096, k=4)
+            tb = tile_sparse_batch(batch)
+            per_step = 4 * (st.SEGMENTS_PER_DMA + step_groups // st.GROUPS_PER_RUN)
+        else:
+            batch = _cell_batch(rng, 4 * SLAB, 6 * SLAB, [[20] * 6] * 4)
+            tb = tile_sparse_batch(batch, hbm_budget_bytes=1e12)
+            per_step = 4 * st.SEGMENTS_PER_DMA  # the slab ids stay in HBM
+        (chunk,) = tb.chunks
+        sub = chunk.m_arrays[3].shape[0] > chunk.m_arrays[0].shape[0]
+        assert sub == (form == "sparse_cell")
+        pieces = []
+        bounds = st._piece_bounds
+        monkeypatch.setattr(
+            st, "_piece_bounds",
+            lambda *a: pieces.append(bounds(*a)) or pieces[-1],
+        )
+        monkeypatch.setattr(st, "_SMEM_PREFETCH_BUDGET", per_step)
+        # the budget is read at trace time and is not a jit key
+        st._tiled_apply_jit.clear_cache()
+        try:
+            for direction in ("margins", "gradient"):
+                steps = _direction_steps(tb, direction)
+                assert steps >= 2
+                got, want, _ = _apply_and_walk(tb, direction, rng)
+                np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+                assert len(pieces[-1]) == steps
+        finally:
+            st._tiled_apply_jit.clear_cache()
 
 
 class TestTileLayoutCache:
@@ -1102,9 +1134,9 @@ class TestTopologyKeyedCaches:
         assert t2[-1][2] == 2 and t2 != t1
 
     def test_keys_hold_exactly_the_constants_that_remain(self, monkeypatch):
-        """One kernel, two rungs: the layout cache's key and the kernel
-        executable's static arguments name the constants the module still
-        has, and nothing that is gone."""
+        """One kernel, one schedule, two rungs: the layout cache's key and
+        the kernel executable's static arguments name the constants the
+        module still has, and nothing that is gone."""
         import inspect
 
         import photon_ml_tpu.ops.sparse_tiled as st
@@ -1116,23 +1148,25 @@ class TestTopologyKeyedCaches:
         assert tile_cache.tuned_constants() == (
             st.GROUP, st.SLAB, st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA,
             st.GROUPS_PER_RUN, st.HEAD_MIN_FILL, st.SUB_SLABS,
-            st.SUB_GROUP_COST, bool(st.PIPELINE_SEGMENTS), "f32", topology,
+            st.SUB_GROUP_COST, "f32", topology,
         )
         # a stream's FORM is no static argument: the kernel reads it off the
         # stream's own shape (one read slab a run, or SUB_SLABS a group)
         params = list(inspect.signature(st._tiled_apply_jit).parameters)
         assert params == [
             "layout_arrays", "src", "out_pad", "src_pad", "square_vals",
-            "groups", "segs", "run_groups", "pipeline", "storage",
-            "interpret", "topology",
+            "groups", "segs", "run_groups", "storage", "interpret",
+            "topology",
         ]
+        assert not hasattr(st, "PIPELINE_SEGMENTS")
+        assert not hasattr(st, "_run_segment_schedule")
         # _tiled_apply hands the jitted call exactly those, in that order
         monkeypatch.setattr(st, "_tiled_apply_jit", lambda *args: args)
         args = st._tiled_apply(("streams",), "src", 2048, 1024, True)
         assert args == (
             ("streams",), "src", 2048, 1024, True,
             st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA, st.GROUPS_PER_RUN,
-            bool(st.PIPELINE_SEGMENTS), "f32", st._interpret(), topology,
+            "f32", st._interpret(), topology,
         )
         assert st.KERNEL_DTYPES == ("f32", "int8")
 
@@ -1771,14 +1805,10 @@ class TestSparseCellForm:
         )
 
     @pytest.mark.kernel
-    @pytest.mark.parametrize("pipeline", [1, 0])
     @pytest.mark.parametrize("case", list(_FORM_CASES))
     def test_kernels_match_the_walk_and_the_dense_matrix(
-        self, rng, monkeypatch, case, pipeline
+        self, rng, monkeypatch, case
     ):
-        import photon_ml_tpu.ops.sparse_tiled as st
-
-        monkeypatch.setattr(st, "PIPELINE_SEGMENTS", pipeline)
         batch, tb, _ = self._built(rng, monkeypatch, case)
         self._check_against_walk_and_dense(batch, tb, rng)
 
@@ -1806,31 +1836,18 @@ class TestSparseCellForm:
 
     @staticmethod
     def _check_against_walk_and_dense(batch, tb, rng):
-        n, d = batch.num_rows, batch.num_features
-        w = rng.normal(size=d).astype(np.float32)
-        r = rng.normal(size=n).astype(np.float32)
-        got = (
-            np.asarray(tb.matvec(jnp.asarray(w))),
-            np.asarray(tb.rmatvec(jnp.asarray(r))),
-            np.asarray(tb.rmatvec_sq(jnp.asarray(r))),
-        )
-        (chunk,) = tb.chunks
-        w_pad = np.pad(w, (0, chunk.d_pad - d))
-        r_pad = np.pad(r, (0, chunk.n_pad - n))
-        walked = (
-            _walk_apply(chunk.m_arrays, w_pad, chunk.n_pad, "f32")[:n],
-            _walk_apply(chunk.g_arrays, r_pad, chunk.d_pad, "f32")[:d],
-            _walk_apply(chunk.g_arrays, r_pad, chunk.d_pad, "f32", True)[:d],
-        )
         dense = densify(batch)
-        want = (
-            np.asarray(dense.matvec(jnp.asarray(w))),
-            np.asarray(dense.rmatvec(jnp.asarray(r))),
-            np.asarray(dense.rmatvec_sq(jnp.asarray(r))),
-        )
-        for g, by_walk, by_dense in zip(got, walked, want):
-            np.testing.assert_allclose(g, by_walk, rtol=1e-5, atol=1e-5)
-            np.testing.assert_allclose(g, by_dense, rtol=1e-4, atol=1e-4)
+        by_dense = {
+            "margins": dense.matvec, "gradient": dense.rmatvec,
+            "gradient_sq": dense.rmatvec_sq,
+        }
+        for direction in _DIRECTIONS:
+            got, by_walk, src = _apply_and_walk(tb, direction, rng)
+            np.testing.assert_allclose(got, by_walk, rtol=1e-5, atol=1e-5)
+            np.testing.assert_allclose(
+                got, np.asarray(by_dense[direction](jnp.asarray(src))),
+                rtol=1e-4, atol=1e-4,
+            )
 
     def test_head_budget_counts_the_tail_at_its_own_padding(self, rng, monkeypatch):
         """The head's budget arithmetic takes the tail's padding from the

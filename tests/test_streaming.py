@@ -848,57 +848,41 @@ class TestTiledStreamedChunks:
         )
 
     @pytest.mark.kernel
-    def test_pipelined_schedule_bit_identical(self, rng, monkeypatch):
-        """PIPELINE_SEGMENTS on/off through the STREAMED consumer: the
-        chunked objective's value/gradient/Hv/diag sums and its
-        device-resident visit scores must be BIT-IDENTICAL between the
-        skewed and straight-line kernel schedules (interpret mode,
-        retuned-down constants). The toggle misses the layout cache and
-        the jit key, so each build is a fresh compile — never a stale
-        reuse."""
+    def test_tiled_visit_scores_match_the_untiled_chunks(self, rng, monkeypatch):
+        """The STREAMED consumer's device-resident visit scores
+        (``stream_scores``, through the shared scoring program keyed on the
+        tuned constants) on the tile-COO path against the same chunks left
+        as padded-sparse rows on the XLA path (interpret mode, retuned-down
+        constants), and a second visit re-enters the scoring executable."""
         import photon_ml_tpu.ops.sparse_tiled as st_mod
+        from photon_ml_tpu.ops.streaming import _score_matvec_keyed
 
         monkeypatch.setattr(st_mod, "GROUPS_PER_STEP", 8)
         monkeypatch.setattr(st_mod, "SEGMENTS_PER_DMA", 2)
-        # halved rows (same 2-chunk structure): bitwise parity between the
-        # two schedules is size-independent, trace cost is not
         n, d, k = 1024, 4096, 4
         idx = rng.integers(0, d, size=(n, k)).astype(np.int32)
         val = rng.normal(size=(n, k)).astype(np.float32)
         y = (rng.uniform(size=n) < 0.5).astype(np.float32)
         chunks = sparse_chunks(idx, val, y, chunk_rows=512)
-        w = jnp.asarray(rng.normal(size=d), jnp.float32)
-        outs = {}
-        score_cache_sizes = {}
-        from photon_ml_tpu.ops.streaming import _score_matvec_keyed
-
-        # start from an empty scoring-program cache: an EARLIER kernel test
-        # over the same rng-fixture shapes (the GAME visit-scoring parity
-        # test) may already have compiled both schedules, which would make
-        # the cache-growth assertion below vacuously fail (seed state: it
-        # compared 11 > 11) — the assertion must be self-contained
-        _score_matvec_keyed._clear_cache()
-        for flag in (1, 0):
-            monkeypatch.setattr(st_mod, "PIPELINE_SEGMENTS", flag)
-            obj = StreamingGLMObjective(
-                chunks, LOSS, num_features=d, l2_weight=0.4, tile_sparse=True
-            )
-            v, g = obj.value_and_grad(w)
-            outs[flag] = (
-                float(v),
-                np.asarray(g),
-                np.asarray(obj.hessian_diag(w)),
-                obj.stream_scores(np.asarray(w), num_rows=n),
-            )
-            score_cache_sizes[flag] = _score_matvec_keyed._cache_size()
-        assert outs[1][0] == outs[0][0]
-        for pipelined, straight in zip(outs[1][1:], outs[0][1:]):
-            np.testing.assert_array_equal(pipelined, straight)
-        # the scorer really compiled per schedule (the toggle reshapes
-        # nothing, so without the tuned-constants static key the second
-        # flag would silently re-enter the first executable and this
-        # test's scoring leg would compare flag=1 against itself)
-        assert score_cache_sizes[0] > score_cache_sizes[1]
+        w = rng.normal(size=d).astype(np.float32)
+        tiled = StreamingGLMObjective(
+            chunks, LOSS, num_features=d, l2_weight=0.4, tile_sparse=True
+        )
+        plain = StreamingGLMObjective(
+            chunks, LOSS, num_features=d, l2_weight=0.4, tile_sparse=False
+        )
+        assert tiled._tile_layouts is not None and plain._tile_layouts is None
+        got = tiled.stream_scores(w, num_rows=n)
+        np.testing.assert_allclose(
+            got, plain.stream_scores(w, num_rows=n), rtol=1e-4, atol=1e-4
+        )
+        np.testing.assert_allclose(
+            got, (val * w[idx]).sum(axis=1), rtol=1e-4, atol=1e-4
+        )
+        # the next visit compiles nothing: same constants, same program
+        size = _score_matvec_keyed._cache_size()
+        np.testing.assert_array_equal(tiled.stream_scores(w, num_rows=n), got)
+        assert _score_matvec_keyed._cache_size() == size
 
     def test_tiled_chunk_swap_guard(self, rng):
         """Swapping chunks under cached layouts is allowed only when the
