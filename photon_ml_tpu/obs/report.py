@@ -431,6 +431,17 @@ def summarize_run(path: str, records: list[dict] | None = None) -> dict:
             "tail_cell_fill": tail / cells if cells > 0 else None,
             "tail_pad_ratio": slots / (2.0 * tail) if tail > 0 else None,
         }
+    # dense objectives (dense_layout.*, ops/glm.make_objective): the real
+    # columns of every dense objective built, and the columns the blocks of
+    # the kernels it takes add to them (the feature-major float32 kernels
+    # round the features up to whole sublane groups). Present only on runs
+    # that built a dense objective.
+    if "dense_layout.columns" in counters or \
+            "dense_layout.columns" in base_counters:
+        out["dense_layout"] = {
+            "columns": counter_v("dense_layout.columns"),
+            "padded_columns": counter_v("dense_layout.padded_columns"),
+        }
     # per-entity feature projection (re_project.*, game/projector): the
     # mean solved-width ratio and the per-lane bytes the subspace solves
     # shaved off the full-width schedule, plus the ladder narrative
@@ -717,6 +728,13 @@ def format_summary(s: dict) -> str:
             f"tile-COO tail, {_fmt_qty(til['head_nonzeros'])} "
             f"({100.0 * til['head_nonzero_share']:.1f}%) in a dense head of "
             f"{int(til['head_columns'])} columns"
+        )
+    den = s.get("dense_layout") or {}
+    if den.get("columns"):
+        lines.append(
+            f"  dense-layout: {_fmt_qty(den['columns'])} columns of dense "
+            f"objectives, {_fmt_qty(den['padded_columns'])} added by their "
+            "kernels' blocks"
         )
     prj = s.get("re_project") or {}
     if prj.get("mean_ratio") is not None or prj.get("classes"):

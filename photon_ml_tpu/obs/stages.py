@@ -38,9 +38,12 @@ RE_SCORE = "re.score"  # the W[ids] gathers and (n, d_e) work of scoring
 GLM_OBJECTIVE = "glm.objective"  # every pass over the data (ops/glm)
 GLM_HEAD = "glm.head"  # inside it: the dense head's multiply-reduces
 GLM_TAIL = "glm.tail"  # inside it: the tile-COO kernels (ops/sparse_tiled)
+GLM_HVP = "glm.hvp"  # inside it: a Hessian-vector pass, fused kernel or XLA
 LBFGS_TWO_LOOP = "lbfgs.two_loop"  # the search direction (optim/lbfgs)
 LBFGS_LINE_SEARCH = "lbfgs.line_search"  # trial points and their loops
 LBFGS_UPDATE = "lbfgs.update"  # acceptance, ring buffers, next state
+TRON_CG = "tron.cg"  # truncated CG's dots, axpys and boundary step (optim/tron)
+TRON_UPDATE = "tron.update"  # acceptance, trust radius, histories, next state
 NEWTON_SOLVE = "newton.solve"  # factorisation and step (optim/newton)
 COORD_PREFIX = "coord."  # + the coordinate id: which coordinate's visit
 
@@ -49,7 +52,7 @@ COORD_PREFIX = "coord."  # + the coordinate id: which coordinate's visit
 # cache with the names it was compiled with: after a scope moves with no
 # instruction changing, a warm cache would keep serving the old names to
 # every profile. Raise this when a site or a name of this module changes.
-VERSION = 3
+VERSION = 4
 
 _NOT_SEGMENT = re.compile(r"[^A-Za-z0-9_.\-]")
 

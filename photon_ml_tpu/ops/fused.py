@@ -7,7 +7,7 @@ by how well the streaming overlaps compute. These kernels tile ``X`` over
 rows and, per tile resident in VMEM, compute margins (MXU), the pointwise
 loss and its derivatives (VPU), and the transposed gradient contraction
 (MXU) before moving on — ``X`` streams from HBM exactly ONCE per
-evaluation:
+evaluation (bfloat16 on the MXU; float32 on the VPU, below):
 
 - ``fused_value_grad``: (Σ w·l, Xᵀr, Σr) in one pass.
 - ``fused_hvp``: (Xᵀ(d2·(Xv)), Σ d2·(Xv)) in one pass — margins and
@@ -41,6 +41,27 @@ reference's ``photon-api::ml.function.ValueAndGradientAggregator`` /
 kernel; the reduction across devices stays the objective's single
 ``lax.psum``.
 
+Float32 storage means exact float32 contractions, and those stay off the
+MXU: a matrix-vector product leaves all but one of its 128 columns idle,
+and at ``highest`` precision it is six bf16 passes over splits of the tile.
+Timed on a v5e at 400,000 rows (PERF.md §6, PR 34): XLA's two
+multiply-reduce sweeps 8.51 ms a Hessian-vector pass, the ``highest`` MXU
+dots 5.09 ms at 2,048 columns, the VPU form below 4.39 ms there, the
+feature-major kernel 4.31 ms at 2,000. So one float32 form of each
+contraction is kept a layout, both on the VPU:
+
+- a width that is a multiple of 128 lies row-major on the chip and takes
+  the row-major kernels (``_margins_f32`` / ``_contract_f32``: multiply and
+  add 128-lane blocks, one XLU transpose of a (128, 128) accumulator to
+  turn row sums into lanes);
+- any other width of at least 128 (LIBSVM epsilon's 2,000) lies
+  FEATURE-MAJOR on the chip, features down the sublanes and rows along the
+  lanes with not a byte of padding, so the feature-major kernels
+  (``_margins_fm`` / ``_contract_fm``) read ``X.T``, which is that very
+  array: margins are born along the lanes and nothing is transposed;
+- narrower than 128 columns, or bfloat16 at a width that is no multiple of
+  128, stays on the XLA path (``supports_fused``).
+
 Semantics match ``GLMObjective`` exactly:
 - zero-weight rows contribute exactly 0 (padding can hold any values),
 - bfloat16 feature storage keeps bf16 MXU operands with float32
@@ -68,11 +89,19 @@ Array = jnp.ndarray
 # transposed operand; the chip has more physical VMEM than the cap.
 _VMEM_BUDGET = 14 * 1024 * 1024
 _VMEM_LIMIT = 32 * 1024 * 1024
-# An f32 tile's full-precision dots keep about four more tile-sized
-# temporaries (the bf16 splits of the MXU-resident operand): 42 MB of
-# scoped VMEM for a 7 MiB tile, by the v5e compiler's own count.
-_F32_TILE_COPIES = 6
 _LANES = 128
+_SUBLANES = 8
+# rows of a float32 row-major tile contracted at a time on the VPU: one
+# (128, 128) accumulator a coefficient row stays in vector registers across
+# the tile's lane blocks, and is the square the XLU transposes
+_F32_ROWS = 128
+# The feature-major kernels (X read as Xᵀ: features down the sublanes, rows
+# of X along the lanes): lanes contracted at a time (four registers a
+# sublane group), sublane groups a loop step, and the grid steps whose
+# (d, 128) gradient partials share one output block.
+_FM_LANES = 512
+_FM_UNROLL = 5
+_FM_GROUP = 32
 _MIN_BLOCK_ROWS = 256  # covers the bf16 (16, 128) min tile with headroom
 _MAX_BLOCK_ROWS = 8192
 # contract the minor (feature) dimension of both operands: (k, d)·(bn, d)ᵀ
@@ -80,30 +109,68 @@ _NT = (((1,), (1,)), ((), ()))
 
 
 def supports_fused(n: int, d: int, dtype) -> bool:
-    """Static gate: shapes/dtypes the kernels handle efficiently.
+    """Static gate: the widths and dtypes the kernels take. What decides is
+    what the caller's array is, never a switch.
 
-    d must be lane-aligned (the (1, d) partials and (bn, d) tiles are laid
-    out in 128-wide lanes) and a double-buffered minimum row tile of X must
-    fit the VMEM budget — very high-d problems belong to the sparse path.
-    The per-row streams do not enter: lane-dense, they are 4 B a row each.
+    - d a multiple of 128, bfloat16 or float32: the row-major kernels, whose
+      (bn, d) tiles and (1, d) partials are whole 128-lane tiles (a TPU
+      stores such a matrix row-major).
+    - float32 of any other width of at least one lane tile (d >= 128, as
+      LIBSVM epsilon's 2,000): the feature-major kernels
+      (``reads_feature_major``). A TPU stores that matrix feature-major, the
+      rows along the lanes, with no padding; ``ops/glm.auto_fused`` checks
+      that the array at hand is stored so.
+    - d < 128 stays on the XLA path (the descent cells' 65-column fixed
+      effect: ROADMAP S3), and so does bfloat16 at a width that is no
+      multiple of 128.
+
+    And a double-buffered minimum tile of X must fit the VMEM budget: very
+    high-d problems belong to the sparse path. The per-row streams do not
+    enter: lane-dense, they are 4 B a row each.
     """
-    if dtype not in (jnp.float32, jnp.bfloat16):
+    if dtype not in (jnp.float32, jnp.bfloat16) or d < _LANES:
         return False
+    if reads_feature_major(d, dtype):
+        return _block_rows(n, sublane_width(d), 4) is not None
     if d % _LANES != 0:
         return False
     return _block_rows(n, d, jnp.dtype(dtype).itemsize) is not None
 
 
+def reads_feature_major(d: int, dtype) -> bool:
+    """Which kernels a dense (n, d) matrix takes: the feature-major ones
+    (X read as Xᵀ, the rows along the lanes) for a float32 matrix whose
+    width is no multiple of 128, the row-major ones otherwise. It follows
+    the chip's own storage: a v5e keeps f32[400000, 2000] feature-major
+    (``major_to_minor`` (1, 0): 2,000 sublane rows of 400,000 lanes, not a
+    byte of padding) and f32[400000, 2048] row-major (PERF.md §6, PR 34),
+    so either kernel reads its matrix where it lies, with no copy."""
+    return dtype == jnp.float32 and d % _LANES != 0
+
+
+def stored_feature_major(X) -> bool:
+    """Whether the concrete array ``X`` lies feature-major on its device
+    (its ``format``'s ``major_to_minor`` is (1, 0)); False where it cannot
+    be told (a host array, a tracer)."""
+    layout = getattr(getattr(X, "format", None), "layout", None)
+    order = getattr(layout, "major_to_minor", None)
+    return order is not None and tuple(order) == (1, 0)
+
+
+def sublane_width(d: int) -> int:
+    """d rounded up to whole 8-sublane groups: the rows a feature-major
+    matrix of d features occupies."""
+    return -(-d // _SUBLANES) * _SUBLANES
+
+
 def _block_rows(n: int, d: int, itemsize: int) -> int | None:
     """Largest power-of-two row tile whose double-buffered X block fits
-    the VMEM budget, and whose f32 temporaries fit the scoped limit (None
-    if even the minimum tile does not)."""
+    the VMEM budget (None if even the minimum tile does not)."""
     best = None
     bn = _MIN_BLOCK_ROWS
     while bn <= _MAX_BLOCK_ROWS:
         tile = bn * d * itemsize
-        if 2 * tile > _VMEM_BUDGET or (
-                itemsize == 4 and _F32_TILE_COPIES * tile > _VMEM_LIMIT):
+        if 2 * tile > _VMEM_BUDGET:
             break
         best = bn
         if bn >= n:
@@ -126,37 +193,69 @@ def _split_refs(refs, has_off: bool, has_wt: bool):
     return (x_ref, y_ref, off_ref, wt_ref) + tuple(refs[k:])
 
 
-def _tile(x_ref, n, masked):
+def _tile(x_ref, n, masked, fm):
     """The resident X tile and, for a ragged last tile, the (1, bn) mask of
     its in-range rows. Out-of-range tile rows hold unspecified values; they
     are zeroed so the contraction cannot pick up Inf/NaN garbage through
-    0·x."""
+    0·x. A float32 tile is handed on as the ref: its contractions read it
+    a block at a time and mask what they compute (``_margins``,
+    ``_contract``). ``fm``: the tile is feature-major, (d, bn)."""
+    bn = x_ref.shape[1 if fm else 0]
+    row = None
+    if masked:
+        start = pl.program_id(0) * bn
+        row = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1) + start < n
+    if x_ref.dtype == jnp.float32:
+        return x_ref, row
     x = x_ref[...]
     if not masked:
         return x, None
-    bn = x.shape[0]
-    start = pl.program_id(0) * bn
     col = jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0) + start
-    row = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1) + start
-    return jnp.where(col < n, x, jnp.zeros_like(x)), row < n
+    return jnp.where(col < n, x, jnp.zeros_like(x)), row
 
 
-def _margins(vecs_ref, shifts_ref, x):
+def _margins(vecs_ref, shifts_ref, x, mask, fm, d):
     """(k, bn) lane-dense margins vecs·xᵀ − shifts of the k coefficient
-    rows against the tile: the tile is the transposed MXU operand (as
-    attention's q·kᵀ), so every row's margin lands in its own lane."""
+    rows against the tile: every row's margin lands in its own lane.
+
+    bfloat16: one MXU dot with the tile as the transposed operand (as
+    attention's q·kᵀ). float32: on the VPU, exact with no ``highest``
+    splits (``_margins_f32``, or ``_margins_fm`` over a feature-major
+    tile); the margins of a ragged tile's out-of-range rows, whose x is
+    unspecified, are set to 0."""
+    if x.dtype == jnp.float32:
+        m = _margins_fm(vecs_ref, x, d) if fm else _margins_f32(vecs_ref, x)
+        m = m - shifts_ref[...]
+        return m if mask is None else jnp.where(mask, m, 0.0)
     return jax.lax.dot_general(
         vecs_ref[...].astype(x.dtype), x, _NT,
-        preferred_element_type=jnp.float32, precision=_precision(x),
+        preferred_element_type=jnp.float32,
     ) - shifts_ref[...]
 
 
-def _precision(x):
-    # MXU f32 dots default to a single bf16 pass in Mosaic; request full
-    # f32 precision when the data is stored f32 (bf16 storage keeps the
-    # fast single pass — that is its point).
-    return (jax.lax.Precision.HIGHEST if x.dtype == jnp.float32
-            else jax.lax.Precision.DEFAULT)
+def _margins_f32(vecs_ref, x_ref):
+    """A float32 tile's margins without the MXU, where a matrix-vector
+    product at ``highest`` is six bf16 passes (5.09 ms a pass of 400,000 x
+    2,048 on a v5e against this form's 4.39; PERF.md §6, PR 34): 128 rows
+    at a time, multiply each (128, 128) lane block by its slice of a
+    coefficient row and add the blocks (VPU), then transpose the
+    accumulator (XLU) and add its sublanes, which leaves the 128 margins in
+    128 lanes."""
+    bn, d = x_ref.shape
+    k = vecs_ref.shape[0]
+    out = [[] for _ in range(k)]
+    for r0 in range(0, bn, _F32_ROWS):
+        acc = [None] * k
+        for c0 in range(0, d, _LANES):
+            xb = x_ref[r0:r0 + _F32_ROWS, c0:c0 + _LANES]
+            for i in range(k):
+                p = xb * vecs_ref[i:i + 1, c0:c0 + _LANES]
+                acc[i] = p if acc[i] is None else acc[i] + p
+        for i in range(k):
+            out[i].append(jnp.sum(acc[i].T, axis=0, keepdims=True))
+    return jnp.concatenate(
+        [jnp.concatenate(o, axis=1) for o in out], axis=0
+    )
 
 
 def _row(ref):
@@ -177,10 +276,147 @@ def _weighted(vals, wt_ref, mask):
     return vals
 
 
-def _contract(r, x):
-    """rᵀX as the (1, bn)·(bn, d) MXU dot, r cast to the storage dtype."""
-    return jnp.dot(r.astype(x.dtype), x, preferred_element_type=jnp.float32,
-                   precision=_precision(x))
+def _contract(out_ref, r, x, mask, fm):
+    """rᵀX into the tile's output block: the (1, bn)·(bn, d) MXU dot for
+    bfloat16, r cast to the storage dtype; for a float32 tile the VPU form
+    (``_contract_f32``, or ``_contract_fm`` over a feature-major tile)."""
+    if fm:
+        _contract_fm(out_ref, r, x, mask)
+    elif x.dtype == jnp.float32:
+        out_ref[...] = _contract_f32(r, x, mask)
+    else:
+        out_ref[...] = jnp.dot(
+            r.astype(x.dtype), x, preferred_element_type=jnp.float32
+        )
+
+
+def _contract_f32(r, x_ref, mask):
+    """(1, d) rᵀX of a float32 tile on the VPU: 128 rows at a time, r laid
+    down the sublanes by one transpose of its sublane broadcast, each
+    (128, 128) lane block multiplied by it and folded to one (8, 128)
+    register of partial sums; the eight sublanes are added once a lane
+    block, at the end. ``mask`` (the ragged last tile's in-range rows)
+    zeroes the products of out-of-range rows, whose x is unspecified."""
+    bn, d = x_ref.shape
+    parts = [None] * (d // _LANES)
+    for r0 in range(0, bn, _F32_ROWS):
+        rows = slice(r0, r0 + _F32_ROWS)
+        rb = jnp.broadcast_to(r[:, rows], (_LANES, _F32_ROWS)).T
+        ok = None
+        if mask is not None:  # Mosaic transposes no booleans
+            ok = jnp.broadcast_to(
+                mask[:, rows].astype(jnp.float32), (_LANES, _F32_ROWS)
+            ).T > 0.5
+        for j in range(d // _LANES):
+            p = x_ref[rows, j * _LANES:(j + 1) * _LANES] * rb
+            if ok is not None:
+                p = jnp.where(ok, p, 0.0)
+            p = jnp.sum(p.reshape(_F32_ROWS // _SUBLANES, _SUBLANES, _LANES), axis=0)
+            parts[j] = p if parts[j] is None else parts[j] + p
+    return jnp.concatenate(
+        [jnp.sum(p, axis=0, keepdims=True) for p in parts], axis=1
+    )
+
+
+def _fm_each_group(d: int, body, carry):
+    """``carry = body(first row of the group, carry)`` over every sublane
+    group of 8 features of a feature-major tile: a loop of ``_FM_UNROLL``
+    groups a step, the rest as straight-line code. The row is a Python
+    integer in the straight-line part, which always holds a last group of
+    fewer than 8 features (the one ``_margins_fm`` masks)."""
+    groups = sublane_width(d) // _SUBLANES
+    steps = (groups - (1 if d % _SUBLANES else 0)) // _FM_UNROLL
+
+    def step(s, carry):
+        for t in range(_FM_UNROLL):
+            row = pl.multiple_of((s * _FM_UNROLL + t) * _SUBLANES, _SUBLANES)
+            carry = body(row, carry)
+        return carry
+
+    if steps:
+        carry = jax.lax.fori_loop(0, steps, step, carry)
+    for g in range(steps * _FM_UNROLL, groups):
+        carry = body(g * _SUBLANES, carry)
+    return carry
+
+
+def _margins_fm(vecs_ref, xt_ref, d):
+    """(k, bn) margins of a feature-major float32 tile ``xt`` (d8, bn):
+    ``_FM_LANES`` lanes at a time, every sublane group of 8 features
+    multiplied by its slice of a coefficient row, which comes laid across
+    128 lanes ((k, d8, 128): no lane broadcast in the loop), and added to
+    one (8, lanes) accumulator a coefficient row; the eight sublanes are
+    added at the end. No transpose anywhere: the margins are born along
+    the lanes. Feature rows past ``d`` (the block overruns the array where
+    d is no multiple of 8) read as 0."""
+    k = vecs_ref.shape[0]
+    bn = xt_ref.shape[1]
+    width = min(_FM_LANES, bn)
+    out = [[] for _ in range(k)]
+    for c0 in range(0, bn, width):
+        def body(row, acc):
+            xg = xt_ref[pl.ds(row, _SUBLANES), c0:c0 + width]
+            if isinstance(row, int) and row + _SUBLANES > d:
+                sub = jax.lax.broadcasted_iota(jnp.int32, (_SUBLANES, 1), 0)
+                xg = jnp.where(sub < d - row, xg, 0.0)
+            return tuple(
+                a + xg * jnp.concatenate(
+                    [vecs_ref[i, pl.ds(row, _SUBLANES), :]] * (width // _LANES),
+                    axis=1,
+                )
+                for i, a in enumerate(acc)
+            )
+
+        zero = jnp.zeros((_SUBLANES, width), jnp.float32)
+        acc = _fm_each_group(d, body, (zero,) * k)
+        for i in range(k):
+            out[i].append(jnp.sum(acc[i], axis=0, keepdims=True))
+    return jnp.concatenate(
+        [jnp.concatenate(o, axis=1) for o in out], axis=0
+    )
+
+
+def _contract_fm(out_ref, r, xt_ref, mask):
+    """Xᵀ·r of a feature-major float32 tile, added into the (d8, 128)
+    output block that ``_FM_GROUP`` consecutive tiles share: every sublane
+    group of 8 features times r along the lanes, the lane blocks folded to
+    one (8, 128) register of partial sums (VPU only). The 128 lanes and the
+    blocks are added outside. Sequential adds are 32 deep at most, so the
+    partials keep the tree shape of the row-major kernels' per-tile slots.
+    ``mask`` zeroes the products of a ragged last tile's out-of-range
+    lanes, whose x is unspecified: on that tile alone."""
+    d8, bn = xt_ref.shape
+    width = min(_FM_LANES, bn)
+
+    @pl.when(pl.program_id(0) % _FM_GROUP == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    def add(masked):
+        for c0 in range(0, bn, width):
+            rb = jnp.broadcast_to(r[:, c0:c0 + width], (_SUBLANES, width))
+            ok = None
+            if masked:
+                ok = jnp.broadcast_to(mask[:, c0:c0 + width], (_SUBLANES, width))
+
+            def body(row, carry):
+                p = xt_ref[pl.ds(row, _SUBLANES), c0:c0 + width] * rb
+                if ok is not None:
+                    p = jnp.where(ok, p, 0.0)
+                f = p[:, :_LANES]
+                for t in range(_LANES, width, _LANES):
+                    f = f + p[:, t:t + _LANES]
+                out_ref[pl.ds(row, _SUBLANES), :] += f
+                return carry
+
+            _fm_each_group(d8, body, 0)
+
+    if mask is None:
+        add(False)
+    else:
+        last = pl.program_id(0) == pl.num_programs(0) - 1
+        pl.when(last)(lambda: add(True))
+        pl.when(jnp.logical_not(last))(lambda: add(False))
 
 
 def _lane_sums(v):
@@ -189,21 +425,22 @@ def _lane_sums(v):
     return jnp.sum(v.reshape(-1, _LANES), axis=0, keepdims=True)
 
 
-def _vg_kernel(*refs, loss, n, masked, has_off, has_wt):
+def _vg_kernel(*refs, loss, n, d, fm, masked, has_off, has_wt):
     x_ref, y_ref, off_ref, wt_ref, u_ref, c_ref, val_ref, g_ref, rs_ref = (
         _split_refs(refs, has_off, has_wt)
     )
-    x, mask = _tile(x_ref, n, masked)
-    m = _margins(u_ref, c_ref, x)
+    x, mask = _tile(x_ref, n, masked, fm)
+    m = _margins(u_ref, c_ref, x, mask, fm, d)
     if has_off:
         m = m + _row(off_ref)
     y = _row(y_ref)
     lv, r = _weighted([loss.value(m, y), loss.d1(m, y)], wt_ref, mask)
-    # Each tile writes its OWN output slot; partials are tree-reduced in
-    # f32 outside the kernel. A single running accumulator would add tile
-    # partials sequentially, whose O(grid)·eps rounding is enough to stall
-    # the optimizer's Armijo test near convergence (observed on-chip).
-    g_ref[...] = _contract(r, x)
+    # Each tile writes its OWN output slot (the feature-major tiles one
+    # slot a group of tiles); partials are tree-reduced in f32 outside the
+    # kernel. A single running accumulator would add tile partials
+    # sequentially, whose O(grid)·eps rounding is enough to stall the
+    # optimizer's Armijo test near convergence (observed on-chip).
+    _contract(g_ref, r, x, mask, fm)
     val_ref[...] = _lane_sums(lv)
     rs_ref[...] = _lane_sums(r)
 
@@ -223,14 +460,27 @@ def _part_shape(grid, width):
     return jax.ShapeDtypeStruct((grid, 1, width), jnp.float32)
 
 
-def _prep(X, labels, offsets, weights):
-    """Shared wrapper setup: tile sizing and the X + per-row-stream input
-    lists (one copy, so value_grad and hvp can never diverge in
-    tiling/specs). A per-row stream enters as the flat f32 vector viewed
-    (grid, bn/128, 128): 4 B a row in HBM and in VMEM, a free view when the
-    tile divides n and one zero-pad of the vector otherwise."""
+def _prep(X, labels, offsets, weights, vecs):
+    """Shared wrapper setup: tile sizing, the X + per-row-stream + vector
+    input lists and the gradient output (one copy, so value_grad and hvp
+    can never diverge in tiling/specs). A per-row stream enters as the flat
+    f32 vector viewed (grid, bn/128, 128): 4 B a row in HBM and in VMEM, a
+    free view when the tile divides n and one zero-pad of the vector
+    otherwise. ``vecs`` is the (k, d) float32 coefficient rows.
+
+    Returns ``(grid, statics, ins, in_specs, g_spec, g_shape, finish)``;
+    ``finish`` adds the gradient output's partials to the (d,) result.
+
+    The feature-major kernels (``reads_feature_major``) take ``X.T``: the
+    array itself where the chip stores X feature-major (a change of view,
+    no copy), in (d8, bn) blocks, d8 the features rounded up to whole
+    sublane groups (the block overruns the array there and the kernel
+    masks it). Their coefficient rows come laid across 128 lanes, zero
+    past d, and their gradient partials are (d8, 128) a group of tiles."""
     n, d = X.shape
-    bn = _block_rows(n, d, jnp.dtype(X.dtype).itemsize)
+    fm = reads_feature_major(d, X.dtype)
+    width = sublane_width(d) if fm else d
+    bn = _block_rows(n, width, jnp.dtype(X.dtype).itemsize)
     if bn is None:
         raise ValueError(f"no VMEM-feasible tile for (n={n}, d={d})")
     grid = pl.cdiv(n, bn)
@@ -243,15 +493,34 @@ def _prep(X, labels, offsets, weights):
 
     stream_spec = pl.BlockSpec((None, bn // _LANES, _LANES),
                                lambda i: (i, 0, 0))
-    ins = [X, stream(labels)]
-    in_specs = [pl.BlockSpec((bn, d), lambda i: (i, 0)), stream_spec]
+    k = vecs.shape[0]
+    if fm:
+        ins = [X.T, stream(labels)]
+        in_specs = [pl.BlockSpec((width, bn), lambda i: (0, i)), stream_spec]
+        vecs = jnp.pad(vecs, ((0, 0), (0, width - d)))
+        vecs = jnp.broadcast_to(vecs[:, :, None], (k, width, _LANES))
+        vec_spec = pl.BlockSpec((k, width, _LANES), lambda i: (0, 0, 0))
+        g_spec = pl.BlockSpec((None, width, _LANES),
+                              lambda i: (i // _FM_GROUP, 0, 0))
+        g_shape = jax.ShapeDtypeStruct(
+            (pl.cdiv(grid, _FM_GROUP), width, _LANES), jnp.float32
+        )
+        finish = lambda g: jnp.sum(g, axis=(0, 2))[:d]
+    else:
+        ins = [X, stream(labels)]
+        in_specs = [pl.BlockSpec((bn, d), lambda i: (i, 0)), stream_spec]
+        vec_spec = _const_spec((k, d))
+        g_spec, g_shape = _part_spec(d), _part_shape(grid, d)
+        finish = lambda g: jnp.sum(g, axis=(0, 1))
     for a in (offsets, weights):
         if a is not None:
             ins.append(stream(a))
             in_specs.append(stream_spec)
-    statics = dict(n=n, masked=n % bn != 0, has_off=offsets is not None,
-                   has_wt=weights is not None)
-    return d, grid, statics, ins, in_specs
+    ins.append(vecs)
+    in_specs.append(vec_spec)
+    statics = dict(n=n, d=d, fm=fm, masked=n % bn != 0,
+                   has_off=offsets is not None, has_wt=weights is not None)
+    return grid, statics, ins, in_specs, g_spec, g_shape, finish
 
 
 _COMPILER_PARAMS = pltpu.CompilerParams(
@@ -266,37 +535,39 @@ def fused_value_grad(X, labels, offsets, weights, u, c, *, loss,
     margins m = X@u + offsets − c. ``offsets=None`` means identically 0,
     ``weights=None`` identically 1 (the stream is not read at all).
     Returns float32 (val, grad, r_sum)."""
-    d, grid, statics, ins, in_specs = _prep(X, labels, offsets, weights)
-    ins += [u.reshape(1, d).astype(jnp.float32),
-            jnp.asarray(c, jnp.float32).reshape(1, 1)]
-    in_specs += [_const_spec((1, d)), _const_spec((1, 1))]
+    grid, statics, ins, in_specs, g_spec, g_shape, finish = _prep(
+        X, labels, offsets, weights,
+        u.reshape(1, X.shape[1]).astype(jnp.float32),
+    )
+    ins.append(jnp.asarray(c, jnp.float32).reshape(1, 1))
+    in_specs.append(_const_spec((1, 1)))
 
     val, g, rs = pl.pallas_call(
         functools.partial(_vg_kernel, loss=loss, **statics),
         grid=(grid,),
         in_specs=in_specs,
-        out_specs=[_part_spec(_LANES), _part_spec(d), _part_spec(_LANES)],
-        out_shape=[_part_shape(grid, _LANES), _part_shape(grid, d),
+        out_specs=[_part_spec(_LANES), g_spec, _part_spec(_LANES)],
+        out_shape=[_part_shape(grid, _LANES), g_shape,
                    _part_shape(grid, _LANES)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*ins)
-    return jnp.sum(val), jnp.sum(g, axis=(0, 1)), jnp.sum(rs)
+    return jnp.sum(val), finish(g), jnp.sum(rs)
 
 
-def _hvp_kernel(*refs, loss, n, masked, has_off, has_wt):
+def _hvp_kernel(*refs, loss, n, d, fm, masked, has_off, has_wt):
     x_ref, y_ref, off_ref, wt_ref, uv_ref, sc_ref, hv_ref, qs_ref = (
         _split_refs(refs, has_off, has_wt)
     )
-    x, mask = _tile(x_ref, n, masked)
-    muv = _margins(uv_ref, sc_ref, x)  # (2, bn): margins and X·v − cv
+    x, mask = _tile(x_ref, n, masked, fm)
+    muv = _margins(uv_ref, sc_ref, x, mask, fm, d)  # (2, bn): margins and X·v − cv
     m, mv = muv[0:1], muv[1:2]
     if has_off:
         m = m + _row(off_ref)
     (d2,) = _weighted([loss.d2(m, _row(y_ref))], wt_ref, mask)
     q = d2 * mv
     # per-tile partials, reduced outside (see _vg_kernel)
-    hv_ref[...] = _contract(q, x)
+    _contract(hv_ref, q, x, mask, fm)
     qs_ref[...] = _lane_sums(q)
 
 
@@ -305,19 +576,20 @@ def fused_hvp(X, labels, offsets, weights, u, v, c, cv, *, loss,
     """One X-read Gauss-Newton Hv: (Xᵀq, Σq) with q = w·l''(m, y)·(Xv − cv)
     and m = X@u + offsets − c. ``offsets``/``weights`` may be None as in
     ``fused_value_grad``. Returns float32 (hv, q_sum)."""
-    d, grid, statics, ins, in_specs = _prep(X, labels, offsets, weights)
-    ins += [jnp.stack([u, v]).astype(jnp.float32),
-            jnp.stack([jnp.asarray(c, jnp.float32),
-                       jnp.asarray(cv, jnp.float32)]).reshape(2, 1)]
-    in_specs += [_const_spec((2, d)), _const_spec((2, 1))]
+    grid, statics, ins, in_specs, g_spec, g_shape, finish = _prep(
+        X, labels, offsets, weights, jnp.stack([u, v]).astype(jnp.float32),
+    )
+    ins.append(jnp.stack([jnp.asarray(c, jnp.float32),
+                          jnp.asarray(cv, jnp.float32)]).reshape(2, 1))
+    in_specs.append(_const_spec((2, 1)))
 
     hv, qs = pl.pallas_call(
         functools.partial(_hvp_kernel, loss=loss, **statics),
         grid=(grid,),
         in_specs=in_specs,
-        out_specs=[_part_spec(d), _part_spec(_LANES)],
-        out_shape=[_part_shape(grid, d), _part_shape(grid, _LANES)],
+        out_specs=[g_spec, _part_spec(_LANES)],
+        out_shape=[g_shape, _part_shape(grid, _LANES)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*ins)
-    return jnp.sum(hv, axis=(0, 1)), jnp.sum(qs)
+    return finish(hv), jnp.sum(qs)

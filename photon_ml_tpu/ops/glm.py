@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from photon_ml_tpu.normalization import NormalizationContext, no_normalization
-from photon_ml_tpu.obs.stages import GLM_OBJECTIVE, stage
+from photon_ml_tpu.obs.stages import GLM_HVP, GLM_OBJECTIVE, stage
 from photon_ml_tpu.ops.batch import Batch, DenseBatch
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.types import VarianceComputationType
@@ -279,7 +279,7 @@ class GLMObjective:
         """Gauss-Newton/Hessian-vector product H·v = AᵀDA·v + λ₂·v (A = the
         normalized design matrix, D = diag(weight·d2)). One forward matmul +
         one reverse matmul; for TRON's CG loop this is the hot kernel."""
-        with stage(GLM_OBJECTIVE):
+        with stage(GLM_OBJECTIVE), stage(GLM_HVP):
             v_eff = self.norm.factors * v
             if self.fused and isinstance(self.batch, DenseBatch):
                 from photon_ml_tpu.ops.fused import fused_hvp
@@ -419,10 +419,14 @@ def make_objective(
     regularization (and from normalization if ``norm`` is built with it).
 
     ``fused=None`` auto-enables the one-pass Pallas kernels on TPU for
-    dense batches with supported shapes (``ops/fused.py``); pass
-    ``False``/``True`` to force (``True`` off-TPU runs the kernels in
-    interpreter mode — correct but slow, for tests). Set the environment
-    variable ``PHOTON_DISABLE_FUSED=1`` to veto auto-enabling.
+    dense batches they take (``auto_fused``; by ``ops/fused.supports_fused``
+    a bfloat16 or float32 width that is a multiple of 128 takes the
+    row-major kernels, a float32 matrix of any other width of 128 or more
+    that the chip keeps feature-major takes the feature-major ones, and a
+    narrower matrix stays on XLA's sweeps); pass ``False``/``True`` to force
+    (``True`` off-TPU runs the kernels in interpreter mode — correct but
+    slow, for tests). Set the environment variable
+    ``PHOTON_DISABLE_FUSED=1`` to veto auto-enabling.
 
     ``data_hints`` = (offsets all zero, weights all one), for callers that
     know their device-resident data (host numpy arrays are auto-detected
@@ -443,6 +447,8 @@ def make_objective(
         offsets_zero, weights_one = (
             data_hints if data_hints is not None else _constant_hints(batch)
         )
+    if isinstance(batch, DenseBatch):
+        _count_dense_layout(d, batch.X.dtype, bool(fused))
     return GLMObjective(
         batch=batch,
         norm=norm,
@@ -456,6 +462,22 @@ def make_objective(
         prior_mean=None if prior is None else jnp.asarray(prior.means, jnp.float32),
         prior_precision=None if prior is None else prior.precisions,
     )
+
+
+def _count_dense_layout(d: int, dtype, fused: bool) -> None:
+    """``dense_layout.columns`` (the real width of every dense objective
+    built) and ``dense_layout.padded_columns`` (the columns its kernels'
+    blocks add to it: the feature-major kernels round the features up to
+    whole sublane groups, the row-major kernels and the XLA path add
+    none), in the always-on registry."""
+    from photon_ml_tpu.obs.metrics import REGISTRY
+    from photon_ml_tpu.ops import fused as kernels
+
+    padded = 0
+    if fused and kernels.reads_feature_major(d, dtype):
+        padded = kernels.sublane_width(d) - d
+    REGISTRY.counter_inc("dense_layout.columns", float(d))
+    REGISTRY.counter_inc("dense_layout.padded_columns", float(padded))
 
 
 def fused_disabled() -> bool:
@@ -474,21 +496,30 @@ def fused_disabled() -> bool:
 
 def auto_fused(batch: Batch) -> bool:
     """Should this (concrete) batch use the one-pass Pallas kernels?
-    True on TPU for dense, lane-aligned, VMEM-feasible shapes. Callers that
+    True on TPU for dense shapes ``ops/fused.supports_fused`` takes, stored
+    as the kernels they take read them. Callers that
     construct objectives inside a transform (``shard_map``, ``vmap``) must
     decide BEFORE entering it — under a transform X is a tracer and this
     returns False (pallas under vmap batching rules is untested; under
     ``shard_map`` pass the pre-computed answer through a static arg, as
     ``parallel/distributed.py`` does with per-device row counts)."""
-    from photon_ml_tpu.ops.fused import supports_fused
+    from photon_ml_tpu.ops import fused
 
-    return (
+    if not (
         isinstance(batch, DenseBatch)
         and not isinstance(batch.X, jax.core.Tracer)
         and jax.default_backend() == "tpu"
         and not fused_disabled()
-        and supports_fused(batch.num_rows, batch.num_features, batch.X.dtype)
-    )
+        and fused.supports_fused(
+            batch.num_rows, batch.num_features, batch.X.dtype)
+    ):
+        return False
+    # the feature-major kernels read X as its transpose: free where the
+    # chip keeps the array that way (it does, for a float32 width that is
+    # no multiple of 128), a relayout of the whole matrix where it does not
+    if fused.reads_feature_major(batch.num_features, batch.X.dtype):
+        return fused.stored_feature_major(batch.X)
+    return True
 
 
 def _constant_hints(batch: Batch) -> tuple[bool, bool]:

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from photon_ml_tpu.config import OptimizerConfig
+from photon_ml_tpu.obs.stages import TRON_CG, TRON_UPDATE, stage
 from photon_ml_tpu.optim.common import (
     ConvergenceReason,
     OptimizationResult,
@@ -122,6 +123,10 @@ def _tron_funcs(objective: Any, config: OptimizerConfig):
     T = config.max_iterations
 
     def init(w0: Array) -> _TronState:
+        with stage(TRON_UPDATE):
+            return _init(w0)
+
+    def _init(w0: Array) -> _TronState:
         dtype = w0.dtype
         f0, g0 = objective.value_and_grad(w0)
         g0_norm = jnp.linalg.norm(g0)
@@ -147,7 +152,18 @@ def _tron_funcs(objective: Any, config: OptimizerConfig):
         return jnp.logical_and(st.it < T, jnp.logical_not(st.done))
 
     def body(st: _TronState) -> _TronState:
-        s, r, cg_k = _trcg(lambda v: objective.hvp(st.w, v), st.g, st.delta, config.max_cg_iterations)
+        # the objective names its own passes (``glm.objective``, and
+        # ``glm.hvp`` inside it), so a reader of ``tron.*`` outside
+        # ``glm.objective`` sees the optimizer's vector algebra alone
+        with stage(TRON_CG):
+            s, r, cg_k = _trcg(
+                lambda v: objective.hvp(st.w, v), st.g, st.delta,
+                config.max_cg_iterations,
+            )
+        with stage(TRON_UPDATE):
+            return _update(st, s, r, cg_k)
+
+    def _update(st: _TronState, s: Array, r: Array, cg_k: Array) -> _TronState:
         gs = jnp.dot(st.g, s)
         # r = -g - H·s ⇒ sᵀHs = -gs - s·r ⇒ predicted reduction:
         prered = -0.5 * (gs - jnp.dot(s, r))
@@ -214,10 +230,12 @@ def _tron_funcs(objective: Any, config: OptimizerConfig):
             g=g_out,
             delta=delta,
             it=it,
-            # each CG step is one Hv pass over the data (the fused hvp
-            # streams X once); the acceptance value_and_grad is one more —
-            # the PASS count is the physical work unit the bench's
-            # per-pass marginals difference against (VERDICT r4 weak #4)
+            # full-data passes: each CG step is one Hv pass and the
+            # acceptance value_and_grad one more, so a fit of ``it``
+            # outer iterations took ``passes - it - 1`` CG steps. It is
+            # ``OptimizationResult.objective_passes``, which the benchmark
+            # reads as ``optim.passes_per_fit`` and, by that identity,
+            # ``optim.cg_steps_per_fit`` (benchmark/runners/fit_tron.py)
             passes=st.passes + cg_k + jnp.int32(1),
             reason=reason,
             done=done,

@@ -1,7 +1,8 @@
-"""The three compiled programs whose stage names the tests hold (not a
-test module): the fused outer iteration of a tiny GAME descent (fixed
-effect + one random effect), ``lbfgs_minimize`` over a tile-COO batch, and
-``DistributedTrainer``'s ``_sharded_solve``. Each builder returns
+"""The compiled programs whose stage names the tests hold (not a test
+module): the fused outer iteration of a tiny GAME descent (fixed effect +
+one random effect), ``lbfgs_minimize`` over a tile-COO batch,
+``DistributedTrainer``'s ``_sharded_solve``, and ``tron_minimize`` over a
+dense float32 batch through the feature-major kernels. Each builder returns
 ``(jitted function, positional arguments, keyword arguments)``; the
 arguments are concrete arrays (or, for the sharded solve, shapes on the
 mesh handed in), so a caller can run, lower, or compile deviceless
@@ -40,10 +41,14 @@ SPARSE_DESCENT_STAGES = (
 )
 # a fit over a tile-COO layout with a dense head: the two parts of a pass
 TILE_FIT_STAGES = FIT_STAGES + ("glm.head", "glm.tail")
+# a TRON fit: Hessian-vector passes inside the objective, CG and the
+# trust-region update outside it
+TRON_FIT_STAGES = ("glm.objective", "glm.hvp", "tron.cg", "tron.update")
 PROGRAM_STAGES = {
     "descent": DESCENT_STAGES, "tile_fit": TILE_FIT_STAGES,
     "sharded": FIT_STAGES,
     "sparse_descent": SPARSE_DESCENT_STAGES,
+    "tron_fit": TRON_FIT_STAGES,
 }
 
 
@@ -163,6 +168,37 @@ def tile_fit_program():
     }
 
 
+def tron_fit_program():
+    """``tron_minimize`` over a dense float32 batch whose width is no
+    multiple of 128 (the feature-major kernels), offsets all zero."""
+    from photon_ml_tpu.config import OptimizerConfig
+    from photon_ml_tpu.ops.batch import DenseBatch
+    from photon_ml_tpu.ops.glm import make_objective
+    from photon_ml_tpu.ops.losses import loss_for_task
+    from photon_ml_tpu.optim.tron import tron_minimize
+    from photon_ml_tpu.types import OptimizerType, TaskType
+
+    rng = np.random.default_rng(0)
+    n, d = 1 << 12, 200
+    batch = DenseBatch(
+        X=jnp.asarray(rng.normal(size=(n, d)), jnp.float32),
+        labels=jnp.asarray(rng.random(n) < 0.5, jnp.float32),
+        offsets=jnp.zeros((n,), jnp.float32),
+        weights=jnp.ones((n,), jnp.float32),
+    )
+    objective = make_objective(
+        batch, loss_for_task(TaskType.LOGISTIC_REGRESSION), l2_weight=1.0,
+        fused=True, data_hints=(True, False),
+    )
+    config = OptimizerConfig(
+        optimizer_type=OptimizerType.TRON, max_iterations=3, tolerance=0.0,
+        max_cg_iterations=4,
+    )
+    return tron_minimize, (objective, jnp.zeros((d,), jnp.float32)), {
+        "config": config
+    }
+
+
 def sharded_program(mesh):
     """``_sharded_solve`` over a bf16 dense batch row-sharded on ``mesh``
     (axis ``data``), fused kernel on, as shapes."""
@@ -202,7 +238,10 @@ def build(name: str, mesh):
         return sharded_program(mesh)
     if name == "sparse_descent":
         return descent_program(sparse=True)
-    return {"descent": descent_program, "tile_fit": tile_fit_program}[name]()
+    return {
+        "descent": descent_program, "tile_fit": tile_fit_program,
+        "tron_fit": tron_fit_program,
+    }[name]()
 
 
 def without_scopes(monkeypatch) -> None:

@@ -251,14 +251,119 @@ def test_fused_operands_are_lane_dense(kernel, streams, n):
 def test_supports_fused_gates():
     assert supports_fused(1024, 512, jnp.float32)
     assert supports_fused(1024, 512, jnp.bfloat16)
-    assert not supports_fused(1024, 500, jnp.float32)  # lane-unaligned d
+    # float32 takes any width of at least one lane tile (the feature-major
+    # kernels, as the chip stores such a matrix); the MXU's bfloat16 dots
+    # contract whole lane tiles
+    assert supports_fused(1024, 500, jnp.float32)
+    assert supports_fused(400_000, 2000, jnp.float32)
+    assert not supports_fused(1024, 500, jnp.bfloat16)
+    # narrower than one lane tile stays on the XLA path, whatever the dtype
+    assert not supports_fused(5_000_066, 65, jnp.float32)
+    assert not supports_fused(1024, 127, jnp.float32)
+    assert supports_fused(1024, 128, jnp.float32)
     assert not supports_fused(1024, 512, jnp.int8)
     assert not supports_fused(1024, 1 << 17, jnp.float32)  # tile over budget
-    # an f32 tile's full-precision dots are bounded by scoped VMEM: Mosaic
-    # refused d = 5504 while the gate still let it in (deviceless, PR 26)
-    assert supports_fused(1024, 5376, jnp.float32)
-    assert not supports_fused(1024, 5504, jnp.float32)
+    # the double-buffered minimum tile of 256 rows bounds the width
+    assert supports_fused(1024, 7168, jnp.float32)
+    assert not supports_fused(1024, 7296, jnp.float32)
+    assert supports_fused(1024, 7160, jnp.float32)  # feature-major: 7,160 rows
+    assert not supports_fused(1024, 7170, jnp.float32)
     assert supports_fused(1024, 14336, jnp.bfloat16)
+
+
+def _reference_passes(batch, w, v, l2):
+    """The benchmark's plain reference on the batch's real columns, with the
+    batch's weights all one and offsets zero."""
+    from benchmark.reference import tron as reference
+
+    X, y = batch.X, batch.labels
+    f, g = reference.value_grad(X, y, w, l2, block_rows=256)
+    return f, g, reference.hvp(X, y, w, v, l2, block_rows=256)
+
+
+@pytest.mark.parametrize("kernel", ["value_grad", "hvp"])
+@pytest.mark.parametrize("d", [130, 200, 256, 2000])
+def test_fused_takes_any_float32_width_of_a_lane_tile_or_more(rng, d, kernel):
+    """A float32 matrix whose width is no multiple of 128 goes through the
+    (feature-major) kernels: against the XLA path and the benchmark's
+    reference, outputs of the real width, nothing of the rows the block
+    overruns in any of them; 256 takes the row-major kernels as before."""
+    from photon_ml_tpu.ops import fused as F
+
+    n = 300 if d < 2000 else 700  # a ragged last tile either way
+    task = TaskType.LOGISTIC_REGRESSION
+    batch = _problem(rng, n, d, task, zero_weights=False)
+    batch = DenseBatch(batch.X, batch.labels, jnp.zeros((n,), jnp.float32),
+                       jnp.ones((n,), jnp.float32))
+    assert supports_fused(n, d, jnp.float32)
+    loss = loss_for_task(task)
+    ref, fused = (
+        make_objective(batch, loss, l2_weight=0.7, fused=f) for f in (False, True)
+    )
+    w = jnp.asarray(rng.normal(size=d).astype(np.float32) * (2.0 / d**0.5))
+    v = jnp.asarray(rng.normal(size=d).astype(np.float32))
+    f_ref, g_ref, hv_ref = _reference_passes(batch, w, v, 0.7)
+
+    def close(a, b):  # in the 2-norm: float32 sums in another order
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        assert np.linalg.norm(a - b) <= 1e-5 * np.linalg.norm(b)
+
+    if kernel == "value_grad":
+        (f0, g0), (f1, g1) = ref.value_and_grad(w), fused.value_and_grad(w)
+        assert g1.shape == (d,) and g1.dtype == jnp.float32
+        np.testing.assert_allclose(f1, f0, rtol=1e-5)
+        np.testing.assert_allclose(f1, f_ref, rtol=1e-5)
+        close(g1, g0)
+        close(g1, g_ref)
+        raw = F.fused_value_grad(batch.X, batch.labels, None, None, w, 0.0,
+                                 loss=loss, interpret=True)[1]
+    else:
+        hv0, hv1 = ref.hvp(w, v), fused.hvp(w, v)
+        assert hv1.shape == (d,) and hv1.dtype == jnp.float32
+        close(hv1, hv0)
+        close(hv1, hv_ref)
+        raw = F.fused_hvp(batch.X, batch.labels, None, None, w, v, 0.0, 0.0,
+                          loss=loss, interpret=True)[0]
+    assert raw.shape == (d,) and bool(jnp.all(jnp.isfinite(raw)))
+    if d % 128:  # the feature-major kernels: blocks of whole sublane groups
+        assert F.reads_feature_major(d, jnp.float32)
+        d8 = F.sublane_width(d)
+        *_, ins, in_specs, g_spec, g_shape, _ = F._prep(
+            batch.X, batch.labels, None, None, w.reshape(1, d)
+        )
+        assert ins[0].shape == (d, n) and in_specs[0].block_shape[0] == d8
+        # the block overruns the array past d: the coefficients there are 0
+        assert ins[-1].shape == (1, d8, 128)
+        assert bool(jnp.all(ins[-1][0, d:] == 0.0))
+        assert g_shape.shape[1:] == (d8, 128)
+    else:
+        assert not F.reads_feature_major(d, jnp.float32)
+
+
+def test_auto_fused_reads_the_width_and_how_the_array_is_stored(monkeypatch):
+    """d = 65 (the descent cells' fixed effect) is not these kernels': on a
+    TPU ``auto_fused`` still says no. A float32 matrix of 2,000 columns
+    takes the feature-major kernels where it is stored feature-major, as a
+    TPU stores it (here the CPU's row-major array stands for one that is
+    not); an aligned width is asked nothing new."""
+    from photon_ml_tpu.ops import fused as F
+    from photon_ml_tpu.ops import glm
+
+    def batch(d, dtype=jnp.float32):
+        return DenseBatch(jnp.zeros((512, d), dtype), jnp.zeros((512,)),
+                          jnp.zeros((512,)), jnp.ones((512,)))
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    wide = batch(2000)
+    assert F.stored_feature_major(wide.X) is False
+    assert F.stored_feature_major(np.zeros((4, 4))) is False
+    assert glm.auto_fused(batch(65)) is False
+    assert glm.auto_fused(wide) is False
+    assert glm.auto_fused(batch(2000, jnp.bfloat16)) is False
+    assert glm.auto_fused(batch(2048)) is True
+    monkeypatch.setattr(F, "stored_feature_major", lambda X: True)
+    assert glm.auto_fused(wide) is True
+    assert glm.auto_fused(batch(65)) is False
 
 
 def test_disable_fused_knob_strict_parse(monkeypatch):
