@@ -50,7 +50,7 @@ from photon_ml_tpu.obs.stages import (
     VISIT_RE,
     stage,
 )
-from photon_ml_tpu.ops.glm import compute_variances, make_objective
+from photon_ml_tpu.ops.glm import auto_fused, compute_variances, make_objective
 from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.optim.common import OptimizationResult, select_minimize_fn
 from photon_ml_tpu.parallel.distributed import sharded_minimize
@@ -295,7 +295,7 @@ class FixedEffectCoordinate:
             # closure would bake the feature arrays into the executable)
             base = self._training_batch(jnp.zeros_like(self.batch.offsets))
             object.__setattr__(self, "_visit_base", base)
-            object.__setattr__(self, "_visit_fn", self._build_visit_fn())
+            object.__setattr__(self, "_visit_fn", self._build_visit_fn(base))
         fn = self.__dict__["_visit_fn"]
 
         def make_static(initial):
@@ -359,14 +359,18 @@ class FixedEffectCoordinate:
         model, tracker = postprocess(aux)
         return model, tracker, new_score, new_total
 
-    def _build_visit_fn(self):
+    def _build_visit_fn(self, base):
         """The jitted visit body (built once per coordinate; closes over
-        the batch, config, prior, and cached layout)."""
+        the config, prior, and cached layout). Whether the objective takes
+        the one-pass kernels is decided here, on the concrete ``base``
+        batch: inside the trace X is a tracer, of which ``auto_fused``
+        knows neither the device nor how the array is stored."""
         opt = self.config
         loss = loss_for_task(self.task_type)
         l1 = opt.regularization.l1_weight(opt.regularization_weight)
         l2 = opt.regularization.l2_weight(opt.regularization_weight)
         minimize_fn, extra = select_minimize_fn(opt.optimizer, l1)
+        fused = auto_fused(base)
         prior = None
         if self.prior_model is not None:
             from photon_ml_tpu.ops.glm import GaussianPrior
@@ -393,6 +397,7 @@ class FixedEffectCoordinate:
                 obj = make_objective(
                     train_batch, loss, l2_weight=l2, norm=norm,
                     intercept_index=self.intercept_index, prior=prior,
+                    fused=fused,
                 )
                 result = minimize_fn(obj, w0_n, opt.optimizer, **extra)
                 w = result.w

@@ -54,13 +54,15 @@ contraction is kept a layout, both on the VPU:
   the row-major kernels (``_margins_f32`` / ``_contract_f32``: multiply and
   add 128-lane blocks, one XLU transpose of a (128, 128) accumulator to
   turn row sums into lanes);
-- any other width of at least 128 (LIBSVM epsilon's 2,000) lies
-  FEATURE-MAJOR on the chip, features down the sublanes and rows along the
-  lanes with not a byte of padding, so the feature-major kernels
-  (``_margins_fm`` / ``_contract_fm``) read ``X.T``, which is that very
-  array: margins are born along the lanes and nothing is transposed;
-- narrower than 128 columns, or bfloat16 at a width that is no multiple of
-  128, stays on the XLA path (``supports_fused``).
+- any other width of at least one sublane group (LIBSVM epsilon's 2,000,
+  the GLMix descent's 65-column fixed effect) lies FEATURE-MAJOR on the
+  chip, features down the sublanes and rows along the lanes with not a
+  byte of padding, so the feature-major kernels (``_margins_fm`` /
+  ``_contract_fm``) read ``X.T``, which is that very array: margins are
+  born along the lanes and nothing is transposed; a tile goes 512 rows of
+  X at a time, in a loop where it holds more (``_fm_pass``);
+- bfloat16 at a width that is no multiple of 128, or fewer than 8 float32
+  columns, stays on the XLA path (``supports_fused``).
 
 Semantics match ``GLMObjective`` exactly:
 - zero-weight rows contribute exactly 0 (padding can hold any values),
@@ -115,23 +117,24 @@ def supports_fused(n: int, d: int, dtype) -> bool:
     - d a multiple of 128, bfloat16 or float32: the row-major kernels, whose
       (bn, d) tiles and (1, d) partials are whole 128-lane tiles (a TPU
       stores such a matrix row-major).
-    - float32 of any other width of at least one lane tile (d >= 128, as
-      LIBSVM epsilon's 2,000): the feature-major kernels
-      (``reads_feature_major``). A TPU stores that matrix feature-major, the
-      rows along the lanes, with no padding; ``ops/glm.auto_fused`` checks
-      that the array at hand is stored so.
-    - d < 128 stays on the XLA path (the descent cells' 65-column fixed
-      effect: ROADMAP S3), and so does bfloat16 at a width that is no
-      multiple of 128.
+    - float32 of any other width of at least one sublane group (d >= 8:
+      LIBSVM epsilon's 2,000, the descent cells' 65-column fixed effect):
+      the feature-major kernels (``reads_feature_major``). A TPU stores
+      that matrix feature-major, the rows along the lanes, with no padding;
+      ``ops/glm.auto_fused`` checks that the array at hand is stored so.
+      The floor is the kernels' unit of work: they contract whole groups of
+      8 features and mask only the last, partial one (7 of 72 sublane rows
+      at d = 65); under 8 columns the partial group is all there is.
+    - bfloat16 at a width that is no multiple of 128 stays on the XLA path.
 
     And a double-buffered minimum tile of X must fit the VMEM budget: very
     high-d problems belong to the sparse path. The per-row streams do not
     enter: lane-dense, they are 4 B a row each.
     """
-    if dtype not in (jnp.float32, jnp.bfloat16) or d < _LANES:
+    if dtype not in (jnp.float32, jnp.bfloat16):
         return False
     if reads_feature_major(d, dtype):
-        return _block_rows(n, sublane_width(d), 4) is not None
+        return d >= _SUBLANES and _block_rows(n, sublane_width(d), 4) is not None
     if d % _LANES != 0:
         return False
     return _block_rows(n, d, jnp.dtype(dtype).itemsize) is not None
@@ -193,14 +196,14 @@ def _split_refs(refs, has_off: bool, has_wt: bool):
     return (x_ref, y_ref, off_ref, wt_ref) + tuple(refs[k:])
 
 
-def _tile(x_ref, n, masked, fm):
-    """The resident X tile and, for a ragged last tile, the (1, bn) mask of
-    its in-range rows. Out-of-range tile rows hold unspecified values; they
-    are zeroed so the contraction cannot pick up Inf/NaN garbage through
-    0·x. A float32 tile is handed on as the ref: its contractions read it
-    a block at a time and mask what they compute (``_margins``,
-    ``_contract``). ``fm``: the tile is feature-major, (d, bn)."""
-    bn = x_ref.shape[1 if fm else 0]
+def _tile(x_ref, n, masked):
+    """The resident row-major X tile and, for a ragged last tile, the
+    (1, bn) mask of its in-range rows. Out-of-range tile rows hold
+    unspecified values; they are zeroed so the contraction cannot pick up
+    Inf/NaN garbage through 0·x. A float32 tile is handed on as the ref:
+    its contractions read it a block at a time and mask what they compute
+    (``_margins``, ``_contract``)."""
+    bn = x_ref.shape[0]
     row = None
     if masked:
         start = pl.program_id(0) * bn
@@ -214,18 +217,17 @@ def _tile(x_ref, n, masked, fm):
     return jnp.where(col < n, x, jnp.zeros_like(x)), row
 
 
-def _margins(vecs_ref, shifts_ref, x, mask, fm, d):
+def _margins(vecs_ref, shifts_ref, x, mask):
     """(k, bn) lane-dense margins vecs·xᵀ − shifts of the k coefficient
-    rows against the tile: every row's margin lands in its own lane.
+    rows against a row-major tile: every row's margin lands in its own
+    lane.
 
     bfloat16: one MXU dot with the tile as the transposed operand (as
     attention's q·kᵀ). float32: on the VPU, exact with no ``highest``
-    splits (``_margins_f32``, or ``_margins_fm`` over a feature-major
-    tile); the margins of a ragged tile's out-of-range rows, whose x is
-    unspecified, are set to 0."""
+    splits (``_margins_f32``); the margins of a ragged tile's out-of-range
+    rows, whose x is unspecified, are set to 0."""
     if x.dtype == jnp.float32:
-        m = _margins_fm(vecs_ref, x, d) if fm else _margins_f32(vecs_ref, x)
-        m = m - shifts_ref[...]
+        m = _margins_f32(vecs_ref, x) - shifts_ref[...]
         return m if mask is None else jnp.where(mask, m, 0.0)
     return jax.lax.dot_general(
         vecs_ref[...].astype(x.dtype), x, _NT,
@@ -258,31 +260,30 @@ def _margins_f32(vecs_ref, x_ref):
     )
 
 
-def _row(ref):
-    """A per-row stream's (bn/128, 128) block as the (1, bn) row the
-    margins are in — a relayout of the resident block, no HBM traffic."""
-    return ref[...].reshape(1, -1)
+def _row(ref, rows=slice(None)):
+    """A per-row stream's (bn/128, 128) block as the (1, bn) row the margins
+    are in — a relayout of the resident block, no HBM traffic. ``rows``:
+    the block's rows of one window of a feature-major tile (``_fm_pass``)."""
+    return ref[rows, :].reshape(1, -1)
 
 
-def _weighted(vals, wt_ref, mask):
+def _weighted(vals, wt_ref, mask, rows=slice(None)):
     """w·v per row with zero-weight and out-of-range rows exactly 0 (the
     wrapper pads the weight stream with zeros, so its padding needs no
     mask of its own)."""
     if wt_ref is not None:
-        wt = _row(wt_ref)
+        wt = _row(wt_ref, rows)
         return [jnp.where(wt != 0.0, wt * v, 0.0) for v in vals]
     if mask is not None:
         return [jnp.where(mask, v, 0.0) for v in vals]
     return vals
 
 
-def _contract(out_ref, r, x, mask, fm):
-    """rᵀX into the tile's output block: the (1, bn)·(bn, d) MXU dot for
-    bfloat16, r cast to the storage dtype; for a float32 tile the VPU form
-    (``_contract_f32``, or ``_contract_fm`` over a feature-major tile)."""
-    if fm:
-        _contract_fm(out_ref, r, x, mask)
-    elif x.dtype == jnp.float32:
+def _contract(out_ref, r, x, mask):
+    """rᵀX into a row-major tile's output block: the (1, bn)·(bn, d) MXU
+    dot for bfloat16, r cast to the storage dtype; for a float32 tile the
+    VPU form (``_contract_f32``)."""
+    if x.dtype == jnp.float32:
         out_ref[...] = _contract_f32(r, x, mask)
     else:
         out_ref[...] = jnp.dot(
@@ -340,83 +341,122 @@ def _fm_each_group(d: int, body, carry):
     return carry
 
 
-def _margins_fm(vecs_ref, xt_ref, d):
-    """(k, bn) margins of a feature-major float32 tile ``xt`` (d8, bn):
-    ``_FM_LANES`` lanes at a time, every sublane group of 8 features
+def _margins_fm(vecs_ref, xt_ref, d, lanes):
+    """(k, width) margins of the window ``lanes`` of a feature-major
+    float32 tile ``xt`` (d8, bn): every sublane group of 8 features
     multiplied by its slice of a coefficient row, which comes laid across
     128 lanes ((k, d8, 128): no lane broadcast in the loop), and added to
-    one (8, lanes) accumulator a coefficient row; the eight sublanes are
+    one (8, width) accumulator a coefficient row; the eight sublanes are
     added at the end. No transpose anywhere: the margins are born along
     the lanes. Feature rows past ``d`` (the block overruns the array where
     d is no multiple of 8) read as 0."""
     k = vecs_ref.shape[0]
-    bn = xt_ref.shape[1]
-    width = min(_FM_LANES, bn)
-    out = [[] for _ in range(k)]
-    for c0 in range(0, bn, width):
-        def body(row, acc):
-            xg = xt_ref[pl.ds(row, _SUBLANES), c0:c0 + width]
-            if isinstance(row, int) and row + _SUBLANES > d:
-                sub = jax.lax.broadcasted_iota(jnp.int32, (_SUBLANES, 1), 0)
-                xg = jnp.where(sub < d - row, xg, 0.0)
-            return tuple(
-                a + xg * jnp.concatenate(
-                    [vecs_ref[i, pl.ds(row, _SUBLANES), :]] * (width // _LANES),
-                    axis=1,
-                )
-                for i, a in enumerate(acc)
-            )
+    width = lanes.size
 
-        zero = jnp.zeros((_SUBLANES, width), jnp.float32)
-        acc = _fm_each_group(d, body, (zero,) * k)
-        for i in range(k):
-            out[i].append(jnp.sum(acc[i], axis=0, keepdims=True))
+    def body(row, acc):
+        xg = xt_ref[pl.ds(row, _SUBLANES), lanes]
+        if isinstance(row, int) and row + _SUBLANES > d:
+            sub = jax.lax.broadcasted_iota(jnp.int32, (_SUBLANES, 1), 0)
+            xg = jnp.where(sub < d - row, xg, 0.0)
+        return tuple(
+            a + xg * jnp.concatenate(
+                [vecs_ref[i, pl.ds(row, _SUBLANES), :]] * (width // _LANES),
+                axis=1,
+            )
+            for i, a in enumerate(acc)
+        )
+
+    zero = jnp.zeros((_SUBLANES, width), jnp.float32)
+    acc = _fm_each_group(d, body, (zero,) * k)
     return jnp.concatenate(
-        [jnp.concatenate(o, axis=1) for o in out], axis=0
+        [jnp.sum(a, axis=0, keepdims=True) for a in acc], axis=0
     )
 
 
-def _contract_fm(out_ref, r, xt_ref, mask):
-    """Xᵀ·r of a feature-major float32 tile, added into the (d8, 128)
-    output block that ``_FM_GROUP`` consecutive tiles share: every sublane
-    group of 8 features times r along the lanes, the lane blocks folded to
-    one (8, 128) register of partial sums (VPU only). The 128 lanes and the
-    blocks are added outside. Sequential adds are 32 deep at most, so the
-    partials keep the tree shape of the row-major kernels' per-tile slots.
-    ``mask`` zeroes the products of a ragged last tile's out-of-range
-    lanes, whose x is unspecified: on that tile alone."""
-    d8, bn = xt_ref.shape
-    width = min(_FM_LANES, bn)
-
-    @pl.when(pl.program_id(0) % _FM_GROUP == 0)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
+def _contract_fm(out_ref, r, xt_ref, mask, last, lanes):
+    """Xᵀ·r of the window ``lanes`` of a feature-major float32 tile, added
+    into the (d8, 128) output block that ``_FM_GROUP`` consecutive tiles
+    share (``_fm_pass`` clears it): every sublane group of 8 features times
+    r along the lanes, the lane blocks folded to one (8, 128) register of
+    partial sums (VPU only). The 128 lanes and the blocks are added
+    outside. Sequential adds into a block are as deep as 32 tiles have
+    windows (32 at 2,000 columns, 512 at 65), so the partials keep the tree
+    shape of the row-major kernels' per-tile slots. ``mask`` zeroes the
+    products of a ragged last tile's out-of-range lanes, whose x is
+    unspecified: on that tile (``last``) alone."""
+    d8 = xt_ref.shape[0]
+    width = lanes.size
 
     def add(masked):
-        for c0 in range(0, bn, width):
-            rb = jnp.broadcast_to(r[:, c0:c0 + width], (_SUBLANES, width))
-            ok = None
-            if masked:
-                ok = jnp.broadcast_to(mask[:, c0:c0 + width], (_SUBLANES, width))
+        rb = jnp.broadcast_to(r, (_SUBLANES, width))
+        ok = jnp.broadcast_to(mask, (_SUBLANES, width)) if masked else None
 
-            def body(row, carry):
-                p = xt_ref[pl.ds(row, _SUBLANES), c0:c0 + width] * rb
-                if ok is not None:
-                    p = jnp.where(ok, p, 0.0)
-                f = p[:, :_LANES]
-                for t in range(_LANES, width, _LANES):
-                    f = f + p[:, t:t + _LANES]
-                out_ref[pl.ds(row, _SUBLANES), :] += f
-                return carry
+        def body(row, carry):
+            p = xt_ref[pl.ds(row, _SUBLANES), lanes] * rb
+            if ok is not None:
+                p = jnp.where(ok, p, 0.0)
+            f = p[:, :_LANES]
+            for t in range(_LANES, width, _LANES):
+                f = f + p[:, t:t + _LANES]
+            out_ref[pl.ds(row, _SUBLANES), :] += f
+            return carry
 
-            _fm_each_group(d8, body, 0)
+        _fm_each_group(d8, body, 0)
 
     if mask is None:
         add(False)
     else:
-        last = pl.program_id(0) == pl.num_programs(0) - 1
         pl.when(last)(lambda: add(True))
         pl.when(jnp.logical_not(last))(lambda: add(False))
+
+
+def _fm_pass(xt_ref, n, d, masked, vecs_ref, shifts_ref, out_ref, count,
+             point):
+    """One pass over a feature-major float32 tile ``xt`` (d8, bn),
+    ``_FM_LANES`` rows of X at a time: a window's margins
+    (``_margins_fm``), ``point(margins, mask, rows)`` for the row to
+    contract and the ``count`` rows to sum (``rows``: the window in a
+    per-row stream's block), that row's Xᵀ· into ``out_ref``
+    (``_contract_fm``). Returns the tile's (1, 128) lane sums of the rows
+    to sum.
+
+    Where the tile holds more than one window (a narrow matrix, whose tile
+    is thousands of lanes long) the windows are a loop and not
+    straight-line code, so the kernel is traced and lowered once a window's
+    length, not once a tile's: sixteen times less Python at d = 65, where
+    it was 20 s of every process's set-up (PERF.md §6, PR 35). What depends
+    on the grid step is read before the loop (the HLO interpreter has no
+    ``program_id`` inside one)."""
+    bn = xt_ref.shape[1]
+    width = min(_FM_LANES, bn)
+    step = pl.program_id(0)
+    last = step == pl.num_programs(0) - 1
+
+    @pl.when(step % _FM_GROUP == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    def window(c, sums):
+        first = c * width
+        if not isinstance(c, int):
+            first = pl.multiple_of(first, width)
+        lanes = pl.ds(first, width)
+        rows = pl.ds(c * (width // _LANES), width // _LANES)
+        mask = None
+        if masked:
+            mask = (jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+                    + (step * bn + first) < n)
+        m = _margins_fm(vecs_ref, xt_ref, d, lanes) - shifts_ref[...]
+        if mask is not None:
+            m = jnp.where(mask, m, 0.0)
+        q, parts = point(m, mask, rows)
+        _contract_fm(out_ref, q, xt_ref, mask, last, lanes)
+        return tuple(s + _lane_sums(p) for s, p in zip(sums, parts))
+
+    sums = (jnp.zeros((1, _LANES), jnp.float32),) * count
+    if width == bn:
+        return window(0, sums)
+    return jax.lax.fori_loop(0, bn // width, window, sums)
 
 
 def _lane_sums(v):
@@ -429,18 +469,27 @@ def _vg_kernel(*refs, loss, n, d, fm, masked, has_off, has_wt):
     x_ref, y_ref, off_ref, wt_ref, u_ref, c_ref, val_ref, g_ref, rs_ref = (
         _split_refs(refs, has_off, has_wt)
     )
-    x, mask = _tile(x_ref, n, masked, fm)
-    m = _margins(u_ref, c_ref, x, mask, fm, d)
-    if has_off:
-        m = m + _row(off_ref)
-    y = _row(y_ref)
-    lv, r = _weighted([loss.value(m, y), loss.d1(m, y)], wt_ref, mask)
+
+    def point(m, mask, rows=slice(None)):
+        if has_off:
+            m = m + _row(off_ref, rows)
+        y = _row(y_ref, rows)
+        lv, r = _weighted([loss.value(m, y), loss.d1(m, y)], wt_ref, mask, rows)
+        return r, (lv, r)
+
     # Each tile writes its OWN output slot (the feature-major tiles one
     # slot a group of tiles); partials are tree-reduced in f32 outside the
     # kernel. A single running accumulator would add tile partials
     # sequentially, whose O(grid)·eps rounding is enough to stall the
     # optimizer's Armijo test near convergence (observed on-chip).
-    _contract(g_ref, r, x, mask, fm)
+    if fm:
+        val_ref[...], rs_ref[...] = _fm_pass(
+            x_ref, n, d, masked, u_ref, c_ref, g_ref, 2, point
+        )
+        return
+    x, mask = _tile(x_ref, n, masked)
+    r, (lv, _) = point(_margins(u_ref, c_ref, x, mask), mask)
+    _contract(g_ref, r, x, mask)
     val_ref[...] = _lane_sums(lv)
     rs_ref[...] = _lane_sums(r)
 
@@ -559,15 +608,24 @@ def _hvp_kernel(*refs, loss, n, d, fm, masked, has_off, has_wt):
     x_ref, y_ref, off_ref, wt_ref, uv_ref, sc_ref, hv_ref, qs_ref = (
         _split_refs(refs, has_off, has_wt)
     )
-    x, mask = _tile(x_ref, n, masked, fm)
-    muv = _margins(uv_ref, sc_ref, x, mask, fm, d)  # (2, bn): margins and X·v − cv
-    m, mv = muv[0:1], muv[1:2]
-    if has_off:
-        m = m + _row(off_ref)
-    (d2,) = _weighted([loss.d2(m, _row(y_ref))], wt_ref, mask)
-    q = d2 * mv
+
+    def point(muv, mask, rows=slice(None)):
+        m, mv = muv[0:1], muv[1:2]  # margins and X·v − cv
+        if has_off:
+            m = m + _row(off_ref, rows)
+        (d2,) = _weighted([loss.d2(m, _row(y_ref, rows))], wt_ref, mask, rows)
+        q = d2 * mv
+        return q, (q,)
+
     # per-tile partials, reduced outside (see _vg_kernel)
-    _contract(hv_ref, q, x, mask, fm)
+    if fm:
+        (qs_ref[...],) = _fm_pass(
+            x_ref, n, d, masked, uv_ref, sc_ref, hv_ref, 1, point
+        )
+        return
+    x, mask = _tile(x_ref, n, masked)
+    q, _ = point(_margins(uv_ref, sc_ref, x, mask), mask)
+    _contract(hv_ref, q, x, mask)
     qs_ref[...] = _lane_sums(q)
 
 

@@ -421,9 +421,9 @@ def make_objective(
     ``fused=None`` auto-enables the one-pass Pallas kernels on TPU for
     dense batches they take (``auto_fused``; by ``ops/fused.supports_fused``
     a bfloat16 or float32 width that is a multiple of 128 takes the
-    row-major kernels, a float32 matrix of any other width of 128 or more
-    that the chip keeps feature-major takes the feature-major ones, and a
-    narrower matrix stays on XLA's sweeps); pass ``False``/``True`` to force
+    row-major kernels, a float32 matrix of any other width of 8 or more
+    that the chip keeps feature-major takes the feature-major ones, and
+    the rest stays on XLA's sweeps); pass ``False``/``True`` to force
     (``True`` off-TPU runs the kernels in interpreter mode — correct but
     slow, for tests). Set the environment variable
     ``PHOTON_DISABLE_FUSED=1`` to veto auto-enabling.
@@ -498,11 +498,13 @@ def auto_fused(batch: Batch) -> bool:
     """Should this (concrete) batch use the one-pass Pallas kernels?
     True on TPU for dense shapes ``ops/fused.supports_fused`` takes, stored
     as the kernels they take read them. Callers that
-    construct objectives inside a transform (``shard_map``, ``vmap``) must
-    decide BEFORE entering it — under a transform X is a tracer and this
-    returns False (pallas under vmap batching rules is untested; under
-    ``shard_map`` pass the pre-computed answer through a static arg, as
-    ``parallel/distributed.py`` does with per-device row counts)."""
+    construct objectives inside a transform (``jit``, ``shard_map``,
+    ``vmap``) must decide BEFORE entering it — under a transform X is a
+    tracer and this returns False (pallas under vmap batching rules is
+    untested; under ``jit`` or ``shard_map`` pass the pre-computed answer
+    through a static arg, as ``game/coordinate``'s fixed visit does from its
+    base batch and ``parallel/distributed.py`` with per-device row
+    counts)."""
     from photon_ml_tpu.ops import fused
 
     if not (

@@ -44,6 +44,10 @@ Array = jnp.ndarray
 
 _ARMIJO_C1 = 1e-4
 _CURVATURE_EPS = 1e-10
+# the part of a float32 f under which a change of f is rounding, not signal
+_F_RESOLUTION = 1e-7
+# what a step that f cannot judge must leave of the gradient norm, at most
+_BLIND_SHRINK = 0.5
 
 
 class _LbfgsState(NamedTuple):
@@ -62,6 +66,27 @@ class _LbfgsState(NamedTuple):
     g0_norm: Array
     loss_hist: Array
     gnorm_hist: Array
+
+
+def _blind_progress(blind, f_new, f, g_new_norm, g_norm):
+    """Is a step that Armijo refuses progress all the same? Yes where the
+    step is ``blind`` (its first-order gain is under f's float32
+    resolution, so f_new - f is rounding, whichever its sign), f did not
+    rise by more than that resolution, and the gradient norm at least
+    HALVED: a quasi-Newton step that close to the optimum cuts it by far
+    more (205 -> 44 -> 0.12 -> 0.02 on the chip), while a norm at its own
+    rounding floor drifts down by a few percent a step for as long as it is
+    let (a warm per-entity lane ran 200 iterations so). Halving bounds the
+    steps taken this way by log2 of the norm's range.
+
+    A float32 sum over 5·10⁶ rows sits at 3·10⁶, one unit in its last
+    place 0.25, and Armijo alone stopped the GLMix fixed effect at 2.4e-4
+    of its first gradient, tolerance 1e-7 (PERF.md §6, PR 35). The rule
+    reads only what the loop observes, so every objective gets it: one
+    whose steps f can see is solved as before, bit for bit."""
+    level = f_new <= f + _F_RESOLUTION * jnp.abs(f)
+    shrank = g_new_norm <= _BLIND_SHRINK * g_norm
+    return jnp.logical_and(jnp.logical_and(blind, level), shrank)
 
 
 def _pseudo_gradient(w: Array, g: Array, l1w: Array) -> Array:
@@ -210,7 +235,7 @@ def _lbfgs_funcs(objective: Any, config: OptimizerConfig, l1w: Array | None):
                 # representable improvement is possible; stop backtracking
                 # instead of spinning max_line_search_steps objective passes
                 # on the terminal iteration.
-                return jnp.abs(jnp.dot(st.pg, w_new - st.w)) < 1e-7 * jnp.abs(st.f)
+                return jnp.abs(jnp.dot(st.pg, w_new - st.w)) < _F_RESOLUTION * jnp.abs(st.f)
 
             def ls_should_continue(f_new, w_new, k):
                 insufficient = jnp.logical_or(f_new > armijo_rhs(w_new), jnp.isnan(f_new))
@@ -286,11 +311,19 @@ def _lbfgs_funcs(objective: Any, config: OptimizerConfig, l1w: Array | None):
             # Substantive steps with f_new == f are still accepted: near the
             # optimum of a large-n sum objective, f sits on an f32 plateau
             # while real steps keep improving w and the gradient norm.
-            degenerate = jnp.logical_and(hopeless(w_new), f2 >= st.f)
-            ls_ok = jnp.logical_and(
-                jnp.logical_and(f2 <= rhs, jnp.logical_not(degenerate)),
-                jnp.logical_not(jnp.isnan(f2)),
-            )
+            blind = hopeless(w_new)
+            degenerate = jnp.logical_and(blind, f2 >= st.f)
+            ls_ok = jnp.logical_and(f2 <= rhs, jnp.logical_not(degenerate))
+            g2_norm = jnp.linalg.norm(pg2)
+            # Where f cannot see the step, the gradient can: a step that
+            # leaves f where it was, to the same resolution, and HALVES the
+            # gradient is progress, and is taken. The halving bounds the
+            # spinning that the degenerate case guards against. A solve in
+            # which f sees every step never comes here.
+            ls_ok = jnp.logical_or(ls_ok, _blind_progress(
+                blind, f2, st.f, g2_norm, jnp.linalg.norm(st.pg)
+            ))
+            ls_ok = jnp.logical_and(ls_ok, jnp.logical_not(jnp.isnan(f2)))
             s = w_new - st.w
             y = g2 - st.g
             sy = jnp.dot(s, y)
@@ -301,7 +334,6 @@ def _lbfgs_funcs(objective: Any, config: OptimizerConfig, l1w: Array | None):
             rho = jnp.where(store, st.rho.at[slot].set(1.0 / jnp.maximum(sy, _CURVATURE_EPS)), st.rho)
             count = jnp.where(store, st.count + 1, st.count)
 
-            g2_norm = jnp.linalg.norm(pg2)
             converged = grad_converged(g2_norm, st.g0_norm, config.tolerance)
 
             # On line-search failure keep the old iterate and stop.

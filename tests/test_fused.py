@@ -251,15 +251,19 @@ def test_fused_operands_are_lane_dense(kernel, streams, n):
 def test_supports_fused_gates():
     assert supports_fused(1024, 512, jnp.float32)
     assert supports_fused(1024, 512, jnp.bfloat16)
-    # float32 takes any width of at least one lane tile (the feature-major
-    # kernels, as the chip stores such a matrix); the MXU's bfloat16 dots
-    # contract whole lane tiles
+    # float32 takes any width of at least one sublane group (the
+    # feature-major kernels, as the chip stores such a matrix); the MXU's
+    # bfloat16 dots contract whole lane tiles
     assert supports_fused(1024, 500, jnp.float32)
     assert supports_fused(400_000, 2000, jnp.float32)
     assert not supports_fused(1024, 500, jnp.bfloat16)
-    # narrower than one lane tile stays on the XLA path, whatever the dtype
-    assert not supports_fused(5_000_066, 65, jnp.float32)
-    assert not supports_fused(1024, 127, jnp.float32)
+    # narrower than one lane tile: float32 down to one sublane group (the
+    # descent cells' 65-column fixed effect), bfloat16 never
+    assert supports_fused(5_000_066, 65, jnp.float32)
+    assert supports_fused(1024, 127, jnp.float32)
+    assert supports_fused(1024, 8, jnp.float32)
+    assert not supports_fused(1024, 7, jnp.float32)
+    assert not supports_fused(5_000_066, 65, jnp.bfloat16)
     assert supports_fused(1024, 128, jnp.float32)
     assert not supports_fused(1024, 512, jnp.int8)
     assert not supports_fused(1024, 1 << 17, jnp.float32)  # tile over budget
@@ -340,12 +344,58 @@ def test_fused_takes_any_float32_width_of_a_lane_tile_or_more(rng, d, kernel):
         assert not F.reads_feature_major(d, jnp.float32)
 
 
+@pytest.mark.parametrize("kernel", ["value_grad", "hvp"])
+@pytest.mark.parametrize("n", [300, 8192 + 300])
+def test_fused_takes_the_descents_65_columns_with_offsets_and_weights(rng, n, kernel):
+    """The GLMix descent's fixed effect (64 features and an intercept) with
+    residual offsets and weights present, a ragged last tile of one tile and
+    of two: the feature-major kernels against the XLA objective and against
+    a plain float64 reference of the weighted logistic objective."""
+    d, l2 = 65, 0.7
+    task = TaskType.LOGISTIC_REGRESSION
+    batch = _problem(rng, n, d, task)
+    assert supports_fused(n, d, jnp.float32)
+    loss = loss_for_task(task)
+    ref, fused = (
+        make_objective(batch, loss, l2_weight=l2, fused=f) for f in (False, True)
+    )
+    w = jnp.asarray(rng.normal(size=d).astype(np.float32) * 0.25)
+    v = jnp.asarray(rng.normal(size=d).astype(np.float32))
+
+    X, y, off, wt = (np.asarray(a, np.float64) for a in
+                     (batch.X, batch.labels, batch.offsets, batch.weights))
+    w64, v64 = np.asarray(w, np.float64), np.asarray(v, np.float64)
+    m = X @ w64 + off
+    p = 0.5 * (1.0 + np.tanh(0.5 * m))
+    f_ref = np.sum(wt * np.logaddexp(0.0, -(2.0 * y - 1.0) * m)) + 0.5 * l2 * w64 @ w64
+    g_ref = X.T @ (wt * (p - y)) + l2 * w64
+    hv_ref = X.T @ (wt * p * (1.0 - p) * (X @ v64)) + l2 * v64
+
+    def close(a, b):  # in the 2-norm: float32 sums in another order
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        assert np.linalg.norm(a - b) <= 1e-5 * np.linalg.norm(b)
+
+    if kernel == "value_grad":
+        (f0, g0), (f1, g1) = ref.value_and_grad(w), fused.value_and_grad(w)
+        assert g1.shape == (d,) and g1.dtype == jnp.float32
+        np.testing.assert_allclose(f1, f0, rtol=1e-5)
+        np.testing.assert_allclose(f1, f_ref, rtol=1e-5)
+        close(g1, g0)
+        close(g1, g_ref)
+    else:
+        hv0, hv1 = ref.hvp(w, v), fused.hvp(w, v)
+        assert hv1.shape == (d,) and hv1.dtype == jnp.float32
+        close(hv1, hv0)
+        close(hv1, hv_ref)
+
+
 def test_auto_fused_reads_the_width_and_how_the_array_is_stored(monkeypatch):
-    """d = 65 (the descent cells' fixed effect) is not these kernels': on a
-    TPU ``auto_fused`` still says no. A float32 matrix of 2,000 columns
+    """A float32 matrix of 2,000 columns, or of the descent cells' 65,
     takes the feature-major kernels where it is stored feature-major, as a
     TPU stores it (here the CPU's row-major array stands for one that is
-    not); an aligned width is asked nothing new."""
+    not: a narrow matrix of few rows); under one sublane group, and in
+    bfloat16, ``auto_fused`` still says no; an aligned width is asked
+    nothing new."""
     from photon_ml_tpu.ops import fused as F
     from photon_ml_tpu.ops import glm
 
@@ -363,7 +413,9 @@ def test_auto_fused_reads_the_width_and_how_the_array_is_stored(monkeypatch):
     assert glm.auto_fused(batch(2048)) is True
     monkeypatch.setattr(F, "stored_feature_major", lambda X: True)
     assert glm.auto_fused(wide) is True
-    assert glm.auto_fused(batch(65)) is False
+    assert glm.auto_fused(batch(65)) is True
+    assert glm.auto_fused(batch(7)) is False
+    assert glm.auto_fused(batch(65, jnp.bfloat16)) is False
 
 
 def test_disable_fused_knob_strict_parse(monkeypatch):

@@ -492,3 +492,65 @@ def test_run_start_offsets_leave_the_descent_bitwise(path, monkeypatch):
             got[name].view(np.uint32), want[name].view(np.uint32), err_msg=name
         )
     assert got["iterations.per_userId"].max() > 1
+
+
+@pytest.mark.parametrize("path", ["visit", "descent"])
+def test_fixed_visit_takes_the_kernels_at_65_columns(path, rng, monkeypatch):
+    """The fixed effect of the GLMix cells (64 features and an intercept):
+    its visit decides on the CONCRETE base batch whether the objective takes
+    the one-pass kernels (inside the trace ``auto_fused`` sees a tracer and
+    says no at any width) and hands the answer on as a static. With the
+    kernels taken (interpret mode here) the visit gives the coefficients and
+    scores of XLA's path, and L-BFGS evaluates value and gradient once a
+    trial: one pass an iteration fewer."""
+    from photon_ml_tpu.game import coordinate as coordinate_module
+
+    data, batch = _game_setup(
+        rng, n=2500, d_fixed=64, effects={"userId": (10, 2)}, entity_scale=0.0
+    )
+    assert data.X.shape == (2500, 65)
+    task = TaskType.LOGISTIC_REGRESSION
+    asked = []
+
+    def run(fused):
+        def decide(b):
+            asked.append(isinstance(b.X, jax.core.Tracer))
+            return fused
+
+        monkeypatch.setattr(coordinate_module, "auto_fused", decide)
+        coord = FixedEffectCoordinate(
+            coordinate_id="fixed", batch=batch, feature_shard_id="global",
+            config=OptimizationConfig(
+                optimizer=CFG,
+                regularization=RegularizationContext(RegularizationType.L2),
+                regularization_weight=1.0,
+            ),
+            task_type=task, intercept_index=data.intercept_index,
+        )
+        if path == "visit":
+            model, tracker, score, _ = coord.visit(batch.offsets, None)
+            trackers = [tracker]
+        else:
+            res = CoordinateDescent({"fixed": coord}, batch, task).run(["fixed"], 2)
+            model, trackers = res.model["fixed"], res.trackers["fixed"]
+            score = res.training_scores["fixed"]
+        base, fn = coord._visit_base, coord._visit_fn
+        jaxpr = jax.make_jaxpr(fn)(
+            base, batch.offsets, batch.offsets, jnp.zeros((65,), jnp.float32)
+        )
+        return model.model.coefficients.means, score, trackers, str(jaxpr)
+
+    w_x, s_x, t_x, text_x = run(False)
+    w_k, s_k, t_k, text_k = run(True)
+    assert asked == [False, False]  # asked once a coordinate, of a concrete batch
+    assert "pallas_call" in text_k and "pallas_call" not in text_x
+    np.testing.assert_allclose(np.asarray(w_k), np.asarray(w_x), atol=1e-3)
+    np.testing.assert_allclose(np.asarray(s_k), np.asarray(s_x), atol=5e-3)
+    assert len(t_k) == len(t_x)
+    # the cold visit (a warm one spends its passes backtracking from a unit
+    # step): XLA's path takes a value pass a trial and a gradient pass an
+    # iteration, the kernels one pass a trial, and few iterations a second
+    its_k, its_x = int(t_k[0].iterations), int(t_x[0].iterations)
+    assert its_k > 1 and its_x > 1
+    assert int(t_x[0].objective_passes) >= 1 + 2 * its_x
+    assert 1 + its_k <= int(t_k[0].objective_passes) < 1 + 2 * its_k

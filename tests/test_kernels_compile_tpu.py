@@ -265,6 +265,9 @@ class TestFusedCompiles:
             (400_000, 2000, jnp.float32),
             (37, 130, jnp.float32),
             (5000, 7160, jnp.float32),
+            # the GLMix descent's fixed effect (PR 35): tiles of 8,192
+            # lanes, their sixteen windows a loop with a ragged last tile
+            (5_000_066, 65, jnp.float32),
         ],
     )
     def test_ragged_and_wide_shapes(self, topo, n, d, dtype):
@@ -273,6 +276,57 @@ class TestFusedCompiles:
         widest tiles the VMEM budget lets in."""
         assert fused.supports_fused(n, d, dtype)
         _compile_fused_pair(topo, n, d, dtype, aux=True)
+
+
+def test_fixed_visit_reads_the_65_column_matrix_where_it_lies(topo, monkeypatch):
+    """``ml20m_fixed_only``'s visit with the kernels taken: the chip hands
+    f32[5000066, 65] over feature-major (XLA's ``{0,1}``), the kernels read
+    its transpose, which is that array, and the score is one sweep of it in
+    place. No copy of the matrix and no 128-lane image of it (2.56 GB, once
+    a launch, on XLA's path) may come back."""
+    from photon_ml_tpu.config import (
+        OptimizationConfig,
+        OptimizerConfig,
+        RegularizationContext,
+    )
+    from photon_ml_tpu.game import DenseFeatures, FixedEffectCoordinate
+    from photon_ml_tpu.game import coordinate as coordinate_module
+    from photon_ml_tpu.game.data import GameBatch
+    from photon_ml_tpu.ops import glm
+    from photon_ml_tpu.ops.batch import DenseBatch
+    from photon_ml_tpu.types import RegularizationType
+
+    monkeypatch.setattr(glm, "_interpret_fused", lambda: False)
+    monkeypatch.setattr(coordinate_module, "auto_fused", lambda batch: True)
+    n, d = 5_000_066, 65
+    spec = _spec(topo)
+    row, X = spec((n,), jnp.float32), spec((n, d), jnp.float32)
+    coordinate = FixedEffectCoordinate(
+        coordinate_id="fixed",
+        batch=GameBatch(labels=row, offsets=row, weights=row,
+                        features={"global": DenseFeatures(X=X)}, id_tags={}),
+        feature_shard_id="global",
+        config=OptimizationConfig(
+            optimizer=OptimizerConfig(max_iterations=20, tolerance=1e-7),
+            regularization=RegularizationContext(RegularizationType.L2),
+            regularization_weight=1.0,
+        ),
+        task_type=TaskType.LOGISTIC_REGRESSION, intercept_index=d - 1,
+    )
+    base = DenseBatch(X=X, labels=row, offsets=row, weights=row)
+    jax.clear_caches()  # ``lbfgs_minimize``'s cached trace: see below
+    try:
+        compiled = coordinate._build_visit_fn(base).lower(
+            base, row, row, spec((d,), jnp.float32)
+        ).compile()
+    finally:
+        jax.clear_caches()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert f"f32[{n},{d}]{{0,1" in text  # the entry layout: feature-major
+    assert not re.search(rf"= f32\[{n},(?:{d}|128)\]\S* copy\(", text)
+    assert f"f32[{n},128]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 27
 
 
 def _compile_sharded_fused_solve(topo, monkeypatch, n, dtype, data_hints):

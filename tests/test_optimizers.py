@@ -188,6 +188,160 @@ def test_already_converged_start(rng):
     assert int(res.reason) == ConvergenceReason.GRADIENT_CONVERGED
 
 
+# -- the line search where float32 f cannot see a step (PR 35) ---------------
+
+
+@partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["A", "b", "level", "sign"],
+    meta_fields=["one_pass_value_grad"],
+)
+@dataclass(frozen=True)
+class PlateauObjective:
+    """f(w) = level + sign · 0.5 (w-b)ᵀA(w-b) in float32, with ``level`` so
+    large that the quadratic is under one unit in f's last place: f reads
+    ``level`` at every w, as a loss summed over millions of rows does near
+    its optimum, while the gradient sign · A(w-b) is exact. ``sign`` -1
+    is the same plateau with a gradient that GROWS along every step."""
+
+    A: jnp.ndarray
+    b: jnp.ndarray
+    level: jnp.ndarray
+    sign: jnp.ndarray
+    one_pass_value_grad: bool = False
+
+    def value(self, w):
+        r = w - self.b
+        return self.level + self.sign * 0.5 * jnp.dot(r, self.A @ r)
+
+    def value_and_grad(self, w):
+        return self.value(w), self.sign * (self.A @ (w - self.b))
+
+
+def _plateau(rng, one_pass, sign=1.0, d=8):
+    # eigenvalues within (0.5, 1.5): on the plateau nothing tells the line
+    # search to backtrack, so the first, unit step must itself be a
+    # contraction of the gradient
+    M = rng.normal(size=(d, d))
+    M = M + M.T
+    A = np.eye(d) + 0.4 * M / np.linalg.norm(M, 2)
+    f32 = lambda v: jnp.asarray(v, jnp.float32)
+    # |w0 - b| ~ 0.03: the whole quadratic is under 0.01, one unit in the
+    # last place of 3·10⁶ is 0.25
+    return PlateauObjective(
+        A=f32(A), b=f32(rng.normal(size=d) * 0.01), level=f32(3.0e6),
+        sign=f32(sign), one_pass_value_grad=one_pass,
+    )
+
+
+def _solve(obj, w0, cfg, l1w=None, rule=None, monkeypatch=None):
+    """``_lbfgs_impl`` under a jit of its own, so that a patched
+    ``_blind_progress`` is what the trace sees (``rule`` False: never)."""
+    from photon_ml_tpu.optim import lbfgs
+
+    if rule is False:
+        monkeypatch.setattr(
+            lbfgs, "_blind_progress", lambda blind, *_: jnp.zeros_like(blind)
+        )
+    return jax.jit(lambda o, w: lbfgs._lbfgs_impl(o, w, cfg, l1w))(obj, w0)
+
+
+@pytest.mark.parametrize(
+    "blind,f_new,g_new,want",
+    [
+        (True, 3.0e6, 0.5, True),  # f where it was, gradient halved
+        (True, 3.0e6 - 0.25, 0.1, True),  # a fall that is rounding, too
+        (True, 3.0e6 + 0.25, 0.1, True),  # a rise under 1e-7 of f: rounding
+        (True, 3.0e6 + 1.0, 0.1, False),  # a rise f can see
+        (True, 3.0e6, 0.9, False),  # the gradient drifted down: the floor
+        (True, 3.0e6, 1.0, False),  # the gradient did not shrink
+        (True, 3.0e6, 2.0, False),  # the gradient grew
+        (False, 3.0e6, 0.1, False),  # f sees the step: Armijo's alone
+        (True, np.nan, 0.1, False),
+    ],
+)
+def test_blind_progress_rule(blind, f_new, g_new, want):
+    from photon_ml_tpu.optim.lbfgs import _blind_progress
+
+    f32 = lambda v: jnp.asarray(v, jnp.float32)
+    got = _blind_progress(jnp.asarray(blind), f32(f_new), f32(3.0e6), f32(g_new), f32(1.0))
+    assert bool(got) is want
+
+
+@pytest.mark.parametrize("one_pass", [False, True], ids=["two_pass", "one_pass"])
+def test_float32_plateau_is_solved_by_the_gradient(one_pass, rng, monkeypatch):
+    """Where f reads the same at every w, Armijo alone stops at the first
+    iteration; the steps that halve the gradient are taken instead, and the
+    solve ends at its tolerance well inside the iteration cap."""
+    obj = _plateau(rng, one_pass)
+    w0 = jnp.zeros(8, jnp.float32)
+    cfg = OptimizerConfig(max_iterations=40, tolerance=1e-5)
+    res = _solve(obj, w0, cfg)
+    n = int(res.iterations)
+    assert int(res.reason) == ConvergenceReason.GRADIENT_CONVERGED
+    assert 1 < n < 40
+    g = np.asarray(res.grad_norm_history)[: n + 1]
+    assert np.all(g[1:] <= 0.5 * g[:-1]), "every step taken halved the gradient"
+    assert g[-1] <= 1e-5 < 1e-3 * g[0]  # the tolerance is of max(1, |g0|)
+    assert np.all(np.asarray(res.loss_history)[: n + 1] == 3.0e6), "f saw nothing"
+    np.testing.assert_allclose(res.w, obj.b, atol=2e-5)
+    # one pass a trial, or a trial value and a value-and-gradient
+    assert int(res.objective_passes) == 1 + (1 if one_pass else 2) * n
+
+    old = _solve(obj, w0, cfg, rule=False, monkeypatch=monkeypatch)
+    assert int(old.reason) == ConvergenceReason.LINE_SEARCH_FAILED
+    assert int(old.iterations) == 1
+    np.testing.assert_array_equal(old.w, w0)
+
+
+@pytest.mark.parametrize("one_pass", [False, True], ids=["two_pass", "one_pass"])
+def test_float32_plateau_with_a_growing_gradient_stops(one_pass, rng):
+    """The same plateau where every step RAISES the gradient norm: nothing
+    is taken, the solve stops at once where it stood."""
+    obj = _plateau(rng, one_pass, sign=-1.0)
+    w0 = jnp.zeros(8, jnp.float32)
+    res = _solve(obj, w0, OptimizerConfig(max_iterations=40, tolerance=1e-5))
+    assert int(res.reason) == ConvergenceReason.LINE_SEARCH_FAILED
+    assert int(res.iterations) == 1
+    np.testing.assert_array_equal(res.w, w0)
+    assert float(res.grad_norm) == float(res.grad_norm_history[0])
+
+
+def _seen_problems(rng):
+    """Solves in which f sees every step but, at most, the last."""
+    quad = _quad(rng)
+    logistic64 = _logistic_problem(rng)
+    X = rng.normal(size=(400, 6)).astype(np.float32)
+    y = (rng.uniform(size=400) < 0.4).astype(np.float32)
+    logistic32 = make_objective(
+        dense_batch_from_numpy(X, y, dtype=jnp.float32), LOSSES["logistic"],
+        l2_weight=1.0,
+    )
+    return {
+        "quadratic": (quad, jnp.zeros(8), 1e-10, None),
+        "logistic_f64": (logistic64, jnp.zeros(8, jnp.float64), 1e-6, None),
+        "logistic_f32": (logistic32, jnp.zeros(6, jnp.float32), 1e-4, None),
+        "owlqn_f64": (logistic64, jnp.zeros(8, jnp.float64), 1e-6,
+                      2.0 * logistic64.reg_mask),
+    }
+
+
+@pytest.mark.parametrize(
+    "name", ["quadratic", "logistic_f64", "logistic_f32", "owlqn_f64"]
+)
+def test_rule_leaves_a_solve_that_f_sees_bit_for_bit(name, rng, monkeypatch):
+    obj, w0, tol, l1w = _seen_problems(rng)[name]
+    cfg = OptimizerConfig(max_iterations=200, tolerance=tol)
+    new = _solve(obj, w0, cfg, l1w)
+    old = _solve(obj, w0, cfg, l1w, rule=False, monkeypatch=monkeypatch)
+    assert int(new.reason) == ConvergenceReason.GRADIENT_CONVERGED
+    for field in ("w", "value", "grad_norm", "iterations", "reason",
+                  "objective_passes", "loss_history", "grad_norm_history"):
+        np.testing.assert_array_equal(
+            getattr(new, field), getattr(old, field), err_msg=field
+        )
+
+
 class TestNewtonCholesky:
     def test_matches_lbfgs_optimum(self, rng):
         """Damped Newton lands on the L-BFGS optimum in far fewer

@@ -38,7 +38,11 @@ from photon_ml_tpu.types import OptimizerType, RegularizationType, TaskType
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)  # the benchmark's reference
+# the dense random effect's results as recorded before the sparse one came
+# (PR 27), and as L-BFGS leaves them since a step that float32 f cannot
+# judge is taken where it halves the gradient (PR 35)
 BEFORE = os.path.join(ROOT, "tests", "data", "dense_re_before_subspaces.npz")
+RECORDED = os.path.join(ROOT, "tests", "data", "dense_re_recorded.npz")
 
 TASK = TaskType.LOGISTIC_REGRESSION
 TIGHT = OptimizerConfig(max_iterations=200, tolerance=1e-9)
@@ -376,20 +380,32 @@ def test_subspace_counters_are_counted_at_prepare_time():
 # ---------------------------------------------------------------------------
 # the dense random effect has not moved
 # ---------------------------------------------------------------------------
+def _solver_problem(task):
+    rng = np.random.default_rng(12345)
+    n, d, entities = 300, 4, 8
+    ids = rng.integers(0, entities, size=n).astype(np.int32)
+    X = rng.normal(size=(n, d)).astype(np.float32)
+    margin = np.sum(rng.normal(size=(entities, d)).astype(np.float32)[ids] * X, axis=1)
+    if task is TaskType.LOGISTIC_REGRESSION:
+        y = (rng.uniform(size=n) < 1 / (1 + np.exp(-margin))).astype(np.float32)
+    else:
+        y = (margin + rng.normal(scale=0.05, size=n)).astype(np.float32)
+    return ids, X, y, entities
+
+
+def _descent_data(width):
+    rng = np.random.default_rng(12345)
+    return synthetic_game_data(rng, 800, 5, {"userId": (15, width)},
+                               task=TASK, entity_scale=1.5)
+
+
 def _dense_re_results() -> dict[str, np.ndarray]:
     """``tests/test_game.py``'s problems through the dense random effect:
     the batched solver alone, and a descent's fused and eager visits."""
     out = {}
     for task in (TaskType.LINEAR_REGRESSION, TaskType.LOGISTIC_REGRESSION):
-        rng = np.random.default_rng(12345)
-        n, d, entities = 300, 4, 8
-        ids = rng.integers(0, entities, size=n).astype(np.int32)
-        X = rng.normal(size=(n, d)).astype(np.float32)
-        margin = np.sum(rng.normal(size=(entities, d)).astype(np.float32)[ids] * X, axis=1)
-        if task is TaskType.LOGISTIC_REGRESSION:
-            y = (rng.uniform(size=n) < 1 / (1 + np.exp(-margin))).astype(np.float32)
-        else:
-            y = (margin + rng.normal(scale=0.05, size=n)).astype(np.float32)
+        ids, X, y, entities = _solver_problem(task)
+        n = len(y)
         g = group_by_entity(ids, num_entities=entities)
         res = train_random_effects(
             DenseFeatures(X=jnp.asarray(X)), y, np.zeros(n, np.float32),
@@ -405,9 +421,7 @@ def _dense_re_results() -> dict[str, np.ndarray]:
             tolerance=1e-7), 8, False),
         ("eager", OptimizerConfig(max_iterations=50, tolerance=1e-9), 3, True),
     ):
-        rng = np.random.default_rng(12345)
-        data = synthetic_game_data(rng, 800, 5, {"userId": (15, width)},
-                                   task=TASK, entity_scale=1.5)
+        data = _descent_data(width)
         batch = make_game_batch(
             data.y, {"global": data.X, "shard": data.entity_X["userId"]},
             id_tags={"userId": data.entity_ids["userId"]},
@@ -453,9 +467,52 @@ def dense_now():
     "descent.eager.per_user", "descent.eager.fixed", "descent.eager.scores",
 ])
 def test_the_dense_random_effect_is_bit_for_bit_what_it_was(dense_now, name):
-    before = np.load(BEFORE)
+    before = np.load(RECORDED)
     assert dense_now[name].dtype == before[name].dtype
     np.testing.assert_array_equal(dense_now[name], before[name])
+
+
+def _entity_gradient_norms(X, ids, y, offsets, W, task, l2=1.0):
+    """Per entity, the float64 gradient norm of its own L2-regularised
+    problem at its row of ``W``."""
+    X, y, W = (np.asarray(a, np.float64) for a in (X, y, W))
+    m = offsets + np.sum(X * W[ids], axis=1)
+    r = (1 / (1 + np.exp(-m)) if task is TaskType.LOGISTIC_REGRESSION else m) - y
+    g = l2 * W
+    np.add.at(g, ids, r[:, None] * X)
+    return np.linalg.norm(g, axis=1)
+
+
+@pytest.mark.parametrize("name", [
+    "solver.LINEAR_REGRESSION", "solver.LOGISTIC_REGRESSION",
+    "descent.lbfgs", "descent.newton8", "descent.eager",
+])
+def test_the_rerecorded_solves_are_the_tighter_ones(name):
+    """What PR 35 re-recorded: the worst entity's gradient at the recorded
+    coefficients is a tenth of what it was at the ones recorded before, or
+    less (solver: 1.8e-3 -> 1.2e-4 and 2.8e-3 -> 2.1e-4, each the one lane
+    whose last step did not halve its gradient; the descents' last visit:
+    1.1e-2 -> 2.9e-4, 2.1e-3 -> 8.9e-5), and in the solver's problems,
+    which both recordings solve from the same start, no entity's is
+    larger."""
+    norms = []
+    for path in (BEFORE, RECORDED):
+        rec = np.load(path)
+        if name.startswith("solver."):
+            task = TaskType[name.split(".")[1]]
+            ids, X, y, _ = _solver_problem(task)
+            norms.append(_entity_gradient_norms(X, ids, y, 0.0, rec[name], task))
+        else:
+            data = _descent_data(8 if name.endswith("newton8") else 3)
+            fixed = np.asarray(data.X, np.float64) @ rec[f"{name}.fixed"]
+            norms.append(_entity_gradient_norms(
+                data.entity_X["userId"], np.asarray(data.entity_ids["userId"]),
+                data.y, fixed, rec[f"{name}.per_user"], TASK,
+            ))
+    was, now = norms
+    assert now.max() <= 0.1 * was.max()
+    if name.startswith("solver."):
+        assert np.all(now <= was)
 
 
 @pytest.mark.parametrize("prepared", [True, False])
