@@ -43,6 +43,7 @@ from photon_ml_tpu.normalization import (
     NormalizationContext,
     require_intercept_for_shifts,
 )
+from photon_ml_tpu.obs.spans import COORD_FIXED, COORD_RE, span
 from photon_ml_tpu.obs.stages import (
     RE_OFFSETS,
     RE_SCORE,
@@ -293,9 +294,10 @@ class FixedEffectCoordinate:
             # OUTSIDE the trace (densify/tile are host-side transforms); the
             # jit rebinds per-visit offsets onto this pytree ARGUMENT (a
             # closure would bake the feature arrays into the executable)
-            base = self._training_batch(jnp.zeros_like(self.batch.offsets))
-            object.__setattr__(self, "_visit_base", base)
-            object.__setattr__(self, "_visit_fn", self._build_visit_fn(base))
+            with span(COORD_FIXED):
+                base = self._training_batch(jnp.zeros_like(self.batch.offsets))
+                object.__setattr__(self, "_visit_base", base)
+                object.__setattr__(self, "_visit_fn", self._build_visit_fn(base))
         fn = self.__dict__["_visit_fn"]
 
         def make_static(initial):
@@ -497,16 +499,17 @@ class RandomEffectCoordinate:
         each descent iteration only gathers fresh offsets on device."""
         cached = self.__dict__.get("_prepared_cache")
         if cached is None:
-            cached = prepare_buckets(
-                self._features(),
-                np.asarray(self.batch.labels),
-                np.asarray(self.batch.weights),
-                self.buckets,
-                self.mesh,
-                self.axis_name,
-                features_to_samples_ratio=self.features_to_samples_ratio,
-                intercept_index=None if self.projector is not None else self.intercept_index,
-            )
+            with span(COORD_RE):
+                cached = prepare_buckets(
+                    self._features(),
+                    np.asarray(self.batch.labels),
+                    np.asarray(self.batch.weights),
+                    self.buckets,
+                    self.mesh,
+                    self.axis_name,
+                    features_to_samples_ratio=self.features_to_samples_ratio,
+                    intercept_index=None if self.projector is not None else self.intercept_index,
+                )
             object.__setattr__(self, "_prepared_cache", cached)
         return cached
 
@@ -707,7 +710,8 @@ class RandomEffectCoordinate:
             # scored nonzero-major inside the program; staged once
             cached = self.__dict__.get("_score_features_cache")
             if cached is None:
-                cached = NonzeroMajorSparseFeatures.of(feats)
+                with span(COORD_RE):
+                    cached = NonzeroMajorSparseFeatures.of(feats)
                 object.__setattr__(self, "_score_features_cache", cached)
             feats = cached
         ids = self.batch.id_tags[self.random_effect_type]

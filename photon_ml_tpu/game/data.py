@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from photon_ml_tpu.obs.spans import GAME_BATCH, GAME_BUCKET, GAME_GROUP, spanned
 from photon_ml_tpu.ops.batch import (
     Batch,
     DenseBatch,
@@ -180,6 +181,7 @@ class GameBatch:
         return {k: np.asarray(v) for k, v in self.id_tags.items()}
 
 
+@spanned(GAME_BATCH)
 def make_game_batch(
     labels: np.ndarray,
     features: Mapping[str, np.ndarray | Features],
@@ -225,6 +227,7 @@ class EntityGrouping:
     active_rows: list[np.ndarray]  # E arrays of row indices into the batch
 
 
+@spanned(GAME_GROUP)
 def group_by_entity(
     entity_ids: np.ndarray,
     num_entities: int | None = None,
@@ -354,6 +357,7 @@ def _capacity_slots(
     return active, slot, caps
 
 
+@spanned(GAME_BUCKET)
 def bucket_entities(
     grouping: EntityGrouping,
     capacities: tuple[int, ...] | None = None,

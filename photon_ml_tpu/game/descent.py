@@ -24,6 +24,16 @@ import numpy as np
 
 from photon_ml_tpu.evaluation import EvaluationResults, evaluate_all
 from photon_ml_tpu.obs import emit_event, span
+from photon_ml_tpu.obs.spans import (
+    DESCENT_CHECKPOINT,
+    DESCENT_COLLECT,
+    DESCENT_ITER,
+    DESCENT_LAUNCH,
+    DESCENT_PREPARE,
+    DESCENT_RUN,
+    DESCENT_VALIDATION,
+    DESCENT_VISIT,
+)
 from photon_ml_tpu.obs.stages import coord, stage
 from photon_ml_tpu.game.coordinate import Coordinate
 from photon_ml_tpu.game.data import GameBatch
@@ -40,7 +50,7 @@ def _build_fused_outer(coordinates: Mapping[str, Any], seq: Sequence[str]):
     coordinates' pure ``advance`` hooks wire one visit's result into the
     next visit's warm start, exactly as the host loop does through the
     model objects). Returns a host callable ``run_outer(model, total,
-    scores, r) -> (model, total, scores, trackers_by_cid_per_iter)``, or
+    scores, r, record) -> (model, total, scores, trackers_by_cid_per_iter)``, or
     None when any coordinate needs host-side staging per visit
     (mesh-sharded, per-visit down-sampling).
 
@@ -93,36 +103,47 @@ def _build_fused_outer(coordinates: Mapping[str, Any], seq: Sequence[str]):
             jax.tree.map(lambda a: a[i], stacked) for i in range(r)
         )
 
-    def run_outer(model, total, scores, r=1):
-        owns = tuple(
-            scores[cid] if cid in scores else jnp.zeros_like(total)
-            for cid in seq
-        )
-        statics = tuple(
-            p[0](model.models.get(cid)) for p, cid in zip(parts, seq)
-        )
-        total, owns, stacked = fused(total, owns, statics, r)
-        scores = dict(scores)
-        # per-iteration trackers come back STACKED (leading R axis);
-        # postprocess each iteration's slice — one dispatch, no host syncs
-        sliced = slice_all(stacked, r)
-        trackers_per_iter: list[dict[str, Any]] = []
-        for it in range(r):
-            iter_trackers: dict[str, Any] = {}
-            for i, (cid, p) in enumerate(zip(seq, parts)):
-                aux_it = sliced[it][i]
-                # only the chunk's LAST iteration needs the sub-model (a
-                # projected coordinate's model build dispatches a device
-                # matmul — r−1 of those per chunk would claw back the
-                # dispatch savings the chunking exists for)
-                last = it == r - 1
-                sub_model, tracker = p[2](aux_it, build_model=last)
-                iter_trackers[cid] = tracker
-                if last:
-                    model = model.updated(cid, sub_model)
-            trackers_per_iter.append(iter_trackers)
-        for i, cid in enumerate(seq):
-            scores[cid] = owns[i]
+    def run_outer(model, total, scores, r=1, record=None):
+        # three host steps a launch, each a span (obs/spans.py): what the
+        # device waits for between two launches is one of them.
+        # ``record(model, total, scores, trackers_per_iter)`` is the
+        # caller's own bookkeeping of the launch, run inside the last span
+        with span(DESCENT_PREPARE):
+            owns = tuple(
+                scores[cid] if cid in scores else jnp.zeros_like(total)
+                for cid in seq
+            )
+            statics = tuple(
+                p[0](model.models.get(cid)) for p, cid in zip(parts, seq)
+            )
+        # one span per fused LAUNCH: the per-iteration boundaries do not
+        # exist on the host inside a scanned chunk
+        with span(DESCENT_LAUNCH, iterations=r):
+            total, owns, stacked = fused(total, owns, statics, r)
+        with span(DESCENT_COLLECT):
+            scores = dict(scores)
+            # per-iteration trackers come back STACKED (leading R axis);
+            # postprocess each iteration's slice — one dispatch, no host syncs
+            sliced = slice_all(stacked, r)
+            trackers_per_iter: list[dict[str, Any]] = []
+            for it in range(r):
+                iter_trackers: dict[str, Any] = {}
+                for i, (cid, p) in enumerate(zip(seq, parts)):
+                    aux_it = sliced[it][i]
+                    # only the chunk's LAST iteration needs the sub-model (a
+                    # projected coordinate's model build dispatches a device
+                    # matmul — r−1 of those per chunk would claw back the
+                    # dispatch savings the chunking exists for)
+                    last = it == r - 1
+                    sub_model, tracker = p[2](aux_it, build_model=last)
+                    iter_trackers[cid] = tracker
+                    if last:
+                        model = model.updated(cid, sub_model)
+                trackers_per_iter.append(iter_trackers)
+            for i, cid in enumerate(seq):
+                scores[cid] = owns[i]
+            if record is not None:
+                record(model, total, scores, trackers_per_iter)
         return model, total, scores, trackers_per_iter
 
     return run_outer
@@ -251,11 +272,12 @@ class CoordinateDescent:
             if cid not in self.coordinates:
                 raise KeyError(f"update sequence names unknown coordinate {cid!r}")
         try:
-            return self._run_inner(
-                update_sequence, num_iterations, initial_model,
-                checkpoint_dir, checkpoint_fingerprint,
-                resume_fingerprints,
-            )
+            with span(DESCENT_RUN, iterations=num_iterations):
+                return self._run_inner(
+                    update_sequence, num_iterations, initial_model,
+                    checkpoint_dir, checkpoint_fingerprint,
+                    resume_fingerprints,
+                )
         except BaseException as e:
             self._raise_if_peer_lost(e, checkpoint_dir)
             raise
@@ -364,9 +386,11 @@ class CoordinateDescent:
         if not (self.validation_batch is not None and self.evaluators):
             key = tuple(update_sequence)
             if key not in self._fused_outer_cache:
-                self._fused_outer_cache[key] = _build_fused_outer(
-                    self.coordinates, update_sequence
-                )
+                # a coordinate's first use stages its tensors here
+                with span(DESCENT_PREPARE):
+                    self._fused_outer_cache[key] = _build_fused_outer(
+                        self.coordinates, update_sequence
+                    )
             fused_outer = self._fused_outer_cache[key]
 
         def append_tracker(cid: str, tracker) -> None:
@@ -395,7 +419,7 @@ class CoordinateDescent:
             if checkpoint_dir is not None and _is_output_process():
                 from photon_ml_tpu.checkpoint import save_checkpoint
 
-                with span("descent/checkpoint", iteration=it):
+                with span(DESCENT_CHECKPOINT, iteration=it):
                     save_checkpoint(
                         checkpoint_dir,
                         model,
@@ -405,6 +429,14 @@ class CoordinateDescent:
                         total=np.asarray(total),
                         data_digest=digest,
                     )
+
+        def record_launch(first, model, total, scores, trackers_per_iter):
+            # a fused launch's logical iterations, as events
+            for j, iter_trackers in enumerate(trackers_per_iter):
+                for cid in update_sequence:
+                    append_tracker(cid, iter_trackers[cid])
+                    self._log(f"iter {first + j} coordinate {cid}: trained")
+                end_of_iteration(first + j, {}, model, scores, total)
 
         if fused_outer is not None:
             # iteration chunking: run outer iterations in power-of-two
@@ -419,20 +451,9 @@ class CoordinateDescent:
             it = start_iteration
             while it < num_iterations:
                 r = min(_pow2_floor(num_iterations - it), cap)
-                # one span per fused LAUNCH: the per-iteration boundaries
-                # do not exist on the host inside a scanned chunk — the
-                # logical iterations are emitted as events below instead
-                with span(
-                    "descent/fused-outer", first_iteration=it, iterations=r
-                ):
-                    model, total, scores, trackers_per_iter = fused_outer(
-                        model, total, scores, r
-                    )
-                for j in range(r):
-                    for cid in update_sequence:
-                        append_tracker(cid, trackers_per_iter[j][cid])
-                        self._log(f"iter {it + j} coordinate {cid}: trained")
-                    end_of_iteration(it + j, {}, model, scores, total)
+                model, total, scores, _ = fused_outer(
+                    model, total, scores, r, partial(record_launch, it)
+                )
                 it += r
             return CoordinateDescentResult(
                 model=model,
@@ -511,10 +532,10 @@ class CoordinateDescent:
         model = state["model"]
         scores = state["scores"]
         total = state["total"]
-        with span("descent/iter", iteration=it):
+        with span(DESCENT_ITER, iteration=it):
                 for cid in update_sequence:
                     coord = self.coordinates[cid]
-                    with span("descent/visit", iteration=it, coordinate=cid):
+                    with span(DESCENT_VISIT, iteration=it, coordinate=cid):
                         visit = getattr(coord, "visit", None)
                         if visit is not None:
                             # fused path: offsets → solve → score → total
@@ -539,7 +560,7 @@ class CoordinateDescent:
 
                     if self.validation_batch is not None and self.evaluators:
                         with span(
-                            "descent/validation", iteration=it, coordinate=cid
+                            DESCENT_VALIDATION, iteration=it, coordinate=cid
                         ):
                             vscores = model.score(self.validation_batch)
                             res = evaluate_all(

@@ -35,8 +35,9 @@ subsystem exactly as before — the same single-writer discipline the
 drivers use for models and metrics, byte for byte.
 
 Disabled fast path: when no sink is configured, ``emit`` is a single
-attribute check and every ``span()`` returns a shared no-op context
-manager — telemetry can stay wired through production paths.
+attribute check and a ``span()`` writes no record (it still names itself
+to the profiler and adds its seconds to the registry: ``obs/spans.py``) —
+telemetry can stay wired through production paths.
 """
 
 from __future__ import annotations
@@ -431,6 +432,22 @@ def _knob_snapshot() -> dict:
 
 _jax_monitoring_installed = False
 
+# the compile pipeline's other steps, each under its own always-on timer.
+# They are booked only while a span of the program is open on the calling
+# thread (obs/spans.py), so the seconds are the program's own: a caller's
+# data generator or reference compiles under no span of ours.
+TRACE_TIMER = "jax.trace_s"  # Python tracing to a jaxpr
+LOWER_TIMER = "jax.lower_s"  # the jaxpr to an MLIR module, Pallas kernels included
+# a hit of the persistent cache: a part of jax.compile_s, which times
+# "compile or load"
+CACHE_LOAD_TIMER = "jax.cache_load_s"
+_JAX_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_JAX_STEP_TIMERS = {
+    _JAX_TRACE: TRACE_TIMER,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": LOWER_TIMER,
+    "/jax/compilation_cache/cache_retrieval_time_sec": CACHE_LOAD_TIMER,
+}
+
 
 def _on_jax_duration(name: str, secs: float, **kw) -> None:
     try:
@@ -438,6 +455,11 @@ def _on_jax_duration(name: str, secs: float, **kw) -> None:
             # the leaf XLA compile phase only: jax nests it inside broader
             # "compile" events, and summing every match double-counts
             _metrics.REGISTRY.timer_add("jax.compile_s", secs)
+        elif name in _JAX_STEP_TIMERS:
+            from photon_ml_tpu.obs import spans
+
+            if spans.open_spans() and (name != _JAX_TRACE or _outermost_trace()):
+                _metrics.REGISTRY.timer_add(_JAX_STEP_TIMERS[name], secs)
         sink = _ACTIVE
         if sink is not None:
             sink.emit(
@@ -446,6 +468,17 @@ def _on_jax_duration(name: str, secs: float, **kw) -> None:
             )
     except Exception:
         pass  # monitoring must never break compilation
+
+
+def _outermost_trace() -> bool:
+    """Whether the tracing event being reported is the outermost one. JAX
+    reports a duration for every ``jit`` it traces, an inner one inside the
+    outer one's trace and inside its seconds; the event fires when a trace
+    has just ended, so an inner one still finds its caller's trace open.
+    Counting the outermost alone counts every traced second once."""
+    import jax
+
+    return jax.core.trace_ctx.is_top_level()
 
 
 def _install_jax_monitoring() -> None:

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from photon_ml_tpu.obs.spans import LAYOUT_OPTIMIZE, LAYOUT_TO_HOST, span, spanned
 from photon_ml_tpu.obs.stages import RE_SPARSE_PASS, stage
 
 Array = jnp.ndarray
@@ -242,6 +243,7 @@ def maybe_densify(
     return densify(batch, dtype)
 
 
+@spanned(LAYOUT_OPTIMIZE)
 def optimize_batch_layout(
     batch: Batch,
     hbm_budget_bytes: float = 6e9,
@@ -273,7 +275,11 @@ def optimize_batch_layout(
         from photon_ml_tpu.ops import tile_cache
         from photon_ml_tpu.ops.sparse_tiled import supports_tiling
 
-        if supports_tiling(out):
+        # the gate looks at the values, which brings them to the host: the
+        # first of the transfers the build needs
+        with span(LAYOUT_TO_HOST):
+            tiled = supports_tiling(out)
+        if tiled:
             # process-wide layout cache: identical sparsity structure
             # (re-ingested data, repeated fits) never re-packs
             return tile_cache.tiled_layout_for(

@@ -118,6 +118,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from photon_ml_tpu.obs.spans import (
+    LAYOUT_HEAD,
+    LAYOUT_MERGE,
+    LAYOUT_PACK,
+    LAYOUT_STAGE,
+    LAYOUT_TO_HOST,
+    span,
+)
 from photon_ml_tpu.obs.stages import GLM_HEAD, GLM_TAIL, stage
 
 Array = jnp.ndarray
@@ -1063,22 +1071,24 @@ def _build_chunk(
     """One chunk's two layouts, and how many cells hold a nonzero (the same
     cells in both directions, so both choose one form)."""
     storage = kernel_dtype()  # ONE call-time read for both directions
-    m = build_write_major_layout(rows, cols, vals, n_pad, d_pad,
-                                 storage=storage, by_occupancy=by_occupancy)
-    g = build_write_major_layout(cols, rows, vals, d_pad, n_pad,
-                                 storage=storage, by_occupancy=by_occupancy)
+    with span(LAYOUT_PACK):
+        m = build_write_major_layout(rows, cols, vals, n_pad, d_pad,
+                                     storage=storage, by_occupancy=by_occupancy)
+        g = build_write_major_layout(cols, rows, vals, d_pad, n_pad,
+                                     storage=storage, by_occupancy=by_occupancy)
     as_j = lambda lay: tuple(
         jnp.asarray(a)
         for a in (lay.packed, lay.wslab, lay.rslab, lay.rrun, lay.srun)
     )
-    chunk = _TileChunk(
-        m_arrays=as_j(m),
-        g_arrays=as_j(g),
-        row_start=row_start,
-        col_start=col_start,
-        n_pad=n_pad,
-        d_pad=d_pad,
-    )
+    with span(LAYOUT_STAGE):
+        chunk = _TileChunk(
+            m_arrays=as_j(m),
+            g_arrays=as_j(g),
+            row_start=row_start,
+            col_start=col_start,
+            n_pad=n_pad,
+            d_pad=d_pad,
+        )
     return chunk, m.cells
 
 
@@ -1230,43 +1240,49 @@ def tile_sparse_batch(batch, keep_empty_chunks: bool = False,
     """
     from photon_ml_tpu.obs.metrics import REGISTRY
 
-    indices = np.asarray(batch.indices)
-    values = np.asarray(batch.values).astype(np.float32)
-    n, k = indices.shape
-    d = batch.num_features
-    live = values != 0.0
-    stored = int(np.count_nonzero(live))
+    # the build's phases are spans (obs/spans.py): rows to the host, the
+    # head, the merge, and per chunk the pack and the staging
+    if hbm_budget_bytes is not None and (keep_empty_chunks or fe_range is not None):
+        raise ValueError(
+            "hbm_budget_bytes selects the resident single-device layout; "
+            "it cannot go with keep_empty_chunks or fe_range"
+        )
+    with span(LAYOUT_TO_HOST):
+        indices = np.asarray(batch.indices)
+        values = np.asarray(batch.values).astype(np.float32)
+        n, k = indices.shape
+        d = batch.num_features
+        live = values != 0.0
+        stored = int(np.count_nonzero(live))
     head_cols = head_X = None
-    if hbm_budget_bytes is not None:
-        if keep_empty_chunks or fe_range is not None:
-            raise ValueError(
-                "hbm_budget_bytes selects the resident single-device layout; "
-                "it cannot go with keep_empty_chunks or fe_range"
+    with span(LAYOUT_HEAD):
+        if hbm_budget_bytes is not None:
+            head_cols = _head_columns(
+                np.bincount(indices[live], minlength=d), n,
+                hbm_budget_bytes - indices.nbytes - values.nbytes,
+                functools.partial(_tail_padding, indices, live, d),
             )
-        head_cols = _head_columns(
-            np.bincount(indices[live], minlength=d), n,
-            hbm_budget_bytes - indices.nbytes - values.nbytes,
-            functools.partial(_tail_padding, indices, live, d),
-        )
-    if head_cols is not None:
-        in_tail = np.ones(d, bool)
-        in_tail[head_cols] = False
-        live &= in_tail[indices]
-        head_cols = jnp.asarray(head_cols, jnp.int32)
-        head_X = _head_matrix(
-            jnp.asarray(batch.indices), jnp.asarray(batch.values), head_cols, d
-        )
-    tail = int(np.count_nonzero(live))
+        if head_cols is not None:
+            in_tail = np.ones(d, bool)
+            in_tail[head_cols] = False
+            live &= in_tail[indices]
+            head_cols = jnp.asarray(head_cols, jnp.int32)
+            head_X = _head_matrix(
+                jnp.asarray(batch.indices), jnp.asarray(batch.values), head_cols, d
+            )
+        tail = int(np.count_nonzero(live))
     REGISTRY.counter_inc(
         "tile_layout.head_columns", 0.0 if head_cols is None else len(head_cols)
     )
     REGISTRY.counter_inc("tile_layout.head_nonzeros", float(stored - tail))
     REGISTRY.counter_inc("tile_layout.tail_nonzeros", float(tail))
-    values = _merge_repeats(indices, values, live, d)
-    keep = (live & (values != 0.0)).reshape(-1)
-    rows = np.repeat(np.arange(n, dtype=np.int64), k)[keep]
-    cols = indices.reshape(-1).astype(np.int64)[keep]
-    vals = values.reshape(-1)[keep]
+    with span(LAYOUT_MERGE):
+        values = _merge_repeats(indices, values, live, d)
+    with span(LAYOUT_PACK):
+        keep = (live & (values != 0.0)).reshape(-1)
+        rows = np.repeat(np.arange(n, dtype=np.int64), k)[keep]
+        cols = indices.reshape(-1).astype(np.int64)[keep]
+        vals = values.reshape(-1)[keep]
 
     n_pad_total = -(-n // SLAB) * SLAB
     d_pad_total = -(-d // SLAB) * SLAB
@@ -1280,15 +1296,17 @@ def tile_sparse_batch(batch, keep_empty_chunks: bool = False,
         for cc in range(n_col_chunks):
             c0 = cc * _MAX_TABLE_COLS
             c1 = min(c0 + _MAX_TABLE_COLS, d_pad_total)
-            m = in_r & (cols >= c0) & (cols < c1)
-            if (
-                n_row_chunks * n_col_chunks > 1
-                and not keep_empty_chunks
-                and not m.any()
-            ):
-                continue
+            with span(LAYOUT_PACK):
+                m = in_r & (cols >= c0) & (cols < c1)
+                if (
+                    n_row_chunks * n_col_chunks > 1
+                    and not keep_empty_chunks
+                    and not m.any()
+                ):
+                    continue
+                mine = rows[m] - r0, cols[m] - c0, vals[m]
             chunk, cells = _build_chunk(
-                rows[m] - r0, cols[m] - c0, vals[m],
+                *mine,
                 row_start=r0, col_start=c0,
                 n_pad=r1 - r0, d_pad=c1 - c0,
                 # the resident layout alone: shards and streamed chunks

@@ -48,6 +48,8 @@ from collections import OrderedDict
 
 import numpy as np
 
+from photon_ml_tpu.obs.spans import LAYOUT_FINGERPRINT, LAYOUT_TO_HOST, span
+
 _DEFAULT_CAPACITY = 32
 # total packed-stream bytes the cache may pin across entries: an A2-scale
 # layout (both directions) is ~0.5 GB, so the default holds a few large
@@ -107,13 +109,15 @@ def structure_fingerprint(indices, values) -> tuple:
     value bytes) — the streamed objective's swap guard uses exactly this
     (labels/offsets/weights are deliberately absent: the GAME trainer's
     per-visit residual swap keeps the same layout)."""
-    idx = np.ascontiguousarray(np.asarray(indices))
-    val = np.ascontiguousarray(np.asarray(values, np.float32))
-    return (
-        idx.shape,
-        hashlib.sha256(idx.tobytes()).hexdigest(),
-        hashlib.sha256(val.tobytes()).hexdigest(),
-    )
+    with span(LAYOUT_TO_HOST):  # device arrays come to the host here
+        idx = np.ascontiguousarray(np.asarray(indices))
+        val = np.ascontiguousarray(np.asarray(values, np.float32))
+    with span(LAYOUT_FINGERPRINT):
+        return (
+            idx.shape,
+            hashlib.sha256(idx.tobytes()).hexdigest(),
+            hashlib.sha256(val.tobytes()).hexdigest(),
+        )
 
 
 def sparsity_fingerprint(indices, values, num_features: int) -> tuple:

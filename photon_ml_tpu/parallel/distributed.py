@@ -31,6 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from photon_ml_tpu.config import OptimizerConfig
 from photon_ml_tpu.normalization import NormalizationContext
 from photon_ml_tpu.obs.metrics import REGISTRY
+from photon_ml_tpu.obs.spans import DISTRIBUTED_TRAIN, spanned
 from photon_ml_tpu.ops.batch import Batch, pad_batch
 from photon_ml_tpu.ops.glm import make_objective
 from photon_ml_tpu.ops.losses import PointwiseLoss
@@ -328,6 +329,7 @@ class DistributedTrainer:
     intercept_index: int | None = None
     axis_name: str = "data"
 
+    @spanned(DISTRIBUTED_TRAIN)
     def train(self, batch: Batch, w0: Array) -> OptimizationResult:
         fn, kwargs = select_minimize_fn(self.config, self.l1_weight)
         return sharded_minimize(

@@ -27,6 +27,8 @@ from photon_ml_tpu.normalization import (
     NormalizationContext,
     require_intercept_for_shifts,
 )
+from photon_ml_tpu.obs import emit_event, enabled, span
+from photon_ml_tpu.obs.spans import GLM_LAMBDA, GLM_TRAIN, spanned
 from photon_ml_tpu.ops.batch import Batch
 from photon_ml_tpu.ops.glm import GLMObjective, compute_variances, make_objective
 from photon_ml_tpu.ops.losses import loss_for_task
@@ -54,6 +56,7 @@ class GLMTrainingResult:
         return self.models[self.best_weight]
 
 
+@spanned(GLM_TRAIN)
 def train_glm(
     batch: Batch,
     task: TaskType,
@@ -146,13 +149,11 @@ def train_glm(
     best_weight: float | None = None
     best_value = float("nan")
 
-    from photon_ml_tpu.obs import emit_event, enabled, span
-
     # ascending λ with warm start (reference sweeps the same way)
     for lam in sorted(regularization_weights):
         l1 = regularization.l1_weight(lam)
         l2 = regularization.l2_weight(lam)
-        with span("glm/lambda", weight=float(lam)):
+        with span(GLM_LAMBDA, weight=float(lam)):
             obj = make_objective(
                 batch,
                 loss,

@@ -79,13 +79,24 @@ class TestSpans:
             )
             assert w["tid"] != consumer["tid"]
 
-    def test_disabled_sink_is_shared_noop(self):
+    def test_disabled_sink_is_shared_noop(self, tmp_path):
+        """The name is from when a span without a sink was one shared
+        no-op. Now it is live on its two clocks and nothing else: the
+        registry timer ``span.<name>`` moves by one call, no id is made,
+        nothing is emitted."""
         obs.shutdown()
-        assert obs.span("x") is obs.span("y", k=2) is obs.NOOP_SPAN
-        # no stack touch, no emission — and events are a cheap early-out
-        with obs.span("x"):
+        obs.REGISTRY.reset_timers("span")
+        assert not hasattr(obs, "NOOP_SPAN")
+        with obs.span("x/quiet", k=2):
             assert obs.current_span_id() is None
             obs.emit_event("nothing", k=1)
+        timers = obs.REGISTRY.timer_snapshot("span.")
+        assert timers["span.x/quiet"]["calls"] == 1
+        assert timers["span.x/quiet"]["seconds"] >= 0.0
+        # a sink configured afterwards holds no trace of it
+        path = obs.configure(str(tmp_path))
+        obs.shutdown()
+        assert [r["event"] for r in _records(path)] == ["run_start", "run_end"]
 
     def test_exception_still_emits_and_unwinds(self, telemetry):
         with pytest.raises(RuntimeError):
