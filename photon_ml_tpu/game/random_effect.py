@@ -41,8 +41,9 @@ from photon_ml_tpu.game.data import (
     gather_bucket,
 )
 from photon_ml_tpu.obs.stages import RE_OFFSETS, RE_SOLVE, RE_SUBSPACE, stage
+from photon_ml_tpu.ops import fused as kernels
 from photon_ml_tpu.ops.batch import Batch, DenseBatch, LocalSparseBatch
-from photon_ml_tpu.ops.glm import make_objective
+from photon_ml_tpu.ops.glm import fused_for_shape, make_objective
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.optim.common import (
     hash_expand_coefficients,
@@ -702,6 +703,23 @@ def subspace_chunk_lanes(capacity: int, width: int, lanes: int) -> int:
     return -(-lanes // chunks)
 
 
+def subspace_one_read(capacity: int, width: int, dtype=jnp.float32) -> bool:
+    """Whether the lanes of a (capacity, width) subspace class evaluate
+    value and gradient in ONE read of their densified matrix, by
+    ``ops/fused``'s row-major value-and-gradient kernel batched over the
+    lanes of a chunk, or in two by ``SubspaceDenseBatch``'s
+    multiply-reduces. A function of what is static under the solve's
+    ``vmap`` and nothing else: the backend (a TPU), the kernels' own gate,
+    and a tile no longer than the lane, since every lane pays for a whole
+    tile: a 64-row lane would contract 128 rows to use half of them, and
+    stays on the sweeps."""
+    return (
+        fused_for_shape(capacity, width, dtype)
+        and not kernels.reads_feature_major(width, dtype)
+        and kernels.tile_rows(capacity, width, dtype) <= capacity
+    )
+
+
 def _sparse_subspace(
     features: SparseFeatures, buckets: EntityBuckets,
     intercept_index: int | None,
@@ -709,7 +727,10 @@ def _sparse_subspace(
     """The per-entity index maps of a sparse shard over the rows its
     buckets train on, and the buckets re-classed by width rung. Counted in
     the registry: ``re_subspace.{entities, support_columns, padded_columns,
-    width_classes}`` and the timer ``re_subspace.build``."""
+    width_classes}``, the timer ``re_subspace.build``, and the float32
+    bytes of every class's densified lanes, ``re_subspace.dense_bytes``,
+    beside those of the classes whose value-and-gradient reads them once,
+    ``re_subspace.one_read_bytes`` (``subspace_one_read``)."""
     from photon_ml_tpu.game.projector import sparse_index_map
     from photon_ml_tpu.obs.metrics import REGISTRY
 
@@ -735,6 +756,16 @@ def _sparse_subspace(
     REGISTRY.counter_inc(
         "re_subspace.width_classes", float(len(set(buckets.widths)))
     )
+    dense = one_read = 0.0
+    for cap, width, ents in zip(
+        buckets.capacities, buckets.widths, buckets.entity_ids
+    ):
+        size = 4.0 * len(ents) * cap * width
+        dense += size
+        if subspace_one_read(cap, width):
+            one_read += size
+    REGISTRY.counter_inc("re_subspace.dense_bytes", dense)
+    REGISTRY.counter_inc("re_subspace.one_read_bytes", one_read)
     return index_map, buckets
 
 
@@ -889,17 +920,9 @@ def _solve_bucket(
     variances (zeros when NONE)."""
     from photon_ml_tpu.ops.glm import compute_variances
 
-    from photon_ml_tpu.ops.glm import GaussianPrior
-
     def solve_one(batch: Batch, w0_e: Array, mu_e, var_e):
-        prior = None
-        if mu_e is not None:
-            prior = GaussianPrior(means=mu_e, variances=var_e)
-        if isinstance(batch, LocalSparseBatch):
-            batch = batch.densified()
-        obj = make_objective(
-            batch, loss, l2_weight=l2_weight, norm=norm,
-            intercept_index=intercept_index, prior=prior,
+        obj = _lane_objective(
+            batch, loss, l2_weight, norm, intercept_index, mu_e, var_e
         )
         res = minimize_fn(obj, w0_e, config, **minimize_kwargs)
         var = compute_variances(obj, res.w, variance_computation)
@@ -955,18 +978,28 @@ def _solve_bucket(
 
 
 def _lane_objective(batch, loss, l2_weight, norm, intercept_index, mu_e, var_e):
-    """One entity lane's objective — EXACTLY ``_solve_bucket.solve_one``'s
-    construction, shared by the chunked init/run/finalize programs."""
+    """One entity lane's objective: the one constructor of
+    ``_solve_bucket.solve_one`` and of the chunked init/run/finalize
+    programs, so the compacted schedule cannot drift from the single
+    launch. It runs under their ``vmap``, where ``batch`` holds tracers:
+    whether a subspace lane's value-and-gradient goes through
+    ``ops/fused``'s kernel is decided here from the class's static shape
+    (``subspace_one_read``); a dense lane leaves it to ``make_objective``,
+    which answers no for a tracer."""
     from photon_ml_tpu.ops.glm import GaussianPrior
 
     prior = None
     if mu_e is not None:
         prior = GaussianPrior(means=mu_e, variances=var_e)
+    one_read = None
     if isinstance(batch, LocalSparseBatch):
+        one_read = subspace_one_read(
+            batch.labels.shape[-1], batch.num_features, batch.values.dtype
+        )
         batch = batch.densified()
     return make_objective(
         batch, loss, l2_weight=l2_weight, norm=norm,
-        intercept_index=intercept_index, prior=prior,
+        intercept_index=intercept_index, prior=prior, fused=one_read,
     )
 
 

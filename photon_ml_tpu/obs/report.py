@@ -381,12 +381,15 @@ def summarize_run(path: str, records: list[dict] | None = None) -> dict:
     # per-entity index maps of sparse random effects (re_subspace.*,
     # game/random_effect.prepare_buckets): entities mapped, the columns
     # their rows touch, the columns of the width rungs they are solved at,
-    # the width classes, and the host build's seconds. Present only on
-    # runs that prepared a sparse random effect.
+    # the width classes, the host build's seconds, the float32 bytes of
+    # the densified lanes and the share of them in classes whose
+    # value-and-gradient reads them once (ops/fused's kernel). Present only
+    # on runs that prepared a sparse random effect.
     if "re_subspace.entities" in counters or \
             "re_subspace.entities" in base_counters:
         support = counter_v("re_subspace.support_columns")
         padded = counter_v("re_subspace.padded_columns")
+        dense = counter_v("re_subspace.dense_bytes")
         out["re_subspace"] = {
             "entities": counter_v("re_subspace.entities"),
             "support_columns": support,
@@ -394,6 +397,11 @@ def summarize_run(path: str, records: list[dict] | None = None) -> dict:
             "width_pad_ratio": padded / support if support > 0 else None,
             "width_classes": counter_v("re_subspace.width_classes"),
             "build_s": timer_s("re_subspace.build"),
+            "dense_bytes": dense,
+            "one_read_byte_share": (
+                counter_v("re_subspace.one_read_bytes") / dense
+                if dense > 0 else None
+            ),
         }
     # residual offsets of random-effect buckets (re_offsets.*,
     # game/random_effect.prepare_buckets): the slots (lanes x capacity) of
@@ -713,6 +721,10 @@ def format_summary(s: dict) -> str:
             + (f" solved at {pad:.2f}x" if pad else "")
             + f" in {int(sub['width_classes'])} width classes, "
             f"index maps built in {_fmt_s(sub['build_s'])}"
+            + (f"; {_fmt_qty(sub['dense_bytes'])} B of densified lanes, "
+               f"{100.0 * sub['one_read_byte_share']:.1f}% read once a "
+               f"value-and-gradient"
+               if sub.get("one_read_byte_share") is not None else "")
         )
     ofs = s.get("re_offsets") or {}
     if ofs.get("run_slot_share") is not None:

@@ -52,7 +52,7 @@ COORD_PREFIX = "coord."  # + the coordinate id: which coordinate's visit
 # cache with the names it was compiled with: after a scope moves with no
 # instruction changing, a warm cache would keep serving the old names to
 # every profile. Raise this when a site or a name of this module changes.
-VERSION = 4
+VERSION = 5
 
 _NOT_SEGMENT = re.compile(r"[^A-Za-z0-9_.\-]")
 

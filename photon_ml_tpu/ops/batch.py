@@ -80,6 +80,19 @@ class DenseBatch:
         """(X ⊙ X)ᵀ @ r — Hessian diagonal: Σ_i r_i x_ij²."""
         return self._mm((self.X * self.X).T, r)
 
+    def value_grad_pass(self, u: Array, c: Array, loss, *, offsets, weights,
+                        interpret: bool):
+        """(Σ w·l, Xᵀr, Σr) at margins X@u + offsets − c in ONE read of X:
+        ``ops/fused.fused_value_grad``, for an objective built ``fused``.
+        ``offsets`` / ``weights``: this batch's, or None where the
+        objective knows the stream to be all 0 / all 1."""
+        from photon_ml_tpu.ops.fused import fused_value_grad
+
+        return fused_value_grad(
+            self.X, self.labels, offsets, weights, u, c, loss=loss,
+            interpret=interpret,
+        )
+
 
 @partial(
     jax.tree_util.register_dataclass,
@@ -128,11 +141,18 @@ class SubspaceDenseBatch(DenseBatch):
     densified``). The contractions are float32 multiply-reduces, not MXU
     matmuls: a matrix-vector product leaves the MXU idle anyway, a TPU's
     default float32 matmul rounds its operands to bfloat16, and the
-    multiply-reduce reads X at the HBM's rate and is exact."""
+    multiply-reduce reads X at the HBM's rate and is exact. Where the
+    lane's objective is built ``fused`` (``game/random_effect.
+    subspace_one_read``) its value-and-gradient is ``ops/fused``'s
+    row-major float32 kernel, as exact and on the VPU too, batched over
+    the lanes of a chunk."""
 
-    # a trial value costs one read of X and a value-and-gradient two, so
-    # evaluating both at every trial point (two reads when the first trial
-    # is accepted, as it mostly is) beats value-then-gradient (three)
+    # value and gradient at every trial point: one read of X a trial
+    # through the kernel, where a value alone would cost that same read;
+    # on the multiply-reduces a trial value costs one read and a
+    # value-and-gradient two, so both at every trial (two reads when the
+    # first trial is accepted, as it mostly is) beat value-then-gradient
+    # (three)
     one_pass_value_grad = True
 
     def matvec(self, w: Array) -> Array:
@@ -146,6 +166,28 @@ class SubspaceDenseBatch(DenseBatch):
     def rmatvec_sq(self, r: Array) -> Array:
         with stage(RE_SPARSE_PASS):
             return jnp.sum(self.X * self.X * r[:, None], axis=0)
+
+    def value_grad_pass(self, u: Array, c: Array, loss, *, offsets, weights,
+                        interpret: bool):
+        return _subspace_value_grad(
+            self.X, self.labels, offsets, weights, u, c, loss=loss,
+            interpret=interpret,
+        )
+
+
+@partial(jax.jit, static_argnames=("loss", "interpret"))
+def _subspace_value_grad(X, labels, offsets, weights, u, c, *, loss, interpret):
+    """A subspace lane's one-read value-and-gradient. Under its own ``jit``
+    so that L-BFGS's three evaluation sites (the start, a line search's
+    first trial, its later ones) share ONE trace and ONE Mosaic lowering of
+    a class's kernel: Python does both in every process, compile cache or
+    not, and a visit holds a kernel for every (capacity, width) class."""
+    from photon_ml_tpu.ops.fused import fused_value_grad
+
+    with stage(RE_SPARSE_PASS):
+        return fused_value_grad(
+            X, labels, offsets, weights, u, c, loss=loss, interpret=interpret
+        )
 
 
 @partial(

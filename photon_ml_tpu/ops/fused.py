@@ -51,9 +51,12 @@ feature-major kernel 4.31 ms at 2,000. So one float32 form of each
 contraction is kept a layout, both on the VPU:
 
 - a width that is a multiple of 128 lies row-major on the chip and takes
-  the row-major kernels (``_margins_f32`` / ``_contract_f32``: multiply and
-  add 128-lane blocks, one XLU transpose of a (128, 128) accumulator to
-  turn row sums into lanes);
+  the row-major kernels (``_rm_pass``: multiply and add 128-lane blocks,
+  one XLU transpose of a (128, 128) accumulator to turn row sums into
+  lanes). ``game/random_effect`` runs them batched over the lanes of a
+  subspace class (a ``vmap``: the lane axis becomes the outer grid axis),
+  which is why a tile comes down to 128 rows (``tile_rows``) and its
+  blocks are loops;
 - any other width of at least one sublane group (LIBSVM epsilon's 2,000,
   the GLMix descent's 65-column fixed effect) lies FEATURE-MAJOR on the
   chip, features down the sublanes and rows along the lanes with not a
@@ -97,6 +100,8 @@ _SUBLANES = 8
 # (128, 128) accumulator a coefficient row stays in vector registers across
 # the tile's lane blocks, and is the square the XLU transposes
 _F32_ROWS = 128
+# 128-lane blocks of a row-major float32 tile's width a loop step contracts
+_RM_UNROLL = 8
 # The feature-major kernels (X read as Xᵀ: features down the sublanes, rows
 # of X along the lanes): lanes contracted at a time (four registers a
 # sublane group), sublane groups a loop step, and the grid steps whose
@@ -134,10 +139,11 @@ def supports_fused(n: int, d: int, dtype) -> bool:
     if dtype not in (jnp.float32, jnp.bfloat16):
         return False
     if reads_feature_major(d, dtype):
-        return d >= _SUBLANES and _block_rows(n, sublane_width(d), 4) is not None
-    if d % _LANES != 0:
+        if d < _SUBLANES:
+            return False
+    elif d % _LANES != 0:
         return False
-    return _block_rows(n, d, jnp.dtype(dtype).itemsize) is not None
+    return tile_rows(n, d, dtype) is not None
 
 
 def reads_feature_major(d: int, dtype) -> bool:
@@ -166,11 +172,28 @@ def sublane_width(d: int) -> int:
     return -(-d // _SUBLANES) * _SUBLANES
 
 
-def _block_rows(n: int, d: int, itemsize: int) -> int | None:
-    """Largest power-of-two row tile whose double-buffered X block fits
-    the VMEM budget (None if even the minimum tile does not)."""
+def tile_rows(n: int, d: int, dtype) -> int | None:
+    """Rows of X a grid step holds for an (n, d) matrix of ``dtype`` (None
+    where no tile fits): ``_block_rows`` at the width and the shortest tile
+    of the kernels that matrix takes. A float32 row-major tile may come
+    down to ``_F32_ROWS``, the unit its contractions work in, where the
+    matrix is that short or the budget asks for it (128 rows of 8,192
+    columns are 4 MiB; 256 would not fit twice); the MXU's bfloat16 tiles
+    and the feature-major ones keep ``_MIN_BLOCK_ROWS``."""
+    fm = reads_feature_major(d, dtype)
+    shortest = _F32_ROWS if dtype == jnp.float32 and not fm else _MIN_BLOCK_ROWS
+    return _block_rows(
+        n, sublane_width(d) if fm else d, jnp.dtype(dtype).itemsize, shortest
+    )
+
+
+def _block_rows(n: int, d: int, itemsize: int,
+                shortest: int = _MIN_BLOCK_ROWS) -> int | None:
+    """Largest power-of-two row tile from ``shortest`` whose
+    double-buffered X block fits the VMEM budget (None if even the
+    shortest does not), and no longer than it takes to hold ``n`` rows."""
     best = None
-    bn = _MIN_BLOCK_ROWS
+    bn = shortest
     while bn <= _MAX_BLOCK_ROWS:
         tile = bn * d * itemsize
         if 2 * tile > _VMEM_BUDGET:
@@ -197,67 +220,29 @@ def _split_refs(refs, has_off: bool, has_wt: bool):
 
 
 def _tile(x_ref, n, masked):
-    """The resident row-major X tile and, for a ragged last tile, the
+    """The resident bfloat16 X tile and, for a ragged last tile, the
     (1, bn) mask of its in-range rows. Out-of-range tile rows hold
     unspecified values; they are zeroed so the contraction cannot pick up
-    Inf/NaN garbage through 0·x. A float32 tile is handed on as the ref:
-    its contractions read it a block at a time and mask what they compute
-    (``_margins``, ``_contract``)."""
-    bn = x_ref.shape[0]
-    row = None
-    if masked:
-        start = pl.program_id(0) * bn
-        row = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1) + start < n
-    if x_ref.dtype == jnp.float32:
-        return x_ref, row
+    Inf/NaN garbage through 0·x."""
     x = x_ref[...]
     if not masked:
         return x, None
+    bn = x_ref.shape[0]
+    start = pl.program_id(0) * bn
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1) + start < n
     col = jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0) + start
     return jnp.where(col < n, x, jnp.zeros_like(x)), row
 
 
-def _margins(vecs_ref, shifts_ref, x, mask):
+def _margins(vecs_ref, shifts_ref, x):
     """(k, bn) lane-dense margins vecs·xᵀ − shifts of the k coefficient
-    rows against a row-major tile: every row's margin lands in its own
-    lane.
-
-    bfloat16: one MXU dot with the tile as the transposed operand (as
-    attention's q·kᵀ). float32: on the VPU, exact with no ``highest``
-    splits (``_margins_f32``); the margins of a ragged tile's out-of-range
-    rows, whose x is unspecified, are set to 0."""
-    if x.dtype == jnp.float32:
-        m = _margins_f32(vecs_ref, x) - shifts_ref[...]
-        return m if mask is None else jnp.where(mask, m, 0.0)
+    rows against a bfloat16 tile, every row's margin in its own lane: one
+    MXU dot with the tile as the transposed operand (as attention's
+    q·kᵀ)."""
     return jax.lax.dot_general(
         vecs_ref[...].astype(x.dtype), x, _NT,
         preferred_element_type=jnp.float32,
     ) - shifts_ref[...]
-
-
-def _margins_f32(vecs_ref, x_ref):
-    """A float32 tile's margins without the MXU, where a matrix-vector
-    product at ``highest`` is six bf16 passes (5.09 ms a pass of 400,000 x
-    2,048 on a v5e against this form's 4.39; PERF.md §6, PR 34): 128 rows
-    at a time, multiply each (128, 128) lane block by its slice of a
-    coefficient row and add the blocks (VPU), then transpose the
-    accumulator (XLU) and add its sublanes, which leaves the 128 margins in
-    128 lanes."""
-    bn, d = x_ref.shape
-    k = vecs_ref.shape[0]
-    out = [[] for _ in range(k)]
-    for r0 in range(0, bn, _F32_ROWS):
-        acc = [None] * k
-        for c0 in range(0, d, _LANES):
-            xb = x_ref[r0:r0 + _F32_ROWS, c0:c0 + _LANES]
-            for i in range(k):
-                p = xb * vecs_ref[i:i + 1, c0:c0 + _LANES]
-                acc[i] = p if acc[i] is None else acc[i] + p
-        for i in range(k):
-            out[i].append(jnp.sum(acc[i].T, axis=0, keepdims=True))
-    return jnp.concatenate(
-        [jnp.concatenate(o, axis=1) for o in out], axis=0
-    )
 
 
 def _row(ref, rows=slice(None)):
@@ -279,44 +264,113 @@ def _weighted(vals, wt_ref, mask, rows=slice(None)):
     return vals
 
 
-def _contract(out_ref, r, x, mask):
-    """rᵀX into a row-major tile's output block: the (1, bn)·(bn, d) MXU
-    dot for bfloat16, r cast to the storage dtype; for a float32 tile the
-    VPU form (``_contract_f32``)."""
-    if x.dtype == jnp.float32:
-        out_ref[...] = _contract_f32(r, x, mask)
-    else:
-        out_ref[...] = jnp.dot(
-            r.astype(x.dtype), x, preferred_element_type=jnp.float32
-        )
+def _contract(r, x):
+    """(1, d) rᵀX of a bfloat16 tile: the (1, bn)·(bn, d) MXU dot, r cast
+    to the storage dtype."""
+    return jnp.dot(r.astype(x.dtype), x, preferred_element_type=jnp.float32)
 
 
-def _contract_f32(r, x_ref, mask):
-    """(1, d) rᵀX of a float32 tile on the VPU: 128 rows at a time, r laid
-    down the sublanes by one transpose of its sublane broadcast, each
-    (128, 128) lane block multiplied by it and folded to one (8, 128)
-    register of partial sums; the eight sublanes are added once a lane
-    block, at the end. ``mask`` (the ragged last tile's in-range rows)
-    zeroes the products of out-of-range rows, whose x is unspecified."""
+def _rm_each_block(d: int, body, carry):
+    """``carry = body(lanes, carry)`` over every 128-lane block of a
+    row-major float32 tile's width: a loop of ``_RM_UNROLL`` blocks a step
+    where the width holds more, the rest (or all) as straight-line code."""
+    blocks = d // _LANES
+    steps = blocks // _RM_UNROLL if blocks > _RM_UNROLL else 0
+
+    def step(s, carry):
+        for t in range(_RM_UNROLL):
+            first = pl.multiple_of((s * _RM_UNROLL + t) * _LANES, _LANES)
+            carry = body(pl.ds(first, _LANES), carry)
+        return carry
+
+    if steps:
+        carry = jax.lax.fori_loop(0, steps, step, carry)
+    for b in range(steps * _RM_UNROLL, blocks):
+        carry = body(pl.ds(b * _LANES, _LANES), carry)
+    return carry
+
+
+def _rm_pass(x_ref, n, masked, vecs_ref, shifts_ref, out_ref, acc_ref, count,
+             point):
+    """One pass over a row-major float32 tile (bn, d) on the VPU,
+    ``_F32_ROWS`` rows at a time, exact with no ``highest`` splits and
+    without the MXU, where a matrix-vector product is six bf16 passes (5.09
+    ms a pass of 400,000 x 2,048 on a v5e against this form's 4.39;
+    PERF.md §6, PR 34).
+
+    A block of 128 rows: multiply each (128, 128) lane block by its slice
+    of a coefficient row and add the blocks, then transpose the
+    accumulator (XLU) and add its sublanes, which leaves the 128 margins in
+    128 lanes; ``point(margins, mask, rows)`` gives the row to contract and
+    the ``count`` rows to sum, as in ``_fm_pass``; that row, laid down the
+    sublanes by one transpose of its sublane broadcast, multiplies every
+    lane block again, each product folded to one (8, 128) register that is
+    added into ``acc_ref`` (8, d). The eight sublanes are added once a
+    tile, into ``out_ref`` (1, d). Returns the tile's (1, 128) lane sums of
+    the rows to sum.
+
+    Row blocks and lane blocks are loops, not straight-line code
+    (``_rm_each_block``), so the kernel is traced and lowered once a block
+    and not once a tile: a random effect's visit holds a kernel for every
+    subspace class, and Python pays for each in every process (PERF.md §6,
+    PR 37). A ragged last tile's out-of-range rows, whose x is unspecified,
+    get margins 0 and products 0. What depends on the grid step is read
+    before the loop (the HLO interpreter has no ``program_id`` inside
+    one)."""
     bn, d = x_ref.shape
-    parts = [None] * (d // _LANES)
-    for r0 in range(0, bn, _F32_ROWS):
-        rows = slice(r0, r0 + _F32_ROWS)
-        rb = jnp.broadcast_to(r[:, rows], (_LANES, _F32_ROWS)).T
-        ok = None
-        if mask is not None:  # Mosaic transposes no booleans
+    k = vecs_ref.shape[0]
+    start = pl.program_id(0) * bn if masked else None
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(b, sums):
+        first = b * _F32_ROWS
+        if not isinstance(b, int):
+            first = pl.multiple_of(first, _F32_ROWS)
+        rows = pl.ds(first, _F32_ROWS)
+        mask = ok = None
+        if masked:
+            mask = (jax.lax.broadcasted_iota(jnp.int32, (1, _F32_ROWS), 1)
+                    + (start + first) < n)
+            # Mosaic transposes no booleans
             ok = jnp.broadcast_to(
-                mask[:, rows].astype(jnp.float32), (_LANES, _F32_ROWS)
+                mask.astype(jnp.float32), (_LANES, _F32_ROWS)
             ).T > 0.5
-        for j in range(d // _LANES):
-            p = x_ref[rows, j * _LANES:(j + 1) * _LANES] * rb
+
+        def margins(lanes, acc):
+            xb = x_ref[rows, lanes]
+            return tuple(
+                a + xb * vecs_ref[i:i + 1, lanes] for i, a in enumerate(acc)
+            )
+
+        zero = jnp.zeros((_F32_ROWS, _LANES), jnp.float32)
+        acc = _rm_each_block(d, margins, (zero,) * k)
+        m = jnp.concatenate(
+            [jnp.sum(a.T, axis=0, keepdims=True) for a in acc], axis=0
+        ) - shifts_ref[...]
+        if mask is not None:
+            m = jnp.where(mask, m, 0.0)
+        q, parts = point(m, mask, pl.ds(b, 1))
+        qb = jnp.broadcast_to(q, (_LANES, _F32_ROWS)).T
+
+        def contract(lanes, carry):
+            p = x_ref[rows, lanes] * qb
             if ok is not None:
                 p = jnp.where(ok, p, 0.0)
-            p = jnp.sum(p.reshape(_F32_ROWS // _SUBLANES, _SUBLANES, _LANES), axis=0)
-            parts[j] = p if parts[j] is None else parts[j] + p
-    return jnp.concatenate(
-        [jnp.sum(p, axis=0, keepdims=True) for p in parts], axis=1
-    )
+            acc_ref[:, lanes] += jnp.sum(
+                p.reshape(_F32_ROWS // _SUBLANES, _SUBLANES, _LANES), axis=0
+            )
+            return carry
+
+        _rm_each_block(d, contract, 0)
+        return tuple(s + p for s, p in zip(sums, parts))
+
+    sums = (jnp.zeros((1, _LANES), jnp.float32),) * count
+    if bn == _F32_ROWS:
+        sums = block(0, sums)
+    else:
+        sums = jax.lax.fori_loop(0, bn // _F32_ROWS, block, sums)
+    out_ref[...] = jnp.sum(acc_ref[...], axis=0, keepdims=True)
+    return sums
 
 
 def _fm_each_group(d: int, body, carry):
@@ -466,7 +520,7 @@ def _lane_sums(v):
 
 
 def _vg_kernel(*refs, loss, n, d, fm, masked, has_off, has_wt):
-    x_ref, y_ref, off_ref, wt_ref, u_ref, c_ref, val_ref, g_ref, rs_ref = (
+    x_ref, y_ref, off_ref, wt_ref, u_ref, c_ref, val_ref, g_ref, rs_ref, *acc = (
         _split_refs(refs, has_off, has_wt)
     )
 
@@ -487,9 +541,14 @@ def _vg_kernel(*refs, loss, n, d, fm, masked, has_off, has_wt):
             x_ref, n, d, masked, u_ref, c_ref, g_ref, 2, point
         )
         return
+    if acc:
+        val_ref[...], rs_ref[...] = _rm_pass(
+            x_ref, n, masked, u_ref, c_ref, g_ref, acc[0], 2, point
+        )
+        return
     x, mask = _tile(x_ref, n, masked)
-    r, (lv, _) = point(_margins(u_ref, c_ref, x, mask), mask)
-    _contract(g_ref, r, x, mask)
+    r, (lv, _) = point(_margins(u_ref, c_ref, x), mask)
+    g_ref[...] = _contract(r, x)
     val_ref[...] = _lane_sums(lv)
     rs_ref[...] = _lane_sums(r)
 
@@ -517,8 +576,10 @@ def _prep(X, labels, offsets, weights, vecs):
     free view when the tile divides n and one zero-pad of the vector
     otherwise. ``vecs`` is the (k, d) float32 coefficient rows.
 
-    Returns ``(grid, statics, ins, in_specs, g_spec, g_shape, finish)``;
-    ``finish`` adds the gradient output's partials to the (d,) result.
+    Returns ``(grid, statics, ins, in_specs, g_spec, g_shape, scratch,
+    finish)``; ``finish`` adds the gradient output's partials to the (d,)
+    result, and ``scratch`` is the row-major float32 pass's (8, d)
+    accumulator (``_rm_pass``; no other kernel has one).
 
     The feature-major kernels (``reads_feature_major``) take ``X.T``: the
     array itself where the chip stores X feature-major (a change of view,
@@ -529,7 +590,7 @@ def _prep(X, labels, offsets, weights, vecs):
     n, d = X.shape
     fm = reads_feature_major(d, X.dtype)
     width = sublane_width(d) if fm else d
-    bn = _block_rows(n, width, jnp.dtype(X.dtype).itemsize)
+    bn = tile_rows(n, d, X.dtype)
     if bn is None:
         raise ValueError(f"no VMEM-feasible tile for (n={n}, d={d})")
     grid = pl.cdiv(n, bn)
@@ -561,6 +622,9 @@ def _prep(X, labels, offsets, weights, vecs):
         vec_spec = _const_spec((k, d))
         g_spec, g_shape = _part_spec(d), _part_shape(grid, d)
         finish = lambda g: jnp.sum(g, axis=(0, 1))
+    scratch = []
+    if X.dtype == jnp.float32 and not fm:
+        scratch = [pltpu.VMEM((_SUBLANES, d), jnp.float32)]
     for a in (offsets, weights):
         if a is not None:
             ins.append(stream(a))
@@ -569,7 +633,7 @@ def _prep(X, labels, offsets, weights, vecs):
     in_specs.append(vec_spec)
     statics = dict(n=n, d=d, fm=fm, masked=n % bn != 0,
                    has_off=offsets is not None, has_wt=weights is not None)
-    return grid, statics, ins, in_specs, g_spec, g_shape, finish
+    return grid, statics, ins, in_specs, g_spec, g_shape, scratch, finish
 
 
 _COMPILER_PARAMS = pltpu.CompilerParams(
@@ -584,7 +648,7 @@ def fused_value_grad(X, labels, offsets, weights, u, c, *, loss,
     margins m = X@u + offsets − c. ``offsets=None`` means identically 0,
     ``weights=None`` identically 1 (the stream is not read at all).
     Returns float32 (val, grad, r_sum)."""
-    grid, statics, ins, in_specs, g_spec, g_shape, finish = _prep(
+    grid, statics, ins, in_specs, g_spec, g_shape, scratch, finish = _prep(
         X, labels, offsets, weights,
         u.reshape(1, X.shape[1]).astype(jnp.float32),
     )
@@ -598,6 +662,7 @@ def fused_value_grad(X, labels, offsets, weights, u, c, *, loss,
         out_specs=[_part_spec(_LANES), g_spec, _part_spec(_LANES)],
         out_shape=[_part_shape(grid, _LANES), g_shape,
                    _part_shape(grid, _LANES)],
+        scratch_shapes=scratch,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*ins)
@@ -605,7 +670,7 @@ def fused_value_grad(X, labels, offsets, weights, u, c, *, loss,
 
 
 def _hvp_kernel(*refs, loss, n, d, fm, masked, has_off, has_wt):
-    x_ref, y_ref, off_ref, wt_ref, uv_ref, sc_ref, hv_ref, qs_ref = (
+    x_ref, y_ref, off_ref, wt_ref, uv_ref, sc_ref, hv_ref, qs_ref, *acc = (
         _split_refs(refs, has_off, has_wt)
     )
 
@@ -623,9 +688,14 @@ def _hvp_kernel(*refs, loss, n, d, fm, masked, has_off, has_wt):
             x_ref, n, d, masked, uv_ref, sc_ref, hv_ref, 1, point
         )
         return
+    if acc:
+        (qs_ref[...],) = _rm_pass(
+            x_ref, n, masked, uv_ref, sc_ref, hv_ref, acc[0], 1, point
+        )
+        return
     x, mask = _tile(x_ref, n, masked)
-    q, _ = point(_margins(uv_ref, sc_ref, x, mask), mask)
-    _contract(hv_ref, q, x, mask)
+    q, _ = point(_margins(uv_ref, sc_ref, x), mask)
+    hv_ref[...] = _contract(q, x)
     qs_ref[...] = _lane_sums(q)
 
 
@@ -634,7 +704,7 @@ def fused_hvp(X, labels, offsets, weights, u, v, c, cv, *, loss,
     """One X-read Gauss-Newton Hv: (Xᵀq, Σq) with q = w·l''(m, y)·(Xv − cv)
     and m = X@u + offsets − c. ``offsets``/``weights`` may be None as in
     ``fused_value_grad``. Returns float32 (hv, q_sum)."""
-    grid, statics, ins, in_specs, g_spec, g_shape, finish = _prep(
+    grid, statics, ins, in_specs, g_spec, g_shape, scratch, finish = _prep(
         X, labels, offsets, weights, jnp.stack([u, v]).astype(jnp.float32),
     )
     ins.append(jnp.stack([jnp.asarray(c, jnp.float32),
@@ -647,6 +717,7 @@ def fused_hvp(X, labels, offsets, weights, u, v, c, cv, *, loss,
         in_specs=in_specs,
         out_specs=[g_spec, _part_spec(_LANES)],
         out_shape=[g_shape, _part_shape(grid, _LANES)],
+        scratch_shapes=scratch,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*ins)

@@ -160,7 +160,8 @@ class GLMObjective:
         (b) the tile-COO sparse kernels make the typical one-trial
         iteration cheaper that way (margins+grad = 2 kernel passes beats
         margins-trial + margins+grad = 3), or (c) the batch says the same
-        of itself (``SubspaceDenseBatch``: 2 reads of X against 3)."""
+        of itself (``SubspaceDenseBatch``: 1 read of X against 2 through
+        the kernel, 2 against 3 on XLA's sweeps)."""
         from photon_ml_tpu.ops.sparse_tiled import TiledSparseBatch
 
         return (
@@ -255,14 +256,11 @@ class GLMObjective:
     def value_and_grad(self, w: Array) -> tuple[Array, Array]:
         with stage(GLM_OBJECTIVE):
             if self.fused and isinstance(self.batch, DenseBatch):
-                from photon_ml_tpu.ops.fused import fused_value_grad
-
                 u, c = self.norm.to_effective(w)
-                local = fused_value_grad(
-                    self.batch.X, self.batch.labels,
-                    None if self.offsets_zero else self.batch.offsets,
-                    None if self.weights_one else self.batch.weights,
-                    u, c, loss=self.loss,
+                local = self.batch.value_grad_pass(
+                    u, c, self.loss,
+                    offsets=None if self.offsets_zero else self.batch.offsets,
+                    weights=None if self.weights_one else self.batch.weights,
                     interpret=_interpret_fused(),
                 )
             else:
@@ -494,26 +492,39 @@ def fused_disabled() -> bool:
     return False
 
 
+def fused_for_shape(n: int, d: int, dtype) -> bool:
+    """What ``auto_fused`` asks of the shapes alone: a TPU backend, no
+    ``PHOTON_DISABLE_FUSED`` veto, and ``ops/fused.supports_fused``. For
+    callers that hold no concrete array where they decide (a lane of a
+    ``vmap``: ``game/random_effect.subspace_one_read``)."""
+    from photon_ml_tpu.ops import fused
+
+    return (
+        jax.default_backend() == "tpu"
+        and not fused_disabled()
+        and fused.supports_fused(n, d, dtype)
+    )
+
+
 def auto_fused(batch: Batch) -> bool:
     """Should this (concrete) batch use the one-pass Pallas kernels?
     True on TPU for dense shapes ``ops/fused.supports_fused`` takes, stored
     as the kernels they take read them. Callers that
     construct objectives inside a transform (``jit``, ``shard_map``,
-    ``vmap``) must decide BEFORE entering it — under a transform X is a
-    tracer and this returns False (pallas under vmap batching rules is
-    untested; under ``jit`` or ``shard_map`` pass the pre-computed answer
-    through a static arg, as ``game/coordinate``'s fixed visit does from its
-    base batch and ``parallel/distributed.py`` with per-device row
-    counts)."""
+    ``vmap``) must decide BEFORE entering it: under a transform X is a
+    tracer, whose storage cannot be seen, and this returns False. They pass
+    the pre-computed answer in as ``fused=``: through a static arg under
+    ``jit`` or ``shard_map``, as ``game/coordinate``'s fixed visit does from
+    its base batch and ``parallel/distributed.py`` with per-device row
+    counts; from the lanes' shapes under ``vmap``, where the kernels batch
+    (the mapped axis becomes their outer grid axis), as
+    ``game/random_effect``'s subspace lanes do."""
     from photon_ml_tpu.ops import fused
 
     if not (
         isinstance(batch, DenseBatch)
         and not isinstance(batch.X, jax.core.Tracer)
-        and jax.default_backend() == "tpu"
-        and not fused_disabled()
-        and fused.supports_fused(
-            batch.num_rows, batch.num_features, batch.X.dtype)
+        and fused_for_shape(batch.num_rows, batch.num_features, batch.X.dtype)
     ):
         return False
     # the feature-major kernels read X as its transpose: free where the

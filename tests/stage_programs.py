@@ -44,16 +44,21 @@ TILE_FIT_STAGES = FIT_STAGES + ("glm.head", "glm.tail")
 # a TRON fit: Hessian-vector passes inside the objective, CG and the
 # trust-region update outside it
 TRON_FIT_STAGES = ("glm.objective", "glm.hvp", "tron.cg", "tron.update")
+# ``sparse_descent_kernels``: the same with lanes long enough (512 rows of
+# 1,024 columns) for ``ops/fused``'s row-major float32 kernel, traced under
+# ``lanes_take_the_kernel``
 PROGRAM_STAGES = {
     "descent": DESCENT_STAGES, "tile_fit": TILE_FIT_STAGES,
     "sharded": FIT_STAGES,
     "sparse_descent": SPARSE_DESCENT_STAGES,
+    "sparse_descent_kernels": SPARSE_DESCENT_STAGES,
     "tron_fit": TRON_FIT_STAGES,
 }
 
 
-def descent_coordinates(n=256, d=5, entities=12, seed=0, sparse=False):
-    """``sparse``: the random effect's shard is 300 columns wide with 4
+def descent_coordinates(n=256, d=5, entities=12, seed=0, sparse=False,
+                        columns=300):
+    """``sparse``: the random effect's shard is ``columns`` wide with 4
     nonzeros a row, and its entities are trained by L-BFGS."""
     from photon_ml_tpu.config import (
         OptimizationConfig,
@@ -76,9 +81,9 @@ def descent_coordinates(n=256, d=5, entities=12, seed=0, sparse=False):
     y = (rng.random(n) < 0.5).astype(np.float32)
     if sparse:
         per_user = SparseFeatures(
-            indices=jnp.asarray(rng.integers(0, 300, (n, 4)), jnp.int32),
+            indices=jnp.asarray(rng.integers(0, columns, (n, 4)), jnp.int32),
             values=jnp.asarray(rng.uniform(0.2, 1.0, (n, 4)), jnp.float32),
-            num_features=300,
+            num_features=columns,
         )
     else:
         per_user = DenseFeatures(X=rng.normal(size=(n, 3)).astype(np.float32))
@@ -118,12 +123,12 @@ def descent_coordinates(n=256, d=5, entities=12, seed=0, sparse=False):
     return coordinates, batch, task
 
 
-def descent_program(sparse=False):
+def descent_program(sparse=False, **size):
     """The jitted ``fused`` of ``game/descent._build_fused_outer`` with the
     arguments ``run_outer`` gives it from the zero model."""
     from photon_ml_tpu.game.descent import _build_fused_outer
 
-    coordinates, batch, _ = descent_coordinates(sparse=sparse)
+    coordinates, batch, _ = descent_coordinates(sparse=sparse, **size)
     seq = list(DESCENT_COORDINATES)
     run_outer = _build_fused_outer(coordinates, seq)
     fused = next(
@@ -238,10 +243,22 @@ def build(name: str, mesh):
         return sharded_program(mesh)
     if name == "sparse_descent":
         return descent_program(sparse=True)
+    if name == "sparse_descent_kernels":
+        return descent_program(sparse=True, n=2048, entities=6, columns=1024)
     return {
         "descent": descent_program, "tile_fit": tile_fit_program,
         "tron_fit": tron_fit_program,
     }[name]()
+
+
+def lanes_take_the_kernel(monkeypatch) -> None:
+    """``game/random_effect.subspace_one_read`` as a TPU backend answers
+    it (the kernels' own shape gate), for programs traced from here on. The
+    caller drops JAX's trace caches on both sides."""
+    from photon_ml_tpu.game import random_effect
+    from photon_ml_tpu.ops import fused
+
+    monkeypatch.setattr(random_effect, "fused_for_shape", fused.supports_fused)
 
 
 def without_scopes(monkeypatch) -> None:

@@ -267,12 +267,137 @@ def test_supports_fused_gates():
     assert supports_fused(1024, 128, jnp.float32)
     assert not supports_fused(1024, 512, jnp.int8)
     assert not supports_fused(1024, 1 << 17, jnp.float32)  # tile over budget
-    # the double-buffered minimum tile of 256 rows bounds the width
+    # the double-buffered shortest tile bounds the width: 128 rows of a
+    # row-major float32 matrix (the subspace lanes' 8,192 columns fit), 256
+    # of a feature-major or a bfloat16 one
     assert supports_fused(1024, 7168, jnp.float32)
-    assert not supports_fused(1024, 7296, jnp.float32)
+    assert supports_fused(1024, 8192, jnp.float32)
+    assert supports_fused(1024, 14336, jnp.float32)
+    assert not supports_fused(1024, 14464, jnp.float32)
     assert supports_fused(1024, 7160, jnp.float32)  # feature-major: 7,160 rows
     assert not supports_fused(1024, 7170, jnp.float32)
     assert supports_fused(1024, 14336, jnp.bfloat16)
+    assert not supports_fused(1024, 14464, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("n, d, dtype, tile, grid, masked", [
+    # dense_dp4_fit's shard a chip; epsilon_tron_fit; the fixed effect of
+    # ml20m_descent / ml20m_fixed_only and of sparse_re_descent
+    (1 << 22, 512, jnp.bfloat16, 4096, 1024, False),
+    (400_000, 2000, jnp.float32, 512, 782, True),
+    (5_000_066, 65, jnp.float32, 8192, 611, True),
+    (2_500_033, 65, jnp.float32, 8192, 306, True),
+])
+def test_the_other_cells_tiles_are_where_they_were(n, d, dtype, tile, grid, masked):
+    """The float32 row-major tile came down to 128 rows for the subspace
+    lanes (PR 37); the shapes the other cells run are bfloat16 or
+    feature-major and keep the tiles they had (``_block_rows`` at PR 36)."""
+    from photon_ml_tpu.ops import fused as F
+
+    assert F.tile_rows(n, d, dtype) == tile
+    got_grid, statics, *_, scratch, _ = _prep_of(n, d, dtype)
+    assert (got_grid, statics["masked"]) == (grid, masked)
+    assert statics["fm"] == (dtype == jnp.float32) and scratch == []
+
+
+def _prep_of(n, d, dtype):
+    """``_prep`` on shapes alone (nothing of that size is built)."""
+    from photon_ml_tpu.ops import fused as F
+
+    out = {}
+
+    def run(X, y, vecs):
+        out["prep"] = F._prep(X, y, None, None, vecs)
+        return 0
+
+    jax.eval_shape(
+        run, jax.ShapeDtypeStruct((n, d), dtype),
+        jax.ShapeDtypeStruct((n,), jnp.float32),
+        jax.ShapeDtypeStruct((1, d), jnp.float32),
+    )
+    return out["prep"]
+
+
+@pytest.mark.parametrize("rows, width, tile", [
+    (64, 128, 128), (128, 128, 128), (256, 128, 256), (2048, 1024, 1024),
+    (128, 4096, 128), (256, 4096, 256), (1024, 4096, 256), (128, 8192, 128),
+    (256, 8192, 128), (8192, 8192, 128), (128, 14336, 128),
+])
+def test_a_float32_row_major_tile_comes_down_to_128_rows(rows, width, tile):
+    from photon_ml_tpu.ops import fused as F
+
+    assert F.tile_rows(rows, width, jnp.float32) == tile
+
+
+def _lanes(rng, lanes, rows, width, nnz=4):
+    """``lanes`` subspace lanes as ``prepare_buckets`` stages them: flat
+    local indices with a REPEATED column in every row (its slots add),
+    offsets, and trailing padding rows of weight 0 whose labels, offsets and
+    nonzeros are garbage."""
+    from photon_ml_tpu.ops.batch import LocalSparseBatch
+
+    idx = rng.integers(0, width, (lanes, rows, nnz)).astype(np.int32)
+    idx[..., 1] = idx[..., 0]
+    val = rng.uniform(0.2, 1.0, (lanes, rows, nnz)).astype(np.float32)
+    y = (rng.uniform(size=(lanes, rows)) < 0.5).astype(np.float32)
+    off = (0.3 * rng.normal(size=(lanes, rows))).astype(np.float32)
+    wt = rng.uniform(0.5, 1.5, (lanes, rows)).astype(np.float32)
+    pad = max(rows // 5, 3)
+    wt[:, -pad:] = 0.0
+    y[:, -pad:] = 1e30
+    off[:, -pad:] = -1e30
+    val[:, -pad:] = 1e30
+    return LocalSparseBatch(
+        indices=jnp.asarray(idx.reshape(lanes, -1)),
+        values=jnp.asarray(val.reshape(lanes, -1)), labels=jnp.asarray(y),
+        offsets=jnp.asarray(off), weights=jnp.asarray(wt), num_features=width,
+    )
+
+
+@pytest.mark.parametrize("rows, width", [
+    (64, 128), (128, 128), (256, 128), (128, 4096), (256, 4096), (128, 8192),
+    (256, 8192), (64, 1024), (512, 256),
+])
+def test_vmapped_kernel_matches_the_subspace_multiply_reduces(rng, rows, width):
+    """``ops/fused``'s row-major float32 value-and-gradient kernel batched
+    over lanes (a ``vmap``: the lane axis is its outer grid axis) against
+    ``SubspaceDenseBatch``'s two multiply-reduce sweeps, to float32
+    rounding: on both sides of every edge of ``game/random_effect.
+    subspace_one_read`` (64 rows, where the lane is shorter than its
+    128-row tile and the tile is ragged; 128; 256; one lane block of
+    columns; 8,192 columns, where the budget brings the tile to 128 rows
+    and a 256-row lane takes two tiles; 512 rows in one tile of four row
+    blocks, a loop)."""
+    from photon_ml_tpu.ops import fused as F
+
+    task = TaskType.LOGISTIC_REGRESSION
+    loss = loss_for_task(task)
+    lanes = _lanes(rng, 3, rows, width)
+    U = jnp.asarray(0.2 * rng.normal(size=(3, width)).astype(np.float32))
+    c = jnp.float32(0.25)
+
+    def sweeps(lane, u):
+        b = lane.densified()
+        m = b.matvec(u) + b.offsets - c
+        r = jnp.where(b.weights != 0, b.weights * loss.d1(m, b.labels), 0.0)
+        lv = jnp.where(b.weights != 0, b.weights * loss.value(m, b.labels), 0.0)
+        return jnp.sum(lv), b.rmatvec(r), jnp.sum(r)
+
+    def kernel(lane, u):
+        b = lane.densified()
+        return b.value_grad_pass(
+            u, c, loss, offsets=b.offsets, weights=b.weights, interpret=True
+        )
+
+    want = jax.vmap(sweeps)(lanes, U)
+    got = jax.vmap(kernel)(lanes, U)
+    assert F.tile_rows(rows, width, jnp.float32) <= max(rows, 128)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == jnp.float32
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=0, atol=2e-6 * scale
+        )
 
 
 def _reference_passes(batch, w, v, l2):
@@ -332,7 +457,7 @@ def test_fused_takes_any_float32_width_of_a_lane_tile_or_more(rng, d, kernel):
     if d % 128:  # the feature-major kernels: blocks of whole sublane groups
         assert F.reads_feature_major(d, jnp.float32)
         d8 = F.sublane_width(d)
-        *_, ins, in_specs, g_spec, g_shape, _ = F._prep(
+        *_, ins, in_specs, g_spec, g_shape, _, _ = F._prep(
             batch.X, batch.labels, None, None, w.reshape(1, d)
         )
         assert ins[0].shape == (d, n) and in_specs[0].block_shape[0] == d8

@@ -256,7 +256,11 @@ class TestFusedCompiles:
             (37, 128, jnp.float32),  # one ragged tile, n < 128
             (5000, 128, jnp.bfloat16),  # n < bn = 8192
             (100_000, 512, jnp.bfloat16),  # a ragged last tile of 4096
-            (1000, 7168, jnp.float32),  # the widest f32 the gate lets in
+            (1000, 7168, jnp.float32),  # the widest f32 at a 256-row tile
+            # the float32 row-major tile at 128 rows (PR 37): the widest the
+            # gate lets in; and eight row blocks a tile, a loop, ragged
+            (1000, 14336, jnp.float32),
+            (5000, 1024, jnp.float32),
             (1000, 14336, jnp.bfloat16),  # the widest bf16
             # the feature-major kernels (a float32 width that is no
             # multiple of 128): epsilon_tron_fit's matrix, a ragged last
@@ -403,6 +407,15 @@ def _instructions(text: str) -> list[tuple[str, bool]]:
     ]
 
 
+def _kernel_paths(text: str) -> list[str]:
+    """The ``op_name`` of every Pallas custom call of a compiled program."""
+    return [
+        re.search(r'op_name="([^"]*)"', m.group(0)).group(1)
+        for m in _INSTRUCTION.finditer(text)
+        if 'custom_call_target="tpu_custom_call"' in m.group(0)
+    ]
+
+
 class TestStagesAreMetadataOnly:
     """``obs/stages.py`` scopes change the ``op_name`` metadata of the
     compiled TPU program and nothing else: the same instructions under the
@@ -427,7 +440,8 @@ class TestStagesAreMetadataOnly:
 
     @pytest.mark.parametrize(
         "program",
-        ["descent", "tile_fit", "sharded", "sparse_descent", "tron_fit"],
+        ["descent", "tile_fit", "sharded", "sparse_descent", "tron_fit",
+         "sparse_descent_kernels"],
     )
     def test_same_instructions_with_and_without_scopes(
         self, topo, monkeypatch, program
@@ -438,6 +452,8 @@ class TestStagesAreMetadataOnly:
         # compile the kernels, as a chip would
         monkeypatch.setattr(st, "_interpret", lambda: False)
         monkeypatch.setattr(glm, "_interpret_fused", lambda: False)
+        if program == "sparse_descent_kernels":
+            programs.lanes_take_the_kernel(monkeypatch)
         jax.clear_caches()
         try:
             scoped = self._compiled(topo, program)
@@ -470,8 +486,24 @@ class TestStagesAreMetadataOnly:
             assert all(re.fullmatch(named, k) for k in kernels)
             assert all(a[1] and b[1] for a, b in renamed)  # kernels only
             assert len(renamed) == len(kernels)
+        elif program == "sparse_descent_kernels":
+            # the subspace lanes' value-and-gradient kernel sits inside
+            # ``jit(_subspace_value_grad)``, under the scope that names it:
+            # one a site of ``optim/lbfgs``, each under the solve's stage
+            assert len(kernels) == 3
+            assert all(re.fullmatch(r"re\.sparse_pass\.\d+", k) for k in kernels)
+            assert all(a[1] and b[1] for a, b in renamed)
+            assert len(renamed) == len(kernels)
+            assert _kernel_paths(scoped) and all(
+                "/re.solve/" in path and path.count("/re.sparse_pass/") == 1
+                for path in _kernel_paths(scoped)
+            )
         else:
             assert renamed == []
+            if program in ("descent", "sparse_descent"):
+                # dense lanes, and subspace lanes shorter than a tile,
+                # stay on XLA's sweeps
+                assert kernels == []
         if program == "tile_fit":
             assert kernels and all(
                 re.fullmatch(r"_tiled_apply_jit\.\d+", k) for k in kernels
@@ -490,7 +522,9 @@ SPARSE_RE_CLASSES = (
 )
 
 
-def test_sparse_random_effect_visit_fits_the_chip_at_the_benchmarks_cut(topo):
+def test_sparse_random_effect_visit_fits_the_chip_at_the_benchmarks_cut(
+    topo, monkeypatch, request
+):
     """The fused visit of the benchmark's wide sparse per-user effect
     (2,500,033 rows, 17,312 users, 16,384 columns, 16 nonzeros a row, the
     bucket classes above) compiles for a v5e, and what it needs leaves room
@@ -498,7 +532,20 @@ def test_sparse_random_effect_visit_fits_the_chip_at_the_benchmarks_cut(topo):
     13.4 GB of the chip's 16.9 GB when this test was written (7.9 GB of it
     scratch), the visit alone for less. A change that densifies more lanes
     at a time, or brings an (n, 16) or (k, d) temporary back, shows here
-    without a chip."""
+    without a chip.
+
+    As a TPU backend decides it (PR 37): the eleven classes of 128 rows or
+    more evaluate value and gradient through ``ops/fused``'s row-major
+    float32 kernel, one a class and site of ``optim/lbfgs``, each named
+    after ``re.sparse_pass`` under ``re.solve`` (the stages
+    ``sparse_re.pass_s_per_iter`` and ``sparse_re.pass_roofline`` read);
+    the three 64-row classes keep the multiply-reduces, in the same
+    program."""
+    import stage_programs as programs
+    from photon_ml_tpu.ops import glm
+
+    monkeypatch.setattr(glm, "_interpret_fused", lambda: False)
+    programs.lanes_take_the_kernel(monkeypatch)
     from photon_ml_tpu.config import (
         OptimizationConfig,
         OptimizerConfig,
@@ -559,6 +606,8 @@ def test_sparse_random_effect_visit_fits_the_chip_at_the_benchmarks_cut(topo):
     )
     object.__setattr__(coordinate, "_prepared_cache", prepared)
     visit = coordinate._build_visit_fn()
+    jax.clear_caches()  # the rule is read when ``_solve_bucket`` is traced
+    request.addfinalizer(jax.clear_caches)
     compiled = visit.lower(
         spec((n,), f32), spec((n,), f32), spec((entities, d), f32),
         tuple((pb.static, pb.row_idx, pb.mask, pb.ids, pb.columns) for pb in prepared),
@@ -573,4 +622,14 @@ def test_sparse_random_effect_visit_fits_the_chip_at_the_benchmarks_cut(topo):
     assert total < 9.0e9, (
         need.argument_size_in_bytes, need.output_size_in_bytes,
         need.temp_size_in_bytes,
+    )
+    text = compiled.as_text()
+    taken = [c for c in SPARSE_RE_CLASSES if c[0] >= 128]
+    assert len(taken) == 11
+    kernels = [name for name, is_kernel in _instructions(text) if is_kernel]
+    assert len(kernels) == 3 * len(taken)
+    assert all(re.fullmatch(r"re\.sparse_pass\.\d+", k) for k in kernels)
+    assert all(
+        "/re.solve/" in path and "/re.sparse_pass/" in path
+        for path in _kernel_paths(text)
     )

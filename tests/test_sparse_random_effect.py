@@ -245,6 +245,110 @@ def test_lanes_in_chunks_equal_lanes_at_once(monkeypatch):
     np.testing.assert_array_equal(chunked.iterations, whole.iterations)
 
 
+def _as_on_a_tpu(monkeypatch):
+    """``subspace_one_read`` as a TPU backend answers it: the kernels' own
+    shape gate (they run in interpret mode here)."""
+    import photon_ml_tpu.game.random_effect as re_mod
+    from photon_ml_tpu.ops import fused
+
+    monkeypatch.setattr(re_mod, "fused_for_shape", fused.supports_fused)
+
+
+@pytest.mark.parametrize("capacity, width, on_a_tpu", [
+    (64, 256, False), (64, 1024, False),  # a lane shorter than a 128-row tile
+    (128, 1024, True), (128, 128, True), (256, 2048, True), (2048, 4096, True),
+    (2048, 8192, True), (8192, 8192, True),  # the tile comes down to 128 rows
+    (128, 14336, True), (128, 16384, False),  # two such tiles within the budget
+    (256, 1000, False), (256, 64, False),  # a narrow shard's own width
+])
+def test_the_rule_reads_the_class_shape_and_the_backend(
+    monkeypatch, capacity, width, on_a_tpu
+):
+    """``subspace_one_read``: off a TPU no class takes the kernel, so every
+    result recorded here is what it was; on one, the classes whose lanes
+    hold a whole float32 row-major tile."""
+    from photon_ml_tpu.game.random_effect import subspace_one_read
+
+    assert not subspace_one_read(capacity, width)
+    _as_on_a_tpu(monkeypatch)
+    assert subspace_one_read(capacity, width) == on_a_tpu
+
+
+def _skewed_problem(seed, d, counts, nnz=6):
+    """Entities of ``counts`` rows each, in blocks."""
+    rng = np.random.default_rng(seed)
+    ids = np.repeat(np.arange(len(counts)), counts).astype(np.int32)
+    n = len(ids)
+    idx = rng.integers(0, d, size=(n, nnz)).astype(np.int32)
+    val = rng.uniform(0.2, 1.0, size=(n, nnz)).astype(np.float32)
+    W = rng.normal(size=(len(counts), d)) * 0.8
+    margin = np.sum(val * W[ids[:, None], idx], axis=1)
+    y = (rng.uniform(size=n) < 1.0 / (1.0 + np.exp(-margin))).astype(np.float32)
+    return idx, val, ids, y
+
+
+@pytest.mark.parametrize("schedule", ["single", "chunked", "compacted"])
+def test_the_kernel_arm_solves_what_the_sweeps_solve(monkeypatch, schedule):
+    """``_solve_bucket`` on subspace classes on both sides of the rule (40
+    rows: the sweeps either way; 130 to 300 rows: the kernel where the rule
+    says so), the kernel arm against XLA's: coefficients to 1e-5, iteration
+    counts within one (the kernel adds a tile's partials in its own slot,
+    so sums differ in order). ``chunked``: the lanes a ``lax.map`` of
+    chunks. ``compacted``: the host-driven schedule, which builds its lanes
+    with the same constructor, equals the single launch BITWISE on the
+    kernel arm too."""
+    import jax
+
+    import photon_ml_tpu.game.random_effect as re_mod
+    from photon_ml_tpu.obs.metrics import REGISTRY
+
+    d = 2048
+    counts = [40, 35, 130, 200, 250, 140, 300, 260]
+    idx, val, ids, y = _skewed_problem(12, d, counts)
+    offsets = (0.2 * np.random.default_rng(13).normal(size=len(y))).astype(np.float32)
+    loose = OptimizerConfig(max_iterations=60, tolerance=1e-6)
+
+    def train():
+        jax.clear_caches()  # the rule is read at trace time
+        REGISTRY.reset(prefix="re_subspace")
+        g = group_by_entity(ids, num_entities=len(counts))
+        out = train_random_effects(
+            SparseFeatures(indices=jnp.asarray(idx), values=jnp.asarray(val),
+                           num_features=d),
+            y, offsets, np.ones(len(y), np.float32), bucket_entities(g),
+            len(counts), loss_for_task(TASK), loose, l2_weight=1.0,
+        )
+        counters = REGISTRY.snapshot("re_subspace.")["counters"]
+        return out, {k: v["value"] for k, v in counters.items()}
+
+    if schedule == "chunked":
+        monkeypatch.setattr(re_mod, "_SUBSPACE_CHUNK_BYTES", 2 * 256 * 2048 * 4)
+    sweeps, off = train()
+    assert off["re_subspace.one_read_bytes"] == 0
+    _as_on_a_tpu(monkeypatch)
+    try:
+        kernel, on = train()
+        if schedule == "compacted":
+            monkeypatch.setenv("PHOTON_RE_COMPACT_EVERY", "3")
+            compacted, _ = train()
+    finally:
+        jax.clear_caches()
+    assert 0 < on["re_subspace.one_read_bytes"] < on["re_subspace.dense_bytes"]
+    assert on["re_subspace.dense_bytes"] == off["re_subspace.dense_bytes"]
+    np.testing.assert_allclose(
+        np.asarray(kernel.coefficients), np.asarray(sweeps.coefficients),
+        atol=1e-5, rtol=0,
+    )
+    assert np.abs(kernel.iterations - sweeps.iterations).max() <= 1
+    assert kernel.converged.all() and kernel.iterations.max() > 3
+    if schedule == "compacted":
+        np.testing.assert_array_equal(
+            np.asarray(compacted.coefficients).view(np.uint32),
+            np.asarray(kernel.coefficients).view(np.uint32),
+        )
+        np.testing.assert_array_equal(compacted.iterations, kernel.iterations)
+
+
 def _sparse_descent(idx, val, ids, y, d, entities, eager=False, seed=6):
     rng = np.random.default_rng(seed)
     Xf = rng.normal(size=(len(y), 4)).astype(np.float32)
@@ -369,6 +473,13 @@ def test_subspace_counters_are_counted_at_prepare_time():
         {pb.static.num_features for pb in prepared}
     )
     assert snap["timers"]["re_subspace.build"]["calls"] == 1
+    # the float32 bytes of the densified lanes, and (off a TPU) none of them
+    # read once
+    assert counters["re_subspace.dense_bytes"] == sum(
+        4 * pb.num_real * pb.static.labels.shape[1] * pb.static.num_features
+        for pb in prepared
+    )
+    assert counters["re_subspace.one_read_bytes"] == 0
     for pb in prepared:  # local indices, flat, inside the lane's width
         assert pb.static.indices.shape == (
             pb.static.labels.shape[0], pb.static.labels.shape[1] * 6
@@ -527,6 +638,8 @@ def test_the_run_report_renders_the_subspace_counters(tmp_path, prepared):
         "re_subspace.support_columns": {"value": 2000.0},
         "re_subspace.padded_columns": {"value": 3000.0},
         "re_subspace.width_classes": {"value": 2.0},
+        "re_subspace.dense_bytes": {"value": 4.0e9},
+        "re_subspace.one_read_bytes": {"value": 3.0e9},
     } if prepared else {}
     timers = {"re_subspace.build": {"seconds": 0.25, "count": 1}} if prepared else {}
     sink = TelemetrySink(str(tmp_path), run_id="SUB", shard_index=None)
@@ -546,6 +659,9 @@ def test_the_run_report_renders_the_subspace_counters(tmp_path, prepared):
     assert summary["re_subspace"]["build_s"] == 0.25
     assert "re-subspace: 10 entities" in format_summary(summary)
     assert "solved at 1.50x in 2 width classes" in format_summary(summary)
+    assert summary["re_subspace"]["dense_bytes"] == 4.0e9
+    assert summary["re_subspace"]["one_read_byte_share"] == 0.75
+    assert "75.0% read once a value-and-gradient" in format_summary(summary)
 
 
 # ---------------------------------------------------------------------------

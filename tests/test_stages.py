@@ -23,7 +23,15 @@ from photon_ml_tpu.obs import stages
 def _lowered_text(name: str) -> str:
     mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
     fn, args, kwargs = programs.build(name, mesh)
-    return fn.lower(*args, **kwargs).as_text(debug_info=True)
+    if name != "sparse_descent_kernels":
+        return fn.lower(*args, **kwargs).as_text(debug_info=True)
+    jax.clear_caches()
+    with pytest.MonkeyPatch.context() as patch:
+        programs.lanes_take_the_kernel(patch)
+        try:
+            return fn.lower(*args, **kwargs).as_text(debug_info=True)
+        finally:
+            jax.clear_caches()
 
 
 @pytest.fixture(scope="module")
