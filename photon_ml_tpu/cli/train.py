@@ -88,9 +88,10 @@ def run(
             index_maps=prebuilt,
             entity_maps=warm_tag_maps,
             extend_entities=warm_tag_maps is not None,
+            mesh=mesh,
         )
         logger.info(
-            f"train: {train.batch.num_rows} rows, shards "
+            f"train: {train.batch.num_real_rows} rows, shards "
             f"{ {s: m.size for s, m in train.index_maps.items()} }"
         )
 

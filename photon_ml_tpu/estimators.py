@@ -18,6 +18,7 @@ TPU-first notes:
 
 from __future__ import annotations
 
+import dataclasses
 import json
 from dataclasses import dataclass
 from typing import Callable, Mapping, Sequence
@@ -45,6 +46,9 @@ from photon_ml_tpu.game.data import (
     GameBatch,
     bucket_entities,
     group_by_entity,
+    place_game_batch,
+    placeable_over,
+    rows_placed_over,
 )
 from photon_ml_tpu.game.descent import CoordinateDescent, CoordinateDescentResult
 from photon_ml_tpu.game.models import GameModel
@@ -84,7 +88,6 @@ def build_configuration_grid(
     (``config.regularization_weight_grid``); coordinates without a list keep
     their single configured weight. Parity: the reference's grid over
     ``GameOptimizationConfiguration``s."""
-    import dataclasses
     import itertools
 
     cids = list(config.coordinate_update_sequence)
@@ -234,7 +237,10 @@ class GameEstimator:
         ingest-time replacement for the reference's group-by-entity shuffle)."""
         layouts: dict[str, tuple[EntityGrouping, EntityBuckets, int]] = {}
         for cid, cfg in self.config.random_effect_coordinates.items():
-            ids = np.asarray(batch.id_tags[cfg.random_effect_type])
+            # the real rows: one padded to fill the mesh belongs to no entity
+            ids = np.asarray(batch.id_tags[cfg.random_effect_type])[
+                : batch.num_real_rows
+            ]
             num_entities = int(ids.max()) + 1 if len(ids) else 0
             grouping = group_by_entity(
                 ids,
@@ -312,7 +318,7 @@ class GameEstimator:
                 if opt.down_sampling_rate < 1.0:
                     rows, scale = down_sample(
                         task,
-                        np.asarray(batch.labels),
+                        np.asarray(batch.labels)[: batch.num_real_rows],
                         opt.down_sampling_rate,
                         seed=self.seed,
                     )
@@ -366,6 +372,15 @@ class GameEstimator:
         if configurations is None:
             configurations = build_configuration_grid(cfg)
 
+        if (
+            self.mesh is not None
+            and placeable_over(batch, self.mesh)
+            and not rows_placed_over(batch, self.mesh)  # the reader did
+        ):
+            # rows over the mesh ONCE: with that the mesh alone chooses the
+            # descent's fused path, and no visit pads and puts again
+            batch = place_game_batch(batch, self.mesh)
+
         norm_contexts = self._normalization_contexts(batch)
         entity_layouts = self._entity_layouts(batch)
         specs = self._evaluator_specs()
@@ -408,6 +423,14 @@ class GameEstimator:
                     else _fit_fingerprint(fingerprint_base, configuration)
                 ),
             )
+            if batch.padded_rows:
+                cd_result = dataclasses.replace(
+                    cd_result,
+                    training_scores={
+                        cid: s[: batch.num_real_rows]
+                        for cid, s in cd_result.training_scores.items()
+                    },
+                )
             evaluation = None
             if validation_batch is not None:
                 scores = cd_result.model.score(validation_batch)

@@ -26,6 +26,8 @@ from photon_ml_tpu.game.data import (  # noqa: F401
     capacity_classes,
     group_by_entity,
     make_game_batch,
+    place_game_batch,
+    placeable_over,
 )
 from photon_ml_tpu.game.random_effect import (  # noqa: F401
     RandomEffectTrainingResult,

@@ -11,6 +11,12 @@ static geometry — re-entered, not recompiled, every descent iteration:
 - fixed effect → the sample-sharded ``sharded_minimize`` psum path
   (HOT LOOP 1 of §3.1);
 - random effect → the vmap-batched bucket solver (HOT LOOP 2).
+
+Both hand the descent the parts of a FUSED visit (``_fused_visit_parts``),
+on one device and, since PR 38, under a mesh of one host whose batch was
+placed over it (``fuses_under_mesh``): the visit then runs inside
+``shard_map``, rows over the mesh axis for the fixed effect, entity lanes for
+the random effects, coefficients whole on every device.
 """
 
 from __future__ import annotations
@@ -21,15 +27,18 @@ from typing import Any, Protocol
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from photon_ml_tpu.config import OptimizationConfig
 from photon_ml_tpu.game.data import (
+    DenseFeatures,
     EntityBuckets,
     EntityGrouping,
     GameBatch,
     NonzeroMajorSparseFeatures,
     SparseFeatures,
+    one_process_mesh,
+    rows_placed_over,
 )
 from photon_ml_tpu.game.random_effect import (
     RandomEffectTrainingResult,
@@ -45,6 +54,7 @@ from photon_ml_tpu.normalization import (
 )
 from photon_ml_tpu.obs.spans import COORD_FIXED, COORD_RE, span
 from photon_ml_tpu.obs.stages import (
+    MESH_EXCHANGE,
     RE_OFFSETS,
     RE_SCORE,
     VISIT_FIXED,
@@ -82,6 +92,25 @@ def _require_prior_l2(config) -> None:
             "regularization weight: the prior's pull is "
             "l2_weight * (1/prior_variance)"
         )
+
+
+def fuses_under_mesh(batch: GameBatch, mesh: Mesh, axis_name: str) -> bool:
+    """Whether a coordinate's visit can be traced into the descent's one
+    program under ``mesh``: every device of the mesh is this process's own
+    (one host, fully addressable arrays) and the batch's rows were placed
+    over it (``game/data.place_game_batch``: ``GameEstimator.fit`` and
+    ``AvroDataReader.read`` place every batch they can, so there a mesh
+    alone says so). A mesh that spans processes, or a hand-built batch left
+    on one device, keeps the host loop's path."""
+    return one_process_mesh(mesh) and rows_placed_over(batch, mesh, axis_name)
+
+
+def _one_device_block(a):
+    """The block of a sharded array that its first device holds (the array
+    itself where it has no shards to show: a shape under a deviceless
+    compile)."""
+    shards = getattr(a, "addressable_shards", None)
+    return shards[0].data if shards else a
 
 
 @dataclass(frozen=True)
@@ -142,8 +171,10 @@ class FixedEffectCoordinate:
         every descent visit (VERDICT r3 next-1b: the decision now reaches
         the GAME fixed effect, not just the legacy GLM driver). Returns
         None when the shard's layout is already the right one.
-        Single-device only — the tiled kernel is per-chip; under a mesh the
-        sharded solve keeps the row-sharded XLA path."""
+        One device only: under a mesh the rows stay as they were placed (a
+        dense shard row-sharded, which the fused visit's kernels read a
+        device's block of; a sparse one through ``sharded_minimize``'s own
+        layout decision)."""
         if self.mesh is not None:
             return None
         cached = getattr(self, "_layout_cached", False)
@@ -258,10 +289,11 @@ class FixedEffectCoordinate:
 
     def _degrade_blocker(self) -> str | None:
         """Why this coordinate CANNOT survive an in-place group shrink,
-        or None when it can. A mesh-spanning fixed-effect solve compiles
-        programs over the full device mesh — a dead process's devices
-        cannot leave a live mesh in-process, so the only honest answer
-        is the restart-from-checkpoint abort."""
+        or None when it can. Under a mesh the fixed effect's programs (the
+        host loop's ``sharded_minimize`` and the fused visit's ``shard_map``
+        alike) are compiled over the full device mesh — a dead process's
+        devices cannot leave a live mesh in-process, so the only honest
+        answer is the restart-from-checkpoint abort."""
         if self.mesh is not None:
             return (
                 f"fixed-effect coordinate {self.coordinate_id!r} solves "
@@ -283,10 +315,19 @@ class FixedEffectCoordinate:
         these for a single-coordinate launch; ``descent._build_fused_outer``
         chains every coordinate's ``apply`` into ONE program per outer
         iteration — and, through ``advance``, one program per CHUNK of
-        outer iterations."""
-        if self.mesh is not None or self.train_rows is not None:
-            # sharded solves stage host-side; down-sampling changes row
-            # sets per config — both keep the unfused path
+        outer iterations.
+
+        Under a mesh the parts are the same and ``apply`` runs inside
+        ``shard_map``: a device solves over its own block of rows and the
+        objective's partial sums meet in its ``psum``
+        (``fuses_under_mesh`` says when)."""
+        if self.train_rows is not None:
+            # down-sampling changes row sets per config: the unfused path
+            return None
+        if self.mesh is not None and not (
+            fuses_under_mesh(self.batch, self.mesh, self.axis_name)
+            and isinstance(self.batch.features[self.feature_shard_id], DenseFeatures)
+        ):
             return None
         base = self.__dict__.get("_visit_base")
         if base is None:
@@ -372,7 +413,11 @@ class FixedEffectCoordinate:
         l1 = opt.regularization.l1_weight(opt.regularization_weight)
         l2 = opt.regularization.l2_weight(opt.regularization_weight)
         minimize_fn, extra = select_minimize_fn(opt.optimizer, l1)
-        fused = auto_fused(base)
+        mesh, axis = self.mesh, self.axis_name
+        # under a mesh a device's kernels see its own block of rows
+        fused = auto_fused(
+            base if mesh is None else jax.tree.map(_one_device_block, base)
+        )
         prior = None
         if self.prior_model is not None:
             from photon_ml_tpu.ops.glm import GaussianPrior
@@ -385,7 +430,6 @@ class FixedEffectCoordinate:
             )
         norm = self.normalization
 
-        @jax.jit
         def run(base_batch, total, own_score, w0):
             import dataclasses as _dc
 
@@ -399,7 +443,7 @@ class FixedEffectCoordinate:
                 obj = make_objective(
                     train_batch, loss, l2_weight=l2, norm=norm,
                     intercept_index=self.intercept_index, prior=prior,
-                    fused=fused,
+                    fused=fused, axis_name=None if mesh is None else axis,
                 )
                 result = minimize_fn(obj, w0_n, opt.optimizer, **extra)
                 w = result.w
@@ -413,7 +457,15 @@ class FixedEffectCoordinate:
                 new_score = train_batch.matvec(w)
                 return w, variances, result, new_score, offsets + new_score
 
-        return run
+        if mesh is None:
+            return jax.jit(run)
+        # the partitioning written down: rows over the axis, coefficients
+        # and the tracker whole on every device
+        rows = P(axis)
+        return jax.jit(jax.shard_map(
+            run, mesh=mesh, in_specs=(rows, rows, rows, P()),
+            out_specs=(P(), P(), P(), rows, rows), check_vma=False,
+        ))
 
 
 @dataclass(frozen=True)
@@ -469,8 +521,6 @@ class RandomEffectCoordinate:
     def _features(self):
         feats = self.batch.features[self.feature_shard_id]
         if self.projector is not None:
-            from photon_ml_tpu.game.data import DenseFeatures
-
             if not isinstance(feats, DenseFeatures):
                 raise ValueError("random projection requires dense features")
             # cache the projected shard: it is static across descent
@@ -606,6 +656,7 @@ class RandomEffectCoordinate:
         for key in (
             "_prepared_cache", "_fusion_units_cache", "_visit_fn",
             "_features_cache", "_score_features_cache",
+            "_mesh_bucket_args_cache",
         ):
             self.__dict__.pop(key, None)
 
@@ -614,8 +665,9 @@ class RandomEffectCoordinate:
         (None = it can). Owned-bucket prep (``PHOTON_RE_SHARD=1`` under
         a mesh) degrades cleanly: buckets are staged whole per process
         and the combine is a host collective over the survivor mesh.
-        The legacy LANE-SHARDED prep spans the full device mesh — a
-        mesh cannot shrink in-process, so it keeps the abort."""
+        The LANE-SHARDED prep spans the full device mesh, in the host
+        loop's bucket steps and in the fused visit's ``shard_map`` alike —
+        a mesh cannot shrink in-process, so it keeps the abort."""
         if self.mesh is None:
             return None
         prepared = self.__dict__.get("_prepared_cache")
@@ -679,11 +731,16 @@ class RandomEffectCoordinate:
         return units
 
     def _fused_visit_parts(self):
-        """See ``FixedEffectCoordinate._fused_visit_parts``."""
-        if self.mesh is not None:
-            return None
+        """See ``FixedEffectCoordinate._fused_visit_parts``. Under a mesh
+        (``_fuses_lane_sharded`` says when) a device solves its own quarter
+        of every class's lanes inside ``shard_map``: the residual is made
+        whole on every device before the buckets read it, the solved lanes
+        are gathered and scattered into a coefficient matrix whole on every
+        device after, and each device scores its own rows."""
         from photon_ml_tpu.game.random_effect import compact_every, fuse_buckets
 
+        if self.mesh is not None and not self._fuses_lane_sharded():
+            return None
         if compact_every() > 0:
             # convergence-aware lane compaction (PHOTON_RE_COMPACT_EVERY)
             # snapshots per-lane done masks on host between chunks —
@@ -704,7 +761,7 @@ class RandomEffectCoordinate:
         bucket_args = tuple(
             (pb.static, pb.row_idx, pb.mask, pb.ids, pb.columns)
             for pb in self._prepared
-        )
+        ) if self.mesh is None else self._mesh_bucket_args()
         feats = self._features()
         if isinstance(feats, SparseFeatures):
             # scored nonzero-major inside the program; staged once
@@ -778,6 +835,61 @@ class RandomEffectCoordinate:
 
         return make_static, apply, postprocess, advance
 
+    def _fuses_lane_sharded(self) -> bool:
+        """Whether this coordinate's visit is traced into the descent's one
+        program under its mesh: ``fuses_under_mesh``, a dense shard solved
+        at its full width, and buckets lane-sharded by ``prepare_buckets``.
+        Owned-bucket placement (``PHOTON_RE_SHARD``, with its splits), a
+        sparse or projected shard, per-entity column maps and the
+        geometry-fusion knob keep the host loop's path under a mesh."""
+        from photon_ml_tpu.game.random_effect import fuse_buckets
+        from photon_ml_tpu.parallel.placement import re_shard_enabled
+
+        if not (
+            fuses_under_mesh(self.batch, self.mesh, self.axis_name)
+            and isinstance(self.batch.features[self.feature_shard_id], DenseFeatures)
+            and self.projector is None
+            and self.features_to_samples_ratio is None
+            and not fuse_buckets()
+        ):
+            return False
+        prepared = self.__dict__.get("_prepared_cache")
+        if prepared is None and re_shard_enabled():
+            return False  # before staging anything the visit would not use
+        return all(
+            pb.owner is None and pb.columns is None and pb.hash_S is None
+            for pb in self._prepared
+        )
+
+    def _mesh_bucket_args(self):
+        """The buckets as the mesh visit takes them: the staged tensors
+        beside each class's entity ids by LANE, ``(k_pad,)`` over the mesh
+        axis like the lanes themselves, a padded lane bearing
+        ``num_entities`` (one past the last row of the coefficient matrix:
+        read as a clamp, dropped by a scatter)."""
+        cached = self.__dict__.get("_mesh_bucket_args_cache")
+        if cached is None:
+            lanes = NamedSharding(self.mesh, P(self.axis_name))
+            cached = tuple(
+                (
+                    pb.static, pb.row_idx, pb.mask,
+                    jax.device_put(
+                        np.concatenate([
+                            np.asarray(pb.entity_ids, np.int32),
+                            np.full(
+                                pb.mask.shape[0] - pb.num_real,
+                                self.num_entities, np.int32,
+                            ),
+                        ]),
+                        lanes,
+                    ),
+                    None,
+                )
+                for pb in self._prepared
+            )
+            object.__setattr__(self, "_mesh_bucket_args_cache", cached)
+        return cached
+
     def visit(
         self, total: Array, own_score: Array | None,
         initial: GameSubModel | None = None,
@@ -822,8 +934,8 @@ class RandomEffectCoordinate:
                 prior_W = prior_W @ self.projector.matrix
                 prior_V = None
         prepared = self._prepared
+        mesh, axis = self.mesh, self.axis_name
 
-        @jax.jit
         def run(total, own_score, W0, bucket_args, feats, ids):
             import dataclasses as _dc
 
@@ -839,7 +951,17 @@ class RandomEffectCoordinate:
                     for pb, (s, ri, mk, bi, co) in zip(prepared, bucket_args)
                 ]
                 with stage(RE_OFFSETS):
-                    offsets = total - own_score
+                    offsets = residual = total - own_score
+                if mesh is not None:
+                    # a device's lanes read rows wherever they lie: the
+                    # residual whole on every device, in row order. Every
+                    # lane a device holds is solved (its padded lanes bear
+                    # an id no scatter takes)
+                    with stage(MESH_EXCHANGE):
+                        offsets = jax.lax.all_gather(residual, axis, tiled=True)
+                    prep = [
+                        _dc.replace(pb, num_real=pb.mask.shape[0]) for pb in prep
+                    ]
                 W, V, diag = _train_prepared_core(
                     prep,
                     offsets,
@@ -858,6 +980,21 @@ class RandomEffectCoordinate:
                     prior_coefficients=prior_W,
                     prior_variances=prior_V,
                 )
+                if mesh is not None:
+                    # the matrix whole on every device: the devices' solved
+                    # lanes, all of them, scattered by entity id. Copies
+                    # only, so a row is the bits its owner solved
+                    with stage(MESH_EXCHANGE):
+                        lane_ids = jnp.concatenate([pb.ids for pb in prep])
+                        every_id = jax.lax.all_gather(lane_ids, axis, tiled=True)
+
+                        def whole(M):
+                            own = M.at[lane_ids].get(mode="fill", fill_value=0.0)
+                            every = jax.lax.all_gather(own, axis, tiled=True)
+                            return M.at[every_id].set(every, mode="drop")
+
+                        W = whole(W)
+                        V = None if V is None else whole(V)
                 # scoring in the TRAINING subspace: (XP)w_p == X(P w_p), so the
                 # projected-space score equals the original-space model's
                 from photon_ml_tpu.game.random_effect import random_effect_scores
@@ -867,6 +1004,27 @@ class RandomEffectCoordinate:
                     safe_ids = jnp.where(in_range, ids, 0)
                     raw = random_effect_scores(feats, safe_ids, W)
                     new_score = jnp.where(in_range, raw, 0.0)
-                return W, V, diag, new_score, offsets + new_score
+                return W, V, diag, new_score, residual + new_score
 
-        return run
+        if mesh is None:
+            return jax.jit(run)
+        # the partitioning written down: rows and lanes over the axis, the
+        # coefficient matrix whole on every device
+        over = P(axis)
+        sharded = jax.shard_map(
+            run, mesh=mesh, in_specs=(over, over, P(), over, over, over),
+            out_specs=(P(), P(), over, over, over), check_vma=False,
+        )
+
+        @jax.jit
+        def run_mesh(total, own_score, W0, bucket_args, feats, ids):
+            W, V, diag, new_score, new_total = sharded(
+                total, own_score, W0, bucket_args, feats, ids
+            )
+            # a class's diagnostics come back a lane each, padded lanes last
+            diag = [
+                tuple(a[: pb.num_real] for a in d) for pb, d in zip(prepared, diag)
+            ]
+            return W, V, diag, new_score, new_total
+
+        return run_mesh

@@ -27,6 +27,7 @@ TPU-first notes:
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Mapping, Sequence
@@ -35,7 +36,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from photon_ml_tpu.obs.spans import GAME_BATCH, GAME_BUCKET, GAME_GROUP, spanned
+from photon_ml_tpu.obs.spans import (
+    GAME_BATCH,
+    GAME_BUCKET,
+    GAME_GROUP,
+    GAME_PLACE,
+    spanned,
+)
 from photon_ml_tpu.ops.batch import (
     Batch,
     DenseBatch,
@@ -148,7 +155,7 @@ Features = DenseFeatures | SparseFeatures
 @partial(
     jax.tree_util.register_dataclass,
     data_fields=["labels", "offsets", "weights", "features", "id_tags"],
-    meta_fields=[],
+    meta_fields=["padded_rows"],
 )
 @dataclass(frozen=True)
 class GameBatch:
@@ -165,10 +172,18 @@ class GameBatch:
     weights: Array
     features: dict[str, Features]
     id_tags: dict[str, Array]
+    # rows at the END that ``place_game_batch`` added to fill a mesh: weight,
+    # label, offset, features and entity id 0, so inert in every sum
+    padded_rows: int = field(default=0, metadata=dict(static=True))
 
     @property
     def num_rows(self) -> int:
         return self.labels.shape[0]
+
+    @property
+    def num_real_rows(self) -> int:
+        """The rows the caller gave: ``num_rows`` less ``padded_rows``."""
+        return self.num_rows - self.padded_rows
 
     def batch_for(self, shard_id: str, offsets: Array | None = None) -> Batch:
         """A ``Batch`` view for one coordinate: shard features + global
@@ -189,23 +204,123 @@ def make_game_batch(
     offsets: np.ndarray | None = None,
     weights: np.ndarray | None = None,
     dtype=jnp.float32,
+    mesh=None,
+    axis_name: str = "data",
 ) -> GameBatch:
     """Build a device GameBatch from host arrays. Dense 2-D feature arrays
-    become ``DenseFeatures``; prebuilt containers pass through."""
+    become ``DenseFeatures``; prebuilt containers pass through. With a
+    ``mesh`` the rows are placed over it once (``place_game_batch``) without
+    a whole column ever lying on one device; ``GameEstimator.fit`` places a
+    batch that came without (``placeable_over`` says which it can). A placed
+    batch is what lets the descent run fused under that mesh."""
+    # under a mesh a host column stays on the host until it is placed: put
+    # on the default device first it would be the whole column on one chip
+    col = jnp.asarray if mesh is None else _host_column
+    zeros, ones = (jnp.zeros, jnp.ones) if mesh is None else (np.zeros, np.ones)
     n = len(labels)
     feats: dict[str, Features] = {}
     for sid, f in features.items():
         if isinstance(f, (DenseFeatures, SparseFeatures)):
             feats[sid] = f
         else:
-            feats[sid] = DenseFeatures(X=jnp.asarray(f, dtype))
-    return GameBatch(
-        labels=jnp.asarray(labels, dtype),
-        offsets=jnp.zeros((n,), dtype) if offsets is None else jnp.asarray(offsets, dtype),
-        weights=jnp.ones((n,), dtype) if weights is None else jnp.asarray(weights, dtype),
+            feats[sid] = DenseFeatures(X=col(f, dtype))
+    batch = GameBatch(
+        labels=col(labels, dtype),
+        offsets=zeros((n,), dtype) if offsets is None else col(offsets, dtype),
+        weights=ones((n,), dtype) if weights is None else col(weights, dtype),
         features=feats,
-        id_tags={k: jnp.asarray(v, jnp.int32) for k, v in (id_tags or {}).items()},
+        id_tags={k: col(v, jnp.int32) for k, v in (id_tags or {}).items()},
     )
+    return batch if mesh is None else place_game_batch(batch, mesh, axis_name)
+
+
+def _host_column(a, dtype):
+    if isinstance(a, jax.Array):
+        return a if a.dtype == dtype else a.astype(dtype)
+    return np.asarray(a, dtype)
+
+
+def one_process_mesh(mesh) -> bool:
+    """Whether every device of ``mesh`` is this process's own (one host,
+    fully addressable arrays)."""
+    me = jax.process_index()
+    return all(d.process_index == me for d in mesh.devices.flat)
+
+
+def placeable_over(batch: GameBatch, mesh) -> bool:
+    """Whether ``place_game_batch`` takes ``batch`` over ``mesh``: a mesh of
+    this process's own devices and dense shards only. ``GameEstimator.fit``
+    and ``AvroDataReader.read`` place where this holds, so a mesh alone
+    chooses the fused descent; a mesh that spans processes or a sparse
+    shard keeps the host loop's per-visit staging."""
+    return one_process_mesh(mesh) and all(
+        isinstance(f, DenseFeatures) for f in batch.features.values()
+    )
+
+
+def rows_placed_over(batch: GameBatch, mesh, axis_name: str = "data") -> bool:
+    """Whether every per-row array of ``batch`` already lies row-sharded
+    over ``mesh``'s ``axis_name`` (``place_game_batch`` left it so)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    want = NamedSharding(mesh, P(axis_name))
+    return all(
+        isinstance(a, jax.Array)
+        and not isinstance(a, jax.core.Tracer)
+        and a.sharding.is_equivalent_to(want, a.ndim)
+        for a in jax.tree.leaves(batch)
+    )
+
+
+@spanned(GAME_PLACE)
+def place_game_batch(batch: GameBatch, mesh, axis_name: str = "data") -> GameBatch:
+    """``batch`` with its rows over ``mesh``'s ``axis_name``, placed ONCE:
+    every per-row array (labels, offsets, weights, each dense shard's
+    matrix, each id column) becomes one array row-sharded ``P(axis_name)``,
+    a device holding one contiguous block of rows. Where the row count does
+    not divide by the devices, rows are added at the end up to the next
+    multiple: weight 0, label 0, offset 0, features 0 and entity id 0, so
+    they are inert in every sum, score 0, and belong after the real rows
+    (``batch.num_rows`` then counts them and ``batch.padded_rows`` says how
+    many they are; entities are grouped over the ``num_real_rows`` before
+    them, which keep their row numbers). Arrays that already lie so are left where
+    they are. Dense shards only: a sparse shard's rows keep the path they
+    have (``parallel/distributed.sharded_minimize`` shards them a visit).
+    """
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from photon_ml_tpu.obs.metrics import REGISTRY
+
+    for sid, f in batch.features.items():
+        if not isinstance(f, DenseFeatures):
+            raise NotImplementedError(
+                f"place_game_batch: shard {sid!r} is not dense; only dense "
+                "shards are placed over a mesh"
+            )
+    n_dev = mesh.shape[axis_name]
+    n = batch.num_rows
+    target = -(-n // n_dev) * n_dev
+    sharding = NamedSharding(mesh, P(axis_name))
+
+    def place(a):
+        if isinstance(a, jax.Array) and a.sharding.is_equivalent_to(sharding, a.ndim):
+            return a
+        if target != n:
+            # on the host: a padded copy on one device would be the whole
+            # array on one device
+            a = np.asarray(a)
+            a = np.concatenate(
+                [a, np.zeros((target - n,) + a.shape[1:], a.dtype)]
+            )
+        return jax.device_put(a, sharding)
+
+    out = dataclasses.replace(
+        jax.tree.map(place, batch), padded_rows=batch.padded_rows + target - n
+    )
+    REGISTRY.gauge_set(
+        "mesh.batch_devices", float(len(out.labels.sharding.device_set))
+    )
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -771,6 +886,23 @@ def gather_bucket(
     of each row's entity's map, ``EntityIndexMap.local``) and the bucket
     comes back as lanes of ``LocalSparseBatch`` at width p.
     """
+    return jax.tree.map(
+        jnp.asarray,
+        gather_bucket_host(features, labels, offsets, weights, row_indices, columns),
+    )
+
+
+def gather_bucket_host(
+    features: Features,
+    labels: np.ndarray,
+    offsets: np.ndarray,
+    weights: np.ndarray,
+    row_indices: np.ndarray,
+    columns: np.ndarray | None = None,
+) -> Batch:
+    """``gather_bucket`` with its arrays still on the host (numpy leaves in
+    the same containers): what ``prepare_buckets`` pads and then puts where
+    the lanes belong, a mesh's devices a slice each."""
     idx = np.maximum(row_indices, 0)
     mask = (row_indices >= 0).astype(np.float32)
     lab = np.asarray(labels)[idx] * mask
@@ -780,31 +912,24 @@ def gather_bucket(
         X = np.asarray(features.X)[idx] * mask[:, :, None]  # (k, C, d)
         if columns is not None:
             X = np.take_along_axis(X, columns[:, None, :], axis=2)
-        return DenseBatch(
-            X=jnp.asarray(X),
-            labels=jnp.asarray(lab),
-            offsets=jnp.asarray(off),
-            weights=jnp.asarray(wgt),
-        )
+        return DenseBatch(X=X, labels=lab, offsets=off, weights=wgt)
     ind = np.asarray(features.indices)[idx]  # (k, C, nnz)
     val = np.asarray(features.values)[idx] * mask[..., None]
     if columns is not None:
         k = len(row_indices)
         return LocalSparseBatch(
-            indices=jnp.asarray(
-                np.where(val != 0, ind, 0).reshape(k, -1), jnp.int32
-            ),
-            values=jnp.asarray(val.reshape(k, -1)),
-            labels=jnp.asarray(lab),
-            offsets=jnp.asarray(off),
-            weights=jnp.asarray(wgt),
+            indices=np.where(val != 0, ind, 0).reshape(k, -1).astype(np.int32),
+            values=val.reshape(k, -1),
+            labels=lab,
+            offsets=off,
+            weights=wgt,
             num_features=int(columns.shape[1]),
         )
     return SparseBatch(
-        indices=jnp.asarray(ind),
-        values=jnp.asarray(val),
-        labels=jnp.asarray(lab),
-        offsets=jnp.asarray(off),
-        weights=jnp.asarray(wgt),
+        indices=ind,
+        values=val,
+        labels=lab,
+        offsets=off,
+        weights=wgt,
         num_features=features.num_features,
     )

@@ -51,8 +51,14 @@ def _build_fused_outer(coordinates: Mapping[str, Any], seq: Sequence[str]):
     next visit's warm start, exactly as the host loop does through the
     model objects). Returns a host callable ``run_outer(model, total,
     scores, r, record) -> (model, total, scores, trackers_by_cid_per_iter)``, or
-    None when any coordinate needs host-side staging per visit
-    (mesh-sharded, per-visit down-sampling).
+    None when any coordinate needs host-side staging per visit (per-visit
+    down-sampling; under a mesh, one that spans processes, a batch not
+    placed over it, owned-bucket placement: ``game/coordinate``'s gates).
+
+    Under a mesh the program is the same chain: each coordinate's ``apply``
+    is a ``shard_map`` over the mesh with its partitioning written down, the
+    total and every coordinate's score stay row-sharded through the scan,
+    and the coefficients are whole on every device.
 
     Why: each program launch costs fixed latency on remote-attached
     accelerators; per-visit fusion pays K launches per outer iteration,
@@ -285,13 +291,15 @@ class CoordinateDescent:
     @staticmethod
     def _raise_if_peer_lost(e: BaseException, checkpoint_dir) -> None:
         """The in-memory descent cannot shrink its world mid-run — every
-        compiled program spans the FULL device mesh, so a lost process
-        invalidates the executables themselves (unlike the streamed
-        trainer, whose host-side exchanges re-plan around the survivor
-        set). What it CAN do is turn the 300 s-timeout stack into an
-        actionable, telemetry-visible instruction: restart the job on
-        the surviving hosts and resume from the per-iteration
-        checkpoint this class already writes."""
+        compiled program spans the FULL device mesh (the host loop's
+        per-visit programs over a mesh that spans processes; the fused
+        outer iteration only ever over one process's own devices, where no
+        peer can be lost), so a lost process invalidates the executables
+        themselves (unlike the streamed trainer, whose host-side exchanges
+        re-plan around the survivor set). What it CAN do is turn the 300
+        s-timeout stack into an actionable, telemetry-visible instruction:
+        restart the job on the surviving hosts and resume from the
+        per-iteration checkpoint this class already writes."""
         from photon_ml_tpu.parallel.multihost import PeerLost
 
         if not isinstance(e, PeerLost):

@@ -38,7 +38,8 @@ from photon_ml_tpu.game.data import (
     NonzeroMajorSparseFeatures,
     SparseFeatures,
     class_buckets_by_width,
-    gather_bucket,
+    gather_bucket_host,
+    one_process_mesh,
 )
 from photon_ml_tpu.obs.stages import RE_OFFSETS, RE_SOLVE, RE_SUBSPACE, stage
 from photon_ml_tpu.ops import fused as kernels
@@ -513,6 +514,8 @@ def prepare_buckets(
 
     own_pid = effective_process_index()
     zeros_off = np.zeros_like(np.asarray(labels))
+    # real bucket rows a device of the lane mesh holds, over every class
+    mesh_rows = None
     starts = [_run_starts(r) for r in buckets.row_indices]
     if parents is not None:
         # the sub-buckets of one parent are concatenated again: one form a parent
@@ -523,6 +526,24 @@ def prepare_buckets(
         zip(buckets.entity_ids, buckets.row_indices)
     ):
         k = len(ent_ids)
+        run_starts = starts[bi]
+        if n_dev > 1 and one_process_mesh(mesh):
+            # on one host's mesh (the fused visit's; a mesh that spans
+            # processes keeps the id order its drills pin bit for bit):
+            # which device solves an entity follows where its rows lie, not
+            # the id it bears: a class's lanes in the order of their first
+            # rows, DEALT over the devices (lane i of that order to device
+            # i mod n). Relabelled entities then keep their device (the
+            # devices meet at every exchange, so a visit waits for the
+            # slowest, and which is slowest must not turn on a label), and
+            # the deal evens what the order would pile up: a file sorted by
+            # entity size, or an entity's first row lying the earlier the
+            # more rows it has, puts a class's fullest lanes side by side
+            order = np.argsort(row_idx[:, 0], kind="stable")
+            order = np.concatenate([order[j::n_dev] for j in range(n_dev)])
+            ent_ids, row_idx = ent_ids[order], row_idx[order]
+            if run_starts is not None:
+                run_starts = run_starts[order]
         parent = None if parents is None else int(parents[bi])
         spec = None if ladder is None else ladder.get(int(row_idx.shape[1]))
         if owners is not None and owners[bi] != own_pid:
@@ -543,15 +564,18 @@ def prepare_buckets(
                 lane_multiple = subspace_chunk_lanes(
                     row_idx.shape[1], cols.shape[1], k
                 )
-        static = gather_bucket(
+        # on the host until the end: the class is padded there and each
+        # leaf then goes where its lanes belong, a mesh's devices a slice
+        # each, so that no device ever holds a whole class
+        static = gather_bucket_host(
             features, labels, zeros_off, weights, row_idx, columns=cols
         )
-        runs = starts[bi] is not None
+        runs = run_starts is not None
         REGISTRY.counter_inc("re_offsets.slots", float(row_idx.size))
         REGISTRY.counter_inc("re_offsets.run_slots", float(row_idx.size) if runs else 0.0)
-        idx = jnp.asarray(starts[bi] if runs else np.maximum(row_idx, 0), jnp.int32)
-        mask = jnp.asarray((row_idx >= 0).astype(np.float32))
-        columns = None if cols is None else jnp.asarray(cols)
+        idx = np.asarray(run_starts if runs else np.maximum(row_idx, 0), np.int32)
+        mask = (row_idx >= 0).astype(np.float32)
+        columns = cols
         hash_S = None
         if spec is not None and isinstance(static, DenseBatch):
             # gather the static features to the class support (the same
@@ -564,60 +588,48 @@ def prepare_buckets(
             cols = np.broadcast_to(
                 spec.columns, (k, spec.support_dim)
             )  # (k, d_e) — identical rows; intercept (=d-1) at d_e-1
-            Xs = np.take_along_axis(
-                np.asarray(static.X), cols[:, None, :], axis=2
-            )  # (k, C, d_e)
+            Xs = np.take_along_axis(static.X, cols[:, None, :], axis=2)  # (k, C, d_e)
             if spec.hash_dim is not None:
                 S = spec.hash_matrix()  # (d_e, m) dense signed fold
                 Xs = Xs.astype(np.float32) @ S  # (k, C, m)
                 hash_S = jnp.asarray(S)
-            static = DenseBatch(
-                X=jnp.asarray(Xs),
-                labels=static.labels,
-                offsets=static.offsets,
-                weights=static.weights,
-            )
-            columns = jnp.asarray(cols, jnp.int32)
+            static = dataclasses.replace(static, X=Xs)
+            columns = np.asarray(cols, np.int32)
         if (
             features_to_samples_ratio is not None
             and isinstance(static, DenseBatch)
         ):
             cols = subspace_columns(
-                np.asarray(static.X), features_to_samples_ratio,
-                intercept_index,
+                static.X, features_to_samples_ratio, intercept_index,
             )  # (k, p) sorted ascending → intercept (=d-1) lands at p-1
             if cols is not None:
-                Xp = np.take_along_axis(
-                    np.asarray(static.X), cols[:, None, :], axis=2
-                )  # (k, C, p)
-                static = DenseBatch(
-                    X=jnp.asarray(Xp),
-                    labels=static.labels,
-                    offsets=static.offsets,
-                    weights=static.weights,
+                static = dataclasses.replace(
+                    static,
+                    X=np.take_along_axis(static.X, cols[:, None, :], axis=2),  # (k, C, p)
                 )
-                columns = jnp.asarray(cols, jnp.int32)
+                columns = np.asarray(cols, np.int32)
         if lane_multiple > 1:
             k_pad = _pad_rows(k, lane_multiple)
             if k_pad != k:
-                pad = k_pad - k
-                pad0 = lambda a: jnp.concatenate(
-                    [a, jnp.zeros((pad,) + a.shape[1:], a.dtype)]
+                pad0 = lambda a: np.concatenate(
+                    [a, np.zeros((k_pad - k,) + a.shape[1:], a.dtype)]
                 )
                 static = jax.tree.map(pad0, static)
                 idx, mask = pad0(idx), pad0(mask)
-            if columns is not None and columns.shape[0] != static.labels.shape[0]:
-                pad = static.labels.shape[0] - columns.shape[0]
-                columns = jnp.concatenate(
-                    [columns, jnp.zeros((pad, columns.shape[1]), columns.dtype)]
-                )
+                if columns is not None:
+                    columns = pad0(columns)
         if n_dev > 1:
-            sharding = NamedSharding(mesh, P(axis_name))
-            static = jax.tree.map(lambda a: jax.device_put(a, sharding), static)
-            idx = jax.device_put(idx, sharding)
-            mask = jax.device_put(mask, sharding)
-            if columns is not None:
-                columns = jax.device_put(columns, sharding)
+            put = partial(jax.device_put, device=NamedSharding(mesh, P(axis_name)))
+            per_chip = (mask.reshape(n_dev, -1) != 0).sum(axis=1)
+            mesh_rows = per_chip if mesh_rows is None else mesh_rows + per_chip
+            REGISTRY.counter_inc("re_mesh.lanes", float(k))
+            REGISTRY.counter_inc("re_mesh.padded_lanes", float(len(mask)))
+        else:
+            put = jnp.asarray
+        static = jax.tree.map(put, static)
+        idx, mask = put(idx), put(mask)
+        if columns is not None:
+            columns = put(columns)
         ids = jnp.asarray(ent_ids, jnp.int32)
         dev = None
         if devices is not None and int(devices[bi]) >= 0:
@@ -647,6 +659,10 @@ def prepare_buckets(
                 hash_S=hash_S,
             )
         )
+    if mesh_rows is not None:
+        # what cutting every class's lanes by the mesh leaves uneven
+        REGISTRY.counter_inc("re_mesh.rows_max_chip", float(mesh_rows.max()))
+        REGISTRY.counter_inc("re_mesh.rows_mean_chip", float(mesh_rows.mean()))
     if ladder is not None:
         d_full = int(features.num_features)
         record_projection_metrics(

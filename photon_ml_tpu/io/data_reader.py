@@ -30,6 +30,7 @@ from photon_ml_tpu.game.data import (
     GameBatch,
     SparseFeatures,
     make_game_batch,
+    one_process_mesh,
 )
 from photon_ml_tpu.io.avro import iter_avro_directory
 
@@ -427,8 +428,14 @@ class AvroDataReader:
         extend_entities: bool = False,
         dtype=np.float32,
         use_native: bool = True,
+        mesh=None,
     ) -> GameDataset:
         """Read records → GameDataset.
+
+        ``mesh``: the mesh the batch will train under. Where its rows can be
+        placed over it (``_placing``) the columns go from the host to a
+        device's block of rows each and no device ever holds a whole one;
+        ``dataset.batch.padded_rows`` then counts the rows added to fill it.
 
         ``index_maps`` / ``entity_maps``: pass the training-time maps when
         reading validation/scoring data so columns and entity ids line up
@@ -445,7 +452,8 @@ class AvroDataReader:
         paths = [path] if isinstance(path, str) else list(path)
         if use_native:
             ds = self._read_native(
-                paths, id_tags, index_maps, entity_maps, extend_entities, dtype
+                paths, id_tags, index_maps, entity_maps, extend_entities, dtype,
+                mesh,
             )
             if ds is not None:
                 return ds
@@ -510,9 +518,14 @@ class AvroDataReader:
                 if cfg.has_intercept:
                     out.append((imap.intercept_index, 1.0))
 
+        mesh = _placing(
+            mesh, [index_maps[sid].size for sid in self.feature_shards]
+        )
         features: dict[str, Features] = {}
         for sid in self.feature_shards:
-            features[sid] = _build_features(rows[sid], index_maps[sid].size, dtype)
+            features[sid] = _build_features(
+                rows[sid], index_maps[sid].size, dtype, host=mesh is not None
+            )
 
         batch = make_game_batch(
             labels,
@@ -520,6 +533,7 @@ class AvroDataReader:
             id_tags={t: ids[t] for t in id_tags},
             offsets=offsets,
             weights=weights,
+            mesh=mesh,
         )
         return GameDataset(
             batch=batch,
@@ -539,6 +553,7 @@ class AvroDataReader:
         entity_maps: Mapping[str, Mapping[str, int]] | None,
         extend_entities: bool,
         dtype,
+        mesh=None,
     ) -> GameDataset | None:
         """The C++ columnar decode path; None when unavailable/unsupported
         (caller falls back to the Python codec). Produces the same
@@ -623,6 +638,9 @@ class AvroDataReader:
             raise ValueError(f"record {missing[0]} missing id tag {missing[1]!r}")
 
         # ---- per-shard features ----
+        mesh = _placing(
+            mesh, [index_maps[sid].size for sid in self.feature_shards]
+        )
         features: dict[str, Features] = {}
         for sid, cfg in self.feature_shards.items():
             imap = index_maps[sid]
@@ -668,7 +686,8 @@ class AvroDataReader:
                 )
                 rows, colv, vals = rows[order], colv[order], vals[order]
             features[sid] = _build_features_arrays(
-                rows, colv, vals, n, index_maps[sid].size, dtype
+                rows, colv, vals, n, index_maps[sid].size, dtype,
+                host=mesh is not None,
             )
 
         batch = make_game_batch(
@@ -677,6 +696,7 @@ class AvroDataReader:
             id_tags={t: ids_out[t] for t in id_tags},
             offsets=offsets,
             weights=weights,
+            mesh=mesh,
         )
         return GameDataset(
             batch=batch,
@@ -1118,6 +1138,7 @@ def _build_features_arrays(
     n: int,
     d: int,
     dtype,
+    host: bool = False,
 ) -> Features:
     """Vectorized twin of ``_build_features`` for the native COO stream
     (same densify threshold, same duplicate/padding semantics)."""
@@ -1126,7 +1147,7 @@ def _build_features_arrays(
     if d <= _DENSE_THRESHOLD:
         X = np.zeros((n, d), dtype)
         np.add.at(X, (rows, cols), vals.astype(dtype))
-        return DenseFeatures(X=jnp.asarray(X))
+        return DenseFeatures(X=X if host else jnp.asarray(X))
     counts = np.bincount(rows, minlength=n)
     k = max(int(counts.max()) if n else 1, 1)
     rowptr = np.concatenate([[0], np.cumsum(counts)])
@@ -1140,8 +1161,21 @@ def _build_features_arrays(
     )
 
 
+def _placing(mesh, widths: Sequence[int]):
+    """``mesh`` where the batch a read builds can be placed over it
+    (``game/data.placeable_over``, decided before a column is built: one
+    process's own devices, every shard narrow enough to be dense), else
+    None. A dense shard then stays on the host (``host=True`` below) until
+    ``make_game_batch`` puts a device's rows on that device."""
+    if mesh is None or not one_process_mesh(mesh):
+        return None
+    if any(d > _DENSE_THRESHOLD for d in widths):
+        return None
+    return mesh
+
+
 def _build_features(
-    row_pairs: list[list[tuple[int, float]]], d: int, dtype
+    row_pairs: list[list[tuple[int, float]]], d: int, dtype, host: bool = False
 ) -> Features:
     import jax.numpy as jnp
 
@@ -1151,7 +1185,7 @@ def _build_features(
         for i, pairs in enumerate(row_pairs):
             for j, v in pairs:
                 X[i, j] += v
-        return DenseFeatures(X=jnp.asarray(X))
+        return DenseFeatures(X=X if host else jnp.asarray(X))
     k = max((len(p) for p in row_pairs), default=1) or 1
     indices = np.zeros((n, k), np.int32)
     values = np.zeros((n, k), dtype)

@@ -416,6 +416,27 @@ def summarize_run(path: str, records: list[dict] | None = None) -> dict:
             "run_slots": run_slots,
             "run_slot_share": run_slots / slots if slots > 0 else None,
         }
+    # random-effect lanes cut over a mesh (re_mesh.*, game/random_effect.
+    # prepare_buckets where it shards lanes): the lanes that hold an entity
+    # and the lanes after every class is padded to a multiple of the mesh;
+    # the real bucket rows on the fullest device and on the mean device,
+    # each effect's summed. Present only on runs that lane-sharded a prep.
+    if "re_mesh.lanes" in counters or "re_mesh.lanes" in base_counters:
+        lanes = counter_v("re_mesh.lanes")
+        mean_rows = counter_v("re_mesh.rows_mean_chip")
+        out["re_mesh"] = {
+            "lanes": lanes,
+            "padded_lanes": counter_v("re_mesh.padded_lanes"),
+            "lane_pad_ratio": (
+                counter_v("re_mesh.padded_lanes") / lanes if lanes > 0 else None
+            ),
+            "rows_max_chip": counter_v("re_mesh.rows_max_chip"),
+            "rows_mean_chip": mean_rows,
+            "row_imbalance": (
+                counter_v("re_mesh.rows_max_chip") / mean_rows
+                if mean_rows > 0 else None
+            ),
+        }
     # tile-COO layout builds (tile_layout.*, ops/sparse_tiled.
     # tile_sparse_batch): the stored nonzeros each build left to the
     # kernels' streams (the tail) and those it moved into the dense head of
@@ -732,6 +753,15 @@ def format_summary(s: dict) -> str:
             f"  re-offsets: {_fmt_qty(ofs['slots'])} bucket slots, "
             f"{_fmt_qty(ofs['run_slots'])} "
             f"({100.0 * ofs['run_slot_share']:.1f}%) read by run-start slices"
+        )
+    lanes = s.get("re_mesh") or {}
+    if lanes.get("lane_pad_ratio") is not None:
+        lines.append(
+            f"  re-mesh: {_fmt_qty(lanes['lanes'])} entity lanes padded "
+            f"{lanes['lane_pad_ratio']:.3f}x over the mesh"
+            + (f", the fullest device holds {lanes['row_imbalance']:.3f}x "
+               "the mean of the bucket rows"
+               if lanes.get("row_imbalance") is not None else "")
         )
     til = s.get("tile_layout") or {}
     if til.get("head_nonzero_share") is not None:

@@ -47,7 +47,8 @@ name) once a sink is on; what ELSE reads it:
 
 - the constants below, by the benchmark (``benchmark/host_spans.py``; the
   table span -> site -> metric is in PERF.md section 3): ``game/batch``,
-  ``game/group``, ``game/bucket``, ``coordinate/fixed``,
+  ``game/place`` (PR 38: a batch's rows padded and put over a mesh, inside
+  ``game/batch`` where ``make_game_batch`` is given the mesh), ``game/group``, ``game/bucket``, ``coordinate/fixed``,
   ``coordinate/random-effect``, ``descent/run`` / ``prepare`` / ``launch`` /
   ``collect``, ``layout/optimize`` / ``to-host`` / ``fingerprint`` /
   ``head`` / ``merge`` / ``pack`` / ``stage``, ``glm/train``,
@@ -89,6 +90,7 @@ from photon_ml_tpu.obs.metrics import REGISTRY
 
 # -- the names a metric reads ----------------------------------------------
 GAME_BATCH = "game/batch"  # game/data.make_game_batch: host arrays to the device
+GAME_PLACE = "game/place"  # game/data.place_game_batch: the rows over a mesh, once
 GAME_GROUP = "game/group"  # game/data.group_by_entity: the ingest-time shuffle
 GAME_BUCKET = "game/bucket"  # game/data.bucket_entities: padded row-index matrices
 COORD_FIXED = "coordinate/fixed"  # the fixed effect's base batch, layout, visit fn
@@ -118,7 +120,7 @@ DISTRIBUTED_TRAIN = "distributed/train"  # DistributedTrainer.train
 # outermost open span of the thread counts as top-level (``span_top``).
 TOP_LEVEL = (
     GAME_BATCH, GAME_GROUP, GAME_BUCKET, DESCENT_RUN, LAYOUT_OPTIMIZE,
-    GLM_TRAIN, DISTRIBUTED_TRAIN,
+    GLM_TRAIN, DISTRIBUTED_TRAIN, GAME_PLACE,
 )
 
 # registry timer prefixes (see the module docstring)

@@ -45,6 +45,9 @@ LBFGS_UPDATE = "lbfgs.update"  # acceptance, ring buffers, next state
 TRON_CG = "tron.cg"  # truncated CG's dots, axpys and boundary step (optim/tron)
 TRON_UPDATE = "tron.update"  # acceptance, trust radius, histories, next state
 NEWTON_SOLVE = "newton.solve"  # factorisation and step (optim/newton)
+# under a mesh: the residual's and the coefficients' all-gathers, and the
+# copies that exist only to feed them (game/coordinate's mesh visit)
+MESH_EXCHANGE = "mesh.exchange"
 COORD_PREFIX = "coord."  # + the coordinate id: which coordinate's visit
 
 # Hashed into the persistent compile cache's key by ``utils/compile_cache``.
@@ -52,7 +55,7 @@ COORD_PREFIX = "coord."  # + the coordinate id: which coordinate's visit
 # cache with the names it was compiled with: after a scope moves with no
 # instruction changing, a warm cache would keep serving the old names to
 # every profile. Raise this when a site or a name of this module changes.
-VERSION = 5
+VERSION = 6
 
 _NOT_SEGMENT = re.compile(r"[^A-Za-z0-9_.\-]")
 

@@ -47,8 +47,12 @@ TRON_FIT_STAGES = ("glm.objective", "glm.hvp", "tron.cg", "tron.update")
 # ``sparse_descent_kernels``: the same with lanes long enough (512 rows of
 # 1,024 columns) for ``ops/fused``'s row-major float32 kernel, traced under
 # ``lanes_take_the_kernel``
+# ``mesh_descent``: the same descent with its rows placed over the mesh handed
+# in: both visits under ``shard_map``, the random effect's exchanges named
+MESH_DESCENT_STAGES = DESCENT_STAGES + ("mesh.exchange",)
 PROGRAM_STAGES = {
-    "descent": DESCENT_STAGES, "tile_fit": TILE_FIT_STAGES,
+    "descent": DESCENT_STAGES, "mesh_descent": MESH_DESCENT_STAGES,
+    "tile_fit": TILE_FIT_STAGES,
     "sharded": FIT_STAGES,
     "sparse_descent": SPARSE_DESCENT_STAGES,
     "sparse_descent_kernels": SPARSE_DESCENT_STAGES,
@@ -57,9 +61,10 @@ PROGRAM_STAGES = {
 
 
 def descent_coordinates(n=256, d=5, entities=12, seed=0, sparse=False,
-                        columns=300):
+                        columns=300, mesh=None):
     """``sparse``: the random effect's shard is ``columns`` wide with 4
-    nonzeros a row, and its entities are trained by L-BFGS."""
+    nonzeros a row, and its entities are trained by L-BFGS. ``mesh``: the
+    batch's rows are placed over it and the coordinates are handed it."""
     from photon_ml_tpu.config import (
         OptimizationConfig,
         OptimizerConfig,
@@ -91,7 +96,7 @@ def descent_coordinates(n=256, d=5, entities=12, seed=0, sparse=False,
         y,
         {"global": DenseFeatures(X=rng.normal(size=(n, d + 1)).astype(np.float32)),
          "per_user": per_user},
-        id_tags={"user": ids},
+        id_tags={"user": ids}, mesh=mesh,
     )
     re_optimizer = OptimizerType.LBFGS if sparse else OptimizerType.NEWTON_CHOLESKY
 
@@ -111,13 +116,14 @@ def descent_coordinates(n=256, d=5, entities=12, seed=0, sparse=False,
         fixed: FixedEffectCoordinate(
             coordinate_id=fixed, batch=batch, feature_shard_id="global",
             config=opt(OptimizerType.LBFGS), task_type=task, intercept_index=d,
+            mesh=mesh,
         ),
         per_user: RandomEffectCoordinate(
             coordinate_id=per_user, batch=batch, feature_shard_id="per_user",
             random_effect_type="user",
             config=opt(re_optimizer), grouping=grouping,
             buckets=bucket_entities(grouping), task_type=task,
-            num_entities=entities,
+            num_entities=entities, mesh=mesh,
         ),
     }
     return coordinates, batch, task
@@ -135,7 +141,7 @@ def descent_program(sparse=False, **size):
         cell.cell_contents for cell in run_outer.__closure__
         if getattr(cell.cell_contents, "__name__", "") == "fused"
     )
-    total = jnp.zeros((batch.labels.shape[0],), jnp.float32)
+    total = jnp.zeros_like(batch.offsets)  # over the mesh where the batch is
     owns = tuple(jnp.zeros_like(total) for _ in seq)
     statics = tuple(coordinates[c]._fused_visit_parts()[0](None) for c in seq)
     return fused, (total, owns, statics), {"r": 1}
@@ -241,6 +247,8 @@ def build(name: str, mesh):
     solve's."""
     if name == "sharded":
         return sharded_program(mesh)
+    if name == "mesh_descent":
+        return descent_program(mesh=mesh)
     if name == "sparse_descent":
         return descent_program(sparse=True)
     if name == "sparse_descent_kernels":

@@ -633,3 +633,101 @@ def test_sparse_random_effect_visit_fits_the_chip_at_the_benchmarks_cut(
         "/re.solve/" in path and "/re.sparse_pass/" in path
         for path in _kernel_paths(text)
     )
+
+
+# The capacity classes (capacity, entities) of the per-item effect of
+# ``glmix_ml20m_full`` (20,000,263 rows, 26,744 items), as ``bucket_entities``
+# builds them from ``benchmark/datagen_glmix_mesh.id_columns`` (counted on the
+# CPU, PR 38): the effect whose rows lie all over the file
+ML20M_FULL_ITEM_CLASSES = (
+    (256, 12452), (512, 5219), (1024, 4274), (2048, 2719), (4096, 1351),
+    (8192, 524), (32768, 197), (131072, 8),
+)
+
+
+def test_mesh_visit_of_the_whole_item_effect_fits_a_chip_of_four(
+    topo, monkeypatch, request
+):
+    """The per-item visit of ``ml20m_full_descent4`` under ``shard_map`` for
+    the 2x2 mesh, at the whole size: a chip holds a quarter of every class's
+    lanes and 5,000,066 rows, the residual is made whole (one all-gather to
+    f32[20000264], 80 MB) and the solved lanes are gathered; no per-row
+    matrix is ever the whole batch's. The visit asked 5.81 GB a chip when this
+    was written (5.14 of it scratch); one replicated ``(rows, 8)`` operand is
+    10.2 GB more."""
+    from photon_ml_tpu.config import (
+        OptimizationConfig,
+        OptimizerConfig,
+        RegularizationContext,
+    )
+    from photon_ml_tpu.game import DenseFeatures, RandomEffectCoordinate
+    from photon_ml_tpu.game import coordinate as coordinate_module
+    from photon_ml_tpu.game.data import EntityBuckets, EntityGrouping, GameBatch
+    from photon_ml_tpu.game.random_effect import PreparedBucket
+    from photon_ml_tpu.ops.batch import DenseBatch
+    from photon_ml_tpu.types import OptimizerType, RegularizationType
+
+    monkeypatch.setattr(coordinate_module, "fuses_under_mesh", lambda *a: True)
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    rows, entities, width = 20_000_264, 26_744, 8
+    over, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    f32, i32 = jnp.float32, jnp.int32
+    row = lambda shape, dt=f32: jax.ShapeDtypeStruct(shape, dt, sharding=over)
+    prepared, bucket_args = [], []
+    for capacity, k in ML20M_FULL_ITEM_CLASSES:
+        lanes = -(-k // 4) * 4
+        static = DenseBatch(
+            X=row((lanes, capacity, width)), labels=row((lanes, capacity)),
+            offsets=row((lanes, capacity)), weights=row((lanes, capacity)),
+        )
+        slots = row((lanes, capacity), i32)
+        prepared.append(PreparedBucket(
+            entity_ids=np.zeros(k, np.int64), ids=None, static=static,
+            row_idx=slots, mask=row((lanes, capacity)), num_real=k,
+        ))
+        bucket_args.append(
+            (static, slots, row((lanes, capacity)), row((lanes,), i32), None)
+        )
+    shard = DenseFeatures(X=row((rows, width)))
+    coordinate = RandomEffectCoordinate(
+        coordinate_id="per_itemId",
+        batch=GameBatch(
+            labels=row((rows,)), offsets=row((rows,)), weights=row((rows,)),
+            features={"per_itemId": shard}, id_tags={"itemId": row((rows,), i32)},
+        ),
+        feature_shard_id="per_itemId", random_effect_type="itemId",
+        config=OptimizationConfig(
+            optimizer=OptimizerConfig(
+                optimizer_type=OptimizerType.NEWTON_CHOLESKY, max_iterations=20,
+                tolerance=1e-7,
+            ),
+            regularization=RegularizationContext(RegularizationType.L2),
+            regularization_weight=1.0,
+        ),
+        grouping=EntityGrouping(entities, np.zeros(0), np.zeros(0), []),
+        buckets=EntityBuckets((), [], []), task_type=TaskType.LOGISTIC_REGRESSION,
+        num_entities=entities, mesh=mesh,
+    )
+    object.__setattr__(coordinate, "_prepared_cache", prepared)
+    visit = coordinate._build_visit_fn()
+    jax.clear_caches()
+    request.addfinalizer(jax.clear_caches)
+    compiled = visit.lower(
+        row((rows,)), row((rows,)),
+        jax.ShapeDtypeStruct((entities, width), f32, sharding=whole),
+        tuple(bucket_args), shard, row((rows,), i32),
+    ).compile()
+    need = compiled.memory_analysis()
+    total = (need.argument_size_in_bytes + need.output_size_in_bytes
+             + need.temp_size_in_bytes)
+    assert total < 7.0e9, (
+        need.argument_size_in_bytes, need.output_size_in_bytes,
+        need.temp_size_in_bytes,
+    )
+    text = compiled.as_text()
+    # a device's program: the residual whole, every matrix a quarter
+    assert f"f32[{rows}]" in text and f"f32[{rows // 4},{width}]" in text
+    assert not re.search(rf"\[{rows},\d+\]", text)
+    assert re.search(r"all-gather(-start)?\(", text)
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    assert any("/mesh.exchange/" in p for p in paths)
