@@ -43,6 +43,7 @@ from photon_ml_tpu.game.data import (
 from photon_ml_tpu.game.random_effect import (
     RandomEffectTrainingResult,
     prepare_buckets,
+    shared_order,
     train_prepared,
 )
 from photon_ml_tpu.game.models import FixedEffectModel, GameSubModel, RandomEffectModel
@@ -758,10 +759,12 @@ class RandomEffectCoordinate:
         if fn is None:
             fn = self._build_visit_fn()
             object.__setattr__(self, "_visit_fn", (fuse_key, fn))
-        bucket_args = tuple(
+        # the effect's own order (one array, every bucket's: None where the
+        # file's order serves), then the buckets
+        bucket_args = (shared_order(self._prepared), tuple(
             (pb.static, pb.row_idx, pb.mask, pb.ids, pb.columns)
             for pb in self._prepared
-        ) if self.mesh is None else self._mesh_bucket_args()
+        )) if self.mesh is None else self._mesh_bucket_args()
         feats = self._features()
         if isinstance(feats, SparseFeatures):
             # scored nonzero-major inside the program; staged once
@@ -862,15 +865,16 @@ class RandomEffectCoordinate:
         )
 
     def _mesh_bucket_args(self):
-        """The buckets as the mesh visit takes them: the staged tensors
-        beside each class's entity ids by LANE, ``(k_pad,)`` over the mesh
-        axis like the lanes themselves, a padded lane bearing
+        """The buckets as the mesh visit takes them: the effect's own order
+        (a device's segment of it over the mesh axis, or None), then the
+        staged tensors beside each class's entity ids by LANE, ``(k_pad,)``
+        over the mesh axis like the lanes themselves, a padded lane bearing
         ``num_entities`` (one past the last row of the coefficient matrix:
         read as a clamp, dropped by a scatter)."""
         cached = self.__dict__.get("_mesh_bucket_args_cache")
         if cached is None:
             lanes = NamedSharding(self.mesh, P(self.axis_name))
-            cached = tuple(
+            cached = (shared_order(self._prepared), tuple(
                 (
                     pb.static, pb.row_idx, pb.mask,
                     jax.device_put(
@@ -886,7 +890,7 @@ class RandomEffectCoordinate:
                     None,
                 )
                 for pb in self._prepared
-            )
+            ))
             object.__setattr__(self, "_mesh_bucket_args_cache", cached)
         return cached
 
@@ -946,9 +950,20 @@ class RandomEffectCoordinate:
                 # accumulation bench.py isolates per-config subprocesses for);
                 # the host-side metadata (entity_ids, num_real) rides the
                 # closure, unused in the trace
+                order, per_bucket = bucket_args
+                if mesh is not None and order is not None:
+                    # a device holds its own segment of the order, and a
+                    # lane's start counts from the segment's
+                    base = jax.lax.axis_index(axis) * order.shape[0]
+                    per_bucket = [
+                        (s, ri - base, mk, bi, co) for s, ri, mk, bi, co in per_bucket
+                    ]
                 prep = [
-                    _dc.replace(pb, static=s, row_idx=ri, mask=mk, ids=bi, columns=co)
-                    for pb, (s, ri, mk, bi, co) in zip(prepared, bucket_args)
+                    _dc.replace(
+                        pb, static=s, row_idx=ri, mask=mk, ids=bi, columns=co,
+                        order=order,
+                    )
+                    for pb, (s, ri, mk, bi, co) in zip(prepared, per_bucket)
                 ]
                 with stage(RE_OFFSETS):
                     offsets = residual = total - own_score

@@ -318,9 +318,13 @@ class PreparedBucket:
     entity_ids: np.ndarray  # (k,) original entity ids (host)
     ids: Array | None  # (k,) the same ids staged to device (W scatter key)
     static: Batch | None  # (k_pad, C, …) features/labels/weights
-    # where the bucket's slots sit in the residual offsets, read by
-    # ``_bucket_offsets``: (k_pad, C) int32 row numbers clipped to >= 0, or,
-    # where every lane's rows are consecutive, its (k_pad,) int32 run starts
+    # where the bucket's lanes sit in the offsets ``_bucket_offsets`` reads:
+    # (k_pad,) int32 run starts, lane i holding the C entries from s_i. Into
+    # the residual itself where ``order`` is None (every lane of the effect
+    # is a run of the file's rows), else into the effect's ordered copy
+    # ``offsets[order]``. ``prepare_buckets`` stages no other form; the
+    # (k_pad, C) slot indices ``_bucket_offsets`` also reads are the plain
+    # reading that tests hold these two against
     row_idx: Array | None
     mask: Array | None  # (k_pad, C) 1.0 where the slot holds a real sample
     num_real: int  # k (before device-count padding)
@@ -365,6 +369,12 @@ class PreparedBucket:
     # solved coefficients/variances back to the support before the
     # column scatter.
     hash_S: Array | None = None
+    # the effect's own order (``_effect_order``), ONE int32 array that every
+    # staged bucket of the effect shares: the rows its lanes hold, class by
+    # class and lane by lane, behind a leading 0. A visit gathers
+    # ``offsets[order]`` once and every class slices that. None where the
+    # residual's own order already serves (``row_idx``)
+    order: Array | None = None
 
 
 def prepare_buckets(
@@ -516,17 +526,17 @@ def prepare_buckets(
     zeros_off = np.zeros_like(np.asarray(labels))
     # real bucket rows a device of the lane mesh holds, over every class
     mesh_rows = None
-    starts = [_run_starts(r) for r in buckets.row_indices]
-    if parents is not None:
-        # the sub-buckets of one parent are concatenated again: one form a parent
-        scalar = {p for p, s in zip(parents, starts) if s is None}
-        starts = [None if p in scalar else s for p, s in zip(parents, starts)]
+    # read from the data, for the effect as a whole: one lane anywhere that
+    # is no run of the file's rows and every class reads the ordered copy
+    file_order = all(_run_starts(r) is not None for r in buckets.row_indices)
+    # per staged bucket: its place in ``prepared``, its (k_pad, C) rows on
+    # the host (-1 where a slot holds none), and where its tensors go
+    staged: list[tuple[int, np.ndarray, Any]] = []
     prepared: list[PreparedBucket] = []
     for bi, (ent_ids, row_idx) in enumerate(
         zip(buckets.entity_ids, buckets.row_indices)
     ):
         k = len(ent_ids)
-        run_starts = starts[bi]
         if n_dev > 1 and one_process_mesh(mesh):
             # on one host's mesh (the fused visit's; a mesh that spans
             # processes keeps the id order its drills pin bit for bit):
@@ -542,8 +552,6 @@ def prepare_buckets(
             order = np.argsort(row_idx[:, 0], kind="stable")
             order = np.concatenate([order[j::n_dev] for j in range(n_dev)])
             ent_ids, row_idx = ent_ids[order], row_idx[order]
-            if run_starts is not None:
-                run_starts = run_starts[order]
         parent = None if parents is None else int(parents[bi])
         spec = None if ladder is None else ladder.get(int(row_idx.shape[1]))
         if owners is not None and owners[bi] != own_pid:
@@ -570,10 +578,10 @@ def prepare_buckets(
         static = gather_bucket_host(
             features, labels, zeros_off, weights, row_idx, columns=cols
         )
-        runs = run_starts is not None
+        # every slot is read by a run-start slice: of the residual, or of
+        # the ordered copy that ``re_offsets.ordered_rows`` indices gather
         REGISTRY.counter_inc("re_offsets.slots", float(row_idx.size))
-        REGISTRY.counter_inc("re_offsets.run_slots", float(row_idx.size) if runs else 0.0)
-        idx = np.asarray(run_starts if runs else np.maximum(row_idx, 0), np.int32)
+        REGISTRY.counter_inc("re_offsets.run_slots", float(row_idx.size))
         mask = (row_idx >= 0).astype(np.float32)
         columns = cols
         hash_S = None
@@ -615,7 +623,10 @@ def prepare_buckets(
                     [a, np.zeros((k_pad - k,) + a.shape[1:], a.dtype)]
                 )
                 static = jax.tree.map(pad0, static)
-                idx, mask = pad0(idx), pad0(mask)
+                mask = pad0(mask)
+                row_idx = np.concatenate(
+                    [row_idx, np.full((k_pad - k,) + row_idx.shape[1:], -1, row_idx.dtype)]
+                )
                 if columns is not None:
                     columns = pad0(columns)
         if n_dev > 1:
@@ -627,7 +638,7 @@ def prepare_buckets(
         else:
             put = jnp.asarray
         static = jax.tree.map(put, static)
-        idx, mask = put(idx), put(mask)
+        mask = put(mask)
         if columns is not None:
             columns = put(columns)
         ids = jnp.asarray(ent_ids, jnp.int32)
@@ -639,18 +650,19 @@ def prepare_buckets(
             # knob-off path never commits, keeping default placement
             dev = int(devices[bi])
             target = jax.local_devices()[dev]
-            put = lambda a: jax.device_put(a, target)
+            put = partial(jax.device_put, device=target)
             static = jax.tree.map(put, static)
-            idx, mask, ids = put(idx), put(mask), put(ids)
+            mask, ids = put(mask), put(ids)
             if columns is not None:
                 columns = put(columns)
             if hash_S is not None:
                 hash_S = put(hash_S)
+        staged.append((len(prepared), row_idx, put))
         prepared.append(
             PreparedBucket(
                 entity_ids=ent_ids,
                 ids=ids,
-                static=static, row_idx=idx, mask=mask,
+                static=static, row_idx=None, mask=mask,
                 num_real=k, columns=columns,
                 owner=None if owners is None else int(owners[bi]),
                 parent=parent,
@@ -658,6 +670,24 @@ def prepare_buckets(
                 project=spec,
                 hash_S=hash_S,
             )
+        )
+    rows = [r for _, r, _ in staged]
+    own_order = None
+    if file_order or not staged:
+        starts = [np.maximum(r[:, 0], 0) for r in rows]
+    else:
+        own_order, starts = _effect_order(rows, n_dev)
+        own_order = (
+            jax.device_put(own_order, NamedSharding(mesh, P(axis_name)))
+            if n_dev > 1 else jnp.asarray(own_order)
+        )
+    # the indices a visit gathers one by one (a mesh's filler included)
+    REGISTRY.counter_inc(
+        "re_offsets.ordered_rows", 0.0 if own_order is None else float(own_order.size)
+    )
+    for (at, _, put), s in zip(staged, starts):
+        prepared[at] = dataclasses.replace(
+            prepared[at], row_idx=put(np.asarray(s, np.int32)), order=own_order
         )
     if mesh_rows is not None:
         # what cutting every class's lanes by the mesh leaves uneven
@@ -692,15 +722,61 @@ def prepare_buckets(
     return prepared
 
 
+def shared_order(prepared: Sequence[PreparedBucket]) -> Array | None:
+    """The one ``order`` the staged buckets of an effect share, or None."""
+    return next((pb.order for pb in prepared if pb.order is not None), None)
+
+
 def _run_starts(row_idx: np.ndarray) -> np.ndarray | None:
     """The (k,) first rows of a bucket whose every lane holds one run of
     consecutive rows (slot ``j`` of lane ``i`` holds row ``s_i + j`` wherever it
     holds a row: an input sorted by the effect's id, grouped stably), or
     None where one lane does not. A lane without rows starts at 0. Read
-    from the data, bucket by bucket; ``_bucket_offsets`` reads either form."""
+    from the data: an effect with one bucket that has none reads its
+    offsets through ``_effect_order``."""
     first = np.maximum(row_idx[:, 0], 0)
     runs = first[:, None] + np.arange(row_idx.shape[1], dtype=row_idx.dtype)
     return first if np.array_equal(row_idx >= 0, row_idx == runs) else None
+
+
+def _effect_order(
+    rows: list[np.ndarray], n_dev: int = 1
+) -> tuple[np.ndarray, list[np.ndarray]]:
+    """The order in which an effect that is NOT laid out as runs of the file
+    reads its residual offsets, from its classes' (k_pad, C) host rows (-1
+    in a slot that holds none): ``(order, starts)``. ``order`` is int32 row
+    numbers, a device's segment after the other (``n_dev`` of them, equally
+    long; a class's lanes are cut over the devices in ``n_dev`` consecutive
+    blocks). A segment opens with row 0, so that what a padded slot reads
+    (``_bucket_offsets``: the first entry of what it is given) stays the
+    residual's own first entry, sign of a zero included; then, class by
+    class and lane by lane, the rows a lane holds, slot by slot up to its
+    last (a slot without a row before that reads row 0 and is masked); then
+    row 0 again up to the longest segment's length. ``starts[b]`` is, lane
+    by lane, where class ``b``'s lane begins in ``order`` as a whole (a lane
+    without rows: at its segment's start), so ``_bucket_offsets(offsets[order],
+    starts[b], mask)`` is ``offsets[rows] * mask`` bit for bit, one index a
+    real row in place of one a slot."""
+    held = np.ones(n_dev, np.int64)  # a segment's entries so far: the leading 0
+    parts: list[list[np.ndarray]] = [[] for _ in range(n_dev)]
+    local: list[np.ndarray] = []
+    for r in rows:
+        cap = r.shape[1]
+        real = r >= 0
+        length = np.where(real.any(axis=1), cap - np.argmax(real[:, ::-1], axis=1), 0)
+        flat = np.maximum(r[np.arange(cap) < length[:, None]], 0).astype(np.int32)
+        by_dev = length.reshape(n_dev, -1)
+        taken = by_dev.sum(axis=1)
+        ahead = np.cumsum(by_dev, axis=1) - by_dev  # of a lane, in its device's block
+        local.append(np.where(by_dev > 0, held[:, None] + ahead, 0))
+        for part, piece in zip(parts, np.split(flat, np.cumsum(taken)[:-1])):
+            part.append(piece)
+        held += taken
+    order = np.zeros((n_dev, int(held.max())), np.int32)
+    for seg, part, n in zip(order, parts, held):
+        seg[1:n] = np.concatenate(part)
+    base = np.arange(n_dev)[:, None] * order.shape[1]
+    return order.reshape(-1), [(s + base).reshape(-1) for s in local]
 
 
 # Lanes of one (capacity, width) class that are densified and solved at a
@@ -1328,7 +1404,6 @@ def _bucket_geometry(pb: PreparedBucket):
         jax.tree.structure(pb.static),
         static_leaves,
         pb.mask.shape[1:],  # the capacity C
-        pb.row_idx.ndim,  # run starts (1) never fuse with slot indices (2)
         None if pb.columns is None else pb.columns.shape[1],
         # hash-fold width (PHOTON_RE_PROJECT=hash): same capacity class
         # ⇒ same fold matrix, so equal keys still share one S — this
@@ -1472,6 +1547,7 @@ def _concat_units(
             # one staged fold matrix
             project=prepared[idxs[0]].project,
             hash_S=prepared[idxs[0]].hash_S,
+            order=prepared[idxs[0]].order,  # the effect's: every member's
         )
         units.append((fused, members))
     return units
@@ -1652,6 +1728,10 @@ def _train_prepared_core(
         prior_mu, prior_var = p.means, p.variances
     V = jnp.zeros((num_entities, d), jnp.float32) if compute_variance else None
 
+    order = shared_order(prepared)
+    if order is not None:
+        # ONCE a visit: every bucket below slices this copy by its run starts
+        offsets = _ordered_offsets(offsets, order)
     l2 = jnp.asarray(l2_weight, jnp.float32)
     # entity-sharded owned-bucket mode (PHOTON_RE_SHARD=1 under a mesh):
     # buckets were staged WHOLE by prepare_buckets, so lanes are fully
@@ -2236,16 +2316,24 @@ _ROW = 128  # the lane width an aligned row of the offsets is gathered at
 
 def _bucket_offsets(offsets: Array, row_idx: Array, mask: Array) -> Array:
     """A bucket's (k_pad, C) residual offsets, zero in the padded slots.
-    SHARED by ``_bucket_step`` and ``_lane_prologue``, in either form
-    ``prepare_buckets`` staged: ``offsets[row_idx]`` by (k_pad, C) slot
-    indices (one scalar gather an index, 7 ns each on a v5e whatever it
-    points at), or, from (k_pad,) run starts, lane ``i`` as
-    ``offsets[s_i : s_i + C]``. The slice is one gather of whole aligned
-    128-wide rows, ``ceil(C / 128) + 1`` from row ``s_i // 128``, moved left
-    by ``s_i % 128`` in seven fixed-distance selects: no loop over lanes
-    (``vmap`` of ``dynamic_slice`` lowers to one trip a lane). Bitwise one
-    result: a real slot holds the same row's offset, and a padded slot reads
-    ``offsets[0]`` in both forms before the zero mask, so zero signs agree."""
+    SHARED by ``_bucket_step`` and ``_lane_prologue``. From (k_pad,) run
+    starts, the form ``prepare_buckets`` stages, lane ``i`` is
+    ``offsets[s_i : s_i + C]``, where ``offsets`` is the residual itself or an
+    effect's ordered copy of it (``_ordered_offsets``). The slice is one
+    gather of whole aligned 128-wide rows, ``ceil(C / 128) + 1`` from row
+    ``s_i // 128``, moved left by ``s_i % 128`` in seven fixed-distance
+    selects: no loop over lanes (``vmap`` of ``dynamic_slice`` lowers to one
+    trip a lane). From (k_pad, C) slot indices it is the plain reading,
+    ``offsets[row_idx]``, that tests hold the slices against: one scalar
+    gather an index, and a v5e pays by the INDEX: 7.1 to 7.3 ns each in a
+    one-chip program whether the indices point at scattered rows, sorted
+    ones, consecutive ones or always row 0, and whether the table holds 5M
+    rows or 20M (my chip runs, PRs 30 and 39); the same gather inside the
+    four-chip descent read 12.4 ns an index (PR 38), which the table's size
+    alone does not give. Bitwise one result: a real slot holds the same
+    row's offset, and a padded slot reads ``offsets[0]`` in both forms
+    before the zero mask, so zero signs agree (an ordered copy opens with
+    the residual's own first entry for this)."""
     if row_idx.ndim == 2:
         return offsets[row_idx] * mask
     k, C = mask.shape
@@ -2263,6 +2351,14 @@ def _bucket_offsets(offsets: Array, row_idx: Array, mask: Array) -> Array:
         )
         win = jnp.where((((r >> b) & 1) == 1)[:, None], moved, win)
     return jnp.where(mask != 0, win[:, :C], offsets[0]) * mask
+
+
+@jax.jit
+def _ordered_offsets(offsets: Array, order: Array) -> Array:
+    """The residual offsets in an effect's own order (``_effect_order``): one
+    scalar gather, an index a real row, for all the effect's classes."""
+    with stage(RE_OFFSETS):
+        return offsets[order]
 
 
 def _extract_lanes(M, ids, columns, k, k_pad, d, pad_value=0.0, sharding=None):

@@ -405,16 +405,24 @@ def summarize_run(path: str, records: list[dict] | None = None) -> dict:
         }
     # residual offsets of random-effect buckets (re_offsets.*,
     # game/random_effect.prepare_buckets): the slots (lanes x capacity) of
-    # every staged bucket, and those of the buckets whose lanes are runs of
-    # consecutive rows, read by one run start a lane in place of one index
-    # a slot. Present only on runs that prepared a random effect.
+    # every staged bucket; those read by one run start a lane in place of
+    # one index a slot; and the rows that effects whose lanes are no runs of
+    # the file gather once a visit into their own order, one index each,
+    # for those slices to read. Present only on runs that prepared a random
+    # effect; ordered_rows reads 0 on a run from before the counter.
     if "re_offsets.slots" in counters or "re_offsets.slots" in base_counters:
         slots = counter_v("re_offsets.slots")
         run_slots = counter_v("re_offsets.run_slots")
+        ordered = counter_v("re_offsets.ordered_rows")
         out["re_offsets"] = {
             "slots": slots,
             "run_slots": run_slots,
+            "ordered_rows": ordered,
             "run_slot_share": run_slots / slots if slots > 0 else None,
+            # indices read one by one, over the slots they serve
+            "index_share": (
+                (slots - run_slots + ordered) / slots if slots > 0 else None
+            ),
         }
     # random-effect lanes cut over a mesh (re_mesh.*, game/random_effect.
     # prepare_buckets where it shards lanes): the lanes that hold an entity
@@ -752,7 +760,10 @@ def format_summary(s: dict) -> str:
         lines.append(
             f"  re-offsets: {_fmt_qty(ofs['slots'])} bucket slots, "
             f"{_fmt_qty(ofs['run_slots'])} "
-            f"({100.0 * ofs['run_slot_share']:.1f}%) read by run-start slices"
+            f"({100.0 * ofs['run_slot_share']:.1f}%) read by run-start slices; "
+            f"{_fmt_qty(ofs['ordered_rows'])} rows gathered a visit into an "
+            f"effect's own order ({100.0 * ofs['index_share']:.1f}% of the "
+            "slots read one index each)"
         )
     lanes = s.get("re_mesh") or {}
     if lanes.get("lane_pad_ratio") is not None:

@@ -129,6 +129,32 @@ def descent_coordinates(n=256, d=5, entities=12, seed=0, sparse=False,
     return coordinates, batch, task
 
 
+def slot_index_reading(prepared):
+    """The same prepared buckets reading their residual offsets the plain
+    way, one index a slot (``offsets[row_idx] * mask`` with (k_pad, C)
+    ``row_idx``, which ``_bucket_offsets`` still reads and nothing stages):
+    the reference the run-start forms are held against bit for bit. The slot
+    indices are what the staged starts (and the effect's order, where it has
+    one) say, placed where the bucket's mask is."""
+    import dataclasses
+
+    out = []
+    for pb in prepared:
+        if pb.static is None:  # owned elsewhere: nothing staged
+            out.append(pb)
+            continue
+        mask = np.asarray(pb.mask) != 0
+        at = np.asarray(pb.row_idx)[:, None] + np.arange(mask.shape[1])
+        if pb.order is not None:
+            order = np.asarray(pb.order)
+            at = order[np.minimum(at, len(order) - 1)]
+        slots = np.where(mask, at, 0).astype(np.int32)
+        out.append(dataclasses.replace(
+            pb, row_idx=jax.device_put(slots, pb.mask.sharding), order=None,
+        ))
+    return out
+
+
 def descent_program(sparse=False, **size):
     """The jitted ``fused`` of ``game/descent._build_fused_outer`` with the
     arguments ``run_outer`` gives it from the zero model."""

@@ -416,19 +416,20 @@ class TestBucketMerging:
 
 
 # ---------------------------------------------------------------------------
-# residual offsets: run starts (rows sorted by the effect's id) beside slot
-# indices (rows scattered), one descent, bit for bit the all-slot-index one
+# residual offsets: run starts into the file (rows sorted by the effect's id)
+# beside run starts into the effect's own order (rows scattered: one gather a
+# visit, an index a real row), one descent, bit for bit the slot-index one
 # ---------------------------------------------------------------------------
 def _blocks_and_shuffled_run(path, monkeypatch, refuse):
     """Fixed + per-user (the file sorted by user: blocks) + per-item
-    (scattered) through ``CoordinateDescent.run``; ``refuse`` takes the
-    run-start form away, as the parent commit had it."""
+    (scattered) through ``CoordinateDescent.run``; ``refuse`` has both
+    effects read their offsets by one index a slot, as before PR 30."""
+    from stage_programs import slot_index_reading
+
     from photon_ml_tpu.game import random_effect as re_mod
     from photon_ml_tpu.obs.metrics import REGISTRY
 
     monkeypatch.setenv("PHOTON_RE_COMPACT_EVERY", "2" if path == "compacted" else "0")
-    if refuse:
-        monkeypatch.setattr(re_mod, "_run_starts", lambda rows: None)
     task = TaskType.LOGISTIC_REGRESSION
     effects = {"userId": (25, 3), "itemId": (14, 3)}
     data = synthetic_game_data(np.random.default_rng(21), 900, 4, effects, task=task)
@@ -465,6 +466,17 @@ def _blocks_and_shuffled_run(path, monkeypatch, refuse):
 
     monkeypatch.setattr(re_mod, "_bucket_step_compacted", counted_step)
     REGISTRY.reset(prefix="re_offsets")
+    orders = {}
+    for tag in effects:
+        coord = coords[f"per_{tag}"]
+        orders[tag] = coord._prepared[0].order
+        assert all(pb.row_idx.ndim == 1 for pb in coord._prepared)
+        if refuse:
+            plain = slot_index_reading(coord._prepared)
+            assert all(pb.row_idx.ndim == 2 and pb.order is None for pb in plain)
+            object.__setattr__(coord, "_prepared_cache", plain)
+    # the user effect gathers nothing; the item effect one index a real row
+    assert orders["userId"] is None and orders["itemId"].shape == (len(order) + 1,)
     res = CoordinateDescent(coords, batch, task).run(list(coords), num_iterations=2)
     assert bool(compacted_steps) == (path == "compacted")  # ``_lane_prologue``'s path
     counters = {k: v["value"] for k, v in
@@ -482,10 +494,10 @@ def test_run_start_offsets_leave_the_descent_bitwise(path, monkeypatch):
     got, counters, slots = _blocks_and_shuffled_run(path, monkeypatch, refuse=False)
     assert counters == {
         "re_offsets.slots": slots["userId"] + slots["itemId"],
-        "re_offsets.run_slots": slots["userId"],
+        "re_offsets.run_slots": slots["userId"] + slots["itemId"],
+        "re_offsets.ordered_rows": 901,
     }
-    want, refused, _ = _blocks_and_shuffled_run(path, monkeypatch, refuse=True)
-    assert refused["re_offsets.run_slots"] == 0
+    want, _, _ = _blocks_and_shuffled_run(path, monkeypatch, refuse=True)
     assert got.keys() == want.keys() and len(got) == 8
     for name in got:
         np.testing.assert_array_equal(

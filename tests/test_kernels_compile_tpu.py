@@ -581,7 +581,8 @@ def test_sparse_random_effect_visit_fits_the_chip_at_the_benchmarks_cut(
                 offsets=spec((lanes, capacity), f32),
                 weights=spec((lanes, capacity), f32), num_features=width,
             ),
-            row_idx=spec((lanes, capacity), i32), mask=spec((lanes, capacity), f32),
+            # the cell's users are blocks of the file: run starts, no order
+            row_idx=spec((lanes,), i32), mask=spec((lanes, capacity), f32),
             num_real=k, columns=spec((lanes, width), i32),
         ))
     shard = SparseFeatures(
@@ -610,7 +611,9 @@ def test_sparse_random_effect_visit_fits_the_chip_at_the_benchmarks_cut(
     request.addfinalizer(jax.clear_caches)
     compiled = visit.lower(
         spec((n,), f32), spec((n,), f32), spec((entities, d), f32),
-        tuple((pb.static, pb.row_idx, pb.mask, pb.ids, pb.columns) for pb in prepared),
+        (None, tuple(
+            (pb.static, pb.row_idx, pb.mask, pb.ids, pb.columns) for pb in prepared
+        )),
         NonzeroMajorSparseFeatures(
             indices=spec((nnz, n), i32), values=spec((nnz, n), f32), num_features=d
         ),
@@ -651,10 +654,12 @@ def test_mesh_visit_of_the_whole_item_effect_fits_a_chip_of_four(
     """The per-item visit of ``ml20m_full_descent4`` under ``shard_map`` for
     the 2x2 mesh, at the whole size: a chip holds a quarter of every class's
     lanes and 5,000,066 rows, the residual is made whole (one all-gather to
-    f32[20000264], 80 MB) and the solved lanes are gathered; no per-row
-    matrix is ever the whole batch's. The visit asked 5.81 GB a chip when this
-    was written (5.14 of it scratch); one replicated ``(rows, 8)`` operand is
-    10.2 GB more."""
+    f32[20000264], 80 MB), a chip gathers its own segment of the effect's
+    order out of it once (PR 39: about 5.03M indices, the fullest chip's rows
+    and the leading 0) and the solved lanes are gathered; no per-row matrix is
+    ever the whole batch's and no class is read by one index a slot. The
+    visit asked 5.81 GB a chip when this was written (5.14 of it scratch); one
+    replicated ``(rows, 8)`` operand is 10.2 GB more."""
     from photon_ml_tpu.config import (
         OptimizationConfig,
         OptimizerConfig,
@@ -673,6 +678,8 @@ def test_mesh_visit_of_the_whole_item_effect_fits_a_chip_of_four(
     over, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
     f32, i32 = jnp.float32, jnp.int32
     row = lambda shape, dt=f32: jax.ShapeDtypeStruct(shape, dt, sharding=over)
+    segment = 5_027_600  # mesh.row_imbalance 1.0055 of 5,000,066, and the leading 0
+    order = row((4 * segment,), i32)
     prepared, bucket_args = [], []
     for capacity, k in ML20M_FULL_ITEM_CLASSES:
         lanes = -(-k // 4) * 4
@@ -680,13 +687,13 @@ def test_mesh_visit_of_the_whole_item_effect_fits_a_chip_of_four(
             X=row((lanes, capacity, width)), labels=row((lanes, capacity)),
             offsets=row((lanes, capacity)), weights=row((lanes, capacity)),
         )
-        slots = row((lanes, capacity), i32)
+        starts = row((lanes,), i32)
         prepared.append(PreparedBucket(
             entity_ids=np.zeros(k, np.int64), ids=None, static=static,
-            row_idx=slots, mask=row((lanes, capacity)), num_real=k,
+            row_idx=starts, mask=row((lanes, capacity)), num_real=k, order=order,
         ))
         bucket_args.append(
-            (static, slots, row((lanes, capacity)), row((lanes,), i32), None)
+            (static, starts, row((lanes, capacity)), row((lanes,), i32), None)
         )
     shard = DenseFeatures(X=row((rows, width)))
     coordinate = RandomEffectCoordinate(
@@ -715,7 +722,7 @@ def test_mesh_visit_of_the_whole_item_effect_fits_a_chip_of_four(
     compiled = visit.lower(
         row((rows,)), row((rows,)),
         jax.ShapeDtypeStruct((entities, width), f32, sharding=whole),
-        tuple(bucket_args), shard, row((rows,), i32),
+        (order, tuple(bucket_args)), shard, row((rows,), i32),
     ).compile()
     need = compiled.memory_analysis()
     total = (need.argument_size_in_bytes + need.output_size_in_bytes
@@ -729,5 +736,8 @@ def test_mesh_visit_of_the_whole_item_effect_fits_a_chip_of_four(
     assert f"f32[{rows}]" in text and f"f32[{rows // 4},{width}]" in text
     assert not re.search(rf"\[{rows},\d+\]", text)
     assert re.search(r"all-gather(-start)?\(", text)
+    # the residual in the effect's order, gathered by a chip's segment
+    assert f"f32[{segment}]" in text and f"s32[{segment}]" in text
+    assert not re.search(r"s32\[\d+,(256|512|1024|2048|4096|8192|32768|131072)\]", text)
     paths = set(re.findall(r'op_name="([^"]*)"', text))
     assert any("/mesh.exchange/" in p for p in paths)

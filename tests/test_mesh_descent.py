@@ -57,10 +57,14 @@ def mesh():
     return data_mesh(devices=jax.devices()[:4])
 
 
-def _data(seed=0):
+def _data(seed=0, relabelled=False):
     rng = np.random.default_rng(seed)
     uid = np.sort(rng.integers(0, USERS - 1, N)).astype(np.int32)
     iid = rng.integers(0, ITEMS, N).astype(np.int32)
+    if relabelled:  # the same rows an entity, under other names
+        names = np.random.default_rng(seed + 100)
+        uid = names.permutation(USERS - 1).astype(np.int32)[uid]
+        iid = names.permutation(ITEMS).astype(np.int32)[iid]
     y = (rng.random(N) < 0.5).astype(np.float32)
     Xf = rng.normal(size=(N, D + 1)).astype(np.float32)
     Xf[:, D] = 1.0
@@ -79,8 +83,8 @@ def _optimization(kind):
     )
 
 
-def _descent(mesh, seed=0):
-    y, feats, ids = _data(seed)
+def _descent(mesh, seed=0, relabelled=False):
+    y, feats, ids = _data(seed, relabelled)
     batch = make_game_batch(y, feats, id_tags=ids, mesh=mesh)
     coordinates = {
         "fixed": FixedEffectCoordinate(
@@ -479,6 +483,68 @@ def test_prepare_counts_what_the_lane_cut_leaves_uneven(mesh):
                 assert len(leaf.sharding.device_set) == 4
                 assert leaf.addressable_shards[0].data.shape[0] == leaf.shape[0] // 4
     REGISTRY.reset(prefix="re_mesh.")
+
+
+@pytest.mark.parametrize("relabelled", [False, True])
+def test_the_ordered_copy_under_the_mesh_is_the_slot_index_descent_bit_for_bit(
+    mesh, relabelled
+):
+    """Users in blocks (no order: nothing gathered), items scattered: a device
+    gathers its own segment of the item effect's order out of the whole
+    residual, the segments unequal in rows so the shorter end in filler, and
+    the same segments whatever the entities are called. Coefficients, scores
+    and per-entity iterations of two outer iterations are those of the same
+    descent reading one index a slot, bit for bit."""
+    from stage_programs import slot_index_reading
+
+    REGISTRY.reset(prefix="re_offsets.")
+    descent, _ = _descent(mesh, relabelled=relabelled)
+    user, item = (descent.coordinates[c]._prepared for c in ("per_user", "per_item"))
+    assert all(pb.order is None and pb.row_idx.ndim == 1 for pb in user)
+    order = item[0].order
+    assert all(pb.order is order and pb.row_idx.ndim == 1 for pb in item)
+    assert order.sharding.is_equivalent_to(NamedSharding(mesh, P("data")), 1)
+    held = sum(
+        (np.asarray(pb.mask) != 0).sum(axis=1).reshape(4, -1).sum(axis=1) for pb in item
+    )
+    assert held.sum() == N and len(set(held.tolist())) > 1
+    segments = np.asarray(order).reshape(4, -1)
+    assert segments.shape[1] == 1 + held.max()
+    for segment, rows in zip(segments, held):
+        assert segment[0] == 0 and not segment[1 + rows:].any()  # filler: row 0
+    np.testing.assert_array_equal(np.unique(segments), np.arange(N))
+    counters = {
+        k: v["value"] for k, v in REGISTRY.snapshot("re_offsets.")["counters"].items()
+    }
+    assert counters["re_offsets.ordered_rows"] == segments.size
+    assert counters["re_offsets.run_slots"] == counters["re_offsets.slots"]
+    if relabelled:  # a device keeps its lanes, so its segment
+        plain, _ = _descent(mesh)
+        np.testing.assert_array_equal(
+            segments.ravel(), np.asarray(plain.coordinates["per_item"]._prepared[0].order)
+        )
+    got = descent.run(SEQUENCE, 2)
+    assert tuple(SEQUENCE) in descent._fused_outer_cache  # under shard_map, one launch
+    reference, _ = _descent(mesh, relabelled=relabelled)
+    for cid in ("per_user", "per_item"):
+        coordinate = reference.coordinates[cid]
+        object.__setattr__(
+            coordinate, "_prepared_cache", slot_index_reading(coordinate._prepared)
+        )
+        assert all(pb.row_idx.ndim == 2 for pb in coordinate._prepared)
+    want = reference.run(SEQUENCE, 2)
+    bits = lambda a: np.asarray(a).view(np.uint32)
+    for c in SEQUENCE:
+        np.testing.assert_array_equal(
+            bits(_coefficients(got)[c]), bits(_coefficients(want)[c]), err_msg=c)
+        np.testing.assert_array_equal(
+            bits(_scores(got)[c]), bits(_scores(want)[c]), err_msg=c)
+    for c in ("per_user", "per_item"):
+        iterations = np.asarray(got.trackers[c][-1].iterations)
+        np.testing.assert_array_equal(
+            iterations, np.asarray(want.trackers[c][-1].iterations), err_msg=c)
+        assert iterations.max() > 1
+    REGISTRY.reset(prefix="re_offsets.")
 
 
 @pytest.mark.parametrize("relabelled", [False, True])

@@ -1,8 +1,9 @@
-"""A bucket's residual offsets in either staged form (``game/random_effect.
-_bucket_offsets``): by one index a slot, or, where ``_run_starts`` finds
-every lane of the bucket to be one run of consecutive rows, by one run start
-a lane. The two forms are one result bit for bit, zero signs included; the
-choice is read from the data, bucket by bucket."""
+"""A bucket's residual offsets (``game/random_effect._bucket_offsets``) by one
+run start a lane: into the residual itself where ``_run_starts`` finds every
+lane of the effect to be one run of the file's rows, else into the effect's
+own order (``_effect_order``), which a visit gathers once, one index a real
+row. Both are the plain reading, one index a slot, bit for bit, zero signs
+included; the choice is read from the data, effect by effect."""
 
 import re
 
@@ -20,14 +21,16 @@ from photon_ml_tpu.game import (
 from photon_ml_tpu.game import random_effect as re_mod
 from photon_ml_tpu.game.random_effect import (
     _bucket_offsets,
-    _bucket_step,
+    _effect_order,
+    _ordered_offsets,
     _run_starts,
     prepare_buckets,
+    train_prepared,
 )
 from photon_ml_tpu.obs.metrics import REGISTRY
 from photon_ml_tpu.ops.losses import logistic_loss
-from photon_ml_tpu.optim.common import select_minimize_fn
-from photon_ml_tpu.types import VarianceComputationType
+
+from stage_programs import slot_index_reading
 
 
 def _block_rows(counts, capacity, first=0):
@@ -113,6 +116,89 @@ def test_detector_refuses_a_bucket_with_one_lane_that_is_no_run():
     np.testing.assert_array_equal(_run_starts(empty), [0, 16, 24, 29])
 
 
+def _shuffled_rows(counts_by_class, n, seed=7):
+    """Classes of (k, C) row numbers whose lanes hold rows drawn all over a
+    file of ``n`` rows, ``counts_by_class`` a list of (C, rows a lane[, lanes
+    without rows at the class's end])."""
+    rng = np.random.default_rng(seed)
+    file_rows = rng.permutation(n)
+    at, out = 0, []
+    for cap, counts, *pad_lanes in counts_by_class:
+        rows = np.full((len(counts) + sum(pad_lanes), cap), -1, np.int64)
+        for i, c in enumerate(counts):
+            rows[i, :c] = file_rows[at:at + c]
+            at += c
+        out.append(rows)
+    return out
+
+
+def _ordered_case(name):
+    """(classes of host rows, rows in the data set, devices the lanes are cut over)."""
+    if name == "lanes_shorter_than_c":
+        return _shuffled_rows([(64, [5, 64, 33, 1]), (128, [65, 127])], 700), 700, 1
+    if name == "an_empty_lane":
+        rows = _shuffled_rows([(8, [3, 8, 5]), (32, [9, 32])], 90)
+        rows[0][1] = -1
+        rows[1][0] = -1
+        return rows, 90, 1
+    if name == "classes_c8_and_c8192":
+        return _shuffled_rows([(8, [8, 1, 7, 5, 6]), (8192, [4097, 8192, 5000])], 20000), 20000, 1
+    if name == "the_arrays_last_row_inside_a_lane":
+        rows = _shuffled_rows([(16, [9, 16, 12]), (64, [40])], 300)
+        rows[0][1, 4] = 299  # whatever else holds it: a slot reads what it names
+        rows[1][0, 39] = 0
+        return rows, 300, 1
+    if name == "a_slot_without_a_row_inside_a_lane":
+        rows = _shuffled_rows([(16, [9, 16, 12])], 100)
+        rows[0][0, 3] = -1
+        rows[0][2, 0] = -1
+        return rows, 100, 1
+    if name == "device_padding_lanes_over_four_devices":
+        # 5 + 3 and 2 + 2 lanes: the devices' segments differ in length
+        return _shuffled_rows([(8, [3, 8, 5, 7, 1], 3), (32, [9, 32], 2)], 200), 200, 4
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "lanes_shorter_than_c", "an_empty_lane", "classes_c8_and_c8192",
+    "the_arrays_last_row_inside_a_lane", "a_slot_without_a_row_inside_a_lane",
+    "device_padding_lanes_over_four_devices",
+])
+def test_the_ordered_copy_and_its_slices_are_the_slot_gather_bit_for_bit(name):
+    classes, n, n_dev = _ordered_case(name)
+    order, starts = _effect_order(classes, n_dev)
+    real = sum(int((r >= 0).sum()) for r in classes)
+    width = len(order) // n_dev
+    assert order.dtype == np.int32 and len(order) == n_dev * width
+    if n_dev == 1 and "without_a_row" not in name:
+        assert len(order) == real + 1  # one index a real row, behind the leading 0
+    np.testing.assert_array_equal(order[::width], 0)
+    off = _offsets(n)
+    ordered = _ordered_offsets(off, jnp.asarray(order))
+    assert _bits(ordered[0]) == _bits(off[0])
+    for rows, s in zip(classes, starts):
+        assert s.shape == rows.shape[:1]
+        mask = jnp.asarray((rows >= 0).astype(np.float32))
+        want = off[jnp.asarray(np.maximum(rows, 0), jnp.int32)] * mask
+        got = _bucket_offsets(ordered, jnp.asarray(s, jnp.int32), mask)
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+        padded = np.asarray(mask) == 0  # offsets[0] < 0: every padded slot is -0.0
+        assert padded.any() and np.all(np.signbit(np.asarray(got)[padded]))
+        # a device reads its own segment alone, its starts counted from it
+        lanes = len(rows) // n_dev
+        for j in range(n_dev):
+            block = slice(j * lanes, (j + 1) * lanes)
+            segment = off[jnp.asarray(order[j * width:(j + 1) * width])]
+            local = jnp.asarray(s[block] - j * width, jnp.int32)
+            assert int(local.min()) >= 0 and int(local.max()) < width
+            np.testing.assert_array_equal(
+                _bits(_bucket_offsets(segment, local, mask[block])), _bits(want[block])
+            )
+    if n_dev > 1:
+        used = 1 + sum((r >= 0).reshape(n_dev, -1).sum(axis=1) for r in classes)
+        assert len(set(used.tolist())) > 1 and width == used.max()  # filler, no more than needed
+
+
 def _effect(n_entities, counts_rng, shuffled, d=3, seed=5):
     rng = np.random.default_rng(seed)
     counts = counts_rng(rng, n_entities)
@@ -131,7 +217,7 @@ def _slot_counters():
 
 
 @pytest.mark.parametrize("shuffled", [False, True])
-def test_prepare_stages_run_starts_only_where_every_lane_is_a_run(shuffled):
+def test_prepare_stages_run_starts_into_the_file_or_into_the_effects_order(shuffled):
     ids, X, y = _effect(30, lambda r, e: r.integers(3, 40, size=e), shuffled)
     buckets = bucket_entities(group_by_entity(ids, num_entities=30))
     REGISTRY.reset(prefix="re_offsets")
@@ -139,14 +225,27 @@ def test_prepare_stages_run_starts_only_where_every_lane_is_a_run(shuffled):
         DenseFeatures(X=jnp.asarray(X)), y, np.ones(len(y), np.float32), buckets
     )
     slots = sum(r.size for r in buckets.row_indices)
+    assert len(prepared) > 1
     for pb, rows in zip(prepared, buckets.row_indices):
-        if shuffled:
-            assert pb.row_idx.shape == rows.shape
-        else:
-            np.testing.assert_array_equal(np.asarray(pb.row_idx), rows[:, 0])
+        assert pb.row_idx.shape == rows.shape[:1] and pb.row_idx.dtype == jnp.int32
         assert pb.mask.shape == rows.shape
+        assert pb.order is prepared[0].order  # ONE array, the effect's
+        if not shuffled:
+            np.testing.assert_array_equal(np.asarray(pb.row_idx), rows[:, 0])
+    if shuffled:
+        order = np.asarray(prepared[0].order)
+        assert order.dtype == np.int32 and order[0] == 0
+        # a permutation of the real rows in the effect's order: class by
+        # class, lane by lane, a lane's rows as its slots hold them
+        np.testing.assert_array_equal(
+            order[1:], np.concatenate([r[r >= 0] for r in buckets.row_indices])
+        )
+        np.testing.assert_array_equal(np.sort(order[1:]), np.arange(len(y)))
+    else:
+        assert prepared[0].order is None
     assert _slot_counters() == {
-        "re_offsets.slots": slots, "re_offsets.run_slots": 0 if shuffled else slots,
+        "re_offsets.slots": slots, "re_offsets.run_slots": slots,
+        "re_offsets.ordered_rows": len(y) + 1 if shuffled else 0,
     }
 
 
@@ -164,26 +263,39 @@ def _blocks_with_one_broken_lane():
     return X, y, buckets
 
 
-def test_one_broken_lane_keeps_its_bucket_on_slot_indices_and_no_other(monkeypatch):
-    """That bucket is staged whole by slot indices, the narrow class by run
-    starts, and the solve is the all-slot-index solve bit for bit."""
+def _solve(prepared, n, mesh=None, iterations=30):
+    return train_prepared(
+        prepared, jnp.asarray(np.linspace(-1.0, 1.0, n, dtype=np.float32)), 3, 40,
+        logistic_loss, OptimizerConfig(max_iterations=iterations, tolerance=1e-9),
+        l2_weight=1.0, mesh=mesh,
+    )
+
+
+def test_one_broken_lane_sends_its_whole_effect_through_the_ordered_copy():
+    """Every class of the effect, the narrow one whose lanes are all runs
+    too, slices the one ordered copy; the solve is the slot-index solve bit
+    for bit."""
     X, y, buckets = _blocks_with_one_broken_lane()
     assert buckets.capacities == (8, 32)
-    args = (DenseFeatures(X=jnp.asarray(X)), y, np.zeros(len(y), np.float32),
-            np.ones(len(y), np.float32), buckets, 40, logistic_loss,
-            OptimizerConfig(max_iterations=30, tolerance=1e-8))
+    assert [_run_starts(r) is None for r in buckets.row_indices] == [False, True]
     REGISTRY.reset(prefix="re_offsets")
-    prepared = prepare_buckets(args[0], y, args[3], buckets)
-    assert [pb.row_idx.ndim for pb in prepared] == [1, 2]
+    prepared = prepare_buckets(
+        DenseFeatures(X=jnp.asarray(X)), y, np.ones(len(y), np.float32), buckets
+    )
+    assert [pb.row_idx.ndim for pb in prepared] == [1, 1]
+    assert prepared[0].order is prepared[1].order is not None
+    slots = sum(r.size for r in buckets.row_indices)
     assert _slot_counters() == {
-        "re_offsets.slots": sum(r.size for r in buckets.row_indices),
-        "re_offsets.run_slots": buckets.row_indices[0].size,
+        "re_offsets.slots": slots, "re_offsets.run_slots": slots,
+        "re_offsets.ordered_rows": len(y) + 1,
     }
-    got = train_random_effects(*args, l2_weight=1.0)
-    monkeypatch.setattr(re_mod, "_run_starts", lambda rows: None)
-    want = train_random_effects(*args, l2_weight=1.0)
+    got = _solve(prepared, len(y))
+    reference = slot_index_reading(prepared)
+    assert [pb.row_idx.ndim for pb in reference] == [2, 2]
+    want = _solve(reference, len(y))
     np.testing.assert_array_equal(_bits(got.coefficients), _bits(want.coefficients))
     np.testing.assert_array_equal(got.iterations, want.iterations)
+    assert got.iterations.max() > 1
 
 
 _GATHER = re.compile(
@@ -197,43 +309,61 @@ def _gather_index_counts(text):
             for m in _GATHER.finditer(text)]
 
 
-@pytest.mark.parametrize("form", ["run_starts", "slot_indices"])
-def test_lowered_bucket_step_gathers_no_index_a_slot_from_run_starts(form, monkeypatch):
-    """Exact gate, counted in the CPU lowering: the step of a run bucket
-    holds no gather whose index operand has k_pad * C elements; the same
-    bucket staged by slot indices holds exactly one (the control)."""
+def _item_coordinate(shuffled):
+    from photon_ml_tpu.config import OptimizationConfig, RegularizationContext
+    from photon_ml_tpu.game import RandomEffectCoordinate, make_game_batch
+    from photon_ml_tpu.types import OptimizerType, RegularizationType, TaskType
+
+    ids, X, y = _effect(13, lambda r, e: r.integers(5, 65, size=e), shuffled)
+    batch = make_game_batch(y, {"pi": X}, id_tags={"item": ids})
+    grouping = group_by_entity(ids, num_entities=13)
+    return RandomEffectCoordinate(
+        coordinate_id="per_item", batch=batch, feature_shard_id="pi",
+        random_effect_type="item",
+        config=OptimizationConfig(
+            optimizer=OptimizerConfig(
+                optimizer_type=OptimizerType.NEWTON_CHOLESKY, max_iterations=5,
+                tolerance=1e-6,
+            ),
+            regularization=RegularizationContext(RegularizationType.L2),
+            regularization_weight=1.0,
+        ),
+        grouping=grouping, buckets=bucket_entities(grouping, capacities=(16, 64)),
+        task_type=TaskType.LOGISTIC_REGRESSION, num_entities=13,
+    ), len(y)
+
+
+@pytest.mark.parametrize("form", ["blocks", "shuffled", "slot_indices"])
+def test_lowered_visit_gathers_one_index_a_real_row_and_none_a_slot(form):
+    """Exact gate, counted in the CPU lowering of the fused visit: a shuffled
+    effect's holds ONE gather over its n_real + 1 ordered rows and none whose
+    index operand has a class's k_pad * C elements; an effect in the file's
+    order holds neither; the same shuffled effect read by slot indices holds
+    one a class (the control)."""
+    coord, n = _item_coordinate(shuffled=form != "blocks")
     if form == "slot_indices":
-        monkeypatch.setattr(re_mod, "_run_starts", lambda rows: None)
-    ids, X, y = _effect(13, lambda r, e: r.integers(33, 65, size=e), shuffled=False)
-    buckets = bucket_entities(group_by_entity(ids, num_entities=13), capacities=(64,))
-    (pb,) = prepare_buckets(
-        DenseFeatures(X=jnp.asarray(X)), y, np.ones(len(y), np.float32), buckets
-    )
-    config = OptimizerConfig(max_iterations=5, tolerance=1e-6)
-    minimize_fn, extra = select_minimize_fn(config, 0.0)
-    text = _bucket_step.lower(
-        jnp.zeros((13, 3), jnp.float32), None, jnp.zeros(len(y), jnp.float32),
-        pb.static, pb.row_idx, pb.mask, pb.ids, None, None,
-        jnp.asarray(1.0, jnp.float32), None, None, None,
-        minimize_fn=minimize_fn, loss=logistic_loss, config=config,
-        intercept_index=None, variance_computation=VarianceComputationType.NONE,
-        k=pb.num_real, sharding=None, **extra,
-    ).as_text()
+        object.__setattr__(coord, "_prepared_cache", slot_index_reading(coord._prepared))
+    make_static, _, _, _ = coord._fused_visit_parts()
+    total = jnp.zeros((n,), jnp.float32)
+    text = coord._visit_fn[1].lower(total, total, *make_static(None)).as_text()
     counts = _gather_index_counts(text)
-    assert counts, "the pattern finds no gather at all in the lowered step"
-    slots = pb.mask.shape[0] * pb.mask.shape[1]
-    assert counts.count(slots) == (0 if form == "run_starts" else 1), counts
-    # whole aligned 128-wide rows of the offsets, in the run form alone
+    assert counts, "the pattern finds no gather at all in the lowered visit"
+    slots = [pb.mask.shape[0] * pb.mask.shape[1] for pb in coord._prepared]
+    assert len(slots) == 2 and not set(slots) & {n, n + 1}
+    assert [counts.count(s) for s in slots] == ([1, 1] if form == "slot_indices" else [0, 0])
+    # the scoring gather reads n ids; the ordered copy n + 1 rows
+    assert counts.count(n + 1) == (1 if form == "shuffled" else 0), counts
+    # whole aligned 128-wide rows of the offsets, a class: the run forms alone
     rows_gathered = text.count("slice_sizes = array<i64: 1, 128>")
-    assert rows_gathered == (1 if form == "run_starts" else 0)
+    assert rows_gathered == (0 if form == "slot_indices" else 2)
 
 
 @pytest.mark.parametrize("placement", ["lane_sharded", "owned_split", "device_split"])
-def test_run_starts_follow_every_placement_of_the_lanes(placement, monkeypatch):
-    """Lanes sharded over a mesh, owned sub-bucket atoms and atoms placed on
-    local devices stage and shard the starts as they do the indices: under
-    each, the solve is the slot-index solve bit for bit, and the atoms of one
-    parent bucket share one form (they are concatenated again)."""
+def test_the_ordered_copy_follows_every_placement_of_the_lanes(placement, monkeypatch):
+    """Lanes sharded over a mesh (a device's segment of the order each),
+    owned sub-bucket atoms and atoms placed on local devices all slice the
+    effect's one ordered copy: under each, the solve is the slot-index solve
+    bit for bit."""
     from photon_ml_tpu.parallel import data_mesh
 
     if placement != "lane_sharded":
@@ -243,24 +373,27 @@ def test_run_starts_follow_every_placement_of_the_lanes(placement, monkeypatch):
         monkeypatch.setenv("PHOTON_RE_DEVICE_SPLIT", "1")
     X, y, buckets = _blocks_with_one_broken_lane()
     feats, ones = DenseFeatures(X=jnp.asarray(X)), np.ones(len(y), np.float32)
+    REGISTRY.reset(prefix="re_offsets")
     prepared = prepare_buckets(feats, y, ones, buckets, data_mesh())
-    forms: dict = {}
-    for i, pb in enumerate(prepared):
-        forms.setdefault(i if pb.parent is None else pb.parent, set()).add(pb.row_idx.ndim)
-    assert sorted(map(sorted, forms.values())) == [[1], [2]]
-    if placement != "lane_sharded":
+    assert all(pb.row_idx.ndim == 1 and pb.order is prepared[0].order for pb in prepared)
+    order = np.asarray(prepared[0].order)
+    if placement == "lane_sharded":
+        n_dev = data_mesh().size
+        assert len(order) % n_dev == 0 and len(order) > len(y) + n_dev  # filler
+        np.testing.assert_array_equal(order[::len(order) // n_dev], 0)
+    else:
         assert len(prepared) > len(buckets.capacities)  # the classes were split
-    args = (feats, y, np.zeros(len(y), np.float32), ones, buckets, 40, logistic_loss,
-            OptimizerConfig(max_iterations=8, tolerance=1e-9))
-    got = train_random_effects(*args, l2_weight=1.0, mesh=data_mesh())
-    monkeypatch.setattr(re_mod, "_run_starts", lambda rows: None)
-    want = train_random_effects(*args, l2_weight=1.0, mesh=data_mesh())
+        assert len(order) == len(y) + 1
+    assert _slot_counters()["re_offsets.ordered_rows"] == len(order)
+    np.testing.assert_array_equal(np.unique(order), np.arange(len(y)))
+    got = _solve(prepared, len(y), data_mesh(), iterations=8)
+    want = _solve(slot_index_reading(prepared), len(y), data_mesh(), iterations=8)
     np.testing.assert_array_equal(_bits(got.coefficients), _bits(want.coefficients))
     np.testing.assert_array_equal(got.iterations, want.iterations)
     assert got.iterations.max() > 1
 
 
-@pytest.mark.parametrize("prepared", [True, False])
+@pytest.mark.parametrize("prepared", ["ordered", "before_the_counter", "none"])
 def test_the_run_report_renders_the_slot_counters(tmp_path, prepared):
     from photon_ml_tpu.obs.report import format_summary, summarize_run
     from photon_ml_tpu.obs.sink import TelemetrySink
@@ -268,7 +401,10 @@ def test_the_run_report_renders_the_slot_counters(tmp_path, prepared):
     counters = {
         "re_offsets.slots": {"value": 4000.0},
         "re_offsets.run_slots": {"value": 1000.0},
-    } if prepared else {}
+    } if prepared != "none" else {}
+    if prepared == "ordered":
+        counters["re_offsets.run_slots"] = {"value": 4000.0}
+        counters["re_offsets.ordered_rows"] = {"value": 1800.0}
     sink = TelemetrySink(str(tmp_path), run_id="HEAD", shard_index=None)
     sink.emit({"event": "run_start", "t": 1000.0, "schema_version": 1,
                "run_id": "HEAD", "pid": 0, "process_index": 0, "knobs": {},
@@ -278,9 +414,18 @@ def test_the_run_report_renders_the_slot_counters(tmp_path, prepared):
                            "histograms": {}, "timers": {}}})
     sink.close()
     summary = summarize_run(sink.path)
-    if not prepared:
+    if prepared == "none":
         assert "re_offsets" not in summary
         assert "re-offsets" not in format_summary(summary)
         return
-    assert summary["re_offsets"]["run_slot_share"] == 0.25
-    assert "(25.0%) read by run-start slices" in format_summary(summary)
+    text = format_summary(summary)
+    if prepared == "ordered":
+        assert summary["re_offsets"]["run_slot_share"] == 1.0
+        assert summary["re_offsets"]["index_share"] == 0.45
+        assert "(100.0%) read by run-start slices" in text
+        assert "1.80K rows gathered a visit" in text
+        assert "(45.0% of the slots read one index each)" in text
+    else:  # a run from before ``re_offsets.ordered_rows``: read as 0
+        assert summary["re_offsets"]["run_slot_share"] == 0.25
+        assert summary["re_offsets"]["index_share"] == 0.75
+        assert "(25.0%) read by run-start slices" in text

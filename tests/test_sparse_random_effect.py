@@ -670,12 +670,12 @@ def test_the_run_report_renders_the_subspace_counters(tmp_path, prepared):
 # dense per-item effect over scattered rows
 # ---------------------------------------------------------------------------
 def _blocks_and_shuffled_sparse_run(path, monkeypatch, refuse):
-    from photon_ml_tpu.game import random_effect as re_mod
+    """``refuse``: both effects read their offsets one index a slot."""
+    from stage_programs import slot_index_reading
+
     from photon_ml_tpu.obs.metrics import REGISTRY
 
     monkeypatch.setenv("PHOTON_RE_COMPACT_EVERY", "2" if path == "compacted" else "0")
-    if refuse:
-        monkeypatch.setattr(re_mod, "_run_starts", lambda rows: None)
     d, users, items = 400, 18, 9
     idx, val, ids, y = _sparse_problem(9, n=700, d=d, entities=users)
     order = np.argsort(ids, kind="stable")
@@ -704,6 +704,14 @@ def _blocks_and_shuffled_sparse_run(path, monkeypatch, refuse):
             buckets=bucket_entities(g), task_type=TASK, num_entities=entities,
         )
     REGISTRY.reset(prefix="re_offsets")
+    orders = {cid: coords[cid]._prepared[0].order for cid in ("per_user", "per_item")}
+    # the users' lanes are runs of the file; the items' rows are gathered once
+    assert orders["per_user"] is None and orders["per_item"].shape == (len(y) + 1,)
+    if refuse:
+        for cid in orders:
+            object.__setattr__(
+                coords[cid], "_prepared_cache", slot_index_reading(coords[cid]._prepared)
+            )
     res = CoordinateDescent(coords, batch, TASK).run(list(coords), 2)
     counters = {k: v["value"] for k, v in
                 REGISTRY.snapshot("re_offsets.")["counters"].items()}
@@ -725,16 +733,16 @@ def test_run_start_offsets_leave_the_sparse_descent_bitwise(path, monkeypatch):
     got, counters, slots, forms = _blocks_and_shuffled_sparse_run(
         path, monkeypatch, refuse=False
     )
-    assert forms == {"per_user": {1}, "per_item": {2}}
+    assert forms == {"per_user": {1}, "per_item": {1}}
     assert counters == {
         "re_offsets.slots": slots["per_user"] + slots["per_item"],
-        "re_offsets.run_slots": slots["per_user"],
+        "re_offsets.run_slots": slots["per_user"] + slots["per_item"],
+        "re_offsets.ordered_rows": 701,
     }
-    want, refused, _, forms = _blocks_and_shuffled_sparse_run(
+    want, _, _, forms = _blocks_and_shuffled_sparse_run(
         path, monkeypatch, refuse=True
     )
     assert forms == {"per_user": {2}, "per_item": {2}}
-    assert refused["re_offsets.run_slots"] == 0
     assert got.keys() == want.keys() and len(got) == 8
     for name in got:
         np.testing.assert_array_equal(
