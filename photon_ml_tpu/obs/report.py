@@ -484,6 +484,19 @@ def summarize_run(path: str, records: list[dict] | None = None) -> dict:
             "columns": counter_v("dense_layout.columns"),
             "padded_columns": counter_v("dense_layout.padded_columns"),
         }
+    # L-BFGS / OWL-QN history rings (lbfgs_history.*, optim/lbfgs): the
+    # bytes of the S and Y rings of every solve traced, and those stored as
+    # whole (8, 128) tiles (a solve at least 65,536 wide). Present only on
+    # runs that traced an L-BFGS solve.
+    if "lbfgs_history.ring_bytes" in counters or \
+            "lbfgs_history.ring_bytes" in base_counters:
+        ring = counter_v("lbfgs_history.ring_bytes")
+        tiled = counter_v("lbfgs_history.tiled_bytes")
+        out["lbfgs_history"] = {
+            "ring_bytes": ring,
+            "tiled_bytes": tiled,
+            "tiled_share": tiled / ring if ring > 0 else None,
+        }
     # per-entity feature projection (re_project.*, game/projector): the
     # mean solved-width ratio and the per-lane bytes the subspace solves
     # shaved off the full-width schedule, plus the ladder narrative
@@ -800,6 +813,12 @@ def format_summary(s: dict) -> str:
             f"  dense-layout: {_fmt_qty(den['columns'])} columns of dense "
             f"objectives, {_fmt_qty(den['padded_columns'])} added by their "
             "kernels' blocks"
+        )
+    hist = s.get("lbfgs_history") or {}
+    if hist.get("tiled_share") is not None:
+        lines.append(
+            f"  lbfgs-history: {_fmt_qty(hist['ring_bytes'])} bytes of history "
+            f"rings, {100.0 * hist['tiled_share']:.1f}% stored as whole tiles"
         )
     prj = s.get("re_project") or {}
     if prj.get("mean_ratio") is not None or prj.get("classes"):

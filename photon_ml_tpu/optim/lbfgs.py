@@ -12,7 +12,11 @@ TPU-first design:
   an "evaluation" is a fused matmul pass (+ one psum when sharded) and the
   iteration loop never leaves the device.
 - History buffers are fixed (m, d) arrays with a ring index — no dynamic
-  shapes, so XLA compiles one tile layout for the whole run.
+  shapes, so XLA compiles one tile layout for the whole run. From
+  ``_TILED_MIN_WIDTH`` columns up a slot is stored as whole (8, 128) tiles,
+  ``(m, R, 128)``: a TPU lays an ``(m, d)`` array with m on the sublanes,
+  so one slot of it is one sublane of every tile and reading it costs the
+  whole tile, eight slots' bytes (``_tiled_rows``).
 - OWL-QN shares the implementation: the L1 machinery (pseudo-gradient,
   orthant projection of direction and iterates) switches on statically, so
   the plain L-BFGS path compiles with zero L1 overhead.
@@ -48,6 +52,11 @@ _CURVATURE_EPS = 1e-10
 _F_RESOLUTION = 1e-7
 # what a step that f cannot judge must leave of the gradient norm, at most
 _BLIND_SHRINK = 0.5
+# the width from which the history ring is stored as whole (8, 128) tiles:
+# padding a slot to whole tiles then costs under 1/64 of its bytes
+_TILED_MIN_WIDTH = 64 * 1024
+_LANES = 128
+_TILE = 8 * _LANES
 
 
 class _LbfgsState(NamedTuple):
@@ -55,8 +64,8 @@ class _LbfgsState(NamedTuple):
     f: Array  # objective value at w (incl. L1 term for OWL-QN)
     g: Array  # smooth gradient at w
     pg: Array  # pseudo-gradient (== g when no L1)
-    S: Array  # (m, d) s-history ring
-    Y: Array  # (m, d) y-history ring
+    S: Array  # (m, d) s-history ring; (m, R, 128) from _TILED_MIN_WIDTH up
+    Y: Array  # (m, d) y-history ring; (m, R, 128) from _TILED_MIN_WIDTH up
     rho: Array  # (m,) 1/(sᵀy) ring
     count: Array  # int32: number of pairs ever stored (ring head = count-1 mod m)
     it: Array  # int32 iteration counter
@@ -98,30 +107,66 @@ def _pseudo_gradient(w: Array, g: Array, l1w: Array) -> Array:
     return jnp.where(w > 0.0, gp, jnp.where(w < 0.0, gm, at_zero))
 
 
+def _tiled_rows(d: int) -> int | None:
+    """Rows ``R`` of a history slot stored as whole (8, 128) tiles, a
+    multiple of 8 with ``R * 128 >= d``; None below ``_TILED_MIN_WIDTH``,
+    where the ring stays ``(m, d)``. A slot of ``(m, R, 128)`` is ``R / 8``
+    whole tiles one after the other, so a dynamic slot index reads and
+    writes its own bytes only."""
+    if d < _TILED_MIN_WIDTH:
+        return None
+    return -(-d // _TILE) * 8
+
+
+def _to_tiles(v: Array, rows: int) -> Array:
+    """``(d,)`` -> ``(rows, 128)``, zero-padded: the padding adds exactly 0
+    to every dot and stays 0 through every axpy of the two-loop."""
+    return jnp.pad(v, (0, rows * _LANES - v.shape[0])).reshape(rows, _LANES)
+
+
+def _vdot(a: Array, b: Array) -> Array:
+    # a dot over a tiled slot is a multiply-reduce in its own layout:
+    # flattening it first would copy the slot
+    return jnp.dot(a, b) if a.ndim == 1 else jnp.sum(a * b)
+
+
+def _count_history(m: int, d: int, rows: int | None, dtype) -> None:
+    """``lbfgs_history.ring_bytes`` (the bytes of the S and Y rings of
+    every solve traced) and ``lbfgs_history.tiled_bytes`` (those stored as
+    whole tiles), in the always-on registry, once a trace."""
+    from photon_ml_tpu.obs.metrics import REGISTRY
+
+    slot = d if rows is None else rows * _LANES
+    ring = 2.0 * m * slot * jnp.dtype(dtype).itemsize
+    REGISTRY.counter_inc("lbfgs_history.ring_bytes", ring)
+    REGISTRY.counter_inc("lbfgs_history.tiled_bytes", 0.0 if rows is None else ring)
+
+
 def _two_loop(pg: Array, S: Array, Y: Array, rho: Array, count: Array, m: int) -> Array:
     """Two-loop recursion: returns r ≈ H⁻¹·pg using the ring-buffer history.
-    Unfilled slots contribute exactly zero (their alpha/beta are masked)."""
+    Unfilled slots contribute exactly zero (their alpha/beta are masked).
+    ``pg`` comes in the slots' own form, ``(d,)`` or ``(R, 128)``."""
     valid_n = jnp.minimum(count, m)
 
     def bwd(i, carry):
         q, alpha = carry
         slot = jnp.mod(count - 1 - i, m)
         valid = i < valid_n
-        a = jnp.where(valid, rho[slot] * jnp.dot(S[slot], q), 0.0)
+        a = jnp.where(valid, rho[slot] * _vdot(S[slot], q), 0.0)
         q = q - a * Y[slot]
         return q, alpha.at[slot].set(a)
 
     q, alpha = lax.fori_loop(0, m, bwd, (pg, jnp.zeros((m,), pg.dtype)))
 
     newest = jnp.mod(count - 1, m)
-    yy = jnp.dot(Y[newest], Y[newest])
-    gamma = jnp.where(count > 0, jnp.dot(S[newest], Y[newest]) / jnp.maximum(yy, 1e-30), 1.0)
+    yy = _vdot(Y[newest], Y[newest])
+    gamma = jnp.where(count > 0, _vdot(S[newest], Y[newest]) / jnp.maximum(yy, 1e-30), 1.0)
     r = gamma * q
 
     def fwd(i, r):
         slot = jnp.mod(count - valid_n + i, m)
         valid = i < valid_n
-        beta = rho[slot] * jnp.dot(Y[slot], r)
+        beta = rho[slot] * _vdot(Y[slot], r)
         r = r + jnp.where(valid, alpha[slot] - beta, 0.0) * S[slot]
         return r
 
@@ -167,6 +212,9 @@ def _lbfgs_funcs(objective: Any, config: OptimizerConfig, l1w: Array | None):
     def init(w0: Array) -> _LbfgsState:
         d = w0.shape[0]
         dtype = w0.dtype
+        rows = _tiled_rows(d)
+        _count_history(m, d, rows, dtype)
+        ring = (m, d) if rows is None else (m, rows, _LANES)
         f0, g0, pg0 = value_and_grads(w0)
         g0_norm = jnp.linalg.norm(pg0)
 
@@ -180,8 +228,8 @@ def _lbfgs_funcs(objective: Any, config: OptimizerConfig, l1w: Array | None):
             f=f0,
             g=g0,
             pg=pg0,
-            S=jnp.zeros((m, d), dtype),
-            Y=jnp.zeros((m, d), dtype),
+            S=jnp.zeros(ring, dtype),
+            Y=jnp.zeros(ring, dtype),
             rho=jnp.zeros((m,), dtype),
             count=jnp.int32(0),
             it=jnp.int32(0),
@@ -197,8 +245,14 @@ def _lbfgs_funcs(objective: Any, config: OptimizerConfig, l1w: Array | None):
         return jnp.logical_and(st.it < T, jnp.logical_not(st.done))
 
     def body(st: _LbfgsState) -> _LbfgsState:
+        d = st.w.shape[0]
+        rows = _tiled_rows(d)
         with stage(LBFGS_TWO_LOOP):
-            p = -_two_loop(st.pg, st.S, st.Y, st.rho, st.count, m)
+            if rows is None:
+                p = -_two_loop(st.pg, st.S, st.Y, st.rho, st.count, m)
+            else:
+                r = _two_loop(_to_tiles(st.pg, rows), st.S, st.Y, st.rho, st.count, m)
+                p = -r.reshape(-1)[:d]
             if use_l1:
                 # constrain the search direction to the descent orthant
                 p = jnp.where(p * (-st.pg) > 0.0, p, 0.0)
@@ -329,8 +383,14 @@ def _lbfgs_funcs(objective: Any, config: OptimizerConfig, l1w: Array | None):
             sy = jnp.dot(s, y)
             store = jnp.logical_and(ls_ok, sy > _CURVATURE_EPS)
             slot = jnp.mod(st.count, m)
-            S = jnp.where(store, st.S.at[slot].set(s), st.S)
-            Y = jnp.where(store, st.Y.at[slot].set(y), st.Y)
+            if rows is None:
+                S = jnp.where(store, st.S.at[slot].set(s), st.S)
+                Y = jnp.where(store, st.Y.at[slot].set(y), st.Y)
+            else:
+                # one slot written in place, where the select above reads
+                # and writes both whole rings
+                S = st.S.at[slot].set(jnp.where(store, _to_tiles(s, rows), st.S[slot]))
+                Y = st.Y.at[slot].set(jnp.where(store, _to_tiles(y, rows), st.Y[slot]))
             rho = jnp.where(store, st.rho.at[slot].set(1.0 / jnp.maximum(sy, _CURVATURE_EPS)), st.rho)
             count = jnp.where(store, st.count + 1, st.count)
 

@@ -13,6 +13,8 @@ from __future__ import annotations
 
 import importlib.util
 import re
+from dataclasses import dataclass
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -802,3 +804,90 @@ def test_mesh_visit_of_the_whole_item_effect_fits_a_chip_of_four(
     assert not re.search(r"s32\[\d+,(256|512|1024|2048|4096|8192|32768|131072)\]", text)
     paths = set(re.findall(r'op_name="([^"]*)"', text))
     assert any("/mesh.exchange/" in p for p in paths)
+
+
+# -- the L-BFGS history ring of a wide solve (optim/lbfgs._tiled_rows) -------
+
+
+@partial(jax.tree_util.register_dataclass, data_fields=["a", "b"], meta_fields=[])
+@dataclass(frozen=True)
+class _Diagonal:
+    """f(w) = ½ Σ aⱼ (wⱼ - bⱼ)²: a pass over two vectors, so the compiled
+    program is the optimizer's."""
+
+    a: jnp.ndarray
+    b: jnp.ndarray
+
+    def value(self, w):
+        r = w - self.b
+        return 0.5 * jnp.sum(self.a * r * r)
+
+    def value_and_grad(self, w):
+        r = w - self.b
+        return 0.5 * jnp.sum(self.a * r * r), self.a * r
+
+
+def _compile_lbfgs(topo, d: int, monkeypatch=None):
+    """``lbfgs_minimize`` at width ``d`` for the described v5e; with
+    ``monkeypatch``, with the gate moved past every width: the ``(m, d)``
+    ring every width below it keeps."""
+    from photon_ml_tpu.config import OptimizerConfig
+    from photon_ml_tpu.optim import lbfgs
+
+    if monkeypatch is not None:
+        monkeypatch.setattr(lbfgs, "_TILED_MIN_WIDTH", 1 << 62)
+    spec = _spec(topo)
+    jax.clear_caches()
+    try:
+        return lbfgs.lbfgs_minimize.lower(
+            _Diagonal(spec((d,), jnp.float32), spec((d,), jnp.float32)),
+            spec((d,), jnp.float32), OptimizerConfig(max_iterations=20),
+        ).compile()
+    finally:
+        jax.clear_caches()
+
+
+def _without_metadata(text: str) -> str:
+    """The compiled program without its location tables and metadata."""
+    return re.sub(r", metadata=\{[^}]*\}", "", "\n".join(
+        line for line in text.splitlines()
+        if not re.match(r"\s*(FileNames|FunctionNames|FileLocations|StackFrames)", line)
+        and not re.match(r'\s*\d+ ["{]', line)
+    ))
+
+
+def _big_copies(text: str, d: int) -> int:
+    """Copies of float32 arrays of at least ``d`` elements."""
+    n = 0
+    for m in re.finditer(r"= \(?f32\[([\d,]+)\][^=]*? copy(?:-start)?\(", text):
+        if np.prod([int(x) for x in m.group(1).split(",")]) >= d:
+            n += 1
+    return n
+
+
+def test_a_wide_lbfgs_ring_is_whole_tiles_read_a_slot_at_a_time(topo, monkeypatch):
+    d, m = 1 << 21, 10
+    rows = d // 128
+    tiled = _compile_lbfgs(topo, d)
+    plain = _compile_lbfgs(topo, d, monkeypatch)
+    text, plain_text = tiled.as_text(), plain.as_text()
+    ring = f"f32[{m},{rows},128]{{2,1,0:T(8,128)}}"
+    assert ring in text and f"f32[{m},{d}]" not in text
+    assert f"f32[{m},{d}]{{1,0:T(8,128)}}" in plain_text  # m padded to 16 rows
+    # a slot is read by a dynamic slice of the major dimension, whole tiles
+    sliced = set(re.findall(r"= f32\[([\d,]+)\]\S* dynamic-slice\(", text))
+    assert sliced == {f"1,{rows},128", "1"}  # a slot; rho and alpha's scalars
+    assert "transpose(" not in text
+    assert _big_copies(text, d) <= _big_copies(plain_text, d)
+    # the rings take m rows, not 16: 2 * 6 * R * 128 * 4 bytes fewer
+    padding = 2 * (16 - m) * rows * 128 * 4
+    need = tiled.memory_analysis().temp_size_in_bytes
+    assert need + padding <= plain.memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize("d", [65, 47_236], ids=["descent_fixed", "rcv1"])
+def test_below_the_gate_the_lbfgs_program_is_the_untiled_rings(topo, monkeypatch, d):
+    text = _compile_lbfgs(topo, d).as_text()
+    assert f"f32[10,{d}]" in text
+    plain = _compile_lbfgs(topo, d, monkeypatch).as_text()
+    assert _without_metadata(text) == _without_metadata(plain)

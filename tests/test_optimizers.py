@@ -451,3 +451,152 @@ class TestNewtonCholesky:
             np.asarray(m_l.models["user"].coefficients),
             rtol=1e-2, atol=1e-3,
         )
+
+
+# -- the history ring of a wide solve (optim/lbfgs._tiled_rows) --------------
+
+_WIDE = 70_000  # above lbfgs._TILED_MIN_WIDTH (65,536): the ring is tiled
+
+
+@partial(jax.tree_util.register_dataclass, data_fields=["c"], meta_fields=[])
+@dataclass(frozen=True)
+class LinearObjective:
+    """f(w) = cᵀw: every step's y is 0, so no pair is ever stored."""
+
+    c: jnp.ndarray
+
+    def value(self, w):
+        return jnp.dot(self.c, w)
+
+    def value_and_grad(self, w):
+        return jnp.dot(self.c, w), self.c
+
+
+def _wide_logistic(rng, d=_WIDE, n=48, dtype=jnp.float32):
+    X = rng.normal(size=(n, d)) / np.sqrt(d)
+    y = (rng.uniform(size=n) < 0.5).astype(np.float64)
+    return make_objective(
+        dense_batch_from_numpy(X, y, dtype=dtype), LOSSES["logistic"],
+        l2_weight=0.05,
+    )
+
+
+def _untiled(monkeypatch):
+    """The ring as every width below the gate keeps it, ``(m, d)``."""
+    from photon_ml_tpu.optim import lbfgs
+
+    monkeypatch.setattr(lbfgs, "_TILED_MIN_WIDTH", 1 << 62)
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("l1", [None, 0.02], ids=["lbfgs", "owlqn"])
+def test_tiled_ring_reaches_the_untiled_rings_solve(l1, rng, monkeypatch):
+    """The same recursion on the same pairs: only the order in which a
+    dot sums differs, so in float64 the two solves take the same steps."""
+    obj = _wide_logistic(rng, dtype=jnp.float64)
+    w0 = jnp.zeros(_WIDE, jnp.float64)
+    cfg = OptimizerConfig(max_iterations=20, tolerance=1e-7)
+
+    def solve():
+        if l1 is None:
+            return lbfgs_minimize(obj, w0, cfg)
+        return owlqn_minimize(obj, w0, cfg, l1)
+
+    jax.clear_caches()
+    tiled = solve()
+    _untiled(monkeypatch)
+    plain = solve()
+    jax.clear_caches()
+    assert int(tiled.iterations) == int(plain.iterations) > 5
+    assert int(tiled.objective_passes) == int(plain.objective_passes)
+    np.testing.assert_allclose(
+        np.asarray(tiled.w), np.asarray(plain.w),
+        rtol=0, atol=1e-6 * float(np.max(np.abs(np.asarray(plain.w)))),
+    )
+
+
+@pytest.mark.parametrize("l1", [0.0, 0.02], ids=["lbfgs", "owlqn"])
+def test_chunked_run_above_the_gate_is_the_one_shot_run(l1, rng):
+    from photon_ml_tpu.optim.common import select_chunked_solver, select_minimize_fn
+
+    obj = _wide_logistic(rng)
+    w0 = jnp.zeros(_WIDE, jnp.float32)
+    cfg = OptimizerConfig(max_iterations=12, tolerance=1e-7)
+    minimize_fn, extra = select_minimize_fn(cfg, l1)
+    ref = minimize_fn(obj, w0, cfg, **extra)
+    solver, extra = select_chunked_solver(cfg, l1)
+    state = solver.init(obj, w0, cfg, **extra)
+    assert state.S.ndim == 3
+    for bound in (4, 8, 12):
+        state = solver.run(obj, state, cfg, jnp.int32(bound), **extra)
+    out = solver.finalize(state)
+    for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(out)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("d", [1_000, _WIDE], ids=["below_gate", "above_gate"])
+def test_a_refused_step_leaves_both_rings_bitwise(d, rng):
+    from photon_ml_tpu.optim import lbfgs
+
+    obj = LinearObjective(jnp.asarray(rng.normal(size=d), jnp.float32))
+    cfg = OptimizerConfig(max_iterations=5, tolerance=1e-7)
+    init, _, body = lbfgs._lbfgs_funcs(obj, cfg, None)
+    st = init(jnp.zeros(d, jnp.float32))
+    m = cfg.history_length
+    st = st._replace(
+        S=jnp.asarray(rng.normal(size=st.S.shape), jnp.float32),
+        Y=jnp.asarray(rng.normal(size=st.Y.shape), jnp.float32),
+        rho=jnp.asarray(rng.uniform(0.5, 1.0, size=m), jnp.float32),
+        count=jnp.int32(3),
+    )
+    out = jax.jit(body)(st)
+    assert int(out.it) == 1 and int(out.count) == 3  # stepped, stored nothing
+    np.testing.assert_array_equal(np.asarray(out.S), np.asarray(st.S))
+    np.testing.assert_array_equal(np.asarray(out.Y), np.asarray(st.Y))
+
+
+@pytest.mark.parametrize(
+    "d,ring",
+    [(65_535, (10, 65_535)), (65_536, (10, 512, 128)), (_WIDE, (10, 552, 128))],
+    ids=["65535", "65536", "70000"],
+)
+def test_the_gate_is_the_width_and_the_counter_follows_it(d, ring):
+    from photon_ml_tpu.obs.metrics import REGISTRY
+    from photon_ml_tpu.optim import lbfgs
+
+    obj = LinearObjective(jnp.zeros(d, jnp.float32))
+    init, _, _ = lbfgs._lbfgs_funcs(obj, OptimizerConfig(), None)
+    REGISTRY.reset(prefix="lbfgs_history.")
+    st = jax.eval_shape(init, jax.ShapeDtypeStruct((d,), jnp.float32))
+    assert st.S.shape == st.Y.shape == ring
+    counters = REGISTRY.snapshot("lbfgs_history.")["counters"]
+    ring_bytes = 2 * 4 * int(np.prod(ring))
+    assert counters["lbfgs_history.ring_bytes"]["value"] == ring_bytes
+    tiled = counters["lbfgs_history.tiled_bytes"]["value"]
+    assert tiled == (ring_bytes if len(ring) == 3 else 0)
+
+
+@pytest.mark.parametrize("traced", [True, False], ids=["traced", "no_solve"])
+def test_the_run_report_renders_the_history_counters(tmp_path, traced):
+    from photon_ml_tpu.obs.report import format_summary, summarize_run
+    from photon_ml_tpu.obs.sink import TelemetrySink
+
+    counters = {
+        "lbfgs_history.ring_bytes": {"value": 4000.0},
+        "lbfgs_history.tiled_bytes": {"value": 3000.0},
+    } if traced else {}
+    sink = TelemetrySink(str(tmp_path), run_id="HEAD", shard_index=None)
+    sink.emit({"event": "run_start", "t": 1000.0, "schema_version": 1,
+               "run_id": "HEAD", "pid": 0, "process_index": 0, "knobs": {},
+               "fleet": {"process_count": 1}, "metrics_baseline": {}})
+    sink.emit({"event": "run_end", "t": 1002.0, "run_id": "HEAD",
+               "metrics": {"counters": counters, "gauges": {},
+                           "histograms": {}, "timers": {}}})
+    sink.close()
+    summary = summarize_run(sink.path)
+    if not traced:
+        assert "lbfgs_history" not in summary
+        assert "lbfgs-history" not in format_summary(summary)
+        return
+    assert summary["lbfgs_history"]["tiled_share"] == 0.75
+    assert "75.0% stored as whole tiles" in format_summary(summary)
