@@ -469,6 +469,10 @@ def summarize_run(path: str, records: list[dict] | None = None) -> dict:
             "tail_pad_ratio": slots / (2.0 * tail) if tail > 0 else None,
             # PR 40: the column chunks, and what the fine form holds
             "col_chunks": counter_v("tile_layout.col_chunks"),
+            # the kernel calls traced (tile_apply.*, ops/sparse_tiled.
+            # _tiled_apply) and the column chunks they run as grid steps
+            "kernel_calls": counter_v("tile_apply.calls"),
+            "grid_chunks": counter_v("tile_apply.grid_chunks"),
             "fine_cells": counter_v("tile_layout.fine_cells"),
             "fine_nonzeros": counter_v("tile_layout.fine_nonzeros"),
             "fine_slots": counter_v("tile_layout.fine_slots"),
@@ -806,6 +810,12 @@ def format_summary(s: dict) -> str:
             f"the fine form ({_fmt_qty(til['fine_cells'])} cells, "
             f"{_fmt_qty(til['fine_slots'])} slots) over "
             f"{int(til['col_chunks'])} column chunks"
+        )
+    if til.get("kernel_calls"):
+        lines.append(
+            f"  tile-layout: {int(til['col_chunks'])} column chunks built; "
+            f"{int(til['kernel_calls'])} kernel calls traced, running "
+            f"{int(til['grid_chunks'])} column chunks as grid steps"
         )
     den = s.get("dense_layout") or {}
     if den.get("columns"):

@@ -94,9 +94,11 @@ Design — write-slab-major tile-COO, built ONCE at ingest:
   once, as the head's rule prices the tail (``_head_columns``), and gives
   every chunk that one form. Every build cuts its columns into chunks of
   one width and, where there are several, STACKS them into one
-  ``_TileChunk`` a row chunk, whose pass is a ``lax.scan`` of one kernel
-  call a direction (XLA compiles each custom call of a program apart, and
-  a call a chunk cost minutes). So the resident build takes
+  ``_TileChunk`` a row chunk, end to end in every stream, whose pass is
+  ONE kernel call a direction with a grid step a column chunk (XLA
+  compiles each custom call of a program apart, and a call a chunk cost
+  minutes; a loop of one call a chunk would slice and relay out the
+  chunk's streams every pass). So the resident build takes
   ``_MAX_RESIDENT_COLS`` columns, past the 2^23 that a build keeping the
   run form stops at.
 
@@ -113,10 +115,12 @@ backend the kernels run in Pallas interpreter mode, so CPU tests trace the
 code the TPU compiles (``tests/test_kernels_compile_tpu.py`` compiles it
 for a v5e without a device — the interpreter accepts programs Mosaic
 refuses). Shapes whose tables exceed one kernel's VMEM bounds are split
-into row/col chunks, each its own kernel call, with partial outputs
-concatenated (rows) or summed (cols); a chunk whose scalar-prefetch
-streams exceed SMEM runs as several calls over pieces of its stream,
-outputs summed. Single-device by design:
+into row/col chunks: row chunks are calls of their own, outputs
+concatenated, and a row chunk's column chunks the grid steps of one call,
+margins summed in the kernel; where the chunks' scalar-prefetch streams
+exceed SMEM the call is cut at whole chunks, and a chunk beyond it alone
+runs as several calls over pieces of its stream, outputs summed.
+Single-device by design:
 under a mesh, shard rows first and build one tile-COO per shard (the
 objective's psum handles the reduction).
 """
@@ -526,10 +530,10 @@ class _Copies:
 
 
 def _tile_kernel_seg(
-    wslab_ref, rrun_ref, srun_ref, packed_hbm, src_ref, out_ref,
+    wslab_ref, rrun_ref, srun_ref, packed_hbm, src_ref, init_ref, out_ref,
     acc_scratch, p_scratch, pk_buf, dma_sem, ids_buf=None, ids_sem=None,
-    *, n_steps, step0, groups, segs, run_groups, square_vals, storage,
-    sub_slabs=0,
+    *, n_steps, step0, chunk0, chunk_steps, fold, groups, segs, run_groups,
+    square_vals, storage, sub_slabs=0,
 ):
     """The tile-COO kernel: a ``fori_loop`` over DMA steps, each step
     fetching ``segs * groups`` groups in ONE double-buffered DMA and
@@ -548,9 +552,16 @@ def _tile_kernel_seg(
     batched one-hot compare, matmul operands built as VALUES (no VMEM
     scratch round-trip).
 
-    The call covers DMA steps ``[step0, step0 + n_steps)`` of the packed
-    stream; the SMEM streams arrive sliced to that range, so only the DMA
-    adds ``step0``.
+    A grid step is a column chunk: ``c = pl.program_id(0)``, chunk
+    ``chunk0 + c`` of a stack whose chunks lie end to end in every stream,
+    ``chunk_steps`` DMA steps each. Of each of its chunks the call covers
+    DMA steps ``[step0, step0 + n_steps)``; the SMEM streams arrive sliced
+    to those steps, chunk after chunk, so only the DMAs add ``step0`` and
+    the chunk's offset in the whole streams. Where the source holds a
+    block a chunk (the margins), the chunks' accumulators add into the one
+    output block (``fold``): at ``c == 0`` it is the accumulator, or
+    ``init`` plus it, and after that itself plus it — the order in which a
+    loop over the chunks would add them.
 
     A step starts the next fetch, waits its own, and runs phase 1 then
     phase 2 of each segment in turn. There is no skew of segment s+1's
@@ -597,11 +608,14 @@ def _tile_kernel_seg(
         # lanes from granule u on: where slab u's gather replaces the others'
         lane8 = jax.lax.broadcasted_iota(jnp.int32, (8, GROUP), 1)
         from_granule = [lane8 >= u * (GROUP // sub_slabs) for u in range(sub_slabs)]
+    c = pl.program_id(0)  # the chunk; read outside every loop
+    first = (chunk0 + c) * chunk_steps + step0  # its first step in HBM
+    base = c * n_steps  # and in the call's SMEM streams
     acc_scratch[...] = jnp.zeros_like(acc_scratch)
 
     def dma(slot, t):
         packed = pltpu.make_async_copy(
-            packed_hbm.at[pl.ds((step0 + t) * step_groups, step_groups)],
+            packed_hbm.at[pl.ds((first + t) * step_groups, step_groups)],
             pk_buf.at[slot],
             dma_sem.at[slot],
         )
@@ -610,7 +624,7 @@ def _tile_kernel_seg(
         step_words = step_groups * sub_slabs
         return _Copies(packed, pltpu.make_async_copy(
             rrun_ref.at[pl.ds(
-                pl.multiple_of((step0 + t) * step_words, step_words), step_words
+                pl.multiple_of((first + t) * step_words, step_words), step_words
             )],
             ids_buf.at[pl.ds(
                 pl.multiple_of(slot * step_words, step_words), step_words
@@ -840,22 +854,36 @@ def _tile_kernel_seg(
             dma(nxt, t + 1).start()
 
         dma(slot, t).wait()
+        ts = base + t  # the step in the SMEM streams
         if sub_slabs > SUB_SLABS:
             def segment(s2, c):
-                phase1_fine(slot, t, s2)
-                phase2(slot, t, s2)
+                phase1_fine(slot, ts, s2)
+                phase2(slot, ts, s2)
                 return c
 
             jax.lax.fori_loop(0, segs, segment, 0)
             return carry
         for s2 in range(segs):
-            phase1_of_form(slot, t, s2)
-            phase2(slot, t, s2)
+            phase1_of_form(slot, ts, s2)
+            phase2(slot, ts, s2)
         return carry
 
     dma(0, 0).start()
     jax.lax.fori_loop(0, n_steps, step, 0)
-    out_ref[...] = acc_scratch[...]
+    if not fold:
+        out_ref[...] = acc_scratch[...]
+        return
+
+    @pl.when(c == 0)
+    def _():
+        out_ref[...] = (
+            acc_scratch[...] if init_ref is None
+            else init_ref[...] + acc_scratch[...]
+        )
+
+    @pl.when(c > 0)
+    def _():
+        out_ref[...] = out_ref[...] + acc_scratch[...]
 
 
 @functools.partial(
@@ -863,32 +891,30 @@ def _tile_kernel_seg(
     static_argnames=(
         "out_pad", "src_pad", "square_vals",
         "groups", "segs", "run_groups",
-        "storage", "interpret", "topology",
+        "storage", "interpret", "topology", "stack",
     ),
 )
 def _tiled_apply_jit(
     layout_arrays, src, out_pad, src_pad, square_vals,
     groups, segs, run_groups, storage, interpret,
-    topology=None,
+    topology=None, stack=1,
 ):
     packed, wslab, rslab, rrun, srun = layout_arrays
+    sub_slabs, n_steps, prefetch, calls = _stream_plan(
+        layout_arrays, stack, groups, segs, run_groups, storage
+    )
     step_groups = segs * groups
-    n_steps = int(packed.shape[0]) // step_groups
-    # the stream's form is in its own shape: a read slab a run of
-    # run_groups groups, or (the sparse-cell form) several a group
-    n_groups, n_slab_ids = int(packed.shape[0]), int(rrun.shape[0])
-    sub_slabs = 0 if n_slab_ids * run_groups == n_groups else n_slab_ids // n_groups
-    if sub_slabs and (
-        sub_slabs * n_groups != n_slab_ids or GROUP % sub_slabs
-        or storage != "f32"
-    ):
-        raise ValueError(
-            f"a stream of {n_groups} groups with {n_slab_ids} read slabs on "
-            f"the {storage} rung is no form this kernel reads"
-        )
     src_shape = (src_pad // 128, 128)
     out_shape = (out_pad // 128, 128)
-    src_mat = src.reshape(src_shape)
+    # the margins' source holds a block a chunk, and the chunks' outputs
+    # add (fold); the gradient's is one block, and each chunk writes its own
+    fold = int(src.shape[0]) != src_pad
+    if fold and int(src.shape[0]) != stack * src_pad:
+        raise ValueError(
+            f"a source of {src.shape[0]} is neither one block of {src_pad} "
+            f"nor one a chunk of {stack}"
+        )
+    src_mat = src.reshape(-1, 128)
     if storage != "f32":
         # the gathered operand carries bf16 VALUES under the reduced
         # rung (the source vector changes per call, so per-call int8
@@ -910,100 +936,171 @@ def _tiled_apply_jit(
         pltpu.VMEM(out_shape, jnp.float32),
         p_scratch, pk_buf, pltpu.SemaphoreType.DMA((2,)),
     ]
-    # Scalar-prefetch operands live in SMEM (1 MiB on a v5e) for the whole
-    # call, so the kernel is handed only the streams it reads: the write
-    # slab per segment, the read slab per run, and the dequant scales on
-    # the int8 rung alone. Passing all four cost 8.1 B a group
-    # and put a shape of n=2^19 rows (~166k groups) over the limit.
-    prefetch = [wslab, rrun]
-    if storage == "int8":
-        prefetch.append(srun)
     hbm_inputs = [packed]
     if sub_slabs:
         # the sparse-cell form's slab ids stay in HBM: the kernel brings
         # each DMA step's words into SMEM itself (see _tile_kernel_seg)
-        prefetch = [wslab]
         hbm_inputs.append(rrun)
         scratch = scratch + [
             pltpu.SMEM((2 * step_groups * sub_slabs,), jnp.int32),
             pltpu.SemaphoreType.DMA((2,)),
         ]
     n_prefetch = len(prefetch)
+    whole = lambda i, *_: (0, 0)
 
-    def piece(step0, steps):
-        """One kernel call over DMA steps [step0, step0 + steps): the
-        whole packed stream stays in HBM (no slice copy; the kernel's DMA
-        starts at ``step0``) and only the SMEM streams are sliced."""
+    def call(chunk0, chunks, step0, steps, init=None):
+        """One kernel call over chunks ``[chunk0, chunk0 + chunks)``, a grid
+        step each, and DMA steps ``[step0, step0 + steps)`` of each: the
+        whole streams stay in HBM (no slice copy; the kernel's DMAs start
+        at the chunk's offset and ``step0``) and only the SMEM streams are
+        sliced."""
         kernel = functools.partial(
-            _tile_kernel_seg, n_steps=steps, step0=step0, groups=groups,
-            segs=segs,
+            _tile_kernel_seg, n_steps=steps, step0=step0, chunk0=chunk0,
+            chunk_steps=n_steps, fold=fold and (chunks > 1 or init is not None),
+            groups=groups, segs=segs,
             run_groups=run_groups, square_vals=square_vals,
             storage=storage, sub_slabs=sub_slabs,
         )
 
         def body(*refs):
-            if sub_slabs:  # wslab | packed, slab ids | src, out, scratch
-                return kernel(refs[0], refs[2], None, refs[1], *refs[3:])
-            srun_ref = refs[2] if storage == "int8" else None
-            return kernel(refs[0], refs[1], srun_ref, *refs[n_prefetch:])
+            pre, refs = refs[:n_prefetch], refs[n_prefetch:]
+            hbm, refs = refs[:len(hbm_inputs)], refs[len(hbm_inputs):]
+            src_ref, init_ref = refs[0], refs[1] if init is not None else None
+            refs = refs[1 if init is None else 2:]  # out, scratch
+            rrun_ref = hbm[1] if sub_slabs else pre[1]
+            srun_ref = pre[2] if storage == "int8" else None
+            return kernel(
+                pre[0], rrun_ref, srun_ref, hbm[0], src_ref, init_ref, *refs
+            )
 
+        blocked = [pl.BlockSpec(
+            src_shape, (lambda i, *_: (chunk0 + i, 0)) if fold else whole
+        )]
+        if init is not None:
+            blocked.append(pl.BlockSpec(out_shape, whole))
         f = pl.pallas_call(
             body,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=n_prefetch,
-                grid=(1,),
+                grid=(chunks,),
                 in_specs=[
                     pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
                     for _ in hbm_inputs
-                ] + [pl.BlockSpec(src_shape, lambda i, *_: (0, 0))],
-                out_specs=pl.BlockSpec(out_shape, lambda i, *_: (0, 0)),
+                ] + blocked,
+                out_specs=pl.BlockSpec(
+                    out_shape, whole if fold else lambda i, *_: (i, 0)
+                ),
                 scratch_shapes=scratch,
             ),
-            out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
+            out_shape=jax.ShapeDtypeStruct(
+                (out_shape[0] * (1 if fold else chunks), 128), jnp.float32
+            ),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
                 vmem_limit_bytes=120 * 1024 * 1024,
             ),
             interpret=interpret,
         )
-        per_step = [int(a.shape[0]) // n_steps for a in prefetch]
+        # the call's steps, chunk after chunk, of each SMEM stream
+        lo, count = chunk0 * n_steps + step0, chunks * steps
+        per_step = [int(a.shape[0]) // (stack * n_steps) for a in prefetch]
         sliced = [
-            a[step0 * c:(step0 + steps) * c] for a, c in zip(prefetch, per_step)
+            a[lo * c:(lo + count) * c] for a, c in zip(prefetch, per_step)
         ]
-        return f(*sliced, *hbm_inputs, src_mat)
+        extra = [] if init is None else [init]
+        return f(*sliced, *hbm_inputs, src_mat, *extra)
 
-    # A stream whose prefetch operands exceed SMEM runs as several calls
-    # over consecutive step ranges, partial outputs summed. The split is
-    # read off the built stream's own size — how far a layout pads depends
-    # on where its nonzeros fall (8 uniform nonzeros a row at d=2^17 pad
-    # 4x, 32 pad 1.5x), which no shape-only bound predicts.
-    smem_per_step = sum(
-        int(a.shape[0]) // n_steps * a.dtype.itemsize for a in prefetch
-    )
-    bounds = _piece_bounds(n_steps, smem_per_step)
-    out = piece(0, bounds[0])
-    for lo, hi in zip(bounds, bounds[1:]):
-        out = out + piece(lo, hi - lo)
+    # Calls over whole chunks run in chunk order, each margins call adding
+    # onto the calls before it in the kernel; the calls over one chunk's
+    # step ranges (a chunk beyond SMEM) sum, and the margins' chunks then
+    # add in order. Every sum is the one a loop over the chunks makes.
+    parts = []
+    for chunk0, chunks, step0, steps in calls:
+        if step0:  # a later step range of the chunk before
+            parts[-1] = parts[-1] + call(chunk0, chunks, step0, steps)
+        elif fold and parts and steps == n_steps:
+            parts.append(call(chunk0, chunks, 0, steps, init=parts.pop()))
+        else:
+            parts.append(call(chunk0, chunks, 0, steps))
+    if not fold:
+        return jnp.concatenate(parts).reshape(-1)
+    out = parts[0]
+    for part in parts[1:]:
+        out = out + part
     return out.reshape(-1)
 
 
 # One kernel call's scalar-prefetch streams must fit SMEM: XLA reports
 # 1.00M of it on a v5e and refuses the program beyond. The budget leaves
-# room for the kernel's own scalars.
+# room for the kernel's own scalars. A stack's chunks share a call where
+# their streams fit; only a chunk beyond the budget alone is cut into
+# calls over its steps (``_stream_plan``).
 _SMEM_PREFETCH_BUDGET = 896 * 1024
 
 
-def _piece_bounds(n_steps: int, smem_per_step: int) -> list[int]:
-    """End steps of the kernel calls one stream of ``n_steps`` DMA steps
-    runs as: the fewest near-equal pieces whose scalar-prefetch bytes each
-    fit ``_SMEM_PREFETCH_BUDGET`` (one piece for every stream that fits)."""
-    max_steps = max(_SMEM_PREFETCH_BUDGET // smem_per_step, 1)
-    n_pieces = -(-n_steps // max_steps)
-    return [n_steps * (i + 1) // n_pieces for i in range(n_pieces)]
+def _piece_bounds(n_units: int, smem_per_unit: int) -> list[int]:
+    """End units (DMA steps, or a stack's chunks) of the kernel calls
+    ``n_units`` run as: the fewest near-equal pieces whose scalar-prefetch
+    bytes each fit ``_SMEM_PREFETCH_BUDGET`` (one piece where all fit)."""
+    max_units = max(_SMEM_PREFETCH_BUDGET // smem_per_unit, 1)
+    n_pieces = -(-n_units // max_units)
+    return [n_units * (i + 1) // n_pieces for i in range(n_pieces)]
 
 
-def _tiled_apply(layout_arrays, src, out_pad, src_pad, square_vals=False):
-    """Run one direction's kernel: src (src_pad,) -> out (out_pad,).
+def _stream_plan(layout_arrays, stack, groups, segs, run_groups, storage):
+    """What one direction's streams of ``stack`` chunks (end to end in every
+    stream) hand the kernel: ``(sub_slabs, n_steps, prefetch, calls)``, the
+    form read off the streams' own shapes, each chunk's DMA steps, the
+    scalar-prefetch streams, and the kernel calls as ``(chunk0, chunks,
+    step0, steps)``. The calls are the fewest over whole chunks whose
+    prefetch streams fit SMEM; a chunk beyond it runs as calls over
+    consecutive step ranges. The split is read off the built streams'
+    own size — how far a layout pads depends on where its nonzeros fall (8
+    uniform nonzeros a row at d=2^17 pad 4x, 32 pad 1.5x), which no
+    shape-only bound predicts."""
+    packed, wslab, rslab, rrun, srun = layout_arrays
+    n_groups = int(packed.shape[0]) // stack
+    n_steps = n_groups // (segs * groups)
+    # the stream's form is in its own shape: a read slab a run of
+    # run_groups groups, or (the sparse-cell form) several a group
+    n_slab_ids = int(rrun.shape[0]) // stack
+    sub_slabs = 0 if n_slab_ids * run_groups == n_groups else n_slab_ids // n_groups
+    if sub_slabs and (
+        sub_slabs * n_groups != n_slab_ids or GROUP % sub_slabs
+        or storage != "f32"
+    ):
+        raise ValueError(
+            f"a stream of {n_groups} groups with {n_slab_ids} read slabs on "
+            f"the {storage} rung is no form this kernel reads"
+        )
+    # Scalar-prefetch operands live in SMEM (1 MiB on a v5e) for the whole
+    # call, so the kernel is handed only the streams it reads: the write
+    # slab per segment, the read slab per run, and the dequant scales on
+    # the int8 rung alone. Passing all four cost 8.1 B a group
+    # and put a shape of n=2^19 rows (~166k groups) over the limit.
+    prefetch = [wslab] if sub_slabs else [wslab, rrun]
+    if storage == "int8":
+        prefetch.append(srun)
+    chunk_bytes = sum(
+        int(a.shape[0]) // stack * a.dtype.itemsize for a in prefetch
+    )
+    if chunk_bytes <= _SMEM_PREFETCH_BUDGET:
+        ends = _piece_bounds(stack, chunk_bytes)
+        calls = [(lo, hi - lo, 0, n_steps) for lo, hi in zip([0] + ends, ends)]
+    else:
+        ends = [0] + _piece_bounds(n_steps, chunk_bytes // n_steps)
+        calls = [
+            (c, 1, lo, hi - lo) for c in range(stack)
+            for lo, hi in zip(ends, ends[1:])
+        ]
+    return sub_slabs, n_steps, prefetch, calls
+
+
+def _tiled_apply(layout_arrays, src, out_pad, src_pad, square_vals=False,
+                 stack=1):
+    """Run one direction's kernel over ``stack`` column chunks whose streams
+    lie end to end: the margins take a source of ``stack * src_pad`` and
+    give ``out_pad``, the gradient the reverse.
 
     The tuned constants enter the jitted call as STATIC arguments, read
     from the module at CALL time: they are part of the executable's cache
@@ -1021,7 +1118,12 @@ def _tiled_apply(layout_arrays, src, out_pad, src_pad, square_vals=False):
     eager call whose (knob tuple, stream signature) is fresh captures the
     kernel executable's XLA flops/bytes once — calls under an outer
     trace (the optimizer/scoring jits) skip, and THAT enclosing
-    executable is captured at its own boundary instead."""
+    executable is captured at its own boundary instead.
+
+    ``tile_apply.calls`` and ``tile_apply.grid_chunks`` in the registry
+    count, at every trace (and eager call), the kernel calls this builds
+    and the column chunks they run as grid steps."""
+    from photon_ml_tpu.obs.metrics import REGISTRY
     from photon_ml_tpu.parallel.multihost import effective_topology
 
     args = (
@@ -1031,7 +1133,12 @@ def _tiled_apply(layout_arrays, src, out_pad, src_pad, square_vals=False):
         # effective topology rides as a static key: a degrade-in-place
         # must never re-enter a pre-loss executable by shape coincidence,
         # and a same-topology re-entry compiles nothing new
-        effective_topology(),
+        effective_topology(), stack,
+    )
+    calls = _stream_plan(layout_arrays, stack, *args[5:9])[-1]
+    REGISTRY.counter_inc("tile_apply.calls", float(len(calls)))
+    REGISTRY.counter_inc(
+        "tile_apply.grid_chunks", float(sum(call[1] for call in calls))
     )
     from photon_ml_tpu.obs import devcost
 
@@ -1049,12 +1156,15 @@ class _TileChunk:
     """One row chunk over every column: both direction layouts.
 
     A build whose width needs several column chunks of ``d_pad`` columns
-    STACKS them (PR 40): ``stack`` of them side by side from column 0,
-    every stream with a leading axis of ``stack``, and a pass runs them in
-    a ``lax.scan``, so the program holds ONE kernel call a direction however
-    many there are. XLA compiles every custom call of a program apart, and
-    ten chunks called one by one cost minutes of compile. A lone column
-    chunk (``stack`` 1) has no such axis."""
+    STACKS them: ``stack`` of them side by side from column 0, laid
+    end to end in every stream, each as long as the others (``packed`` is
+    ``(stack * groups, streams, GROUP)``, at the rank of a lone chunk's), so
+    a chunk is one contiguous range of whole DMA steps. A pass is ONE
+    kernel call a direction whose grid steps are the chunks: XLA compiles
+    every custom call of a program apart, and ten chunks called one by
+    one cost minutes of compile; a loop over the chunks would slice and
+    relay out each chunk's streams on every pass. A lone column chunk is
+    ``stack`` 1."""
 
     m_arrays: tuple  # margins: (packed, wslab, rslab, rrun, srun), write=row
     g_arrays: tuple  # gradient: same five streams, write=col
@@ -1064,27 +1174,16 @@ class _TileChunk:
     stack: int = field(default=1, metadata=dict(static=True))
 
     def matvec_part(self, w: Array) -> Array:
-        if self.stack == 1:
-            return _tiled_apply(self.m_arrays, w, self.n_pad, self.d_pad)
-
-        def add(m, part):
-            arrays, w_part = part
-            return m + _tiled_apply(arrays, w_part, self.n_pad, self.d_pad), None
-
-        m, _ = jax.lax.scan(
-            add, jnp.zeros((self.n_pad,), jnp.float32),
-            (self.m_arrays, w.reshape(self.stack, self.d_pad)),
+        return _tiled_apply(
+            self.m_arrays, w, self.n_pad, self.d_pad, stack=self.stack
         )
-        return m
 
     def rmatvec_part(self, r_full: Array, squared: bool) -> Array:
         r = jax.lax.dynamic_slice(r_full, (self.row_start,), (self.n_pad,))
-        apply = lambda arrays: _tiled_apply(
-            arrays, r, self.d_pad, self.n_pad, square_vals=squared
+        return _tiled_apply(
+            self.g_arrays, r, self.d_pad, self.n_pad, square_vals=squared,
+            stack=self.stack,
         )
-        if self.stack == 1:
-            return apply(self.g_arrays)
-        return jax.lax.map(apply, self.g_arrays).reshape(-1)
 
 
 @functools.partial(
@@ -1199,7 +1298,7 @@ def _stage_chunk(parts: list, row_start: int, n_pad: int,
     """The column chunks ``parts`` of one row chunk (``_pack_chunk``'s host
     streams, in column order) on the device as ONE ``_TileChunk``: a lone
     one as it is, several stacked (``stack = len(parts)``), each
-    direction's streams padded to one group count."""
+    direction's streams padded to one group count and laid end to end."""
     with span(LAYOUT_STAGE):
         sides = []
         for side in (0, 1):
@@ -1210,7 +1309,7 @@ def _stage_chunk(parts: list, row_start: int, n_pad: int,
             target = max(s[0].shape[0] for s in streams)
             streams = [_pad_layout_groups(s, target) for s in streams]
             sides.append(tuple(
-                jnp.asarray(np.stack([s[i] for s in streams]))
+                jnp.asarray(np.concatenate([s[i] for s in streams]))
                 for i in range(5)
             ))
         return _TileChunk(
@@ -1451,7 +1550,7 @@ def tile_sparse_batch(batch, keep_empty_chunks: bool = False,
             tail_cells += cells
         chunks.append(_stage_chunk(parts, r0, r1 - r0, col_width))
     tail_slots = float(sum(
-        # packed is ([stack,] groups, streams, GROUP)
+        # packed is (stack * groups, streams, GROUP)
         int(arrays[0].size) // int(arrays[0].shape[-2])
         for c in chunks for arrays in (c.m_arrays, c.g_arrays)
     ))
@@ -1537,7 +1636,8 @@ def supports_tiling(batch, resident: bool = False) -> bool:
     )
 
 
-def _pad_layout_groups(arrays: tuple, target_groups: int) -> tuple:
+def _pad_layout_groups(arrays: tuple, target_groups: int,
+                       stack: int = 1) -> tuple:
     """Extend one direction's (packed, wslab, rslab, rrun, srun) stream
     with filler segments up to ``target_groups`` groups. Fillers use the
     builder's tail convention — write slab 0, read slab 0, value 0, scale
@@ -1545,27 +1645,28 @@ def _pad_layout_groups(arrays: tuple, target_groups: int) -> tuple:
     whole-DMA-step multiple (every built stream already is, so the max
     over shards is too), and a DMA step is a whole number of runs. The
     slab ids and scales grow at the rate the stream's form has them (a
-    run's, or a granule's). Stacked column chunks (a leading axis) grow
-    together."""
-    packed = arrays[0]  # packed is ([stack,] n_groups, S, GROUP)
-    n_groups = packed.shape[-3]
-    if n_groups == target_groups:
+    run's, or a granule's). Stacked column chunks (``stack`` of them end to
+    end, ``target_groups`` counting them all) grow alike, each at its own
+    end."""
+    packed = arrays[0]  # packed is (stack * n_groups, S, GROUP)
+    n_groups = packed.shape[0] // stack
+    add = target_groups // stack - n_groups
+    if not add:
         return arrays
     xp = np if isinstance(packed, np.ndarray) else jnp  # host or device
-    add = target_groups - n_groups
 
-    def grow(a, count, fill, axis=-1):
-        shape = list(a.shape)
-        shape[axis] = count
-        return xp.concatenate([a, xp.full(shape, fill, a.dtype)], axis=axis)
+    def grow(a, fill):
+        per_chunk = a.reshape(stack, -1, *a.shape[1:])
+        shape = list(per_chunk.shape)
+        shape[1] = add * shape[1] // n_groups
+        return xp.concatenate(
+            [per_chunk, xp.full(shape, fill, a.dtype)], axis=1
+        ).reshape(-1, *a.shape[1:])
 
     packed, wslab, rslab, rrun, srun = arrays
     return (
-        grow(packed, add, 0, axis=-3),
-        grow(wslab, add // GROUPS_PER_STEP, 0),
-        grow(rslab, add, 0),
-        grow(rrun, add * rrun.shape[-1] // n_groups, 0),
-        grow(srun, add * srun.shape[-1] // n_groups, 1),
+        grow(packed, 0), grow(wslab, 0), grow(rslab, 0), grow(rrun, 0),
+        grow(srun, 1),
     )
 
 
@@ -1581,7 +1682,7 @@ def pad_chunks_to_common_groups(tbs: list) -> list[list]:
     for j in range(n_chunks):
         targets = {
             side: max(
-                getattr(tb.chunks[j], side)[0].shape[-3] for tb in tbs
+                getattr(tb.chunks[j], side)[0].shape[0] for tb in tbs
             )
             for side in ("m_arrays", "g_arrays")
         }
@@ -1589,10 +1690,12 @@ def pad_chunks_to_common_groups(tbs: list) -> list[list]:
             replace(
                 tb.chunks[j],
                 m_arrays=_pad_layout_groups(
-                    tb.chunks[j].m_arrays, targets["m_arrays"]
+                    tb.chunks[j].m_arrays, targets["m_arrays"],
+                    tb.chunks[j].stack,
                 ),
                 g_arrays=_pad_layout_groups(
-                    tb.chunks[j].g_arrays, targets["g_arrays"]
+                    tb.chunks[j].g_arrays, targets["g_arrays"],
+                    tb.chunks[j].stack,
                 ),
             )
             for tb in tbs

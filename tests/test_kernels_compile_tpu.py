@@ -181,25 +181,127 @@ class TestSparseCellFormCompiles:
             )
 
 
+def _computations(text: str) -> dict[str, list[str]]:
+    """A compiled module's computations: name -> instruction lines."""
+    comps, name = {}, None
+    for line in text.split("\n"):
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _in_loops(comps: dict[str, list[str]]) -> set[str]:
+    """The computations a ``while`` body runs, nested calls included."""
+    calls = {
+        name: set(re.findall(
+            r"(?:calls|body|condition|to_apply)=%([\w.\-]+)", "\n".join(lines)
+        ))
+        for name, lines in comps.items()
+    }
+    todo = [b for lines in comps.values() for line in lines
+            for b in re.findall(r"body=%([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo.extend(calls.get(name, ()))
+    return seen
+
+
+def _moves(text: str) -> list[tuple[bool, str, int]]:
+    """Every copy, dynamic slice and dynamic update slice of the program,
+    alone or as a fusion named after one: ``(in a loop, name, elements)``."""
+    comps = _computations(text)
+    loops = _in_loops(comps)
+    out = []
+    for name, lines in comps.items():
+        for line in lines:
+            op = re.search(r"%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(", line)
+            if not op or not re.search(
+                r"copy|dynamic[-_]slice|dynamic[-_]update[-_]slice",
+                op.group(1) + " " + op.group(3),
+            ):
+                continue
+            dims = [int(x) for x in op.group(2).split(",") if x]
+            out.append((name in loops, op.group(1), int(np.prod(dims))))
+    return out
+
+
 class TestFineFormCompiles:
     """The tail's fine form (PR 40) at ``kdda_fit``'s table sizes (2,101,938
     rows x a column chunk of 2,022,400) and about a chunk's stream length:
     64 slab ids a group in HBM, 8,192 words a DMA step, one call a stream;
-    and the resident build's ten column chunks, stacked, are ONE kernel
-    call a direction in a fit's program (a call a chunk had XLA compile
-    each apart: minutes)."""
+    and the resident build's ten column chunks, stacked end to end, are
+    ONE kernel call a direction in a fit's program, a grid step a chunk (a
+    call a chunk had XLA compile each apart: minutes; a loop of one call a
+    chunk sliced the chunk's streams every pass)."""
 
     N_PAD, D_PAD, GROUPS, CHUNKS = 2_102_272, 2_022_400, 65_664, 10
 
-    def _specs(self, spec, stack=()):
-        g = self.GROUPS
+    def _specs(self, spec, stack=1):
+        g = self.GROUPS * stack
         return (
-            spec(stack + (g, 3, st.GROUP), jnp.int32),
-            spec(stack + (g // st.GROUPS_PER_STEP,), jnp.int32),
-            spec(stack + (g,), jnp.int32),
-            spec(stack + (g * st.FINE_SLABS,), jnp.int32),
-            spec(stack + (g // st.GROUPS_PER_RUN,), jnp.float32),
+            spec((g, 3, st.GROUP), jnp.int32),
+            spec((g // st.GROUPS_PER_STEP,), jnp.int32),
+            spec((g,), jnp.int32),
+            spec((g * st.FINE_SLABS,), jnp.int32),
+            spec((g // st.GROUPS_PER_RUN,), jnp.float32),
         )
+
+    def _batch(self, spec, stack):
+        n = 2_101_938
+        chunk = st._TileChunk(
+            m_arrays=self._specs(spec, stack), g_arrays=self._specs(spec, stack),
+            row_start=0, n_pad=self.N_PAD, d_pad=self.D_PAD, stack=stack,
+        )
+        row = spec((n,), jnp.float32)
+        return st.TiledSparseBatch(
+            chunks=(chunk,), labels=row, offsets=row, weights=row,
+            num_features=stack * self.D_PAD - 7_170, num_rows_real=n,
+            n_pad_total=self.N_PAD, d_pad_total=stack * self.D_PAD,
+        )
+
+    def _fit_loop(self, topo, monkeypatch, stack):
+        """A fit's shape of program: value and gradient passes in a loop."""
+        from photon_ml_tpu.ops.glm import make_objective
+
+        spec = _spec(topo)
+        monkeypatch.setattr(st, "_interpret", lambda: False)
+        tb = self._batch(spec, stack)
+
+        def fit(tb, w):
+            obj = make_objective(tb, LOSS, l2_weight=1.0)
+            return jax.lax.fori_loop(
+                0, 3, lambda i, w: w - 1e-3 * obj.value_and_grad(w)[1], w
+            )
+
+        return jax.jit(fit).lower(
+            tb, spec((tb.num_features,), jnp.float32)
+        ).compile().as_text()
+
+    @pytest.mark.parametrize("stack", [CHUNKS, 1])
+    def test_a_fit_loop_slices_no_chunk_on_any_pass(self, topo, monkeypatch, stack):
+        """One kernel call a direction, and nothing in the loop moves a
+        chunk's stream, slab ids, source or output: the ten chunks at
+        ``kdda_fit``'s shape, and a lone chunk as the control. Each keeps
+        one copy a direction of the whole packed stream, the relayout the
+        kernel reads, hoisted out of the loop."""
+        text = self._fit_loop(topo, monkeypatch, stack)
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+        moves = _moves(text)
+        chunk_sized = {
+            self.GROUPS * 3 * st.GROUP, self.GROUPS * st.FINE_SLABS,
+            self.D_PAD, self.CHUNKS * self.D_PAD,
+        }
+        assert [m for m in moves if m[0] and m[2] in chunk_sized] == []
+        whole = stack * self.GROUPS * 3 * st.GROUP
+        hoisted = [m for m in moves if m[2] == whole]
+        assert len(hoisted) == 2 and not any(m[0] for m in hoisted)
 
     def test_a_steps_slab_ids_fill_whole_hbm_tiles(self):
         step_groups = st.GROUPS_PER_STEP * st.SEGMENTS_PER_DMA
@@ -223,21 +325,9 @@ class TestFineFormCompiles:
     def test_stacked_chunks_are_one_call_a_direction(self, topo, monkeypatch):
         spec = _spec(topo)
         monkeypatch.setattr(st, "_interpret", lambda: False)
-        n, d = 2_101_938, 20_216_830
-        chunk = st._TileChunk(
-            m_arrays=self._specs(spec, (self.CHUNKS,)),
-            g_arrays=self._specs(spec, (self.CHUNKS,)),
-            row_start=0, n_pad=self.N_PAD, d_pad=self.D_PAD,
-            stack=self.CHUNKS,
-        )
-        row = spec((n,), jnp.float32)
-        tb = st.TiledSparseBatch(
-            chunks=(chunk,), labels=row, offsets=row, weights=row,
-            num_features=d, num_rows_real=n, n_pad_total=self.N_PAD,
-            d_pad_total=self.CHUNKS * self.D_PAD,
-        )
+        tb = self._batch(spec, self.CHUNKS)
         text = jax.jit(lambda b, w, r: (b.matvec(w), b.rmatvec(r))).lower(
-            tb, spec((d,), jnp.float32), row
+            tb, spec((tb.num_features,), jnp.float32), tb.labels
         ).compile().as_text()
         assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
 

@@ -1157,17 +1157,22 @@ class TestTopologyKeyedCaches:
         assert params == [
             "layout_arrays", "src", "out_pad", "src_pad", "square_vals",
             "groups", "segs", "run_groups", "storage", "interpret",
-            "topology",
+            "topology", "stack",
         ]
         assert not hasattr(st, "PIPELINE_SEGMENTS")
         assert not hasattr(st, "_run_segment_schedule")
         # _tiled_apply hands the jitted call exactly those, in that order
+        # (it reads the streams' shapes for its counters)
+        lay = st.build_write_major_layout(
+            np.arange(8), np.arange(8), np.ones(8, np.float32), 2048, 1024
+        )
+        streams = (lay.packed, lay.wslab, lay.rslab, lay.rrun, lay.srun)
         monkeypatch.setattr(st, "_tiled_apply_jit", lambda *args: args)
-        args = st._tiled_apply(("streams",), "src", 2048, 1024, True)
-        assert args == (
-            ("streams",), "src", 2048, 1024, True,
+        args = st._tiled_apply(streams, "src", 2048, 1024, True)
+        assert args[0] is streams and args[1:] == (
+            "src", 2048, 1024, True,
             st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA, st.GROUPS_PER_RUN,
-            "f32", st._interpret(), topology,
+            "f32", st._interpret(), topology, 1,
         )
         assert st.KERNEL_DTYPES == ("f32", "int8")
 
@@ -1680,6 +1685,36 @@ class TestDenseHead:
         assert summary["tile_layout"]["head_nonzero_share"] == 0.75
         assert "(75.0%) in a dense head of 384 columns" in format_summary(summary)
 
+    def test_the_run_report_renders_the_kernel_calls(self, tmp_path):
+        """``tile_apply.*`` beside ``col_chunks``: the kernel calls
+        traced and the column chunks they ran as grid steps."""
+        from photon_ml_tpu.obs.report import format_summary, summarize_run
+        from photon_ml_tpu.obs.sink import TelemetrySink
+
+        counters = {
+            "tile_layout.tail_nonzeros": {"value": 1000.0},
+            "tile_layout.col_chunks": {"value": 10.0},
+            "tile_apply.calls": {"value": 2.0},
+            "tile_apply.grid_chunks": {"value": 20.0},
+        }
+        sink = TelemetrySink(str(tmp_path), run_id="CALLS", shard_index=None)
+        sink.emit({"event": "run_start", "t": 1000.0, "schema_version": 1,
+                   "run_id": "CALLS", "pid": 0, "process_index": 0, "knobs": {},
+                   "fleet": {"process_count": 1}, "metrics_baseline": {}})
+        sink.emit({"event": "run_end", "t": 1002.0, "run_id": "CALLS",
+                   "metrics": {"counters": counters, "gauges": {},
+                               "histograms": {}, "timers": {}}})
+        sink.close()
+        summary = summarize_run(sink.path)
+        til = summary["tile_layout"]
+        assert (til["col_chunks"], til["kernel_calls"], til["grid_chunks"]) == (
+            10.0, 2.0, 20.0
+        )
+        assert (
+            "10 column chunks built; 2 kernel calls traced, running 20 "
+            "column chunks as grid steps"
+        ) in format_summary(summary)
+
 
 def _cell_batch(rng, n, d, cells_nnz):
     """A batch whose cell (row slab i, column slab j) holds
@@ -1952,9 +1987,15 @@ class TestFineForm:
         assert tb.d_pad_total == self.N_COL_CHUNKS * chunk.d_pad >= batch.num_features
         for arrays in (chunk.m_arrays, chunk.g_arrays):
             packed, wslab, rslab, rrun, srun = (a.shape for a in arrays)
-            assert packed[0] == rrun[0] == srun[0] == self.N_COL_CHUNKS
-            assert rrun[1] == packed[1] * st.FINE_SLABS
-            assert srun[1] * st.GROUPS_PER_RUN == packed[1]
+            # the chunks lie end to end, at a lone chunk's rank, each as
+            # long as the others and whole DMA steps
+            assert len(packed) == 3 and packed[0] % self.N_COL_CHUNKS == 0
+            step_groups = st.GROUPS_PER_STEP * st.SEGMENTS_PER_DMA
+            assert packed[0] // self.N_COL_CHUNKS % step_groups == 0
+            assert rrun == (packed[0] * st.FINE_SLABS,)
+            assert srun == (packed[0] // st.GROUPS_PER_RUN,)
+            assert wslab == (packed[0] // st.GROUPS_PER_STEP,)
+            assert rslab == packed[:1]
         counters = {
             k: v["value"]
             for k, v in REGISTRY.snapshot("tile_layout.")["counters"].items()
@@ -1969,7 +2010,8 @@ class TestFineForm:
         (plain,) = tile_sparse_batch(batch).chunks
         assert plain.stack == self.N_COL_CHUNKS
         for arrays in (plain.m_arrays, plain.g_arrays):
-            assert arrays[3].shape[1] * st.GROUPS_PER_RUN == arrays[0].shape[1]
+            assert arrays[3].ndim == 1
+            assert arrays[3].shape[0] * st.GROUPS_PER_RUN == arrays[0].shape[0]
 
     @pytest.mark.kernel
     @pytest.mark.parametrize("direction", _DIRECTIONS)
@@ -2093,6 +2135,11 @@ class TestFineForm:
         (stacked,) = sharded.chunks
         assert stacked.stack == self.N_COL_CHUNKS
         for i, (chunk,) in enumerate(zip(*padded)):
+            # a device's streams: its chunks end to end, padded alike
+            for side in ("m_arrays", "g_arrays"):
+                packed = getattr(stacked, side)[0]
+                assert packed.ndim == 4 and packed.shape[0] == 2
+                assert packed.shape[1] % self.N_COL_CHUNKS == 0
             for side in ("m_arrays", "g_arrays"):
                 for a, b in zip(getattr(stacked, side), getattr(chunk, side)):
                     np.testing.assert_array_equal(np.asarray(a)[i], np.asarray(b))
