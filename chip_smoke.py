@@ -6,13 +6,14 @@ calls, over every device JAX reports, at the full width of the models the
 repo ships (depth and row counts cut, weights and data from a seed):
 
 - ``game``: ``cli.train.main`` -> ``cli.score.main`` ->
-  ``publish_game_model`` -> ``cli.serve.run`` on config E's widths (fixed
-  d=64 + intercept, 20,000 users x 8, 4,000 items x 8), read from Avro;
-- ``sparse``: ``cli.train_glm.main`` on a LIBSVM file at config A2's width
-  (d=131,072, 32 nonzeros a row) through the tile-COO Pallas kernels,
-  against the same solve on the untiled XLA ``SparseBatch`` path;
+  ``publish_game_model`` -> ``cli.serve.run`` on the three-coordinate
+  GLMix's widths (fixed d=64 + intercept, 20,000 users x 8, 4,000 items
+  x 8: the widths of the benchmark's GLMix cells), read from Avro;
+- ``sparse``: ``cli.train_glm.main`` on a LIBSVM file at the wide sparse
+  GLM's width (d=131,072, 32 nonzeros a row) through the tile-COO Pallas
+  kernels, against the same solve on the untiled XLA ``SparseBatch`` path;
 - ``dense``: the fused one-pass kernels (``fused_value_grad``,
-  ``fused_hvp``) at the headline shape (n=2^20, d=512, bf16 and f32) under
+  ``fused_hvp``) at the dense GLM's shape (n=2^20, d=512, bf16 and f32) under
   L-BFGS and TRON, against ``make_objective(..., fused=False)``;
 - ``distributed``: ``DistributedTrainer`` over ``data_mesh()`` — the fused
   kernel inside ``shard_map`` and one tile-COO per shard — against the
@@ -45,9 +46,10 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 DEFAULT_OUT = os.path.join(HERE, "chiprun_out", "chip_smoke")
 
-# Full-width sizes of the chip run. Widths are the bench configs' own
-# (bench.py config E, A2, headline); row counts are cut so that the whole
-# smoke, compilation included, stays well inside its 1200 s limit.
+# Full-width sizes of the chip run. Widths are the three model shapes' own
+# (the GLMix, the wide sparse GLM, the dense GLM); row counts are cut so
+# that the whole smoke, compilation included, stays well inside its 1200 s
+# limit.
 FULL_SIZES = {
     "game": dict(
         n_train=1 << 16, n_val=1 << 13, d_fixed=64,
@@ -195,8 +197,8 @@ def _write_game_avro(path, data, lo, hi, d_fixed, effects) -> None:
 
 
 def _game_config(effects):
-    """Config E's three coordinates (bench.py ``_game_setup``): L-BFGS on
-    the fixed effect, Newton-Cholesky per entity, two outer iterations."""
+    """The GLMix's three coordinates: L-BFGS on the fixed effect,
+    Newton-Cholesky per entity, two outer iterations."""
     from photon_ml_tpu.config import (
         FeatureShardConfig,
         FixedEffectCoordinateConfig,
@@ -456,8 +458,7 @@ def leg_game(
 
 def _write_libsvm(path, rng, n, d, k) -> None:
     """``k - 1`` uniform random columns of ``d - 1`` plus the intercept the
-    reader appends: ``d`` columns and ``k`` nonzeros a row in the batch
-    (bench.py ``_make_sparse_problem``'s distribution)."""
+    reader appends: ``d`` columns and ``k`` nonzeros a row in the batch."""
     d_raw, k_raw = d - 1, k - 1
     idx = rng.integers(0, d_raw, size=(n, k_raw))
     idx[0, 0] = d_raw - 1  # the reader sizes the space by the largest id
@@ -616,11 +617,11 @@ def _dense_problem(n, d, seed, dtype):
 def leg_dense(
     *, n: int, d: int, lbfgs_iters: int, tron_iters: int, seed: int = 0,
 ) -> dict:
-    """The fused one-pass kernels at the headline shape, library level as
-    the bench headline runs them: value, gradient and Hv against the
-    unfused XLA objective on the same data, then short L-BFGS and TRON
-    solves. The f32 reference runs at highest matmul precision (a TPU's
-    default f32 matmul is a single bf16 pass).
+    """The fused one-pass kernels at the dense GLM's shape, at library
+    level: value, gradient and Hv against the unfused XLA objective on
+    the same data, then short L-BFGS and TRON solves. The f32 reference
+    runs at highest matmul precision (a TPU's default f32 matmul is a
+    single bf16 pass).
 
     Each comparison runs twice. On bf16-representable probe vectors the
     issue's bounds hold (1e-3 bf16, 1e-5 f32): what is compared is the
@@ -649,7 +650,7 @@ def leg_dense(
     w0 = jnp.zeros((d,), jnp.float32)
     # the objective is a pytree ARGUMENT: closed over, its (n, d) matrix
     # would be baked into the program as a constant (428 s of compile at
-    # the headline shape on the first chip run)
+    # the dense GLM's shape on the first chip run)
     value_and_grad = jax.jit(lambda obj, w: obj.value_and_grad(w))
     hvp = jax.jit(lambda obj, w, v: obj.hvp(w, v))
 

@@ -1,21 +1,21 @@
 """Static analysis for the repo's load-bearing disciplines.
 
-``photon-ml-tpu lint`` runs five AST passes over the package (plus
-``bench.py`` and the README knob table) and fails on violations of the
+``photon-ml-tpu lint`` runs five AST passes over the package (plus the
+README knob table) and fails on violations of the
 invariants fourteen PRs of review kept re-finding by hand:
 
 1. **knobs** — every ``PHOTON_*`` knob registered
    (``analysis/registry.py``) with strict parse idiom and all mirror
-   surfaces wired (bench RETUNE tables, sink knob snapshot, devcost
-   fingerprint, README table), drift failing in both directions.
-2. **jit-keys** — no knob accessor / retune-global / env read inside a
+   surfaces wired (sink knob snapshot, devcost fingerprint, README
+   table), drift failing in both directions.
+2. **jit-keys** — no knob accessor / knob global / env read inside a
    jitted body (the stale-executable class).
 3. **concurrency** — no unlocked mutation of module-level containers in
    worker-pool / process-wide-cache modules.
 4. **exceptions** — no silent ``except`` swallow in ``parallel/``,
    ``game/streaming.py``, ``game/descent.py``.
 5. **telemetry** — emitted event/metric names and the names
-   ``obs/report.py``/``bench.py`` consume agree, both directions.
+   ``obs/report.py``/``obs/export.py`` consume agree, both directions.
 
 Pure stdlib ``ast`` — importing this package never initializes a jax
 backend, so the lint leg is cheap enough for every CI run.

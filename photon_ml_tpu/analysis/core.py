@@ -106,23 +106,19 @@ class ModuleInfo:
 @dataclass
 class Project:
     """The file set one lint run looks at. ``root`` is the repo root (the
-    directory holding ``bench.py``/``README.md``/``pyproject.toml``);
-    the package tree is scanned recursively. Tests construct Projects
-    over fixture trees and may point ``bench_path``/``readme_path`` at
-    modified copies — the drift tests work exactly that way."""
+    directory holding ``README.md``/``pyproject.toml``); the package
+    tree is scanned recursively. Tests construct Projects over fixture
+    trees and may point ``readme_path`` at a modified copy — the drift
+    tests work exactly that way."""
 
     root: str
     package_dirs: tuple[str, ...] = ("photon_ml_tpu",)
-    bench_path: str | None = None  # None -> <root>/bench.py if present
     readme_path: str | None = None  # None -> <root>/README.md if present
     exclude: tuple[str, ...] = ("__pycache__",)
     _modules: dict[str, ModuleInfo] = field(default_factory=dict)
     parse_errors: list[Finding] = field(default_factory=list)
 
     def __post_init__(self) -> None:
-        if self.bench_path is None:
-            cand = os.path.join(self.root, "bench.py")
-            self.bench_path = cand if os.path.exists(cand) else None
         if self.readme_path is None:
             cand = os.path.join(self.root, "README.md")
             self.readme_path = cand if os.path.exists(cand) else None
@@ -161,11 +157,6 @@ class Project:
             mi = self._load(p)
             if mi is not None:
                 yield mi
-
-    def bench_module(self) -> ModuleInfo | None:
-        if self.bench_path and os.path.exists(self.bench_path):
-            return self._load(self.bench_path)
-        return None
 
     def module(self, relpath: str) -> ModuleInfo | None:
         """One specific module by repo-relative path (None if absent)."""

@@ -1,12 +1,12 @@
 """Pass 2 — jit-cache-key analysis (the stale-executable bug class).
 
-The repo's discipline since PR 1: retunable knobs are read at CALL time
-and passed into jitted programs as STATIC arguments, so a retune
+The repo's discipline since PR 1: knobs are read at CALL time and
+passed into jitted programs as STATIC arguments, so a knob flip
 recompiles instead of silently reusing a stale executable. The violation
 this pass hunts is the inverse: a function that enters ``jax.jit`` whose
 BODY calls a knob accessor (``kernel_dtype()``, ``prefetch_depth()``, …)
-or reads a retune-mutable module global (``GROUPS_PER_RUN``,
-``SEGMENTS_PER_DMA``, …) or the environment directly. Values read inside
+or reads the module global a knob accessor falls back to
+(``KERNEL_DTYPE``, ``PREFETCH_DEPTH``, …) or the environment directly. Values read inside
 a traced body are baked into the executable at first trace — the jit
 cache keys only on argument shapes/statics, so a later knob flip REUSES
 the stale program (PR 2's missing-static bug, found by hand then;
@@ -97,7 +97,7 @@ def run(project: Project, registry=None) -> list[Finding]:
     for mi in project.iter_modules():
         for fn in jitted_functions(mi):
             scope = f"{mi.relpath}::{fn.name}"
-            # parameter names shadow retune globals: a static arg named
+            # parameter names shadow knob globals: a static arg named
             # like the global IS the discipline working as intended
             params = {
                 a.arg
@@ -116,7 +116,7 @@ def run(project: Project, registry=None) -> list[Finding]:
                             f"accessor {cn}() "
                             f"({accessor_owner[cn]}) inside its traced "
                             f"body — the value is baked in at first "
-                            f"trace and a retune reuses the stale "
+                            f"trace and a knob flip reuses the stale "
                             f"executable; read it at the call site and "
                             f"pass it as a static argument",
                         ))
@@ -128,7 +128,7 @@ def run(project: Project, registry=None) -> list[Finding]:
                             "jit-retune-global", mi.relpath, node.lineno,
                             f"{fn.name}:{node.attr}",
                             f"jitted function '{fn.name}' reads "
-                            f"retune-mutable global "
+                            f"knob global "
                             f"{dotted_name(node) or node.attr} "
                             f"({global_owner[node.attr]}) inside its "
                             f"traced body — pass it as a static "
@@ -142,7 +142,7 @@ def run(project: Project, registry=None) -> list[Finding]:
                             "jit-retune-global", mi.relpath, node.lineno,
                             f"{fn.name}:{node.id}",
                             f"jitted function '{fn.name}' reads "
-                            f"retune-mutable global {node.id} "
+                            f"knob global {node.id} "
                             f"({global_owner[node.id]}) inside its "
                             f"traced body — pass it as a static "
                             f"argument instead",

@@ -1,10 +1,10 @@
 """Pass 1 — knob discipline.
 
-Finds every ``PHOTON_*`` environment read in the package (and bench.py),
-then cross-checks the knob registry against every mirror surface BY
-PARSING THE SOURCES: the bench ``RETUNE_ENV*`` dicts, the
-``sink._knob_snapshot`` keys, the ``devcost._knob_raw_state`` fingerprint,
-and the generated README knob table. Drift in any direction fails.
+Finds every ``PHOTON_*`` environment read in the package, then
+cross-checks the knob registry against every mirror surface BY PARSING
+THE SOURCES: the ``sink._knob_snapshot`` keys, the
+``devcost._knob_raw_state`` fingerprint, and the generated README knob
+table. Drift in any direction fails.
 
 Codes:
 
@@ -15,8 +15,6 @@ Codes:
   ``not os.environ.get(...)``): the string ``"0"`` is truthy, so ``=0``
   INVERTS the operator's intent (the PHOTON_DISABLE_FUSED bug class).
   Strict-parse (``int(env) != 0``) instead.
-- ``knob-retune-missing`` / ``knob-retune-unregistered`` — registry vs.
-  bench RETUNE tables, both directions.
 - ``knob-sink-missing`` / ``knob-sink-unregistered`` — registry vs. the
   ``_knob_snapshot`` keys, both directions.
 - ``knob-devcost-missing`` — a snapshot-carried knob not fingerprinted in
@@ -95,11 +93,7 @@ def _in_boolean_context(mi: ModuleInfo, node: ast.AST) -> bool:
 def scan_env_reads(project: Project, registry=None) -> list[Finding]:
     knobs = {k.name: k for k in (registry or reg_mod.KNOBS)}
     findings: list[Finding] = []
-    modules = list(project.iter_modules())
-    bench = project.bench_module()
-    if bench is not None:
-        modules.append(bench)
-    for mi in modules:
+    for mi in project.iter_modules():
         for name, node in env_reads(mi):
             knob = knobs.get(name)
             if knob is None:
@@ -126,33 +120,6 @@ def scan_env_reads(project: Project, registry=None) -> list[Finding]:
 # -- mirror-surface extraction ---------------------------------------------
 
 
-def bench_retune_tables(bench: ModuleInfo) -> dict[str, set[str]]:
-    """The PHOTON_* key sets of every module-level RETUNE_ENV* dict."""
-    tables: dict[str, set[str]] = {}
-    for node in bench.tree.body:
-        targets = []
-        if isinstance(node, ast.Assign):
-            targets = [
-                t.id for t in node.targets if isinstance(t, ast.Name)
-            ]
-            value = node.value
-        elif isinstance(node, ast.AnnAssign) and isinstance(
-            node.target, ast.Name
-        ):
-            targets = [node.target.id]
-            value = node.value
-        else:
-            continue
-        for name in targets:
-            if name.startswith("RETUNE_ENV") and isinstance(
-                value, ast.Dict
-            ):
-                tables[name] = {
-                    s for s in (const_str(k) for k in value.keys) if s
-                }
-    return tables
-
-
 def _function(mi: ModuleInfo, name: str) -> ast.FunctionDef | None:
     for node in ast.walk(mi.tree):
         if isinstance(node, ast.FunctionDef) and node.name == name:
@@ -176,15 +143,12 @@ def sink_snapshot_keys(sink_mi: ModuleInfo) -> set[str] | None:
     return keys
 
 
-def devcost_fingerprint(
-    devcost_mi: ModuleInfo,
-) -> tuple[set[str], set[str]] | None:
-    """(env names, attribute/global names) read by ``_knob_raw_state``."""
+def devcost_fingerprint(devcost_mi: ModuleInfo) -> set[str] | None:
+    """The env names ``_knob_raw_state`` reads."""
     fn = _function(devcost_mi, "_knob_raw_state")
     if fn is None:
         return None
     envs: set[str] = set()
-    attrs: set[str] = set()
     for node in ast.walk(fn):
         if (
             isinstance(node, ast.Call)
@@ -195,18 +159,7 @@ def devcost_fingerprint(
             s = const_str(node.args[0])
             if s:
                 envs.add(s)
-        elif isinstance(node, ast.Attribute) and isinstance(
-            node.ctx, ast.Load
-        ):
-            attrs.add(node.attr)
-        elif isinstance(node, ast.Constant) and isinstance(
-            node.value, str
-        ):
-            # tuple-literal global names, e.g. sys.modules lookups that
-            # fingerprint (mod.COMPACT_EVERY, ...) keep attr form; plain
-            # strings stay envs-only, nothing to do here
-            pass
-    return envs, attrs
+    return envs
 
 
 def readme_table_block(readme_path: str) -> str | None:
@@ -239,40 +192,6 @@ def check_surfaces(project: Project, registry=None) -> list[Finding]:
     by_name = {k.name: k for k in knobs}
     findings: list[Finding] = []
 
-    # -- bench RETUNE tables (both directions) -----------------------------
-    bench = project.bench_module()
-    if bench is not None:
-        tables = bench_retune_tables(bench)
-        for table, names in tables.items():
-            for name in sorted(names):
-                k = by_name.get(name)
-                if k is None or k.retune_table != table:
-                    where = (
-                        "is not registered"
-                        if k is None
-                        else f"is registered for "
-                             f"{k.retune_table or 'no retune table'}"
-                    )
-                    findings.append(Finding(
-                        "knob-retune-unregistered", bench.relpath,
-                        bench.tree.body[0].lineno
-                        if bench.tree.body else 1,
-                        name,
-                        f"bench table {table} carries {name}, which "
-                        f"{where} in the knob registry — a knob swept "
-                        f"here without registry/sink/devcost wiring is "
-                        f"exactly the drift this pass exists to catch",
-                    ))
-        for k in knobs:
-            if k.retune_table is None:
-                continue
-            if k.name not in tables.get(k.retune_table, set()):
-                findings.append(Finding(
-                    "knob-retune-missing", bench.relpath, 1, k.name,
-                    f"{k.name} is registered for bench table "
-                    f"{k.retune_table} but the table does not carry it",
-                ))
-
     # -- sink snapshot (both directions) -----------------------------------
     sink_mi = project.module(_SINK_RELPATH)
     if sink_mi is not None:
@@ -298,38 +217,24 @@ def check_surfaces(project: Project, registry=None) -> list[Finding]:
     # -- devcost fingerprint ------------------------------------------------
     devcost_mi = project.module(_DEVCOST_RELPATH)
     if devcost_mi is not None:
-        fp = devcost_fingerprint(devcost_mi)
-        if fp is not None:
-            envs, attrs = fp
+        envs = devcost_fingerprint(devcost_mi)
+        if envs is not None:
             for k in knobs:
                 if not k.needs_devcost:
                     continue
-                # a knob with call-time accessors reads env > global at
-                # SNAPSHOT time, so the env var MUST be fingerprinted —
-                # the global alone goes stale on a mid-process env flip;
-                # accessor-less knobs reach the snapshot only through
-                # their retune global (bench setattr), so either works
-                if k.accessors:
-                    ok = k.name in envs
-                else:
-                    ok = k.name in envs or (
-                        k.retune_global and k.retune_global in attrs
-                    )
-                if ok:
+                # the snapshot reads env > global at SNAPSHOT time, so
+                # the env var MUST be fingerprinted — the global alone
+                # goes stale on a mid-process env flip
+                if k.name in envs:
                     continue
                 findings.append(Finding(
                     "knob-devcost-missing", devcost_mi.relpath, 1,
                     k.name,
                     f"{k.name} feeds sink._knob_snapshot (key "
                     f"'{k.sink_key}') but devcost._knob_raw_state does "
-                    f"not fingerprint "
-                    + (f"its env var (required: the snapshot reads env "
-                       f"> global through {k.accessors[0]}())"
-                       if k.accessors else
-                       f"its env var or retune global "
-                       f"{k.retune_global!r}")
-                    + " — a mid-process flip of only this knob would "
-                    f"reuse a stale memoized snapshot in capture keys",
+                    f"not fingerprint its env var — a mid-process flip of "
+                    f"only this knob would reuse a stale memoized snapshot "
+                    f"in capture keys",
                 ))
 
     # -- README knob table ---------------------------------------------------

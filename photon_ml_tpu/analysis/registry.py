@@ -1,15 +1,15 @@
 """The knob registry: ONE source of truth per ``PHOTON_*`` environment knob.
 
 Fourteen PRs of bitwise-parity-gated knobs left every knob hand-wired
-through up to five mirror surfaces — the bench ``RETUNE_ENV`` tables, the
-telemetry ``run_start`` knob snapshot (``obs/sink._knob_snapshot``), the
-device-cost capture-key fingerprint (``obs/devcost._knob_raw_state``), and
-the README knob table — with nothing but reviewer memory keeping them in
-sync (``obs/devcost.py`` literally documents "the failure mode of
-forgetting"). This module makes the wiring mechanical: each knob declares
-its type, parse idiom, default, owning module, call-time accessors, retune
-module global, and which mirror surfaces must carry it (with explicit,
-reasoned exemptions where a surface legitimately does not apply). The
+through several mirror surfaces — the telemetry ``run_start`` knob
+snapshot (``obs/sink._knob_snapshot``), the device-cost capture-key
+fingerprint (``obs/devcost._knob_raw_state``), and the README knob table —
+with nothing but memory keeping them in sync (``obs/devcost.py``
+literally documents "the failure mode of forgetting"). This module makes
+the wiring mechanical: each knob declares its type, parse idiom, default,
+owning module, call-time accessors, the module global its accessor falls
+back to, and which mirror surfaces must carry it (with explicit, reasoned
+exemptions where a surface legitimately does not apply). The
 ``photon-ml-tpu lint`` knob pass cross-checks every surface against this
 table BY PARSING THE ACTUAL SOURCES, so drift in either direction — a knob
 added to a surface but not here, or registered here but missing from a
@@ -17,15 +17,12 @@ required surface — fails the lint run.
 
 Surface semantics:
 
-- ``retune_table`` — the bench.py RETUNE dict that must carry the knob
-  (``RETUNE_ENV`` / ``RETUNE_ENV_PREFETCH`` / ``RETUNE_ENV_RE`` /
-  ``RETUNE_ENV_SHARD``), or None with an ``exempt`` reason.
 - ``sink_key`` — the key under which ``sink._knob_snapshot`` must report
   the knob (the run_start configuration record), or None with a reason.
 - devcost fingerprint — REQUIRED exactly when ``sink_key`` is set: the
   snapshot is memoized on ``devcost._knob_raw_state``, so every snapshot
-  input must be fingerprinted there (env name or retune global), or a
-  mid-process knob flip reuses a stale snapshot in capture keys.
+  input must be fingerprinted there (its env name), or a mid-process knob
+  flip reuses a stale snapshot in capture keys.
 - README — every registered knob appears in the generated README knob
   table (``photon-ml-tpu lint --write-docs`` renders it from this
   registry; the knob pass fails when the committed table drifts).
@@ -50,18 +47,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
-SURFACES = ("retune", "sink", "devcost", "readme")
-
-#: retune tables the bench defines; the lint pass parses these names out
-#: of bench.py and cross-checks membership in both directions.
-RETUNE_TABLES = (
-    "RETUNE_ENV",
-    "RETUNE_ENV_PREFETCH",
-    "RETUNE_ENV_RE",
-    "RETUNE_ENV_SHARD",
-    "RETUNE_ENV_SERVE",
-    "RETUNE_ENV_STREAM",
-)
+SURFACES = ("sink", "devcost", "readme")
 
 
 @dataclass(frozen=True)
@@ -73,8 +59,10 @@ class Knob:
     owner: str  # repo-relative path of the owning module
     doc: str  # one-line description (README table row)
     accessors: tuple = ()  # call-time accessor function names
-    retune_global: str | None = None  # module global the bench retunes
-    retune_table: str | None = None
+    # the module global the accessor falls back to when the env var is
+    # unset (tests monkeypatch it); the jit-keys pass hunts reads of it
+    # inside jitted bodies
+    retune_global: str | None = None
     sink_key: str | None = None
     exempt: tuple = ()  # ((surface, reason), ...) for absent surfaces
 
@@ -93,60 +81,34 @@ class Knob:
 
 
 _EXEMPT_FAULT = (
-    ("retune", "fault-injection / recovery drill knob, not a perf lever "
-               "the bench sweeps"),
     ("sink", "does not change executables or solve math; drills log their "
              "own fault/recovery telemetry events"),
 )
 _EXEMPT_TRANSPORT = (
-    ("retune", "transport reliability knob; bitwise-neutral to results "
-               "and not swept by bench configs"),
     ("sink", "does not change executables or solve math; retries/CRC "
              "emit their own p2p_* telemetry events"),
 )
 _EXEMPT_DEPLOY = (
-    ("retune", "deployment plumbing (addresses/paths), not a perf lever"),
     ("sink", "no effect on executables or math"),
 )
 
 KNOBS: tuple[Knob, ...] = (
-    # -- sparse-tiled kernel constants (RETUNE_ENV) -------------------------
-    Knob(
-        name="PHOTON_GROUPS_PER_STEP", kind="int", parse="strict_int",
-        default="32", owner="photon_ml_tpu/ops/sparse_tiled.py",
-        doc="groups per DMA step of the sparse-tiled kernels",
-        retune_global="GROUPS_PER_STEP", retune_table="RETUNE_ENV",
-        sink_key="groups_per_step",
-    ),
-    Knob(
-        name="PHOTON_SEGMENTS_PER_DMA", kind="int", parse="strict_int",
-        default="4", owner="photon_ml_tpu/ops/sparse_tiled.py",
-        doc="segments per double-buffered DMA step",
-        retune_global="SEGMENTS_PER_DMA", retune_table="RETUNE_ENV",
-        sink_key="segments_per_dma",
-    ),
-    Knob(
-        name="PHOTON_GROUPS_PER_RUN", kind="int", parse="strict_int",
-        default="2", owner="photon_ml_tpu/ops/sparse_tiled.py",
-        doc="groups per shared-source slab run",
-        retune_global="GROUPS_PER_RUN", retune_table="RETUNE_ENV",
-        sink_key="groups_per_run",
-    ),
+    # -- sparse-tiled kernels ------------------------------------------------
     Knob(
         name="PHOTON_KERNEL_DTYPE", kind="enum", parse="enum",
         default="f32", owner="photon_ml_tpu/ops/sparse_tiled.py",
         doc="storage precision rung: f32 (bitwise anchor) | int8",
         accessors=("kernel_dtype",),
-        retune_global="KERNEL_DTYPE", retune_table="RETUNE_ENV",
+        retune_global="KERNEL_DTYPE",
         sink_key="kernel_dtype",
     ),
-    # -- host-ingest pipeline (RETUNE_ENV_PREFETCH) -------------------------
+    # -- host-ingest pipeline -----------------------------------------------
     Knob(
         name="PHOTON_PREFETCH_DEPTH", kind="int", parse="strict_int",
         default="2", owner="photon_ml_tpu/ops/prefetch.py",
         doc="chunks prepared ahead of the consumer; 0 = synchronous",
         accessors=("prefetch_depth",),
-        retune_global="PREFETCH_DEPTH", retune_table="RETUNE_ENV_PREFETCH",
+        retune_global="PREFETCH_DEPTH",
         sink_key="prefetch_depth",
     ),
     Knob(
@@ -155,16 +117,15 @@ KNOBS: tuple[Knob, ...] = (
         doc="device-resident chunk-cache byte budget",
         accessors=("chunk_cache_budget_bytes",),
         retune_global="CHUNK_CACHE_BUDGET",
-        retune_table="RETUNE_ENV_PREFETCH",
         sink_key="chunk_cache_budget_bytes",
     ),
-    # -- random-effect bucket solves (RETUNE_ENV_RE) ------------------------
+    # -- random-effect bucket solves ----------------------------------------
     Knob(
         name="PHOTON_RE_COMPACT_EVERY", kind="int", parse="strict_int",
         default="0", owner="photon_ml_tpu/game/random_effect.py",
         doc="outer iterations per compaction chunk; 0 = single launch",
         accessors=("compact_every",),
-        retune_global="COMPACT_EVERY", retune_table="RETUNE_ENV_RE",
+        retune_global="COMPACT_EVERY",
         sink_key="re_compact_every",
     ),
     Knob(
@@ -172,7 +133,7 @@ KNOBS: tuple[Knob, ...] = (
         default="0", owner="photon_ml_tpu/game/random_effect.py",
         doc="1 = fuse same-geometry buckets into one launch",
         accessors=("fuse_buckets",),
-        retune_global="FUSE_BUCKETS", retune_table="RETUNE_ENV_RE",
+        retune_global="FUSE_BUCKETS",
         sink_key="re_fuse_buckets",
     ),
     Knob(
@@ -180,7 +141,7 @@ KNOBS: tuple[Knob, ...] = (
         default="allreduce", owner="photon_ml_tpu/game/random_effect.py",
         doc="cross-process combine transport: allreduce | segments",
         accessors=("re_combine_mode",),
-        retune_global="RE_COMBINE", retune_table="RETUNE_ENV_RE",
+        retune_global="RE_COMBINE",
         sink_key="re_combine",
     ),
     Knob(
@@ -188,7 +149,7 @@ KNOBS: tuple[Knob, ...] = (
         default="0", owner="photon_ml_tpu/game/projector.py",
         doc="per-entity feature projection: 0 | support | hash",
         accessors=("re_project_mode",),
-        retune_global="RE_PROJECT", retune_table="RETUNE_ENV_RE",
+        retune_global="RE_PROJECT",
         sink_key="re_project",
     ),
     Knob(
@@ -197,16 +158,16 @@ KNOBS: tuple[Knob, ...] = (
         doc="signed-hash fold width (pow2) for classes whose support "
             "exceeds it (hash mode only)",
         accessors=("re_project_dim",),
-        retune_global="RE_PROJECT_DIM", retune_table="RETUNE_ENV_RE",
+        retune_global="RE_PROJECT_DIM",
         sink_key="re_project_dim",
     ),
-    # -- entity-shard placement (RETUNE_ENV_SHARD) --------------------------
+    # -- entity-shard placement ---------------------------------------------
     Knob(
         name="PHOTON_RE_SHARD", kind="flag", parse="strict_int",
         default="0", owner="photon_ml_tpu/parallel/placement.py",
         doc="1 = skew-aware entity sharding + overlapped P2P exchange",
         accessors=("re_shard_enabled",),
-        retune_global="RE_SHARD", retune_table="RETUNE_ENV_SHARD",
+        retune_global="RE_SHARD",
         sink_key="re_shard",
     ),
     Knob(
@@ -214,7 +175,7 @@ KNOBS: tuple[Knob, ...] = (
         default="0", owner="photon_ml_tpu/parallel/placement.py",
         doc="sub-bucket atom target count; 0 = bucket-atomic placement",
         accessors=("re_split_factor",),
-        retune_global="RE_SPLIT", retune_table="RETUNE_ENV_SHARD",
+        retune_global="RE_SPLIT",
         sink_key="re_split",
     ),
     Knob(
@@ -223,7 +184,7 @@ KNOBS: tuple[Knob, ...] = (
         owner="photon_ml_tpu/parallel/placement.py",
         doc="measured max/mean solve-wall ratio that triggers a re-plan",
         accessors=("replan_imbalance_threshold",),
-        retune_global="REPLAN_IMBALANCE", retune_table="RETUNE_ENV_SHARD",
+        retune_global="REPLAN_IMBALANCE",
         sink_key="re_replan_imbalance",
     ),
     Knob(
@@ -231,7 +192,7 @@ KNOBS: tuple[Knob, ...] = (
         default="0", owner="photon_ml_tpu/parallel/placement.py",
         doc="1 = second-level LPT: owned atoms placed per LOCAL device",
         accessors=("re_device_split_enabled",),
-        retune_global="RE_DEVICE_SPLIT", retune_table="RETUNE_ENV_SHARD",
+        retune_global="RE_DEVICE_SPLIT",
         sink_key="re_device_split",
     ),
     Knob(
@@ -239,16 +200,16 @@ KNOBS: tuple[Knob, ...] = (
         default="rows", owner="photon_ml_tpu/parallel/placement.py",
         doc="atom split/placement weight axis: rows | bytes",
         accessors=("re_split_weight",),
-        retune_global="RE_SPLIT_WEIGHT", retune_table="RETUNE_ENV_SHARD",
+        retune_global="RE_SPLIT_WEIGHT",
         sink_key="re_split_weight",
     ),
-    # -- feature-range-sharded fixed effect (RETUNE_ENV_SHARD) --------------
+    # -- feature-range-sharded fixed effect ---------------------------------
     Knob(
         name="PHOTON_FE_SHARD", kind="flag", parse="strict_int",
         default="0", owner="photon_ml_tpu/data/index_map.py",
         doc="1 = range-shard the fixed-effect feature space across processes",
         accessors=("fe_shard_enabled",),
-        retune_global="FE_SHARD", retune_table="RETUNE_ENV_SHARD",
+        retune_global="FE_SHARD",
         sink_key="fe_shard",
     ),
     Knob(
@@ -256,16 +217,16 @@ KNOBS: tuple[Knob, ...] = (
         default="nnz", owner="photon_ml_tpu/data/index_map.py",
         doc="feature-range boundary weight axis: nnz | width",
         accessors=("fe_split_weight",),
-        retune_global="FE_SPLIT_WEIGHT", retune_table="RETUNE_ENV_SHARD",
+        retune_global="FE_SPLIT_WEIGHT",
         sink_key="fe_split_weight",
     ),
-    # -- online serving (RETUNE_ENV_SERVE) ----------------------------------
+    # -- online serving -----------------------------------------------------
     Knob(
         name="PHOTON_SERVE_HOT_BYTES", kind="int", parse="strict_int",
         default="25% of RE model bytes", owner="photon_ml_tpu/serve/store.py",
         doc="hot-set byte budget for device-resident model shards",
         accessors=("serve_hot_budget_bytes",),
-        retune_global="SERVE_HOT_BYTES", retune_table="RETUNE_ENV_SERVE",
+        retune_global="SERVE_HOT_BYTES",
         sink_key="serve_hot_bytes",
     ),
     Knob(
@@ -273,7 +234,7 @@ KNOBS: tuple[Knob, ...] = (
         default="32", owner="photon_ml_tpu/serve/router.py",
         doc="micro-window flush size (also the padded scoring shape)",
         accessors=("serve_max_batch",),
-        retune_global="SERVE_MAX_BATCH", retune_table="RETUNE_ENV_SERVE",
+        retune_global="SERVE_MAX_BATCH",
         sink_key="serve_max_batch",
     ),
     Knob(
@@ -281,7 +242,7 @@ KNOBS: tuple[Knob, ...] = (
         default="2.0", owner="photon_ml_tpu/serve/router.py",
         doc="oldest-request wait (ms) that forces a partial-window flush",
         accessors=("serve_max_wait_ms",),
-        retune_global="SERVE_MAX_WAIT_MS", retune_table="RETUNE_ENV_SERVE",
+        retune_global="SERVE_MAX_WAIT_MS",
         sink_key="serve_max_wait_ms",
     ),
     Knob(
@@ -289,17 +250,17 @@ KNOBS: tuple[Knob, ...] = (
         default="0 (off)", owner="photon_ml_tpu/serve/refresh.py",
         doc="buffered events per entity that trigger an incremental refresh",
         accessors=("serve_refresh_every",),
-        retune_global="SERVE_REFRESH_EVERY", retune_table="RETUNE_ENV_SERVE",
+        retune_global="SERVE_REFRESH_EVERY",
         sink_key="serve_refresh_every",
     ),
-    # -- streaming executor (RETUNE_ENV_STREAM) -----------------------------
+    # -- streaming executor -------------------------------------------------
     Knob(
         name="PHOTON_STREAM_EXECUTOR", kind="flag", parse="strict_int",
         default="0", owner="photon_ml_tpu/ops/stream_executor.py",
         doc="1 = route streamed consumers through the shared executor "
             "(multi-tenant chunk-cache arbiter + cross-stream scheduling)",
         accessors=("stream_executor_enabled",),
-        retune_global="STREAM_EXECUTOR", retune_table="RETUNE_ENV_STREAM",
+        retune_global="STREAM_EXECUTOR",
         sink_key="stream_executor",
     ),
     Knob(
@@ -309,7 +270,7 @@ KNOBS: tuple[Knob, ...] = (
         doc="per-consumer scheduling priority overrides, "
             "'name=int,...' — higher preempts lower streams' prefetch depth",
         accessors=("stream_priority_spec", "priority_of"),
-        retune_global="STREAM_PRIORITY", retune_table="RETUNE_ENV_STREAM",
+        retune_global="STREAM_PRIORITY",
         sink_key="stream_priority",
     ),
     Knob(
@@ -319,7 +280,7 @@ KNOBS: tuple[Knob, ...] = (
         doc="per-consumer chunk-cache budget shares, 'name=frac,...' — "
             "caps a stream's charged bytes at frac x the cache budget",
         accessors=("stream_share_spec", "share_fraction"),
-        retune_global="STREAM_SHARE", retune_table="RETUNE_ENV_STREAM",
+        retune_global="STREAM_SHARE",
         sink_key="stream_share",
     ),
     # -- observability / selection toggles ---------------------------------
@@ -330,8 +291,6 @@ KNOBS: tuple[Knob, ...] = (
         doc="force per-lane iteration readback for re_solve.* counters",
         accessors=("_iter_accounting_enabled",),
         exempt=(
-            ("retune", "diagnostics readback toggle, not a perf lever; "
-                       "bench R_re_skew sets it explicitly"),
             ("sink", "changes only whether counters are read back, never "
                      "executables or math"),
         ),
@@ -342,7 +301,6 @@ KNOBS: tuple[Knob, ...] = (
         doc="per-process telemetry shards on processes 1..N-1",
         accessors=("fleet_telemetry_enabled",),
         exempt=(
-            ("retune", "telemetry file layout, not a perf lever"),
             ("sink", "configures the sink itself; recorded implicitly by "
                      "which shard files exist"),
         ),
@@ -353,8 +311,6 @@ KNOBS: tuple[Knob, ...] = (
         doc="force analytic device-cost capture on (1, sink-less) or off (0)",
         accessors=("capture_enabled",),
         exempt=(
-            ("retune", "observability gate, not a perf lever; bench --quick "
-                       "sets it explicitly"),
             ("sink", "gates capture only; documented-lenient parse because "
                      "observability must never take down the run"),
         ),
@@ -365,8 +321,6 @@ KNOBS: tuple[Knob, ...] = (
         doc="1 vetoes auto-enabling the fused one-pass Pallas kernels",
         accessors=("fused_disabled",),
         exempt=(
-            ("retune", "an auto-selection veto for TPU dense batches, not "
-                       "a swept lever; CPU bench configs never auto-fuse"),
             ("sink", "the chosen path is visible as the objective's fused "
                      "flag and in executable labels"),
         ),
@@ -498,48 +452,12 @@ def accessor_names() -> frozenset[str]:
 
 
 def retune_global_names() -> frozenset[str]:
-    """Retune-mutable module globals (bench child processes overwrite
-    these from the environment); reading one inside a jitted body without
-    carrying it as a static key is the same stale-executable class."""
+    """The module globals knob accessors fall back to (tests monkeypatch
+    them); reading one inside a jitted body without carrying it as a
+    static key is the same stale-executable class."""
     return frozenset(
         k.retune_global for k in KNOBS if k.retune_global is not None
     )
-
-
-def expected_retune_tables() -> dict[str, set[str]]:
-    out: dict[str, set[str]] = {t: set() for t in RETUNE_TABLES}
-    for k in KNOBS:
-        if k.retune_table is not None:
-            out[k.retune_table].add(k.name)
-    return out
-
-
-def check_retune_tables(actual: dict[str, dict]) -> None:
-    """Runtime twin of the lint cross-check, called by ``bench.py`` at
-    retune-application time: raise on any drift between the bench's
-    RETUNE dicts and this registry, so a bench process cannot even START
-    a sweep over an unregistered (or un-wired) knob."""
-    expected = expected_retune_tables()
-    problems = []
-    for table, env_map in actual.items():
-        names = set(env_map)
-        want = expected.get(table, set())
-        for extra in sorted(names - want):
-            problems.append(
-                f"{table} carries {extra} but the knob registry "
-                f"(photon_ml_tpu/analysis/registry.py) does not place it "
-                f"there — register it (and wire its mirror surfaces)"
-            )
-        for missing in sorted(want - names):
-            problems.append(
-                f"{table} is missing {missing}, which the knob registry "
-                f"requires there"
-            )
-    if problems:
-        raise ValueError(
-            "bench RETUNE tables drifted from the knob registry:\n  "
-            + "\n  ".join(problems)
-        )
 
 
 # -- README knob table (generated; photon-ml-tpu lint --write-docs) ---------
@@ -554,15 +472,14 @@ def render_knob_table() -> str:
     when the committed table and the registry disagree."""
     lines = [
         KNOB_TABLE_BEGIN,
-        "| Knob | Kind | Default | Retune table | Snapshot key | What it does |",
-        "|---|---|---|---|---|---|",
+        "| Knob | Kind | Default | Snapshot key | What it does |",
+        "|---|---|---|---|---|",
     ]
     for k in KNOBS:
-        retune = f"`{k.retune_table}`" if k.retune_table else "—"
         sink = f"`{k.sink_key}`" if k.sink_key else "—"
         lines.append(
             f"| `{k.name}` | {k.kind} ({k.parse}) | {k.default} | "
-            f"{retune} | {sink} | {k.doc} |"
+            f"{sink} | {k.doc} |"
         )
     lines.append(KNOB_TABLE_END)
     return "\n".join(lines)
@@ -577,14 +494,6 @@ def _validate_registry() -> None:
         if k.name in seen:
             raise AssertionError(f"duplicate knob registration: {k.name}")
         seen.add(k.name)
-        if k.retune_table is None and k.exempt_reason("retune") is None:
-            raise AssertionError(
-                f"{k.name}: no retune_table and no 'retune' exemption"
-            )
-        if k.retune_table is not None and k.retune_table not in RETUNE_TABLES:
-            raise AssertionError(
-                f"{k.name}: unknown retune table {k.retune_table}"
-            )
         if k.sink_key is None and k.exempt_reason("sink") is None:
             raise AssertionError(
                 f"{k.name}: no sink_key and no 'sink' exemption"

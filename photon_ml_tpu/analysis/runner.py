@@ -41,13 +41,12 @@ PASSES = {
 
 def discover_root(start: str | None = None) -> str:
     """The repo root: walk up from ``start`` (default cwd) to the first
-    directory holding pyproject.toml or bench.py; fall back to the
+    directory holding pyproject.toml; fall back to the
     installed package's parent (the tier-1 test's path when run from an
     arbitrary cwd)."""
     cur = os.path.abspath(start or os.getcwd())
     while True:
-        if os.path.exists(os.path.join(cur, "pyproject.toml")) or \
-                os.path.exists(os.path.join(cur, "bench.py")):
+        if os.path.exists(os.path.join(cur, "pyproject.toml")):
             return cur
         parent = os.path.dirname(cur)
         if parent == cur:

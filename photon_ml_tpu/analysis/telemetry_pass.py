@@ -2,19 +2,19 @@
 
 Every telemetry name crosses a process boundary as a STRING: an event
 type in a JSONL record, a counter/gauge/timer name in a metrics snapshot.
-``obs/report.py`` (summarize / fleet / gate) and the bench JSON contract
+``obs/report.py`` (summarize / fleet / gate) and ``obs/export.py``
 consume those strings by spelling them again — so a renamed emission
 silently empties a report row, and a consumer typo reads a name nothing
 emits. This pass collects both sides from the AST and flags disagreement:
 
-- **Emitters** (whole package + bench.py): ``emit_event("name", ...)``
+- **Emitters** (whole package): ``emit_event("name", ...)``
   and any local ``*emit*``-named wrapper with a constant first argument
   (the repo wraps ``emit_event`` in never-raise guards like multihost's
   ``_emit_event``); ``{"event": "name"}`` dict literals (the sink/span
   records); ``REGISTRY.counter_inc / gauge_set / timer_add /
   histogram_observe`` first-arg constants. F-string names
   (``f"devcost.{label}.flops"``) become wildcard patterns.
-- **Consumers** (``obs/report.py`` + ``obs/export.py`` + ``bench.py``):
+- **Consumers** (``obs/report.py`` + ``obs/export.py``):
   string constants compared against an ``event`` field, and string
   constants used to index/get/test membership on metric mappings
   (``counters`` / ``gauges`` / ``timers`` / ``histograms`` and their
@@ -26,7 +26,11 @@ zero: a *dangling consumer* must be a STRUCTURALLY extracted consumed
 name with no emitter; a *never-rendered emission* is an emitted name
 whose string appears NOWHERE in the consumer files (any textual mention —
 a literal list the report iterates, a prefix table — counts as
-consumed). Wildcard emissions are matched by their literal prefix.
+consumed). Wildcard emissions are matched by their literal prefix. A
+family reader counts too: the report strips a family prefix
+(``k.startswith("re_shard.")``) and spells only the rest
+(``.get("balance")``), so a name whose prefix and remainder both appear
+as string literals is rendered.
 
 Codes: ``telem-dangling-consumer``, ``telem-unrendered-emission``.
 """
@@ -94,11 +98,7 @@ def _joined_to_pattern(node: ast.JoinedStr) -> tuple[str, re.Pattern] | None:
 
 def collect_emissions(project: Project) -> list[Emission]:
     out: list[Emission] = []
-    modules = list(project.iter_modules())
-    bench = project.bench_module()
-    if bench is not None:
-        modules.append(bench)
-    for mi in modules:
+    for mi in project.iter_modules():
         if mi.relpath in (_REPORT_RELPATH, _EXPORT_RELPATH):
             continue  # consumers; their event literals are not emissions
         for node in ast.walk(mi.tree):
@@ -248,7 +248,7 @@ def collect_consumptions(mi: ModuleInfo) -> list[Consumption]:
 
 
 #: names no single emitter owns — structural fields of every record, or
-#: injected by the sink/bench machinery rather than an emit call
+#: injected by the sink machinery rather than an emit call
 _STRUCTURAL_NAMES = {"event", "t"}
 
 
@@ -262,9 +262,6 @@ def run(project: Project, registry=None) -> list[Finding]:
         mi = project.module(relpath)
         if mi is not None:
             consumer_mis.append(mi)
-    bench_mi = project.bench_module()
-    if bench_mi is not None:
-        consumer_mis.append(bench_mi)
     if not consumer_mis:
         return []
 
@@ -297,27 +294,45 @@ def run(project: Project, registry=None) -> list[Finding]:
             f"empty (renamed or removed emitter?)",
         ))
 
+    def _literal(s: str, prefix_only: bool = False) -> bool:
+        # ``s`` spelled as a string literal, or as the start of one (an
+        # f-string key such as f"device_rows.{d}")
+        return any(
+            q + s + ("" if prefix_only else q) in consumer_text
+            for q in "\"'"
+        )
+
     seen_unrendered: set[str] = set()
     for e in emissions:
         if e.name in seen_unrendered:
             continue
         if e.pattern is None:
-            rendered = f'"{e.name}"' in consumer_text or \
-                f"'{e.name}'" in consumer_text
+            rendered = _literal(e.name) or any(
+                _literal(e.name[:i + 1]) and _literal(e.name[i + 1:])
+                for i, c in enumerate(e.name) if c == "."
+            )
         else:
             # wildcard names count as rendered when any literal segment
             # (e.g. "devcost." of "devcost.*.flops", ".rows_max" of
             # "*.rows_max") appears in a consumer — the report renders
-            # such families by prefix/suffix iteration
-            segments = [s for s in e.name.split("*") if len(s) >= 4]
-            rendered = any(s in consumer_text for s in segments)
+            # such families by prefix/suffix iteration — or when a
+            # segment after the wildcard is spelled past its dot by a
+            # family reader ("balance" of "*.balance")
+            parts = e.name.split("*")
+            rendered = any(
+                s in consumer_text
+                or (j > 0 and s.startswith(".") and _literal(
+                    s[1:], prefix_only=j < len(parts) - 1
+                ))
+                for j, s in enumerate(parts) if len(s) >= 4
+            )
         if not rendered:
             seen_unrendered.add(e.name)
             findings.append(Finding(
                 "telem-unrendered-emission", e.file, e.line,
                 f"{e.kind}:{e.name}",
                 f"{e.kind} '{e.name}' is emitted here but neither "
-                f"obs/report.py nor bench.py ever mentions it — no "
+                f"obs/report.py nor obs/export.py ever mentions it — no "
                 f"summarize/fleet/gate row renders it (dead instrument, "
                 f"or a consumer was never wired)",
             ))

@@ -1,7 +1,7 @@
 """Telemetry run report driver (``photon-ml-tpu report``).
 
 Renders a run's telemetry JSONL (written by any driver's
-``--telemetry-dir`` or ``bench.py --telemetry-dir``) into a per-phase
+``--telemetry-dir``) into a per-phase
 wall/compile/transfer summary plus the analytic device-cost roofline
 table, diffs two runs (the sweep-readout format), exports the span
 timeline as Chrome-trace/Perfetto JSON, validates a run's schema, and
@@ -29,10 +29,9 @@ shard into ONE Chrome-trace timeline (pid = process index). ``gate
 --fleet`` gates the MERGED view — balance/overlap/straggler regressions
 anywhere in the fleet trip it, not just on process 0.
 
-``gate`` accepts a telemetry run JSONL/dir, a ``bench.py`` JSON document
-(``--quick`` stdout capture — the committed ``BASELINE_cost_cpu.json``
-format), or a saved gate-baseline file, on EITHER side; both sides must
-be the same kind or share metric names. ``--thresholds`` takes a JSON
+``gate`` accepts a telemetry run JSONL/dir or a saved gate-baseline
+file, on EITHER side; both sides must be the same kind or share metric
+names. ``--thresholds`` takes a JSON
 object (inline or a file path) of ``{pattern: {"rel": r, "abs": a}}``
 overrides on top of the defaults in ``obs/report.py``. Combining
 ``--baseline`` with ``--write-baseline`` is update-and-verify: the gate
@@ -152,8 +151,7 @@ def _gate_main(argv: list[str]) -> None:
         description="diff a run's cost/wall/quality metrics against a "
                     "baseline; exit 1 on regression",
     )
-    p.add_argument("run", help="telemetry run JSONL/dir, or a bench.py "
-                               "JSON document")
+    p.add_argument("run", help="telemetry run JSONL/dir")
     p.add_argument("--fleet", action="store_true",
                    help="gate the MERGED fleet view of the run "
                         "(canonical file + every .p<k> shard) instead "
@@ -197,7 +195,7 @@ def _gate_main(argv: list[str]) -> None:
 
     def _info(msg: str):
         # informational lines go to stderr under --json: stdout must stay
-        # a single machine-readable object (the bench-contract discipline)
+        # a single machine-readable object on stdout
         import sys
 
         print(msg, file=sys.stderr if args.json else sys.stdout)

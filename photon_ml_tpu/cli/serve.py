@@ -18,8 +18,7 @@ the next slice. Publication is atomic, so the poll either sees the old
 complete snapshot or the new one.
 
 The stdout contract is one JSON summary line (requests, windows,
-latency p50/p99, hot-set hit rate, occupancy, swaps) — the same
-discipline as ``bench.py --quick``.
+latency p50/p99, hot-set hit rate, occupancy, swaps).
 
 Usage:
     photon-ml-tpu serve --model-root published/ \\

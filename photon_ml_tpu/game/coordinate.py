@@ -947,7 +947,7 @@ class RandomEffectCoordinate:
                 # rebind the device tensors through jit ARGUMENTS (closing over
                 # them would bake every bucket tensor and the feature shard
                 # into the executable as trace constants — the closure-capture
-                # accumulation bench.py isolates per-config subprocesses for);
+                # accumulation that grows a long-lived process's memory);
                 # the host-side metadata (entity_ids, num_real) rides the
                 # closure, unused in the trace
                 order, per_bucket = bucket_args

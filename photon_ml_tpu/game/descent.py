@@ -161,7 +161,7 @@ def _build_fused_outer(coordinates: Mapping[str, Any], seq: Sequence[str]):
 _MAX_FUSED_CHUNK = 16
 
 # In-place degrade for the in-memory descent (PHOTON_DESCENT_DEGRADE;
-# bench RETUNE idiom: env > module global, strict int parse, call-time
+# the knob idiom: env > module global, strict int parse, call-time
 # read). 0 (default) keeps today's behavior byte-for-byte: a PeerLost
 # aborts with the actionable restart-from-checkpoint message. 1 catches
 # the loss at the OUTER-ITERATION boundary instead: roll call, shrink

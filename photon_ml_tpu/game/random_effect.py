@@ -70,9 +70,9 @@ def _captured_jit_call(label, fn, *args, **kwargs):
     devcost.capture(label, fn, args, kwargs)
     return fn(*args, **kwargs)
 
-# Convergence-aware bucket-solve knobs (bench RETUNE idiom: the env var
-# wins over the module global, both read at CALL time so bench child
-# processes and tests retune without import-order games).
+# Convergence-aware bucket-solve knobs (the knob idiom: the env var
+# wins over the module global, both read at CALL time so a job's
+# environment and tests set them without import-order games).
 #
 # COMPACT_EVERY > 0 runs each bucket's batched while_loop in chunks of
 # that many outer iterations; between chunks the per-lane done mask is
@@ -132,7 +132,7 @@ def _iter_accounting_enabled() -> bool:
     for the ``re_solve.*`` executed/useful counters. That readback is a
     host sync the deferred-diagnostics design otherwise avoids, so it is
     opt-in: on when a telemetry sink is active (observability runs accept
-    the sync) or when ``PHOTON_RE_ITER_ACCOUNTING=1`` (bench R_re_skew);
+    the sync) or when ``PHOTON_RE_ITER_ACCOUNTING=1``;
     ``=0`` forces it off. The compacted path always counts — it syncs
     the done mask between chunks anyway."""
     env = os.environ.get("PHOTON_RE_ITER_ACCOUNTING")

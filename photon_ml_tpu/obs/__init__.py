@@ -51,7 +51,7 @@ from photon_ml_tpu.obs.spans import (  # noqa: F401
 # Compile visibility is part of the ALWAYS-ON half: install the
 # jax.monitoring listener at import (no backend init; the callback is a
 # cheap no-op between runs) so ``jax.compile_s`` is in every registry
-# snapshot — bench telemetry blocks included — even without a sink.
+# snapshot even without a sink.
 from photon_ml_tpu.obs.sink import _install_jax_monitoring
 
 _install_jax_monitoring()

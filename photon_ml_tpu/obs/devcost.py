@@ -1,7 +1,7 @@
 """Analytic device-cost capture: per-executable XLA cost/memory analysis.
 
-Every perf knob so far (groups-per-run, prefetch depth, compaction,
-kernel dtype) shipped bitwise-parity-tested but BLIND — no
+Every perf knob so far (prefetch depth, compaction, kernel dtype)
+shipped bitwise-parity-tested but BLIND — no
 session has had a TPU attached, so no on-device cost number exists for any
 of them. XLA's AOT surface closes the gap on any backend: for a jitted
 callable, ``fn.lower(*args).compile()`` yields ``cost_analysis()`` (flops,
@@ -10,14 +10,14 @@ bytes) — hardware-independent ANALYTIC estimates on CPU, real HBM numbers
 the moment a chip appears. This module captures those numbers once per
 fresh executable and publishes them as schema-versioned
 ``executable_cost`` telemetry records plus ``devcost.*`` registry gauges,
-so the dtype-ladder and groups-per-run sweeps can be compared
+so the dtype-ladder and prefetch sweeps can be compared
 analytically today and gated in CI (``photon-ml-tpu report gate``).
 
 Capture discipline (the whole point is to never touch the hot path):
 
 - **Cache-miss only.** A process-wide seen-set keyed by ``(label, knob
   tuple, argument signature)`` mirrors the jit caches it shadows: the
-  knob tuple is the retune surface (dtype rung, groups-per-run, …) and the signature is tree structure + shape/dtype
+  knob tuple is the run's knob snapshot (dtype rung, prefetch depth, …) and the signature is tree structure + shape/dtype
   of every array leaf + repr of every static. A repeat call emits
   NOTHING and costs one tree flatten + the signature-tuple build + a
   set lookup (the knob snapshot is memoized on its raw env/global
@@ -26,8 +26,7 @@ Capture discipline (the whole point is to never touch the hot path):
   tracers from outer jits/vmaps; any tracer leaf skips capture (the
   enclosing executable is captured at ITS boundary instead).
 - **Gated.** Capture runs when a telemetry sink is active, or when
-  ``PHOTON_DEVCOST=1`` forces it sink-less (registry gauges only — how
-  ``bench.py --quick`` gets cost numbers into its JSON contract).
+  ``PHOTON_DEVCOST=1`` forces it sink-less (registry gauges only).
   ``PHOTON_DEVCOST=0`` forces it off. Cost: the AOT ``lower().compile()``
   is a SECOND compile of the executable (jax exposes no way to reach the
   jit cache's compiled object, and routing production calls through the
@@ -92,7 +91,7 @@ _warned_bad_env = [False]
 def capture_enabled() -> bool:
     """Capture gate: ``PHOTON_DEVCOST`` wins (int parse — ``1`` forces
     on sink-less, ``0`` forces off), else capture exactly when a
-    telemetry sink is active. Unlike the sibling RETUNE knobs (which
+    telemetry sink is active. Unlike the sibling knobs (which
     change MATH and fail strict), a malformed value here degrades to
     capture-off with one warning: this check sits on every wired
     production boundary, and observability misconfiguration must never
@@ -228,8 +227,7 @@ def _knob_raw_state() -> tuple:
         env.get("PHOTON_FE_SPLIT_WEIGHT"),
         pf.PREFETCH_DEPTH, pf.CHUNK_CACHE_BUDGET,
         len(pf._device_budget_memo),
-        st.GROUPS_PER_STEP, st.SEGMENTS_PER_DMA,
-        st.GROUPS_PER_RUN, st.KERNEL_DTYPE,
+        st.KERNEL_DTYPE,
         re_state,
         shard_state,
         project_state,
@@ -254,9 +252,9 @@ def _knob_items() -> tuple:
 
 
 def knob_key() -> dict:
-    """The retune surface an executable was compiled under — the same
-    knob snapshot a run's ``run_start`` records (dtype rung,
-    groups-per-run, prefetch depth, compaction knobs), so cost records
+    """The knob snapshot an executable was compiled under — the same
+    one a run's ``run_start`` records (dtype rung, prefetch depth,
+    compaction knobs), so cost records
     key by CONFIGURATION, not by luck."""
     return dict(_knob_items())
 
@@ -392,7 +390,7 @@ def capture(
 # per-label running totals behind the devcost.<label>.* gauges: one label
 # can capture several executables (the compaction loop's shrinking fronts,
 # several chunk geometries), and a last-write-wins gauge would show only
-# the LAST one — blinding the bench-JSON gate path to every earlier
+# the LAST one — blinding a registry snapshot to every earlier
 # executable. The gauges therefore carry the SUM of flops/bytes and the
 # MAX peak across the label's captures, the same aggregation the
 # telemetry-JSONL summarize path applies.
@@ -456,7 +454,7 @@ def captured(label_prefix: str, fn: Any) -> Any:
 
 def record_hbm_budget(budget_bytes: float, queried: bool) -> None:
     """Called by ``ops/streaming.device_hbm_budget_bytes`` on every query:
-    gauges always (the bench telemetry block reads them), plus ONE
+    gauges always (every registry snapshot carries them), plus ONE
     ``hbm_budget`` event per sink naming which source won — a run on a
     memory-stats-less backend (CPU: ``fallback_default``) is
     distinguishable from a device-quoted one in ``report`` output."""

@@ -200,18 +200,6 @@ def summarize_run(path: str, records: list[dict] | None = None) -> dict:
     for r in optim:
         reasons[str(r.get("reason"))] = reasons.get(str(r.get("reason")), 0) + 1
 
-    # precision-ladder quality parity (BASELINE protocol: speed is never
-    # reported without a parity check): a reduced-precision bench run
-    # emits a quality_parity event with its AUC/RMSE/loss deltas against
-    # the f32 anchor — surfaced here so a dtype sweep reads its quality
-    # gate from the same report as its wall numbers
-    quality_parity = None
-    for r in records:
-        if r["event"] == "quality_parity":
-            quality_parity = {
-                k: v for k, v in r.items() if k not in ("event", "t")
-            }
-
     # analytic device cost (obs/devcost executable_cost records): one
     # roofline row per (capture label, knob tuple) — flops,
     # bytes-accessed, arithmetic intensity, peak memory. Sums are over
@@ -255,8 +243,8 @@ def summarize_run(path: str, records: list[dict] | None = None) -> dict:
             continue
         # naming must be STABLE for gating: the variant matching the
         # RUN'S OWN knobs keeps the bare label (the name a single-variant
-        # baseline run produced), and off-run variants (e.g. the f32
-        # quality-parity anchor captured inside a bf16 run) are suffixed
+        # baseline run produced), and off-run variants (e.g. an f32
+        # anchor captured inside an int8 run) are suffixed
         # by their delta vs the run knobs — so adding an anchor capture
         # never renames the run's native metrics out from under a
         # committed baseline
@@ -349,7 +337,6 @@ def summarize_run(path: str, records: list[dict] | None = None) -> dict:
         "re_solve": re_solve,
         "re_shard": re_shard,
         "fe_shard": fe_shard,
-        "quality_parity": quality_parity,
         "devcost": devcost,
         "hbm": hbm,
         "warnings": sum(
@@ -667,17 +654,6 @@ def _fmt_qty(v: float | None) -> str:
     return f"{v:.0f}"
 
 
-def _fmt_quality_parity(qp: dict) -> str:
-    # every delta/RMSE metric renders — the gate's whole point is that a
-    # bad number is impossible to miss next to the wall numbers
-    parts = [f"kernel_dtype={qp.get('kernel_dtype')}"]
-    for k in sorted(qp):
-        if k.endswith("_delta") or "rmse" in k:
-            v = qp[k]
-            parts.append(f"{k}={v:+.6f}" if isinstance(v, float) else f"{k}={v}")
-    return ", ".join(parts)
-
-
 def format_summary(s: dict) -> str:
     lines = [
         f"run {s['run_id']}  (schema v{s['schema_version']}, "
@@ -926,10 +902,6 @@ def format_summary(s: dict) -> str:
                 f"{_fmt_qty(c.get('charged_bytes') or 0.0)}B, "
                 f"{int(c.get('yields') or 0)} yields"
             )
-    if s.get("quality_parity"):
-        lines.append(
-            f"  quality-parity: {_fmt_quality_parity(s['quality_parity'])}"
-        )
     dc = s.get("devcost") or {}
     if dc:
         est = any(a.get("peak_is_estimate") for a in dc.values())
@@ -1072,15 +1044,6 @@ def diff_summaries(a: dict, b: dict) -> str:
                 f"    {lab:<32} {_fmt_qty(va):>9} {_fmt_qty(vb):>9} "
                 f"{ratio:>7}"
             )
-    qa, qb = a.get("quality_parity"), b.get("quality_parity")
-    if qa or qb:
-        lines.append("  quality-parity:")
-        lines.append(
-            f"    A: {_fmt_quality_parity(qa) if qa else _UNRECORDED}"
-        )
-        lines.append(
-            f"    B: {_fmt_quality_parity(qb) if qb else _UNRECORDED}"
-        )
     ka, kb = a.get("knobs", {}), b.get("knobs", {})
     # a knob only one run recorded (an older-schema run, or a pre-knob
     # baseline) renders as "(unrecorded)" instead of being dropped — an
@@ -1882,8 +1845,8 @@ def format_fleet(fs: dict) -> str:
 #
 # ``photon-ml-tpu report gate RUN --baseline BASE`` turns the telemetry
 # artifact from a passive record into an active tripwire: a flat metric
-# dict is extracted from each side (telemetry run JSONL, bench JSON doc,
-# or a saved gate-baseline file), every baseline metric is compared
+# dict is extracted from each side (telemetry run JSONL or a saved
+# gate-baseline file), every baseline metric is compared
 # against the current run under a per-metric threshold, and any breach
 # exits nonzero. Thresholds are tiered by what the metric IS: analytic
 # cost numbers (devcost flops/bytes) are deterministic for a given
@@ -1906,7 +1869,6 @@ DEFAULT_GATE_THRESHOLDS: dict[str, dict] = {
     "capture_s": {"rel": 4.0, "abs": 10.0},
     # analytic tiers: byte/flop counts move only when code or knobs move
     "devcost/": {"rel": 0.02},
-    "packed_stream_bytes": {"rel": 0.01},
     "hbm/": {"rel": 0.10},
     # placement tiers: every planner readout (balance ratios, rows_max)
     # is deterministic for a given planner + row distribution, so the
@@ -1925,13 +1887,10 @@ DEFAULT_GATE_THRESHOLDS: dict[str, dict] = {
     # excuse to drift than the whole-class plan — tight tier
     "re_shard/atoms": {"rel": 0.0, "abs": 0.0},
     "re_shard/balance_split": {"rel": 0.02},
-    # device-granularity placement tiers (PHOTON_RE_DEVICE_SPLIT runs
+    # device-granularity placement tier (PHOTON_RE_DEVICE_SPLIT runs
     # only): the per-device LPT is deterministic on the owned-atom
-    # weights, so the balance gates tight like balance_split; the
-    # launch schedule is exact deterministic fusion-unit arithmetic —
-    # one extra launch is a schedule regression, not noise
+    # weights, so the balance gates tight like balance_split
     "re_shard/device_balance": {"rel": 0.02},
-    "re_solve/launches": {"rel": 0.0, "abs": 0.0},
     # combine-traffic tier: bytes per process are deterministic for a
     # given combine mode + placement, so near-tight — a 5% creep is a
     # packing/layout regression, and a mode accidentally falling back
@@ -1984,32 +1943,20 @@ DEFAULT_GATE_THRESHOLDS: dict[str, dict] = {
     "fe_shard/": {"rel": 0.05},
     "fe_shard/ranges": {"rel": 0.0, "abs": 0.0},
     "fe_shard/nnz_balance": {"rel": 0.02},
-    # serving tiers (bench --serve / serving runs only): wall-clock
-    # latency percentiles jitter like every wall tier, so they gate
-    # LOOSE; the hot-set hit rate and mean window occupancy are bounded
-    # [0, 1] ratios that gate on PRESENCE (losing the instrument trips,
-    # a value never does — the >= 0.8 acceptance floor is the bench
-    # doc's own assertion, not the gate's); the two parity flags are
-    # bitwise contracts, so they gate EXACT — a refresh that stops
-    # matching its offline solve, or a serve path that stops matching
-    # the batch score driver, is a correctness break, never noise
+    # serving tiers (serving runs only): wall-clock latency percentiles
+    # jitter like every wall tier, so they gate LOOSE; the hot-set hit
+    # rate and mean window occupancy are bounded [0, 1] ratios that gate
+    # on PRESENCE (losing the instrument trips, a value never does)
     "serve/latency": {"rel": 1.0, "abs": 10.0},
     "serve/hot_hit_rate": {"abs": 1.0},
     "serve/window_occupancy": {"abs": 1.0},
-    "serve/refresh_parity": {"rel": 0.0, "abs": 0.0},
-    "serve/score_parity": {"rel": 0.0, "abs": 0.0},
     # streaming-executor tiers (PHOTON_STREAM_EXECUTOR runs only —
     # executor-off runs never emit stream/* keys, so every committed
     # baseline stays valid unchanged): arbiter transfer bytes are
     # chunk-shape arithmetic but depend on eviction timing under
-    # pressure, so they gate LOOSE; the stream parity flags (bench
-    # X_stream) are bitwise contracts and gate EXACT
+    # pressure, so they gate LOOSE
     "stream/": {"rel": 0.5},
     "stream/cache_evictions": {"rel": 1.0, "abs": 8.0},
-    "stream/parity": {"rel": 0.0, "abs": 0.0},
-    # quality tiers: deltas vs the f32 anchor, absolute headroom at the
-    # parity-gate scale (|ΔAUC| ≤ 0.005 is the ladder's own bf16 gate)
-    "quality/": {"rel": 0.0, "abs": 0.005},
     "optim/iterations": {"rel": 0.25, "abs": 2.0},
     "warnings": {"rel": 0.0, "abs": 0.0},
 }
@@ -2017,9 +1964,9 @@ DEFAULT_GATE_THRESHOLDS: dict[str, dict] = {
 
 def _fmt_gate(v: float | None) -> str:
     """Gate-table cell format: engineering suffixes for big counts, but
-    full precision below 1 — the quality/* tier lives at 1e-3..1e-6 and
-    ``_fmt_qty`` would render every such value (and its limit) as '0',
-    hiding by how much a parity gate was breached."""
+    full precision below 1 — ``_fmt_qty`` would render a ratio or a
+    small count (and its limit) as '0', hiding by how much a gate was
+    breached."""
     if v is None:
         return "-"
     v = float(v)
@@ -2040,20 +1987,6 @@ def resolve_threshold(metric: str, thresholds: dict) -> dict:
     if rule is None:
         rule = thresholds.get("")
     return rule if rule is not None else {"rel": 0.25}
-
-
-def _qp_metrics(qp: dict, prefix: str = "") -> dict:
-    m = {}
-    if not qp:
-        return m
-    for k in ("auc_delta", "loss_rel_delta"):
-        if isinstance(qp.get(k), (int, float)):
-            m[f"{prefix}quality/{k}_abs"] = abs(float(qp[k]))
-    if isinstance(qp.get("margins_rmse_vs_f32"), (int, float)):
-        m[f"{prefix}quality/margins_rmse_vs_f32"] = float(
-            qp["margins_rmse_vs_f32"]
-        )
-    return m
 
 
 def gate_metrics_from_summary(s: dict) -> dict[str, float]:
@@ -2136,7 +2069,6 @@ def gate_metrics_from_summary(s: dict) -> dict[str, float]:
         m["stream/cache_evictions"] = float(
             stm.get("cache_evictions") or 0
         )
-    m.update(_qp_metrics(s.get("quality_parity") or {}))
     o = s.get("optim") or {}
     if o.get("solves"):
         m["optim/iterations"] = float(o.get("iterations") or 0)
@@ -2144,78 +2076,6 @@ def gate_metrics_from_summary(s: dict) -> dict[str, float]:
     hbm = s.get("hbm") or {}
     if hbm.get("peak_bytes_in_use"):
         m["hbm/peak_bytes_in_use"] = float(hbm["peak_bytes_in_use"])
-    return m
-
-
-def gate_metrics_from_bench(doc: dict) -> dict[str, float]:
-    """Flatten a ``bench.py`` JSON document (the ``--quick`` single-line
-    contract, or one ``--config`` child's result) into gateable metrics,
-    namespaced per config. Reads the telemetry block's ``devcost.*`` /
-    ``hbm.*`` gauges, the compile timer, the quality-parity gate and the
-    per-rung packed-stream bytes — everything a dtype or schedule sweep
-    would want tripwired."""
-    configs = doc.get("configs")
-    if configs is None:
-        configs = {"config": doc}
-    m: dict[str, float] = {}
-    for cfg, r in configs.items():
-        if not isinstance(r, dict) or "error" in r:
-            continue  # its baseline metrics then read as MISSING -> fail
-        tel = r.get("telemetry") or {}
-        tmetrics = tel.get("metrics") or {}
-        for g, v in (tmetrics.get("gauges") or {}).items():
-            if g.startswith("devcost."):
-                m[f"{cfg}/devcost/{g[len('devcost.'):]}"] = float(v)
-            elif g.startswith("hbm.") and g != "hbm.budget_queried":
-                m[f"{cfg}/hbm/{g[len('hbm.'):]}"] = float(v)
-            elif g.startswith("re_shard.") and g in (
-                "re_shard.balance",
-                "re_shard.rows_max",
-                "re_shard.round_robin_balance",
-                "re_shard.exchange_overlap_ratio",
-                "re_shard.device_balance",
-            ):
-                m[f"{cfg}/re_shard/{g[len('re_shard.'):]}"] = float(v)
-            elif g in ("fe_shard.ranges", "fe_shard.nnz_balance"):
-                # feature-range sharding readouts (the per-process width
-                # and nnz ride the narrative, not the one-sided gate)
-                m[f"{cfg}/{g.replace('.', '/', 1)}"] = float(v)
-            elif g in ("serve.latency_p50_ms", "serve.latency_p99_ms"):
-                # serving latency gauges (loose wall tier via the
-                # serve/latency substring)
-                m[f"{cfg}/serve/latency{g[len('serve.latency'):]}"] = (
-                    float(v)
-                )
-            elif g == "serve.hot.hit_rate":
-                m[f"{cfg}/serve/hot_hit_rate"] = float(v)
-            elif g == "serve.window.occupancy_mean":
-                m[f"{cfg}/serve/window_occupancy"] = float(v)
-        gauges = tmetrics.get("gauges") or {}
-        if float(gauges.get("re_shard.split_classes") or 0) > 0:
-            # split-granularity tier (mirrors gate_metrics_from_summary)
-            m[f"{cfg}/re_shard/atoms"] = float(
-                gauges.get("re_shard.atoms") or 0
-            )
-            m[f"{cfg}/re_shard/balance_split"] = float(
-                gauges.get("re_shard.balance") or 1.0
-            )
-        timers = tmetrics.get("timers") or {}
-        if "jax.compile_s" in timers:
-            m[f"{cfg}/compile_s"] = float(
-                timers["jax.compile_s"].get("seconds") or 0.0
-            )
-        m.update(
-            _qp_metrics(
-                tel.get("quality_parity") or r.get("quality_parity") or {},
-                prefix=f"{cfg}/",
-            )
-        )
-        if isinstance(r.get("packed_stream_bytes_per_pass"), (int, float)):
-            m[f"{cfg}/packed_stream_bytes_per_pass"] = float(
-                r["packed_stream_bytes_per_pass"]
-            )
-        if isinstance(r.get("sec_per_solve"), (int, float)):
-            m[f"{cfg}/wall_s"] = float(r["sec_per_solve"])
     return m
 
 
@@ -2334,12 +2194,11 @@ def load_gate_metrics(
     path: str, fleet: bool = False
 ) -> tuple[str, dict[str, float]]:
     """(kind, metrics) from any gate-readable artifact: a telemetry run
-    JSONL (or a telemetry DIR — newest run wins), a ``bench.py`` JSON
-    document, or a gate-baseline file written by ``report gate
-    --write-baseline``. ``fleet=True`` loads a telemetry run (file or
-    dir) as the MERGED fleet view — canonical file plus every ``.p<k>``
-    shard — instead of process 0's summary alone; saved gate-baseline
-    files still load as baselines."""
+    JSONL (or a telemetry DIR — newest run wins), or a gate-baseline file
+    written by ``report gate --write-baseline``. ``fleet=True`` loads a
+    telemetry run (file or dir) as the MERGED fleet view — canonical file
+    plus every ``.p<k>`` shard — instead of process 0's summary alone;
+    saved gate-baseline files still load as baselines."""
     if fleet:
         doc = None
         if not os.path.isdir(path):
@@ -2353,12 +2212,6 @@ def load_gate_metrics(
                 k: float(v) for k, v in (doc.get("metrics") or {}).items()
                 if isinstance(v, (int, float))
             }
-        if isinstance(doc, dict) and (
-            "configs" in doc or "telemetry" in doc
-        ) and doc.get("event") != "run_start":
-            # the EITHER-side contract holds under --fleet too: a
-            # bench.py JSON document is a valid (non-fleet) side
-            return "bench", gate_metrics_from_bench(doc)
         return "fleet", gate_metrics_from_fleet(
             summarize_fleet(fleet_run_paths(path))
         )
@@ -2378,10 +2231,6 @@ def load_gate_metrics(
             k: float(v) for k, v in (doc.get("metrics") or {}).items()
             if isinstance(v, (int, float))
         }
-    if isinstance(doc, dict) and (
-        "configs" in doc or "telemetry" in doc
-    ) and doc.get("event") != "run_start":
-        return "bench", gate_metrics_from_bench(doc)
     return "telemetry", gate_metrics_from_summary(summarize_run(path))
 
 

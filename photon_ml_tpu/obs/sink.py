@@ -230,7 +230,7 @@ def _fleet_run_id() -> str:
     file). Collective — every process reaches ``configure`` at the same
     program point, the same contract the drivers' multihost init already
     imposes. Callers that need to avoid the collective pass an explicit
-    ``run_id`` (the bench harness does)."""
+    ``run_id``."""
     import numpy as np
 
     from photon_ml_tpu.parallel.multihost import broadcast_from_host0
@@ -338,8 +338,8 @@ def _shutdown_locked() -> None:
 
 
 def _knob_snapshot() -> dict:
-    """The retune surface a run executed under (same knobs the bench
-    round-trips), so two JSONLs are diffable AS CONFIGURATIONS too."""
+    """The knobs a run executed under, so two JSONLs are diffable AS
+    CONFIGURATIONS too."""
     knobs: dict = {}
     try:
         from photon_ml_tpu.ops import prefetch
@@ -353,9 +353,6 @@ def _knob_snapshot() -> dict:
     try:
         from photon_ml_tpu.ops import sparse_tiled as st
 
-        knobs["groups_per_step"] = int(st.GROUPS_PER_STEP)
-        knobs["segments_per_dma"] = int(st.SEGMENTS_PER_DMA)
-        knobs["groups_per_run"] = int(st.GROUPS_PER_RUN)
         knobs["kernel_dtype"] = st.kernel_dtype()
     except Exception:
         pass
@@ -428,7 +425,7 @@ def _knob_snapshot() -> dict:
 # configure), never unregistered (jax offers no targeted removal); the
 # callbacks consult the active sink so they are cheap no-ops between runs.
 # Durations also land in the registry, so compile wall is in every
-# snapshot — bench telemetry blocks included — even without a sink.
+# snapshot even without a sink.
 
 _jax_monitoring_installed = False
 

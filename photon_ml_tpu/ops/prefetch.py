@@ -42,11 +42,11 @@ are budget-bounded, so a dead objective can pin at most the budgets, not
 grow without bound); a long-running driver that swaps datasets and wants
 the memory back eagerly calls ``clear_cache()``.
 
-Knobs (``RETUNE_ENV``/call-time-read discipline, like the kernel
-constants): ``PHOTON_PREFETCH_DEPTH`` (default 2; 0 = synchronous) and
+Knobs (call-time-read discipline, like ``PHOTON_KERNEL_DTYPE``):
+``PHOTON_PREFETCH_DEPTH`` (default 2; 0 = synchronous) and
 ``PHOTON_CHUNK_CACHE_BUDGET`` (bytes; default = the queried device
-budget). The environment override is read at call time so child bench
-processes and tests retune without import-order games.
+budget). The environment override is read at call time so a job's
+environment and tests set them without import-order games.
 
 Observability: the pipeline's stages report wall-seconds through
 ``utils/profiling.py`` stage counters — ``prefetch.host_pack_s`` (host
@@ -93,7 +93,7 @@ _device_budget_memo: list = []
 
 def prefetch_depth() -> int:
     """The pipeline depth, read at CALL time (env wins over the module
-    global, so bench child processes and tests retune without touching
+    global, so a job's environment and tests set it without touching
     import order)."""
     env = os.environ.get("PHOTON_PREFETCH_DEPTH")
     if env is not None and env != "":

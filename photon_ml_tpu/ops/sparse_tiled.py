@@ -164,7 +164,7 @@ SEGMENTS_PER_DMA = 4  # segments per DMA step (128 groups = 16K nnz per fetch)
 # batched ops instead of per group. Bigger runs amortize more of the
 # per-group skeleton but pad scattered cells harder (a cell always pads to
 # a whole run): at the A2 shapes cells average ~2 groups, so 2 is the
-# padding-neutral default — retune per workload like the two constants
+# padding-neutral default — edit it per workload like the two constants
 # above (must divide GROUPS_PER_STEP).
 GROUPS_PER_RUN = 2  # groups per slab RUN: all read ONE source slab
 # The SPARSE-CELL form of a stream (module docstring, "TWO FORMS"): a cell

@@ -157,7 +157,7 @@ def parse_plan(text: str) -> FaultPlan:
 
 
 # parsed-plan cache keyed on the raw env value: call-time knob reads (the
-# bench RETUNE idiom) without re-parsing per frame; fired-state lives in
+# knob idiom) without re-parsing per frame; fired-state lives in
 # the cached object, so one process's plan is consumed monotonically
 _PLAN_CACHE: dict[str, FaultPlan] = {}
 

@@ -23,7 +23,7 @@ identical on every process (globally-reduced row counts), so every
 process computes the SAME plan with zero extra communication.
 
 Knob: ``PHOTON_RE_SHARD`` (env > module global, strict int parse, read
-at call time — the bench RETUNE idiom). 0 (default) keeps today's
+at call time — the knob idiom). 0 (default) keeps today's
 modular owner rule and exchange schedule bit-for-bit; 1 enables
 skew-aware placement and the overlapped exchange schedule in the
 consumers that opt in (``game/streaming.py``, ``game/random_effect.py``).

@@ -21,8 +21,7 @@ side already proved out:
   (knob ``PHOTON_SERVE_REFRESH_EVERY``).
 - ``loadgen`` — a Zipf open-loop load generator recording p50/p99
   latency, hot-set hit rate and micro-window occupancy into telemetry
-  (``bench.py --serve``; rendered by ``report summarize``/``report
-  fleet``).
+  (``cli/serve``; rendered by ``report summarize``/``report fleet``).
 """
 
 from photon_ml_tpu.serve.loadgen import (  # noqa: F401

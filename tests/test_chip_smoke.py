@@ -146,7 +146,7 @@ def test_sparse_leg(tmp_path, monkeypatch):
     import photon_ml_tpu.ops.streaming as ost
 
     # a budget under the dense matrix's bytes, so the driver's layout
-    # decision tiles at this width as it does at A2's
+    # decision tiles at this width as it does at the full width
     monkeypatch.setattr(ost, "device_hbm_budget_bytes", lambda *a, **k: 4096.0)
     facts = chip_smoke.leg_sparse(str(tmp_path), n=2048, d=4096, k=8, iters=3)
     assert facts["tile_layout_packs"] >= 1 and facts["interpret"] is True

@@ -19,7 +19,6 @@ from photon_ml_tpu.obs import devcost
 from photon_ml_tpu.obs.metrics import REGISTRY
 from photon_ml_tpu.obs.report import (
     DEFAULT_GATE_THRESHOLDS,
-    gate_metrics_from_bench,
     gate_metrics_from_summary,
     gate_run,
     load_gate_metrics,
@@ -77,7 +76,7 @@ class TestCapture:
         assert len(recs) == 2
         assert recs[0]["label"] == "t.prog"
         assert recs[0]["cost_schema_version"] == devcost.COST_SCHEMA_VERSION
-        # registry gauges ride along (the bench JSON contract reads them)
+        # registry gauges ride along (every registry snapshot carries them)
         snap = REGISTRY.snapshot(prefix="devcost")
         assert snap["gauges"]["devcost.t.prog.flops"] > 0
         assert snap["counters"]["devcost.captures"]["value"] == 2
@@ -375,43 +374,13 @@ class TestGate:
             gate_run({"a": 1.0}, {})
 
     def test_metrics_from_summary_and_bench(self, tmp_path):
+        """A telemetry run's summary flattens into gateable metrics."""
         devcost.reset()
         run = _write_cost_run(tmp_path, "g",
                               {"optim.lbfgs_minimize": (10.0, 5.0)})
         m = gate_metrics_from_summary(summarize_run(run))
         assert m["devcost/optim.lbfgs_minimize/bytes_accessed"] == 5.0
         assert "wall_s" in m
-        bench_doc = {
-            "configs": {
-                "A2": {
-                    "sec_per_solve": 1.5,
-                    "packed_stream_bytes_per_pass": 196608,
-                    "telemetry": {
-                        "metrics": {
-                            "gauges": {
-                                "devcost.optim.lbfgs_minimize.flops": 7.0,
-                                "hbm.budget_bytes": 2e9,
-                                "hbm.budget_queried": 0.0,
-                            },
-                            "timers": {
-                                "jax.compile_s": {"seconds": 2.0,
-                                                  "calls": 3},
-                            },
-                        },
-                        "quality_parity": {"auc_delta": -9e-06,
-                                           "margins_rmse_vs_f32": 0.003},
-                    },
-                },
-                "bad": {"error": "boom"},
-            }
-        }
-        bm = gate_metrics_from_bench(bench_doc)
-        assert bm["A2/devcost/optim.lbfgs_minimize.flops"] == 7.0
-        assert bm["A2/packed_stream_bytes_per_pass"] == 196608.0
-        assert bm["A2/quality/auc_delta_abs"] == 9e-06
-        assert bm["A2/compile_s"] == 2.0
-        assert bm["A2/wall_s"] == 1.5
-        assert not any(k.startswith("bad/") for k in bm)
 
     def test_load_gate_metrics_detects_formats(self, tmp_path):
         devcost.reset()
@@ -422,12 +391,14 @@ class TestGate:
         # telemetry DIR resolves to the newest run
         kind, m2 = load_gate_metrics(str(tmp_path / "t"))
         assert kind == "telemetry" and m2 == m
-        bench_path = tmp_path / "bench.json"
-        bench_path.write_text(json.dumps(
+        # a JSON document that is neither a run nor a gate baseline (the
+        # per-config format of a retired harness) is refused, not guessed
+        doc_path = tmp_path / "doc.json"
+        doc_path.write_text(json.dumps(
             {"configs": {"A": {"sec_per_solve": 1.0, "telemetry": {}}}}
         ))
-        kind, bm = load_gate_metrics(str(bench_path))
-        assert kind == "bench" and bm["A/wall_s"] == 1.0
+        with pytest.raises(ValueError, match="invalid telemetry run"):
+            load_gate_metrics(str(doc_path))
         base_path = tmp_path / "base.json"
         base_path.write_text(json.dumps(
             {"gate_baseline": 1, "metrics": {"x": 2.0}}
@@ -468,12 +439,18 @@ class TestCli:
     def test_gate_cli_rejects_incomparable_kinds(self, tmp_path, capsys):
         devcost.reset()
         run = _write_cost_run(tmp_path / "r", "k", {"l": (1.0, 1.0)})
-        bench_path = tmp_path / "bench.json"
-        bench_path.write_text(json.dumps(
-            {"configs": {"A": {"sec_per_solve": 1.0, "telemetry": {}}}}
-        ))
-        code = self._run(["gate", run, "--baseline", str(bench_path)])
-        assert code not in (0, None)
+        # a baseline of a fleet view (the fleet-only metrics every merged
+        # view carries) shares no metric name with one run's summary: the
+        # gate refuses to compare them
+        fleet_base = tmp_path / "fleet.json"
+        fleet_base.write_text(json.dumps({
+            "gate_baseline": 1, "source_kind": "fleet",
+            "metrics": {"fleet/processes": 2.0, "fleet/missing_shards": 0.0,
+                        "fleet/unmatched_p2p": 0.0},
+        }))
+        code = self._run(["gate", run, "--baseline", str(fleet_base)])
+        assert code == 2
+        assert "no comparable metrics" in capsys.readouterr().out
 
     def test_gate_cli_update_and_verify_never_persists_a_failure(
         self, tmp_path, capsys

@@ -90,8 +90,8 @@ class TestPlanFeatureRanges:
 
     def test_nnz_balance_on_zipf_meets_the_r12_gate(self):
         """The prefix cut on an r12-shaped Zipf histogram lands inside the
-        acceptance bound (nnz balance ≤ 1.15x at P∈{2,4}) — the committed
-        MULTICHIP_r12.json numbers are not a lucky draw."""
+        acceptance bound (nnz balance ≤ 1.15x at P∈{2,4}) on a fresh
+        draw, not on one lucky histogram."""
         hist = _zipf_hist(100_000, draws=500_000)
         for p in (2, 4):
             assert plan_feature_ranges(hist, p).balance <= 1.15
@@ -140,7 +140,7 @@ class TestKnobParsing:
         assert fe_shard_enabled() is True
         monkeypatch.setenv("PHOTON_FE_SHARD", "0")
         assert fe_shard_enabled() is False
-        # module global is the env-less fallback (bench retune surface)
+        # module global is the env-less fallback
         monkeypatch.delenv("PHOTON_FE_SHARD")
         monkeypatch.setattr(im, "FE_SHARD", 1)
         assert fe_shard_enabled() is True

@@ -71,30 +71,6 @@ class TestKnobParsing:
         monkeypatch.setenv("PHOTON_KERNEL_DTYPE", " INT8 ")
         assert st.kernel_dtype() == "int8"
 
-    def test_bench_retune_env_applies_and_rejects(self, monkeypatch):
-        import importlib.util
-        import os
-        import sys
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_module_dtype",
-            os.path.join(
-                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                "bench.py",
-            ),
-        )
-        bench = importlib.util.module_from_spec(spec)
-        sys.modules.setdefault("bench_module_dtype", bench)
-        spec.loader.exec_module(bench)
-        monkeypatch.setattr(st, "KERNEL_DTYPE", "f32")
-        monkeypatch.setenv("PHOTON_KERNEL_DTYPE", "int8")
-        bench._apply_retune_env()
-        assert st.KERNEL_DTYPE == "int8"
-        assert st.kernel_dtype() == "int8"
-        monkeypatch.setenv("PHOTON_KERNEL_DTYPE", "f16")
-        with pytest.raises(ValueError, match="f32, int8"):
-            bench._apply_retune_env()
-
 
 class TestTransferPacking:
     """Raw (un-tiled) streamed chunks pack their feature arrays at the
@@ -529,8 +505,7 @@ SLAB_ROWS = 1024  # SLAB-sized row count for the int8 exactness test
 class TestLadderQualityGates:
     """Small GLM fits to convergence on the reduced rung: AUC/loss deltas
     against the f32 anchor stay within the tolerances documented in
-    README's precision-ladder section (the same gate the bench's
-    quality_parity block enforces at benchmark shapes)."""
+    README's precision-ladder section."""
 
     def _fit(self, rng_seed=17):
         from photon_ml_tpu.evaluation.evaluators import auc_roc

@@ -4,15 +4,16 @@ One seeded-violation fixture per pass (bad parse, missing static key,
 unlocked cache mutation, swallowed except, dangling telemetry consumer),
 a clean fixture asserting zero false positives, a suppression-file
 round-trip, and the tier-1 drift tests: the checker runs over THIS
-installed package (so knob/telemetry drift fails the suite, not just
-``scripts/gate_quick.sh``), and a knob injected into a copy of the real
-``bench.py`` RETUNE_ENV without registry wiring is demonstrably caught.
+installed package, so knob/telemetry drift fails the suite, and drift
+seeded into copies of the real modules is demonstrably caught. The
+registry's runtime twins read the knobs through their real accessors.
 
-All host-side stdlib-ast work — no jax tracing, no markers.
+No jax tracing, no markers.
 """
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import textwrap
@@ -27,7 +28,7 @@ from photon_ml_tpu.analysis.core import (
     Project, apply_waivers, load_baseline, write_baseline,
 )
 from photon_ml_tpu.analysis.registry import (
-    KNOBS, Knob, check_retune_tables, render_knob_table,
+    KNOBS, Knob, render_knob_table,
 )
 from photon_ml_tpu.analysis.runner import discover_root, lint
 
@@ -49,12 +50,12 @@ MINI_REGISTRY = (
         name="PHOTON_TEST_INT", kind="int", parse="strict_int",
         default="0", owner="pkg/mod.py", doc="test int knob",
         accessors=("test_int_knob",), retune_global="TEST_INT",
-        exempt=(("retune", "test"), ("sink", "test")),
+        exempt=(("sink", "test"),),
     ),
     Knob(
         name="PHOTON_TEST_PATH", kind="path", parse="raw",
         default="unset", owner="pkg/mod.py", doc="test path knob",
-        exempt=(("retune", "test"), ("sink", "test")),
+        exempt=(("sink", "test"),),
     ),
 )
 
@@ -108,31 +109,6 @@ class TestKnobPass:
         )
         assert fs == []
 
-    def test_retune_table_drift_both_directions(self, tmp_path):
-        registry = MINI_REGISTRY + (Knob(
-            name="PHOTON_TEST_SWEPT", kind="int", parse="strict_int",
-            default="1", owner="pkg/mod.py", doc="swept knob",
-            retune_global="TEST_SWEPT", retune_table="RETUNE_ENV",
-            exempt=(("sink", "test"),),
-        ),)
-        _write(tmp_path, "bench.py", """
-            RETUNE_ENV = {
-                "PHOTON_NOT_IN_REGISTRY": "NOT_IN_REGISTRY",
-            }
-        """)
-        fs = knobs_pass.check_surfaces(
-            _project(tmp_path), registry=registry
-        )
-        codes = sorted(f.code for f in fs)
-        assert codes == [
-            "knob-retune-missing", "knob-retune-unregistered",
-        ]
-        by_code = {f.code: f for f in fs}
-        assert by_code["knob-retune-missing"].scope == "PHOTON_TEST_SWEPT"
-        assert by_code["knob-retune-unregistered"].scope == \
-            "PHOTON_NOT_IN_REGISTRY"
-
-
 # -- pass 2: jit cache keys --------------------------------------------------
 
 
@@ -157,8 +133,8 @@ class TestJitKeysPass:
 
             @partial(jax.jit, static_argnames=("n",))
             def f(x, n):
-                k = os.environ.get("PHOTON_GROUPS_PER_RUN")
-                return x + GROUPS_PER_RUN
+                k = os.environ.get("PHOTON_PREFETCH_DEPTH")
+                return x + PREFETCH_DEPTH
 
             def g(x):
                 return x
@@ -348,6 +324,29 @@ class TestTelemetryPass:
         assert [f.scope for f in fs] == ["event:orphan_event"]
         assert fs[0].code == "telem-unrendered-emission"
 
+    def test_family_reader_counts_as_rendered(self, tmp_path):
+        """The report reads a gauge family by stripping its prefix and
+        spelling only the rest; a member nobody spells is still caught."""
+        project = self._tree(
+            tmp_path,
+            report_body="""
+                def summarize(gauges):
+                    fam = {k[len("shard."):]: v for k, v in gauges.items()
+                           if k.startswith("shard.")}
+                    rows = [fam.get(f"rows.{d}") for d in range(2)]
+                    return fam.get("balance"), fam.get("devices"), rows
+            """,
+            emitter_body="""
+                def run(prefix, d):
+                    REGISTRY.gauge_set("shard.balance", 1.0)
+                    REGISTRY.gauge_set(f"{prefix}.devices", 2.0)
+                    REGISTRY.gauge_set(f"{prefix}.rows.{d}", 3.0)
+                    REGISTRY.gauge_set("shard.orphan", 4.0)
+            """,
+        )
+        fs = telemetry_pass.run(project)
+        assert [f.scope for f in fs] == ["metric:shard.orphan"]
+
     def test_agreeing_surfaces_are_clean(self, tmp_path):
         project = self._tree(
             tmp_path,
@@ -451,7 +450,6 @@ class TestCli:
 class TestRegistry:
     def test_every_knob_requires_or_exempts_each_surface(self):
         for k in KNOBS:
-            assert k.retune_table or k.exempt_reason("retune"), k.name
             assert k.sink_key or k.exempt_reason("sink"), k.name
 
     def test_render_knob_table_covers_registry(self):
@@ -459,11 +457,18 @@ class TestRegistry:
         for k in KNOBS:
             assert f"`{k.name}`" in table, k.name
 
-    def test_46_knobs_none_for_a_segment_schedule_and_the_readme_has_them(self):
-        """The tile-COO kernel has one schedule (PR 33): no knob, retune
-        global or snapshot key selects one, and the README's table is the
-        registry's, row for row."""
-        assert len(KNOBS) == 46
+    def test_43_knobs_none_for_a_segment_schedule_and_the_readme_has_them(self):
+        """The tile-COO kernel has one schedule (PR 33): no knob, module
+        global or snapshot key selects one; its tile geometry constants
+        are edited in ``ops/sparse_tiled``, not set through the
+        environment; and the README's table is the registry's, row for
+        row."""
+        assert len(KNOBS) == 43
+        names = {k.name for k in KNOBS}
+        assert not names & {
+            "PHOTON_GROUPS_PER_STEP", "PHOTON_SEGMENTS_PER_DMA",
+            "PHOTON_GROUPS_PER_RUN",
+        }
         for k in KNOBS:
             for text in (k.name, k.retune_global, k.sink_key, k.doc):
                 assert "pipeline_segments" not in (text or "").lower(), k.name
@@ -473,30 +478,50 @@ class TestRegistry:
         with open(readme) as f:
             assert render_knob_table() in f.read()
 
-    def test_check_retune_tables_raises_on_drift(self):
-        good = {
-            t: {k.name: k.retune_global for k in KNOBS
-                if k.retune_table == t}
-            for t in ("RETUNE_ENV", "RETUNE_ENV_PREFETCH",
-                      "RETUNE_ENV_RE", "RETUNE_ENV_SHARD")
+    def test_sink_snapshot_reports_exactly_the_registered_keys(self):
+        """Runtime twin of the static sink-surface check: every
+        ``_knob_snapshot`` block swallows its own errors, so a key whose
+        accessor fails at run time would vanish silently from every
+        run_start record."""
+        from photon_ml_tpu.obs.sink import _knob_snapshot
+
+        assert set(_knob_snapshot()) == {
+            k.sink_key for k in KNOBS if k.sink_key
         }
-        check_retune_tables(good)  # the committed wiring passes
-        with pytest.raises(ValueError, match="PHOTON_SURPRISE"):
-            bad = {k: dict(v) for k, v in good.items()}
-            bad["RETUNE_ENV"]["PHOTON_SURPRISE"] = "SURPRISE"
-            check_retune_tables(bad)
-        with pytest.raises(ValueError, match="PHOTON_KERNEL_DTYPE"):
-            bad = {k: dict(v) for k, v in good.items()}
-            del bad["RETUNE_ENV"]["PHOTON_KERNEL_DTYPE"]
-            check_retune_tables(bad)
+
+    @pytest.mark.parametrize(
+        "knob",
+        [
+            k for k in KNOBS
+            if k.parse in ("strict_int", "strict_float", "enum")
+            and k.accessors
+        ],
+        ids=lambda k: k.name,
+    )
+    def test_malformed_env_value_is_refused_by_its_accessor(
+        self, monkeypatch, knob
+    ):
+        """Runtime side of the strict-parse idiom the knob pass checks
+        statically: an environment value reaches the knob's accessor at
+        call time, and a malformed one raises instead of falling back to
+        the default."""
+        bad = {"strict_int": "x1", "strict_float": "abc"}.get(
+            knob.parse, "no-such-mode"
+        )
+        module = importlib.import_module(
+            knob.owner[:-len(".py")].replace("/", ".")
+        )
+        monkeypatch.setenv(knob.name, bad)
+        with pytest.raises(ValueError):
+            getattr(module, knob.accessors[0])()
 
 
 # -- tier-1 drift gates over the INSTALLED package ---------------------------
 
 
 class TestRepoDrift:
-    """The acceptance tests: the real repo lints clean, and seeded drift
-    in the real bench.py is caught."""
+    """The acceptance tests: the real repo lints clean, and drift seeded
+    into copies of its real modules is caught."""
 
     def test_repo_lints_clean(self):
         root = discover_root(os.path.dirname(__file__))
@@ -512,32 +537,8 @@ class TestRepoDrift:
         )
         assert doc["exit"] == 0
 
-    def test_knob_added_to_bench_without_wiring_is_caught(self, tmp_path):
-        # the ISSUE-15 acceptance demo: inject an unwired knob into a
-        # copy of the REAL bench RETUNE_ENV; the knob pass must convict
-        root = discover_root(os.path.dirname(__file__))
-        with open(os.path.join(root, "bench.py"), encoding="utf-8") as f:
-            src = f.read()
-        marker = "RETUNE_ENV = {"
-        assert marker in src
-        src = src.replace(
-            marker,
-            marker + '\n    "PHOTON_TOTALLY_NEW_KNOB": "TOTALLY_NEW",',
-            1,
-        )
-        bench_copy = tmp_path / "bench_drifted.py"
-        bench_copy.write_text(src)
-        project = Project(root=root, bench_path=str(bench_copy))
-        fs = knobs_pass.run(project)
-        drift = [
-            f for f in fs
-            if f.code == "knob-retune-unregistered"
-            and f.scope == "PHOTON_TOTALLY_NEW_KNOB"
-        ]
-        assert drift, "injected RETUNE_ENV knob was not caught"
-
     def test_stale_jit_key_seeded_into_real_kernel_is_caught(self):
-        # move a retune-global read INSIDE the real jitted kernel entry
+        # move a knob-global read INSIDE the real jitted kernel entry
         # (the PR-2 stale-executable shape) and assert conviction
         from photon_ml_tpu.analysis.core import ModuleInfo
 

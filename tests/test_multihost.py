@@ -3077,8 +3077,8 @@ def test_owner_segment_combine_bitwise_and_cheaper():
         # the whole point: strictly fewer combine bytes on the wire.
         # Fleet AGGREGATE at this toy E (the framed codec's fixed
         # header ≈ 400 B rivals a near-full owner's dense payload at
-        # E=24); the per-process reduction at real shapes is asserted
-        # by the MULTICHIP_r08 capture (74.9% mean at 4 shards)
+        # E=24); only the direction is asserted here, not the
+        # per-process reduction at real shapes
         seg_total = sum(r["segments"]["bytes"] for r in got.values())
         allred_total = sum(r["allreduce"]["bytes"] for r in got.values())
         assert 0 < seg_total < allred_total, (nproc, seg_total, allred_total)

@@ -595,48 +595,6 @@ class TestReportSurface:
 
 
 class TestBenchKnobParse:
-    def test_retune_env_maps_carry_new_knobs(self):
-        import bench
-
-        assert bench.RETUNE_ENV_RE["PHOTON_RE_COMBINE"] == "RE_COMBINE"
-        assert (
-            bench.RETUNE_ENV_SHARD["PHOTON_RE_REPLAN_IMBALANCE"]
-            == "REPLAN_IMBALANCE"
-        )
-
-    def test_apply_retune_env_parses_string_and_float(self, monkeypatch):
-        import bench
-        from photon_ml_tpu.game import random_effect as re_mod
-        from photon_ml_tpu.parallel import placement
-
-        monkeypatch.setenv("PHOTON_RE_COMBINE", "segments")
-        monkeypatch.setenv("PHOTON_RE_REPLAN_IMBALANCE", "1.25")
-        monkeypatch.setattr(re_mod, "RE_COMBINE", "allreduce")
-        monkeypatch.setattr(placement, "REPLAN_IMBALANCE", 0.0)
-        bench._apply_retune_env()
-        assert re_mod.RE_COMBINE == "segments"
-        assert placement.REPLAN_IMBALANCE == 1.25
-
-    def test_apply_retune_env_rejects_bad_mode(self, monkeypatch):
-        import bench
-
-        monkeypatch.setenv("PHOTON_RE_COMBINE", "broadcast")
-        with pytest.raises(ValueError, match="PHOTON_RE_COMBINE"):
-            bench._apply_retune_env()
-
-    def test_r08_sizes_are_zipf_with_real_entity_count(self):
-        import bench
-
-        sizes = bench._multichip_r08_sizes(1024)
-        assert len(sizes) == 1024
-        assert sizes.min() >= 1 and sizes[0] > sizes[-1]
-        # Zipf(~1): roughly constant row mass per capacity octave —
-        # the property that makes the bucket ladder's classes (the
-        # placement atoms) carry comparable loads
-        head = sizes[sizes >= 64].sum()
-        tail = sizes[sizes < 4].sum()
-        assert head > 0 and tail > 0
-
     def test_knob_snapshot_carries_combine_and_replan(self, monkeypatch):
         from photon_ml_tpu.obs.sink import _knob_snapshot
 
